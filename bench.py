@@ -1,20 +1,20 @@
 #!/usr/bin/env python
-"""Background-scan throughput benchmark on the reference policy packs.
+"""Background-scan throughput benchmark on the committed policy pack.
 
 Measures the north-star workload (BASELINE.md): background-scan of
-synthetic Pods against the reference's real policy packs —
-``test/best_practices`` plus the rendered ``charts/kyverno-policies``
-baseline+restricted profiles — reporting absolute decisions/sec on the
-available accelerator and the ratio vs the pure-host Python engine.
+synthetic Pods against the pack this repo commits (``load_policy_pack``:
+Pod Security Standards baseline + restricted, ``PACK`` and
+``CONFIG4_PACK``) — reporting absolute decisions/sec on the available
+accelerator and the ratio vs the pure-host Python engine.
 
 Prints ONE JSON line:
   {"metric": ..., "value": N, "unit": "decisions/s", "vs_baseline": N}
 vs_baseline is measured against the BASELINE.json north star of 50k
 decisions/s on a v5e-4 slice -> 12.5k/s per chip.
 
-The TPU backend is probed in a subprocess first (backend init failures
-are sticky in-process); on failure the bench still runs on CPU and the
-JSON line records the platform, so a number always exists.
+The default backend is probed in a subprocess first (backend init
+failures are sticky in-process).  A run that finds no backend fails;
+``BENCH_PLATFORM=cpu`` is the explicit CPU choice.
 """
 
 from __future__ import annotations
@@ -47,7 +47,42 @@ def _progress(msg: str) -> None:
 
 PER_CHIP_TARGET = 50_000 / 4  # north star: 50k/s on v5e-4
 
-# kept for __graft_entry__: a small self-contained pack + pod generator
+# BASELINE.json config 3: Pod Security Standards at both levels.  The
+# default autogen adds the Deployment-family and CronJob rules, so these
+# two policies are six rule programs (compiler/pss_compile.py).
+PSS_PACK = """
+apiVersion: kyverno.io/v1
+kind: ClusterPolicy
+metadata:
+  name: podsecurity-baseline
+spec:
+  background: true
+  validationFailureAction: Audit
+  rules:
+    - name: baseline
+      match: {any: [{resources: {kinds: [Pod]}}]}
+      validate:
+        podSecurity:
+          level: baseline
+          version: latest
+---
+apiVersion: kyverno.io/v1
+kind: ClusterPolicy
+metadata:
+  name: podsecurity-restricted
+spec:
+  background: true
+  validationFailureAction: Audit
+  rules:
+    - name: restricted
+      match: {any: [{resources: {kinds: [Pod]}}]}
+      validate:
+        podSecurity:
+          level: restricted
+          version: latest
+"""
+
+# a small self-contained pack + pod generator
 PACK = """
 apiVersion: kyverno.io/v1
 kind: ClusterPolicy
@@ -627,46 +662,33 @@ def run_config5(n: int, platform: str) -> dict:
 
 def probe_platform() -> str:
     """Probe the default JAX backend in a subprocess (init failures are
-    sticky in-process); returns the platform to use."""
-    env = dict(os.environ)
+    sticky in-process); returns its platform.  The child exits, and
+    lets go of the chip, before this process touches JAX.  No backend
+    is an error, never a CPU run: BENCH_PLATFORM=cpu asks for that."""
     code = 'import jax; print(jax.default_backend())'
+    tail = ''
     for attempt in range(2):
         try:
-            out = subprocess.run([sys.executable, '-c', code], env=env,
+            out = subprocess.run([sys.executable, '-c', code],
+                                 env=dict(os.environ),
                                  capture_output=True, text=True, timeout=180)
             if out.returncode == 0 and out.stdout.strip():
                 return out.stdout.strip().splitlines()[-1]
+            tail = out.stderr.strip()[-400:]
         except subprocess.TimeoutExpired:
-            pass
+            tail = 'probe timed out after 180s'
         time.sleep(3)
-    return 'cpu'
+    raise RuntimeError(f'no JAX backend came up: {tail}')
 
 
 def load_policy_pack():
-    import glob
-    import yaml
-    from kyverno_tpu.api.policy import Policy
-    docs = []
-    for f in sorted(glob.glob('/root/reference/test/best_practices/*.yaml')):
-        for d in yaml.safe_load_all(open(f)):
-            if d and d.get('kind') in ('ClusterPolicy', 'Policy'):
-                docs.append(d)
-    try:
-        from kyverno_tpu.utils.helmlite import load_chart_policies
-        docs += load_chart_policies(
-            '/root/reference/charts/kyverno-policies',
-            profiles=('baseline', 'restricted'))
-    except Exception as e:  # noqa: BLE001 - charts are additive
-        print(f'chart load failed: {e}', file=sys.stderr)
-    if not docs:
-        # hermetic container without the reference checkout: the
-        # embedded two-policy pack keeps every bench mode runnable
-        # (the JSON line's n_policies records the degraded scale)
-        import yaml as _yaml
-        docs = [d for d in _yaml.safe_load_all(PACK) if d]
-        print('reference packs missing; using the embedded PACK',
-              file=sys.stderr)
-    return [Policy(d) for d in docs]
+    """The committed pack: Pod Security Standards baseline + restricted
+    (BASELINE.json config 3), ``PACK`` and ``CONFIG4_PACK`` — 11
+    policies, 15 rule programs, all compiled for the device.  Nothing
+    outside the repo is read."""
+    from kyverno_tpu.api.policy import load_policies_from_yaml
+    return [p for pack in (PSS_PACK, PACK, CONFIG4_PACK)
+            for p in load_policies_from_yaml(pack)]
 
 
 def cache_probe(platform: str) -> float:
@@ -1173,17 +1195,26 @@ def run_bench(n: int, platform: str, budget_s: float) -> dict:
         except Exception as e:  # noqa: BLE001 - block is additive
             rescan_block = {'error': f'{type(e).__name__}: {e}'}
 
+    # the two fresh-process probes start children that need the
+    # backend.  Off the CPU this process holds the chip and cannot lend
+    # it: they are skipped (chip_smoke.py run twice is the warm reading
+    # there; bench.py --warm-probe runs the second one on its own)
+    children_ok = platform == 'cpu'
+
     # fresh-process warm time with the persistent compilation cache
     _progress('fresh-process cache probe')
     cache_warm_s = cache_probe(platform) \
-        if os.environ.get('BENCH_CACHE_PROBE', '1') == '1' else -1.0
+        if children_ok and os.environ.get('BENCH_CACHE_PROBE', '1') == '1' \
+        else -1.0
 
     # fresh-process warm block: time-to-first-decision + the executable
     # census across the boundary row counts, ratcheted at
     # WARM_EXECUTABLES_MAX (a regrown bucket zoo fails the bench)
     _progress('fresh-process warm probe')
-    warm_block = warm_probe(platform) \
-        if os.environ.get('BENCH_WARM_PROBE', '1') == '1' else None
+    warm_block = None
+    if os.environ.get('BENCH_WARM_PROBE', '1') == '1':
+        warm_block = warm_probe(platform) if children_ok else \
+            {'skipped': 'this process holds the chip'}
 
     # executable census over the whole run (this process only — the
     # warm/cache probes above run their own fresh processes)
@@ -1244,18 +1275,11 @@ def run_bench(n: int, platform: str, budget_s: float) -> dict:
     return result
 
 
-def _admission_server(policies, resources, target_policies=1000):
-    """Replicated-enforce serving chain shared by the admission latency
-    and concurrency benches (one ~1k-policy scanner compile serves
-    both).  Returns ``(server, handlers, n_replicated, device_served)``;
-    the device-path build wait is bounded (BENCH_ADMISSION_WAIT_S) so
-    the bench always finishes."""
+def replicate_enforce(policies, target_policies=1000):
+    """``policies`` copied round after round under new names, every copy
+    in Enforce mode, until there are ``target_policies`` of them."""
     import copy
-    from kyverno_tpu.policycache.cache import Cache
     from kyverno_tpu.api.policy import Policy
-    from kyverno_tpu.webhooks.handlers import ResourceHandlers
-    from kyverno_tpu.webhooks.server import WebhookServer
-
     if not policies:
         raise ValueError('empty policy pack: nothing to replicate')
     replicated = []
@@ -1269,6 +1293,20 @@ def _admission_server(policies, resources, target_policies=1000):
             if len(replicated) >= target_policies:
                 break
         i += 1
+    return replicated
+
+
+def _admission_server(policies, resources, target_policies=1000):
+    """Replicated-enforce serving chain shared by the admission latency
+    and concurrency benches (one ~1k-policy scanner compile serves
+    both).  Returns ``(server, handlers, n_replicated, device_served)``;
+    the device-path build wait is bounded (BENCH_ADMISSION_WAIT_S) so
+    the bench always finishes."""
+    from kyverno_tpu.policycache.cache import Cache
+    from kyverno_tpu.webhooks.handlers import ResourceHandlers
+    from kyverno_tpu.webhooks.server import WebhookServer
+
+    replicated = replicate_enforce(policies, target_policies)
     cache = Cache()
     cache.warm_up(replicated)
     handlers = ResourceHandlers(cache)
@@ -2312,6 +2350,42 @@ def load_mutate_pack():
     return [Policy(d) for d in yaml.safe_load_all(MUTATE_PACK) if d]
 
 
+def check_mutate_row(engine, policies, pod: dict, row, what: str) -> None:
+    """Hold one MutateScanner row ``(steps, patched)`` to the host
+    engine's cumulative mutate chain over ``pod``: the patched document
+    and every rule response, byte for byte."""
+    import json as _json
+    from kyverno_tpu.engine.api import PolicyContext
+    pctx = PolicyContext(None, new_resource=_json.loads(_json.dumps(pod)))
+    host = []
+    for pol in policies:
+        ctx = pctx.copy()
+        ctx.policy = pol
+        er = engine.mutate(ctx)
+        host.append((pol.name, er))
+        if not er.is_successful():
+            break
+        pctx = pctx.copy()
+        pctx.new_resource = er.patched_resource or pctx.new_resource
+        pctx.json_context.add_resource(pctx.new_resource)
+    steps, patched = row
+    if _json.dumps(patched, sort_keys=True) != \
+            _json.dumps(pctx.new_resource, sort_keys=True):
+        raise AssertionError(f'{what}: patched doc diverged from the '
+                             f'host oracle')
+    if len(steps) != len(host):
+        raise AssertionError(f'{what}: {len(steps)} policy steps, the '
+                             f'host chain has {len(host)}')
+    for (hname, her), (_dpol, der) in zip(host, steps):
+        hcells = [(r.name, str(r.status), r.message, r.patches)
+                  for r in her.policy_response.rules]
+        dcells = [(r.name, str(r.status), r.message, r.patches)
+                  for r in der.policy_response.rules]
+        if hcells != dcells:
+            raise AssertionError(f'{what} policy {hname}: device cells '
+                                 f'diverged from the host oracle')
+
+
 def run_mutate_bench(n: int, platform: str) -> dict:
     """``bench.py --mutate-pack``: the device-side mutate ratchet.
 
@@ -2347,33 +2421,7 @@ def run_mutate_bench(n: int, platform: str) -> dict:
     engine = Engine()
     sample = rng.sample(range(n), min(64, n))
     for i in sample:
-        pctx = PolicyContext(None, new_resource=_json.loads(
-            _json.dumps(pods[i])))
-        host = []
-        for pol in policies:
-            ctx = pctx.copy()
-            ctx.policy = pol
-            er = engine.mutate(ctx)
-            host.append((pol.name, er))
-            if not er.is_successful():
-                break
-            pctx = pctx.copy()
-            pctx.new_resource = er.patched_resource or pctx.new_resource
-            pctx.json_context.add_resource(pctx.new_resource)
-        steps, patched = rows[i]
-        if _json.dumps(patched, sort_keys=True) != \
-                _json.dumps(pctx.new_resource, sort_keys=True):
-            raise AssertionError(f'row {i}: patched doc diverged from '
-                                 f'the host oracle')
-        for (hname, her), (dpol, der) in zip(host, steps):
-            hcells = [(r.name, str(r.status), r.message, r.patches)
-                      for r in her.policy_response.rules]
-            dcells = [(r.name, str(r.status), r.message, r.patches)
-                      for r in der.policy_response.rules]
-            if hcells != dcells:
-                raise AssertionError(
-                    f'row {i} policy {hname}: device cells diverged '
-                    f'from the host oracle')
+        check_mutate_row(engine, policies, pods[i], rows[i], f'row {i}')
     _progress(f'mutate oracle: {len(sample)} rows byte-identical')
 
     # concurrent /mutate webhook drive: batch serving must coalesce
@@ -2581,8 +2629,10 @@ MULTICHIP_OUT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 def _fleet_child(path: str, rows: int) -> None:
     """One federation 'host': run a small mesh workload under its own
     fleet registry and leave a JSONL snapshot behind.  Top-level so
-    multiprocessing spawn can import it from a fresh interpreter."""
-    os.environ.setdefault('JAX_PLATFORMS', 'cpu')
+    multiprocessing spawn can import it from a fresh interpreter.
+    Always on the CPU backend: the round trip is about merging metrics,
+    and the parent may hold the chip."""
+    os.environ['JAX_PLATFORMS'] = 'cpu'
     import random
     from kyverno_tpu.api.policy import load_policies_from_yaml
     from kyverno_tpu.compiler.compile import compile_policies
@@ -2667,14 +2717,10 @@ def multichip_main() -> int:
     pods = [make_pod(rng, i) for i in range(MULTICHIP_ROWS)]
     # each mesh size is its own compile, so the default pack is the
     # small self-contained one; BENCH_MULTICHIP_PACK=full opts into the
-    # reference pack (minutes of compile across the device sweep)
-    policies = []
+    # whole committed pack
     if os.environ.get('BENCH_MULTICHIP_PACK', '') == 'full':
-        try:
-            policies = load_policy_pack()
-        except Exception:  # noqa: BLE001 - reference tree may be absent
-            policies = []
-    if not policies:
+        policies = load_policy_pack()
+    else:
         from kyverno_tpu.api.policy import load_policies_from_yaml
         policies = load_policies_from_yaml(PACK)
     from kyverno_tpu.compiler.compile import compile_policies

@@ -37,9 +37,9 @@ KNOBS: Dict[str, Knob] = dict([
        'Rows per device chunk (the bulk canonical batch capacity); '
        'large scans stream fixed-size chunks.'),
     _k('KTPU_SMALL_BATCH', '64', 'int', 'compiler/scan.py',
-       'Batches at or below this size run on the host-local CPU backend '
-       'instead of paying a remote-accelerator round trip; also the '
-       'small canonical batch capacity (compiler/shapes.py).'),
+       'The small canonical batch capacity (compiler/shapes.py): '
+       'batches at or below this size pad to it — the admission shape. '
+       'It runs on the default device like every other batch.'),
     _k('KTPU_CANONICAL_CAPS', '{KTPU_SMALL_BATCH, KTPU_SCAN_CHUNK}',
        'str', 'compiler/shapes.py',
        'Comma-separated canonical batch capacities (full override of '
@@ -49,7 +49,8 @@ KNOBS: Dict[str, Knob] = dict([
        'each extra entry is one more executable to warm.'),
     _k('KTPU_ENCODE_PROCS', '2 (0 on single-CPU hosts)', 'int',
        'compiler/scan.py',
-       'Forked encoder worker processes; 0 encodes in-process.'),
+       'Encoder worker processes (from a fork server); 0 encodes '
+       'in-process.'),
     _k('KTPU_PIPELINE_DEPTH', '2', 'int', 'compiler/pipeline.py',
        'In-flight chunk budget of the streaming scan pipeline '
        '(encode → h2d → device_eval → d2h legs on worker threads). '
@@ -140,10 +141,6 @@ KNOBS: Dict[str, Knob] = dict([
        'Legacy alias for KTPU_AOT_CACHE_DIR (consulted second).'),
     _k('KTPU_AOT_CACHE_MAX', str(8 << 30), 'int', 'aotcache/store.py',
        'LRU size bound, in bytes, of the AOT executable store.'),
-    _k('KTPU_COMPILE_CACHE', '<repo>/.cache/xla-<scope>', 'path',
-       'aotcache/keys.py',
-       'XLA persistent compilation cache directory (scoped by host '
-       'CPU features and codegen-relevant env).'),
     _k('KTPU_WARM', '1', 'bool', 'aotcache/warmer.py',
        'Start the background warm-up thread that brings the '
        'admission-shape executable to serving readiness.'),

@@ -2,7 +2,7 @@
 
 A host sync inside a jitted region either crashes the trace
 (``TracerArrayConversionError``) or — worse — silently forces a
-device→host readback per call and caps the pipeline at PCIe/tunnel
+device→host readback per call and caps the pipeline at PCIe
 latency.  These passes flag the constructs on any function reachable
 from the ``jax.jit`` / ``pjit`` sites in the tree (``ops/eval.py``,
 ``parallel/mesh.py``, and whatever future modules grow jit entries).

@@ -35,6 +35,8 @@ import pickle
 import threading
 from typing import Any, Optional, Tuple
 
+import jax.monitoring
+
 from ..aotcache import keys as _keys
 from ..aotcache.keys import executable_cache_key  # noqa: F401 (re-export)
 from ..aotcache.store import AotStore, default_store
@@ -179,6 +181,31 @@ def load_executable(key: str, store: Optional[AotStore] = None) -> Any:
     except Exception:  # noqa: BLE001 - backend refused the artifact
         _reject(store, key, 'deserialize_failed')
         return None
+
+
+# -- what the persistent XLA cache handed back ---------------------------------
+
+_XLA_CACHE_HITS = 0
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    global _XLA_CACHE_HITS
+    if event == '/jax/compilation_cache/cache_hits':
+        _XLA_CACHE_HITS += 1
+
+
+jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def xla_cache_hits() -> int:
+    """Compiles this process has had answered by JAX's persistent
+    compilation cache so far (JAX's own monitoring event).  Read it
+    before and after a compile to learn where the executable came from:
+    one that the cache handed back does not survive being serialized a
+    second time on XLA:CPU under jax 0.9 — the copy loads, then fails
+    at execution with ``Function ... not found`` — so only a fresh
+    compile is stored (the XLA cache holds the other kind already)."""
+    return _XLA_CACHE_HITS
 
 
 #: in-flight background stores; flush_stores() joins them (tests, and

@@ -1330,3 +1330,46 @@ def _fill_elem_gather_column(rows: list, lanes: Lanes, meta, egwidth: int,
             (np.asarray(r_idx, np.intp), np.asarray(f_idx, np.intp),
              np.asarray(e_idx, np.intp)),
             vals, palette)
+
+
+# ---------------------------------------------------------------------------
+# The encoder worker process (compiler/scan.py _EncoderPool).  It lives in
+# this module because this module imports no jax: a worker, and the fork
+# server it comes from, import nothing else.
+
+_WORKER_CPS = None
+#: per-worker-process arena: keeps the columnar value palettes warm
+#: across the chunks one encoder worker serves (buffer pooling stays off
+#: in workers — tensors are pickled back after return, so a recycled
+#: buffer could be zeroed mid-serialization)
+_WORKER_PALETTES = None
+
+
+def encode_worker_init(cps) -> None:
+    global _WORKER_CPS
+    _WORKER_CPS = cps
+
+
+def encode_worker(args):
+    global _WORKER_PALETTES
+    import os
+    import time
+    docs, contexts, padded_n = args
+    if _WORKER_PALETTES is None:
+        _WORKER_PALETTES = LaneArena(max_pool=0)
+    # the worker's metric increments and contextvars die with the
+    # process — the pipeline threads re-install the scan's ScanCapture,
+    # and this is the process-side analogue: measure into a fresh local
+    # capture and ship the stage seconds (plus the wall interval, for
+    # the timeline) home with the tensors; the resolving pipeline
+    # thread re-attributes them via devtel.merge_worker_stages.
+    from ..observability import device as devtel
+    cap = devtel.ScanCapture()
+    t0 = time.monotonic()
+    with devtel.install_capture(cap):
+        batch = encode_batch(docs, _WORKER_CPS, padded_n=padded_n,
+                             contexts=contexts, arena=_WORKER_PALETTES)
+    t1 = time.monotonic()
+    cap.add('encode', t1 - t0)
+    return batch.tensors(), dict(cap.stages), (t0, t1, os.getpid())
+

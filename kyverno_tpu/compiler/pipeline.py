@@ -161,6 +161,8 @@ class ChunkPipeline:
                 if attempt <= self.retries and isinstance(e, Exception) \
                         and not self._stop.is_set():
                     t_r = time.monotonic()
+                    from ..observability import device as devtel
+                    devtel.record_stage_retry(name)
                     time.sleep(_RETRY_BACKOFF_S * (2.0 ** (attempt - 1)))
                     if self.timeline is not None:
                         self.timeline.retry(item.seq, name, t_r, attempt)

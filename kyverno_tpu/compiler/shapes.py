@@ -9,7 +9,7 @@ capacity table* — by default just ``{KTPU_SMALL_BATCH, KTPU_SCAN_CHUNK}``
 — so a policy set compiles at most two row shapes, ever:
 
 * batches at or below the small capacity pad to it (the admission
-  shape; runs on the host-local CPU backend);
+  shape);
 * everything else pads to the chunk capacity (the bulk-scan shape;
   multi-chunk scans stream it).
 

@@ -21,7 +21,7 @@ outputs:
                        encoded lanes
 
 The kernel is intentionally tiny (a few element-wise ops and one
-matmul-shaped reduction per output) — it is not AOT-persisted; XLA
+masked reduction per output) — it is not AOT-persisted; XLA
 compiles it once per canonical batch capacity (compiler/shapes.py).
 Rows past the live row count (the ``valid`` lane) are capacity
 padding: their statuses, edit bitmasks, and reasons are forced to
@@ -70,8 +70,8 @@ class MutateKernel:
         self._add_only = np.zeros(s, bool)
         self._replace = np.zeros(s, bool)
         # site → rule selector and the site's bit weight in its rule's
-        # edit mask; both feed the matmul-shaped per-rule reductions
-        self._onehot = np.zeros((s, self.n_rules), np.int64)
+        # edit mask; both feed the per-rule reductions
+        self._onehot = np.zeros((s, self.n_rules), bool)
         self._bit_w = np.zeros(s, np.int64)
         for idx, (ri, k, site) in enumerate(sites):
             v = site.value
@@ -87,7 +87,7 @@ class MutateKernel:
                 self._t_milli[idx] = 0 if m is None else m
             self._add_only[idx] = site.add_only
             self._replace[idx] = site.replace
-            self._onehot[idx, ri] = 1
+            self._onehot[idx, ri] = True
             self._bit_w[idx] = np.int64(1) << np.int64(k)
         self._jitted = None
 
@@ -117,10 +117,15 @@ class MutateKernel:
                                    present & ~eq))
         rep_bad = self._replace & ((istate != 0) | missing)
 
+        # per-rule reductions over the rule's own sites, as masked
+        # reduces: the TPU has no s64 matrix product (its 64-bit
+        # rewrite refuses an s64 dot), and [R, S, NR] is tiny
         def per_rule(flag):
-            return (flag.astype(jnp.int64) @ self._onehot) > 0
+            return jnp.any(flag[:, :, None] & self._onehot, axis=1)
 
-        edits = (edit.astype(jnp.int64) * self._bit_w) @ self._onehot
+        edits = jnp.sum(
+            jnp.where(self._onehot, (edit * self._bit_w)[:, :, None],
+                      jnp.int64(0)), axis=1)
         rep_any = per_rule(rep_bad)
         bad_any = per_rule(bad)
         undec_any = per_rule(undec)
@@ -153,10 +158,11 @@ class MutateKernel:
             return (np.zeros((n, self.n_rules), np.int8),
                     np.zeros((n, self.n_rules), np.int64),
                     np.zeros((n, self.n_rules), np.int8))
-        from ..ops.eval import enable_x64
-        with enable_x64():
+        import jax
+        with jax.enable_x64(True):
             if self._jitted is None:
-                import jax
+                from ..aotcache import enable_persistent_compilation_cache
+                enable_persistent_compilation_cache()
                 self._jitted = jax.jit(self._eval)
             out = self._jitted(lanes)
             return tuple(np.asarray(o) for o in out)

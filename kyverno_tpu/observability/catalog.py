@@ -69,6 +69,15 @@ METRICS: Dict[str, Metric] = {
         'counter', 'Time a scan-pipeline stage spent blocked on a full '
         'downstream queue (stage=intake|encode|h2d|device_eval|d2h) — '
         'which leg bounds the stream.'),
+    'kyverno_tpu_scan_stage_retries_total': Metric(
+        'counter', 'Scan-pipeline stage attempts that raised and were '
+        're-run on the same chunk (KTPU_STAGE_RETRIES), by stage.'),
+    'kyverno_tpu_encode_worker_chunks_total': Metric(
+        'counter', 'Encoder worker-pool outcomes; result=ok (a chunk a '
+        'worker encoded)|presumed_dead (no answer inside '
+        'KTPU_ENCODE_TIMEOUT)|pool_failed (pool would not start or take '
+        'a task). Anything but ok drops the scanner to in-process '
+        'encoding.'),
     # device-coverage ledger (observability/coverage.py)
     'kyverno_tpu_rule_placement_info': Metric(
         'gauge', '1 per compiled (policy, rule, path); placement=device|'

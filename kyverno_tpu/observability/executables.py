@@ -7,13 +7,14 @@ executables have been anonymous entries in the AOT store.  This module
 registers an :class:`ExecutableRecord` at every acquisition site in
 ``ops/eval.py``:
 
-* ``fresh_compile`` — a ``jitted.lower(packed).compile()`` miss (the
-  warm-up wall, measured per executable);
+* ``fresh_compile`` — XLA compiled it in this process (the warm-up
+  wall, measured per executable);
 * ``aot_load`` — deserialized from the AOT disk store
   (``compiler/aot.py``);
-* ``persistent_xla`` — the jit-fallback path (mesh-sharded inputs or
-  AOT disabled) whose first call compiles through ``jax.jit`` backed by
-  the persistent XLA compilation cache.
+* ``persistent_xla`` — traced here, but the backend compile was
+  answered by JAX's persistent compilation cache
+  (``compiler/aot.py`` ``xla_cache_hits``).  On an accelerator, where
+  no AOT key exists, every build is one of these two.
 
 Each record carries the policy-set fingerprint, the canonical row
 capacity, build/load duration, ``compiled.cost_analysis()`` flops and
@@ -90,13 +91,25 @@ def cost_analysis(compiled) -> Dict[str, float]:
     return out
 
 
+def output_platform(outputs) -> str:
+    """Platform of the device the first output array lives on ('' when
+    the dispatch returned nothing that says)."""
+    for arr in outputs or ():
+        try:
+            return next(iter(arr.devices())).platform
+        except Exception:  # noqa: BLE001 - diagnostics only
+            continue
+    return ''
+
+
 class ExecutableRecord:
     """One compiled program's lifecycle.  Mutated only under the
     ledger's lock (dispatch accounting, eviction marking)."""
 
     __slots__ = ('key', 'fingerprint', 'capacity', 'source', 'build_s',
                  'flops', 'bytes_accessed', 'dispatches', 'device_s',
-                 'created_ts', 'last_used_ts', 'evicted', 'evict_reason')
+                 'created_ts', 'last_used_ts', 'evicted', 'evict_reason',
+                 'platform')
 
     def __init__(self, key: str, fingerprint: str, capacity: int,
                  source: str, build_s: float, flops: float,
@@ -114,6 +127,9 @@ class ExecutableRecord:
         self.last_used_ts = ts
         self.evicted = False
         self.evict_reason = ''
+        #: where this program's outputs live, read off the first
+        #: dispatch that hands its output arrays to the ledger
+        self.platform = ''
 
     def to_dict(self) -> dict:
         out: Dict[str, Any] = {
@@ -127,6 +143,8 @@ class ExecutableRecord:
             'created_ts': self.created_ts,
             'last_used_ts': self.last_used_ts,
         }
+        if self.platform:
+            out['platform'] = self.platform
         if self.flops:
             out['flops'] = self.flops
         if self.bytes_accessed:
@@ -157,8 +175,10 @@ class ExecutableLedger:
     def record_build(self, key: str, fingerprint: str = '',
                      capacity: int = 0, source: str = 'fresh_compile',
                      build_s: float = 0.0,
-                     compiled: Any = None) -> ExecutableRecord:
+                     compiled: Any = None,
+                     outputs: Any = None) -> ExecutableRecord:
         costs = cost_analysis(compiled) if compiled is not None else {}
+        platform = output_platform(outputs)
         with self._lock:
             rec = self._records.pop(key, None)
             if rec is not None and not rec.evicted:
@@ -175,6 +195,8 @@ class ExecutableLedger:
                     flops=costs.get('flops', 0.0),
                     bytes_accessed=costs.get('bytes_accessed', 0.0),
                     ts=self.now())
+            if platform:
+                rec.platform = platform
             self._records[key] = rec
             while len(self._records) > self.maxlen:
                 self._records.popitem(last=False)
@@ -182,11 +204,14 @@ class ExecutableLedger:
         self._lifecycle_event('build', rec)
         return rec
 
-    def record_dispatch(self, key: str, device_s: float) -> None:
+    def record_dispatch(self, key: str, device_s: float,
+                        outputs: Any = None) -> None:
         with self._lock:
             rec = self._records.get(key)
             if rec is None:
                 return
+            if not rec.platform:
+                rec.platform = output_platform(outputs)
             rec.dispatches += 1
             rec.device_s += device_s
             rec.last_used_ts = self.now()
@@ -343,18 +368,20 @@ def enabled() -> bool:
 
 def record_build(key: str, fingerprint: str = '', capacity: int = 0,
                  source: str = 'fresh_compile', build_s: float = 0.0,
-                 compiled: Any = None) -> None:
+                 compiled: Any = None, outputs: Any = None) -> None:
     led = _ledger
     if led is not None:
         led.record_build(key, fingerprint=fingerprint,
                          capacity=capacity, source=source,
-                         build_s=build_s, compiled=compiled)
+                         build_s=build_s, compiled=compiled,
+                         outputs=outputs)
 
 
-def record_dispatch(key: str, device_s: float) -> None:
+def record_dispatch(key: str, device_s: float,
+                    outputs: Any = None) -> None:
     led = _ledger
     if led is not None:
-        led.record_dispatch(key, device_s)
+        led.record_dispatch(key, device_s, outputs)
 
 
 def record_eviction(key: str, reason: str) -> None:

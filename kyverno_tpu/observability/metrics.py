@@ -29,9 +29,9 @@ def _series_max() -> int:
     except ValueError:
         return 512
 
-#: compile/scan-scale buckets: fresh-cache policy-set compiles measure
-#: 43-49s (STATUS.md) — the default buckets top out at 10s and every
-#: compile sample would land in +Inf
+#: compile/scan-scale buckets: a fresh policy-set compile takes tens
+#: of seconds — the default buckets top out at 10s and every compile
+#: sample would land in +Inf
 WIDE_BUCKETS = (0.005, 0.025, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 20.0,
                 30.0, 60.0, 120.0)
 

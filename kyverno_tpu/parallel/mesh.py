@@ -47,7 +47,7 @@ def build_sharded_evaluator(cps: CompiledPolicySet, mesh: Mesh,
     """
     from ..aotcache import enable_persistent_compilation_cache
     from ..compiler.ir import N_STATUS_CODES
-    from ..ops.eval import build_evaluator, enable_x64, unpack_batch
+    from ..ops.eval import build_evaluator, unpack_batch
     # sharded executables embed the mesh's device assignment, so the
     # AOT executable store cannot persist them; the XLA persistent
     # compilation cache (keyed on the computation fingerprint) still
@@ -92,7 +92,7 @@ def build_sharded_evaluator(cps: CompiledPolicySet, mesh: Mesh,
         # bake this layout into the wrong executable
         with evaluator.compile_lock:
             evaluator.layout_holder['layout'] = layout
-            with enable_x64():
+            with jax.enable_x64(True):
                 if devtel.enabled():
                     sig = tuple((k, str(v.dtype), tuple(v.shape))
                                 for k, v in sorted(tensors.items()))
@@ -106,6 +106,8 @@ def build_sharded_evaluator(cps: CompiledPolicySet, mesh: Mesh,
                     devtel.record_cache('hit')
                 return jitted(tensors)
 
+    run.jitted = jitted
+    run.evaluator = evaluator
     return run
 
 

@@ -108,6 +108,7 @@ def debug_report() -> dict:
     return {
         'enabled': bool(regs),
         'breakers': [item for r in regs for item in r.report()],
+        'failures_total': sum(r.failures_total for r in regs),
     }
 
 
@@ -133,6 +134,10 @@ class BreakerRegistry:
         self.on_open = on_open
         self._entries: 'OrderedDict[tuple, _Entry]' = OrderedDict()
         self._lock = threading.Lock()
+        #: every device failure ever recorded here.  A success drops
+        #: the key's entry and its count with it, so this is the only
+        #: place a failure that the host loop absorbed stays readable
+        self.failures_total = 0
         _DEBUG.add(self)
 
     # -- internals (lock held) --------------------------------------------
@@ -244,6 +249,7 @@ class BreakerRegistry:
                 entry = _Entry(policies)
                 self._entries[key] = entry
             entry.failures += 1
+            self.failures_total += 1
             entry.last_error = str(error)[:200]
             if entry.state == HALF_OPEN:
                 # the probe failed: back to open, doubled backoff

@@ -292,21 +292,27 @@ print(json.dumps({
 '''
 
 
-def _run_fresh_process(cache_dir, aot_enabled=True, timeout=240):
+def _fresh_python(script, timeout=240, **extra_env):
+    """Run ``script`` in a new one-device CPU interpreter; its last
+    line of output is JSON."""
     env = {k: v for k, v in os.environ.items()
-           if k not in ('XLA_FLAGS', 'JAX_PLATFORMS')}
-    env.update({
-        'JAX_PLATFORMS': 'cpu',
-        'PYTHONPATH': REPO,
-        'KTPU_AOT': '1' if aot_enabled else '0',
-        'KTPU_AOT_CACHE_DIR': os.path.join(str(cache_dir), 'aot'),
-        'KTPU_COMPILE_CACHE': os.path.join(str(cache_dir), 'xla'),
-    })
-    out = subprocess.run([sys.executable, '-c', _SECOND_PROC_SCRIPT],
+           if k not in ('XLA_FLAGS', 'JAX_PLATFORMS',
+                        'JAX_COMPILATION_CACHE_DIR')}
+    env.update({'JAX_PLATFORMS': 'cpu', 'PYTHONPATH': REPO}, **extra_env)
+    out = subprocess.run([sys.executable, '-c', script],
                          env=env, cwd=REPO, capture_output=True,
                          text=True, timeout=timeout)
     assert out.returncode == 0, out.stderr[-4000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _run_fresh_process(cache_dir, aot_enabled=True, timeout=240,
+                       script=None, aot_dir='aot'):
+    return _fresh_python(
+        script or _SECOND_PROC_SCRIPT, timeout=timeout,
+        KTPU_AOT='1' if aot_enabled else '0',
+        KTPU_AOT_CACHE_DIR=os.path.join(str(cache_dir), aot_dir),
+        JAX_COMPILATION_CACHE_DIR=os.path.join(str(cache_dir), 'xla'))
 
 
 def test_second_process_zero_fresh_compiles(tmp_path):
@@ -324,3 +330,176 @@ def test_second_process_zero_fresh_compiles(tmp_path):
     assert uncached['miss'] >= 1, uncached
     for field in ('status', 'detail', 'match'):
         assert second[field] == first[field] == uncached[field], field
+
+
+# ---------------------------------------------------------------------------
+# where the XLA compile cache goes (ISSUE 22 step 6)
+
+_CACHE_DIR_SCRIPT = r'''
+import json, os
+import jax
+from kyverno_tpu.aotcache import keys
+used = keys.enable_persistent_compilation_cache()
+print(json.dumps({
+    'used': used,
+    'config': jax.config.jax_compilation_cache_dir,
+    'min_entry': jax.config.jax_persistent_cache_min_entry_size_bytes,
+    'min_secs': jax.config.jax_persistent_cache_min_compile_time_secs,
+}))
+'''
+
+
+def _cache_dir_process(**extra_env):
+    return _fresh_python(_CACHE_DIR_SCRIPT, timeout=120, **extra_env)
+
+
+class TestCompileCachePlacement:
+    def test_standard_variable_is_left_alone(self, tmp_path):
+        """With JAX_COMPILATION_CACHE_DIR set, no code path sets another
+        directory — not even the feature guard's re-scoped
+        sub-directory, though the marker there names another host."""
+        given = tmp_path / 'from-outside'
+        given.mkdir()
+        (given / aot_keys.HOSTKEY_FILE).write_text('feedface00')
+        got = _cache_dir_process(JAX_COMPILATION_CACHE_DIR=str(given))
+        assert got['used'] == got['config'] == str(given)
+        assert sorted(os.listdir(given)) == [aot_keys.HOSTKEY_FILE]
+        # the two thresholds are still the repo's
+        assert got['min_entry'] == -1 and got['min_secs'] == 0.5
+
+    def test_default_is_one_fixed_directory_per_platform(self):
+        """Unset, the directory is inside the checkout and does not
+        move with the flags: JAX's own cache key covers those."""
+        plain = _cache_dir_process()
+        flagged = _cache_dir_process(
+            XLA_FLAGS='--xla_force_host_platform_device_count=2',
+            JAX_ENABLE_X64='1')
+        assert plain['used'] == plain['config'] == flagged['used'] \
+            == flagged['config']
+        fixed = aot_keys.default_compile_cache_dir('cpu')
+        assert fixed == os.path.join(REPO, '.cache', 'xla-cpu')
+        # the feature guard may re-scope the CPU directory of a shared
+        # checkout, but only ever below the fixed one
+        assert plain['used'] == fixed or \
+            os.path.dirname(plain['used']) == fixed
+
+
+# ---------------------------------------------------------------------------
+# small batches go to the default device (ISSUE 22 step 5)
+
+def test_one_row_batch_asks_for_no_cpu_device(monkeypatch):
+    """With a non-CPU default backend the scanner used to place every
+    batch of 64 rows or fewer — all admission traffic — on the host's
+    XLA:CPU backend.  The routing is gone: a 1-row scan on a (stub)
+    accelerator backend never asks for a CPU device, and its tensors
+    sit on the default device."""
+    import inspect
+    import jax
+    from kyverno_tpu.api.policy import Policy
+    from kyverno_tpu.compiler import scan as scan_mod
+    from kyverno_tpu.ops import eval as eval_mod
+    assert not hasattr(scan_mod.BatchScanner, '_small_device')
+    assert 'device' not in inspect.signature(
+        eval_mod.shard_batch).parameters
+
+    policy = Policy({
+        'apiVersion': 'kyverno.io/v1', 'kind': 'ClusterPolicy',
+        'metadata': {'name': 'require-labels', 'annotations': {
+            'pod-policies.kyverno.io/autogen-controllers': 'none'}},
+        'spec': {'rules': [
+            {'name': 'check-app',
+             'match': {'any': [{'resources': {'kinds': ['Pod']}}]},
+             'validate': {'message': 'app label required',
+                          'pattern': {'metadata': {
+                              'labels': {'app': '?*'}}}}}]}})
+    pod = {'apiVersion': 'v1', 'kind': 'Pod',
+           'metadata': {'name': 'p', 'namespace': 'default'},
+           'spec': {'containers': [{'name': 'c', 'image': 'nginx:1'}]}}
+    scanner = scan_mod.BatchScanner([policy])
+
+    default = jax.devices()[0]
+    real_local_devices = jax.local_devices
+    scanner_asks = []
+
+    def local_devices(*args, **kwargs):
+        caller = sys._getframe(1).f_code.co_filename
+        if caller == scan_mod.__file__:
+            scanner_asks.append((args, kwargs))
+        return real_local_devices(*args, **kwargs)
+
+    placed = []
+    real_shard_batch = eval_mod.shard_batch
+
+    def shard_batch(tensors, *args, **kwargs):
+        packed, layout = real_shard_batch(tensors, *args, **kwargs)
+        # read now: the scanner frees its inputs after the readback
+        placed.append([(int(arr.shape[0]), arr.devices())
+                       for arr in packed.values()])
+        return packed, layout
+
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    monkeypatch.setattr(jax, 'local_devices', local_devices)
+    monkeypatch.setattr(eval_mod, 'shard_batch', shard_batch)
+    [responses] = scanner.scan([pod])
+    assert [r.status for er in responses
+            for r in er.policy_response.rules] == ['fail']
+    assert scanner_asks == []
+    assert placed, 'the 1-row batch never reached shard_batch'
+    for packed in placed:
+        for rows, devices in packed:
+            assert rows == scanner.SMALL_BATCH
+            assert devices == {default}
+
+
+# ---------------------------------------------------------------------------
+# an executable the XLA cache handed back is not stored a second time
+
+_XLA_THEN_AOT_SCRIPT = r'''
+import json, os, random, sys
+import bench
+from kyverno_tpu.api.policy import load_policies_from_yaml
+from kyverno_tpu.observability import device as devtel
+from kyverno_tpu.observability.metrics import MetricsRegistry
+reg = devtel.configure(MetricsRegistry())
+from kyverno_tpu.compiler.scan import BatchScanner
+scanner = BatchScanner(load_policies_from_yaml(bench.PACK))
+rng = random.Random(0)
+status, detail, match = scanner.scan_statuses(
+    [bench.make_pod(rng, i) for i in range(4)])
+from kyverno_tpu.compiler import aot
+aot.flush_stores()
+C = 'kyverno_tpu_compile_cache_requests_total'
+print(json.dumps({
+    'miss': reg.counter_value(C, result='miss'),
+    'aot_load': reg.counter_value(C, result='aot_load'),
+    'aot_store': reg.counter_value(C, result='aot_store'),
+    'xla_hits': aot.xla_cache_hits(),
+    'status': status.tolist(),
+}))
+'''
+
+
+def test_xla_cache_served_executable_is_not_stored_again(tmp_path):
+    """jax 0.9 on XLA:CPU: an executable that the persistent XLA cache
+    handed back serializes into a blob that loads and then fails at
+    execution (``Function ... not found``).  So a process that misses
+    the AOT store but hits the XLA cache stores nothing, and the
+    process after it still runs."""
+    def run(aot_dir):
+        return _run_fresh_process(tmp_path, script=_XLA_THEN_AOT_SCRIPT,
+                                  aot_dir=aot_dir)
+
+    first = run('aot-1')
+    assert first['miss'] == 1 and first['aot_store'] == 1, first
+    if not any(f.startswith('jit_evaluate_packed-')
+               for f in os.listdir(tmp_path / 'xla')):
+        pytest.skip('the compile was under the XLA cache\'s 0.5 s '
+                    'threshold on this machine: nothing to hand back')
+    # same XLA cache, an AOT store that has never seen this policy set
+    second = run('aot-2')
+    assert second['miss'] == 1 and second['xla_hits'] >= 1, second
+    assert second['aot_store'] == 0, second
+    assert not os.path.isdir(tmp_path / 'aot-2') or \
+        not os.listdir(tmp_path / 'aot-2')
+    third = run('aot-2')
+    assert third['status'] == second['status'] == first['status']

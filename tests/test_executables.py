@@ -224,7 +224,7 @@ def _run_probe(cache_dir, timeout=300):
         'KTPU_ENCODE_PROCS': '0',
         'KTPU_AOT': '1',
         'KTPU_AOT_CACHE_DIR': os.path.join(str(cache_dir), 'aot'),
-        'KTPU_COMPILE_CACHE': os.path.join(str(cache_dir), 'xla'),
+        'JAX_COMPILATION_CACHE_DIR': os.path.join(str(cache_dir), 'xla'),
     })
     out = subprocess.run([sys.executable, '-c', _PROBE_SCRIPT],
                          env=env, cwd=REPO, capture_output=True,

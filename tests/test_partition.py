@@ -500,6 +500,7 @@ print(json.dumps({
     'miss': reg.counter_value(C, result='miss'),
     'aot_load': reg.counter_value(C, result='aot_load'),
     'aot_store': reg.counter_value(C, result='aot_store'),
+    'xla_hits': aot.xla_cache_hits(),
     'status': status.tolist(),
     'detail': detail.tolist(),
     'match': match.tolist(),
@@ -515,7 +516,7 @@ def _run_partitioned_process(cache_dir, churn_index=None, timeout=300):
         'PYTHONPATH': REPO,
         'KTPU_AOT': '1',
         'KTPU_AOT_CACHE_DIR': os.path.join(str(cache_dir), 'aot'),
-        'KTPU_COMPILE_CACHE': os.path.join(str(cache_dir), 'xla'),
+        'JAX_COMPILATION_CACHE_DIR': os.path.join(str(cache_dir), 'xla'),
         'KTPU_PARTITIONS': '5',
     })
     if churn_index is not None:
@@ -528,23 +529,30 @@ def _run_partitioned_process(cache_dir, churn_index=None, timeout=300):
 
 
 def test_incremental_warm_recompiles_only_touched_partition(tmp_path):
+    # a miss is kept by exactly one cache: the AOT store takes what XLA
+    # compiled here; what the persistent XLA cache handed back (two
+    # partitions can lower to one HLO, and an edited message changes
+    # the key but not the program) stays there and is not stored again
     first = _run_partitioned_process(tmp_path)
     assert first['touched'] == []
     assert first['miss'] == first['n_partitions']
-    assert first['aot_store'] == first['n_partitions']
+    assert first['aot_store'] + first['xla_hits'] == first['n_partitions']
     assert first['aot_load'] == 0
 
     second = _run_partitioned_process(tmp_path)
-    assert second['miss'] == 0
-    assert second['aot_load'] == second['n_partitions']
+    assert second['miss'] == first['xla_hits']
+    assert second['aot_load'] == first['aot_store']
 
     churn = _run_partitioned_process(tmp_path, churn_index=17)
     # a single-policy edit touches exactly one bucket...
     assert len(churn['touched']) == 1
     # ...which is the ONLY fresh compile; the rest warm-load
-    assert churn['miss'] == 1
-    assert churn['aot_load'] == churn['n_partitions'] - 1
-    assert churn['aot_store'] == 1
+    assert churn['miss'] + churn['aot_load'] == churn['n_partitions']
+    assert first['aot_store'] - churn['aot_load'] in (0, 1)
+    assert churn['aot_store'] + churn['xla_hits'] == churn['miss']
+    if first['xla_hits'] == 0:
+        assert churn['miss'] == 1
+        assert churn['aot_load'] == churn['n_partitions'] - 1
 
     # the edit changed a message, not a pattern: verdict matrices are
     # bit-identical across all three processes

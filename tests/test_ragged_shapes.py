@@ -331,7 +331,7 @@ class TestAotLoadRejection:
         store = aot.default_store()
         key = 'e' * 32
         meta = aot._compile_meta()
-        meta['env_scope'] = 'compiled-with-tpu-plugin'
+        meta['env_scope'] = 'compiled-beside-a-live-tpu'
         store.put(key, aot._pack_blob(b'payload', None, None, meta))
         assert aot.load_executable(key) is None
         assert self._reason_count('env_mismatch') == 1
@@ -429,7 +429,7 @@ def _run_probe(cache_dir, timeout=300):
         'KTPU_ENCODE_PROCS': '0',
         'KTPU_AOT': '1',
         'KTPU_AOT_CACHE_DIR': os.path.join(str(cache_dir), 'aot'),
-        'KTPU_COMPILE_CACHE': os.path.join(str(cache_dir), 'xla'),
+        'JAX_COMPILATION_CACHE_DIR': os.path.join(str(cache_dir), 'xla'),
     })
     out = subprocess.run([sys.executable, '-c', _PROBE_SCRIPT],
                          env=env, cwd=REPO, capture_output=True,

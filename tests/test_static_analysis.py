@@ -9,7 +9,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
@@ -26,9 +25,13 @@ def _run_analyzer(*args):
 
 
 def test_tree_is_clean_in_strict_mode():
-    t0 = time.monotonic()
+    # the budget is the analyzer's own CPU time: under the six test
+    # workers its wall clock also counts what the others are doing
+    t0 = os.times()
     proc = _run_analyzer('--strict', '--json')
-    elapsed = time.monotonic() - t0
+    t1 = os.times()
+    elapsed = (t1.children_user - t0.children_user) + \
+        (t1.children_system - t0.children_system)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     report = json.loads(proc.stdout)
     assert report['counts']['active'] == 0, report['active']
@@ -36,7 +39,8 @@ def test_tree_is_clean_in_strict_mode():
         report['stale_baseline']
     assert not report['errors'], report['errors']
     # CPU-only CI budget: the whole tree must analyze fast
-    assert elapsed < 10.0, f'analyzer took {elapsed:.1f}s (budget 10s)'
+    assert elapsed < 10.0, \
+        f'analyzer took {elapsed:.1f}s of CPU (budget 10s)'
 
 
 def test_baseline_is_minimal_and_justified():

@@ -106,9 +106,8 @@ class TestWildcardKeyCompile:
         assert len(cps.programs) == 1
 
     def test_full_pack_zero_host_rules(self):
-        """VERDICT r3 #9: the full best-practices+charts pack compiles
-        with zero host rules (select-secrets' apiCall context keeps it
-        host-side by design — it is the only permitted exception)."""
+        """The committed pack compiles with no apparmor rule left on
+        the host."""
         import bench
         cps = compile_policies(bench.load_policy_pack())
         names = {r.get('name') for _, r, _ in cps.host_rules}
