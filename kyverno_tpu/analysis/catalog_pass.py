@@ -497,7 +497,8 @@ def load_stage_registry() -> Dict[str, str]:
 def collect_stage_labels(files: List[SourceFile]
                          ) -> List[Tuple[SourceFile, int, str]]:
     """Pipeline stage-label sites across a parsed file set:
-    ``stage('<s>')`` timers, ``add_backpressure('<s>', ...)``
+    ``stage('<s>')`` timers, ``record_stage('<s>', ...)`` samples,
+    ``annotation('<s>')`` marks, ``add_backpressure('<s>', ...)``
     attributions, ``exec_scope(tl, c, '<s>')`` inline wrappers, and the
     literal ``(name, fn)`` stage lists handed to ``ChunkPipeline``.
     Non-literal labels are skipped (variables flow from these same
@@ -512,7 +513,8 @@ def collect_stage_labels(files: List[SourceFile]
             func = node.func
             attr = func.attr if isinstance(func, ast.Attribute) else \
                 (func.id if isinstance(func, ast.Name) else '')
-            if attr in ('stage', 'add_backpressure'):
+            if attr in ('stage', 'add_backpressure', 'record_stage',
+                        'annotation'):
                 arg = node.args[0]
                 if isinstance(arg, ast.Constant) and \
                         isinstance(arg.value, str):

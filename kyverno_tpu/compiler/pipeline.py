@@ -208,7 +208,10 @@ class ChunkPipeline:
                 if item.error is None and not self._stop.is_set():
                     if tl is not None:
                         tl.start(item.seq, name)
-                    self._run_stage(name, fn, item)
+                    # what this thread's stages write into the
+                    # profiler's trace names the chunk they belong to
+                    with devtel.trace_ids(chunk=item.seq):
+                        self._run_stage(name, fn, item)
                     if tl is not None:
                         tl.end(item.seq, name, ok=item.error is None)
                 self._put(qout, name, item)

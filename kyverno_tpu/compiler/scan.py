@@ -484,6 +484,18 @@ class BatchScanner:
                      admission: Optional[tuple] = None,
                      adm_rows: Optional[List[Optional[tuple]]] = None,
                      plan: Optional[Any] = None) -> np.ndarray:
+        """``_match_rows``, timed as the ``match`` stage: once a chunk
+        on the scan path (from ``match_fn``, on the pipeline's encode
+        thread)."""
+        from ..observability import device as devtel
+        with devtel.stage('match', {'rows': len(resources)}):
+            return self._match_rows(resources, wrapped, admission,
+                                    adm_rows, plan)
+
+    def _match_rows(self, resources: List[dict], wrapped: List[Resource],
+                    admission: Optional[tuple] = None,
+                    adm_rows: Optional[List[Optional[tuple]]] = None,
+                    plan: Optional[Any] = None) -> np.ndarray:
         """[R, P] bool match mask, group-cached for simple-match rules.
         ``admission`` carries one scan-wide (admission_info,
         exclude_group_roles, namespace_labels, operation) tuple;
@@ -573,7 +585,7 @@ class BatchScanner:
         retries a failed new-object match against the old object
         (engine.py:303 ``_matches``), and a namespaced policy applies
         only when BOTH objects sit in its namespace (engine.py:239).
-        The old objects run through ``match_matrix`` themselves, so the
+        The old objects run through ``_match_rows`` themselves, so the
         group cache amortizes the retry across a batch exactly like the
         new-object sieve (the per-(row, program) host walk this
         replaced dominated mixed-verb batches at 1k policies)."""
@@ -584,7 +596,7 @@ class BatchScanner:
         old_wrapped = [Resource(d) for d in old_docs]
         sub_adm = [adm_rows[i] for i in rows] if adm_rows is not None \
             else None
-        om = self.match_matrix(old_docs, old_wrapped, adm_rows=sub_adm)
+        om = self._match_rows(old_docs, old_wrapped, adm_rows=sub_adm)
         match = match.copy()
         ridx = np.asarray(rows)
         match[ridx] |= om
@@ -669,6 +681,7 @@ class BatchScanner:
             yield from self._partitioned_status_chunks(
                 resources, contexts, match, match_fn, timeline)
             return
+        import jax
         from ..observability import device as devtel
         from ..observability import timeline as tlmod
         from ..observability import tracing
@@ -757,8 +770,9 @@ class BatchScanner:
                         p['part'], p['part_ctx'], p['bucket'])
                 else:
                     try:
-                        tensors, wstages, wspan = tensors.get(
-                            timeout=self.ENCODE_TIMEOUT_S)
+                        with devtel.stage('encode_wait'):
+                            tensors, wstages, wspan = tensors.get(
+                                timeout=self.ENCODE_TIMEOUT_S)
                     except Exception:  # noqa: BLE001 - worker death
                         self._encoder_pool.mark_broken('presumed_dead')
                         tensors, p['batch'] = inline_encode(
@@ -825,19 +839,23 @@ class BatchScanner:
                 # release the backing buffers
                 with devtel.d2h_guard({'chunk_start': start,
                                        'rows': ln}) as g:
+                    # the dispatch only enqueued: the host's wait for
+                    # the device is here, named apart from the copies
+                    with devtel.stage('device_wait'):
+                        jax.block_until_ready(out)
                     o8 = np.array(out[0])
                     o32 = np.array(out[1])
                     g.add_d2h_bytes(o8.nbytes + o32.nbytes)
-                s, d, fd, adm = expand_compact(o8, o32,
-                                               self._evaluator)
-                self._free_inputs(t, out)
-                cm = p['cm']
-                release_chunk(p)
+                with devtel.stage('expand', {'rows': ln}):
+                    s, d, fd, adm = expand_compact(o8, o32,
+                                                   self._evaluator)
+                    self._free_inputs(t, out)
+                    cm = p['cm']
+                    release_chunk(p)
                 return (start, s[:ln], d[:ln], fd[:ln],
                         adm[:ln] if adm is not None else None, cm)
             s, d, fd = out
             if self.mesh is not None:
-                import jax
                 from ..observability import fleet
                 shard_walls = None
                 t_coll = 0.0
@@ -867,6 +885,8 @@ class BatchScanner:
                         time.perf_counter() - t_coll)
             with devtel.d2h_guard({'chunk_start': start,
                                    'rows': ln}) as g:
+                with devtel.stage('device_wait'):
+                    jax.block_until_ready((s, d, fd))
                 s, d, fd = (np.array(s)[:ln], np.array(d)[:ln],
                             np.array(fd)[:ln])
                 g.add_d2h_bytes(s.nbytes + d.nbytes + fd.nbytes)
@@ -911,7 +931,6 @@ class BatchScanner:
         # process picks its own order between chunk k's d2h and chunk
         # k+1's h2d, and two processes that pick differently wait on
         # each other for ever.  One chunk in flight fixes the order.
-        import jax
         depth = 1 if self.mesh is not None and jax.process_count() > 1 \
             else None
         pipe = ChunkPipeline(
@@ -945,6 +964,7 @@ class BatchScanner:
           (one accelerator; the chunk pipeline still overlaps encode /
           h2d / eval / d2h across chunks)."""
         n = len(resources)
+        import jax
         from ..observability import device as devtel
         from ..observability import timeline as tlmod
         from ..observability import tracing
@@ -1023,6 +1043,8 @@ class BatchScanner:
             parts_out = []
             with devtel.d2h_guard({'chunk_start': start,
                                    'rows': ln}) as g:
+                with devtel.stage('device_wait'):
+                    jax.block_until_ready(p['outs'])
                 for rt, (t, _layout), out in zip(rts, p['shipped'],
                                                  p['outs']):
                     if len(out) == 2:
@@ -1065,6 +1087,24 @@ class BatchScanner:
             capture=tel_capture, parent_span=tel_parent,
             timeline=timeline)
         yield from pipe.run(range(0, n, chunk))
+
+    def _next_chunk(self, chunks, n: int, at: int):
+        """``next(chunks)``, timed as ``chunk_wait``: what the consuming
+        thread spends getting its next chunk.  Where the chunks come
+        through the pipeline that is a wait, and it is marked in the
+        profiler's trace; a single chunk runs its stages inline on this
+        thread, under their own names, so only the histogram and the
+        capture see the sum."""
+        from ..observability import device as devtel
+        t0 = time.monotonic()
+        try:
+            if n > self.CHUNK:
+                with devtel.annotation('chunk_wait',
+                                       chunk=at // max(self.CHUNK, 1)):
+                    return next(chunks)
+            return next(chunks)
+        finally:
+            devtel.record_stage('chunk_wait', time.monotonic() - t0)
 
     def _device_statuses(self, resources: List[dict],
                          contexts: Optional[List[dict]] = None,
@@ -1144,44 +1184,49 @@ class BatchScanner:
         # (engine.py:174 apply_background_checks) only applies to scans
         background_mode = admission is None and admissions is None and \
             pctx_factory is None
-        wrapped = [Resource(r) for r in resources]
-        adm_rows = admissions if admissions is not None else (
-            [admission] * n if admission is not None else None)
-        # per-row admission lanes: encode once per scan; rows whose
-        # tuples do not intern exactly fall back to the host matcher
-        # alone (taxonomy: admission_unencodable), never the batch
-        plan = None
-        if adm_rows is not None and self._adm is not None and \
-                self.mesh is None:
-            old_flags = [bool(o) for o in old_resources] \
-                if old_resources is not None else None
-            plan = admission_lanes.encode_rows(self._adm, adm_rows,
-                                               old_flags)
-            atoms = self._adm_res_atoms(resources, wrapped)
-            plan.lanes['__admres__'] = atoms
-            plan.upper = admission_lanes.match_upper(self._adm, atoms)
-            bad = int(plan.unencodable.sum())
-            if bad:
-                coverage.record_fallback(
-                    'validate', coverage.REASON_ADMISSION_UNENCODABLE,
-                    rows=bad)
-        match = self.match_matrix(resources, wrapped, adm_rows=adm_rows,
-                                  plan=plan)
-        if old_resources is not None and any(old_resources):
-            match = self._fold_old_matches(match, wrapped, adm_rows,
-                                           old_resources)
+        from ..observability import device as devtel
+        with devtel.stage('prepare', {'rows': n}):
+            wrapped = [Resource(r) for r in resources]
+            adm_rows = admissions if admissions is not None else (
+                [admission] * n if admission is not None else None)
+            # per-row admission lanes: encode once per scan; rows whose
+            # tuples do not intern exactly fall back to the host matcher
+            # alone (taxonomy: admission_unencodable), never the batch
+            plan = None
+            if adm_rows is not None and self._adm is not None and \
+                    self.mesh is None:
+                old_flags = [bool(o) for o in old_resources] \
+                    if old_resources is not None else None
+                plan = admission_lanes.encode_rows(self._adm, adm_rows,
+                                                   old_flags)
+                atoms = self._adm_res_atoms(resources, wrapped)
+                plan.lanes['__admres__'] = atoms
+                plan.upper = admission_lanes.match_upper(self._adm, atoms)
+                bad = int(plan.unencodable.sum())
+                if bad:
+                    coverage.record_fallback(
+                        'validate', coverage.REASON_ADMISSION_UNENCODABLE,
+                        rows=bad)
+        # one ``match`` sample a scan: the sieve, the old objects'
+        # retry and the host policies' screen
+        with devtel.stage('match', {'rows': n}):
+            match = self._match_rows(resources, wrapped,
+                                     adm_rows=adm_rows, plan=plan)
+            if old_resources is not None and any(old_resources):
+                match = self._fold_old_matches(match, wrapped, adm_rows,
+                                               old_resources)
+            # which host policies could match each resource at all
+            # (group screen over their simple rules; non-simple rules
+            # force a run).  The screen is valid for admission scans
+            # too: simple-match rules only reference kinds/namespaces
+            # (the matcher ignores operations entirely, and
+            # roles/subjects rules are non-simple), and a screened-out
+            # policy contributes the same empty response the engine
+            # would produce.
+            host_maybe = self._host_policy_maybe(resources, wrapped,
+                                                 old_resources)
         now = time.time()
         ts = int(now)
-
-        # which host policies could match each resource at all (group
-        # screen over their simple rules; non-simple rules force a run).
-        # The screen is valid for admission scans too: simple-match
-        # rules only reference kinds/namespaces (the matcher ignores
-        # operations entirely, and roles/subjects rules are non-simple),
-        # and a screened-out policy contributes the same empty response
-        # the engine would produce.
-        host_maybe = self._host_policy_maybe(resources, wrapped,
-                                             old_resources)
 
         progs = self.cps.programs
         background_ok = getattr(self, '_background_ok', None)
@@ -1213,7 +1258,7 @@ class BatchScanner:
                          'programs': len(progs)}) as span:
                     try:
                         start, status, detail, fdet, adm_out, _cm = \
-                            next(chunks)
+                            self._next_chunk(chunks, n, start)
                     except StopIteration:
                         return
                     if adm_out is not None and plan is not None:
@@ -1226,10 +1271,11 @@ class BatchScanner:
                             match[np.ix_(start + vr, self._adm_cols)] = \
                                 adm_out[vr].astype(bool)
                     span.set_attribute('resources', status.shape[0])
-                    from ..observability import device as devtel
                     t_rep = time.monotonic() if tl is not None else 0.0
                     with devtel.stage('report',
-                                      {'rows': status.shape[0]}) as rstage:
+                                      {'rows': status.shape[0],
+                                       'chunk': start // chunk_cap}
+                                      ) as rstage:
                         chunk_rows = self._assemble_chunk(
                             resources, wrapped, match, start, status,
                             detail, fdet, now, ts, background_mode,
@@ -1255,7 +1301,6 @@ class BatchScanner:
             # per-scan coverage-ratio gauge
             if tally is not None:
                 tally.finish()
-                from ..observability import device as devtel
                 cap = devtel.current_capture()
                 if cap is not None:
                     cap.coverage_ratio = tally.ratio()
@@ -1635,6 +1680,7 @@ class BatchScanner:
             # mask and Resource list never exist
             return self.match_matrix(part, [Resource(r) for r in part])
 
+        from ..observability import device as devtel
         from ..observability import timeline as tlmod
         tl = tlmod.begin_scan()
         chunk_cap = max(self.CHUNK, 1)
@@ -1649,22 +1695,25 @@ class BatchScanner:
         try:
             while done < n:
                 try:
-                    start, status, detail, fdet, _adm, cm = next(chunks)
+                    start, status, detail, fdet, _adm, cm = \
+                        self._next_chunk(chunks, n, done)
                 except StopIteration:
                     return
                 m = status.shape[0]
+                seq = start // chunk_cap
                 host_maybe = None
                 part_docs = resources[start:start + m]
                 if host_idx:
-                    part_wrapped = [Resource(r) for r in part_docs]
-                    host_maybe = self._host_policy_maybe(part_docs,
-                                                         part_wrapped)
-                from ..observability import device as devtel
+                    with devtel.stage('match', {'chunk': seq, 'rows': m}):
+                        part_wrapped = [Resource(r) for r in part_docs]
+                        host_maybe = self._host_policy_maybe(
+                            part_docs, part_wrapped)
                 for w0 in range(0, m, flush):
                     w1 = min(w0 + flush, m)
                     wm = w1 - w0
+                    ids = {'chunk': seq, 'rows': wm}
                     t_rep = time.monotonic() if tl is not None else 0.0
-                    with devtel.stage('report', {'rows': wm}) as rstage:
+                    with devtel.stage('report', ids) as rstage:
                         rows, row_pols, counts = \
                             self._assemble_report_window(
                                 resources, start + w0, wm,
@@ -1679,49 +1728,68 @@ class BatchScanner:
                                     round(ratio, 4))
                     if tl is not None:
                         tl.record('report', start // chunk_cap, t_rep)
-                    for k in range(wm):
-                        i = start + w0 + k
-                        results = rows[k]
-                        pols = row_pols[k]
-                        dirty = False
-                        for p_idx in host_idx:
-                            if host_maybe[p_idx] is not None and \
-                                    not host_maybe[p_idx][w0 + k]:
-                                continue
-                            resp = self._host_run(p_idx, resources[i])
-                            if tally is not None:
-                                self._tally_host_policy(tally, p_idx,
-                                                        resp)
-                            if not resp.policy_response.rules:
-                                continue
-                            pols.append(p_idx)
-                            dirty = True
-                            for result in \
-                                    engine_response_to_report_results(
-                                        resp, now=ts):
-                                results.append(result)
-                                counts[k, self._BUCKET_IDX[
-                                    result['result']]] += 1
-                        if dirty:
-                            # host-policy results interleave by sort
-                            # key; device results arrived pre-sorted,
-                            # so only these rows pay a sort-merge
-                            results.sort(key=lambda r: (
-                                r.get('policy', ''), r.get('rule', ''),
-                                0, (), ts_key))
-                        c = counts[k]
-                        summary = {
-                            'pass': int(c[0]), 'fail': int(c[1]),
-                            'warn': int(c[2]), 'error': int(c[3]),
-                            'skip': int(c[4])}
-                        seen: Dict[int, None] = dict.fromkeys(pols)
-                        yield (results, summary,
-                               [self.policies[p] for p in sorted(seen)])
+                    # ``store``: what the consumer of the rows does
+                    # with the thread between the rows of this window
+                    # (the reports controller: build, label, cache and
+                    # write each report), timed around the yield and
+                    # observed once; the mark spans the row loop.  A
+                    # consumer that zips the rows against a list closes
+                    # the stream AT its last yield, hence the finally
+                    store_s = t_row = 0.0
+                    mark = devtel.annotation('store', **ids)
+                    mark.__enter__()
+                    try:
+                        for k in range(wm):
+                            i = start + w0 + k
+                            results = rows[k]
+                            pols = row_pols[k]
+                            dirty = False
+                            for p_idx in host_idx:
+                                if host_maybe[p_idx] is not None and \
+                                        not host_maybe[p_idx][w0 + k]:
+                                    continue
+                                resp = self._host_run(p_idx, resources[i])
+                                if tally is not None:
+                                    self._tally_host_policy(tally, p_idx,
+                                                            resp)
+                                if not resp.policy_response.rules:
+                                    continue
+                                pols.append(p_idx)
+                                dirty = True
+                                for result in \
+                                        engine_response_to_report_results(
+                                            resp, now=ts):
+                                    results.append(result)
+                                    counts[k, self._BUCKET_IDX[
+                                        result['result']]] += 1
+                            if dirty:
+                                # host-policy results interleave by sort
+                                # key; device results arrived pre-sorted,
+                                # so only these rows pay a sort-merge
+                                results.sort(key=lambda r: (
+                                    r.get('policy', ''),
+                                    r.get('rule', ''), 0, (), ts_key))
+                            c = counts[k]
+                            summary = {
+                                'pass': int(c[0]), 'fail': int(c[1]),
+                                'warn': int(c[2]), 'error': int(c[3]),
+                                'skip': int(c[4])}
+                            seen: Dict[int, None] = dict.fromkeys(pols)
+                            row = (results, summary,
+                                   [self.policies[p] for p in sorted(seen)])
+                            t_row = time.monotonic()
+                            yield row
+                            store_s += time.monotonic() - t_row
+                            t_row = 0.0
+                    finally:
+                        if t_row:
+                            store_s += time.monotonic() - t_row
+                        mark.__exit__(None, None, None)
+                        devtel.record_stage('store', store_s)
                 done += m
         finally:
             if tally is not None:
                 tally.finish()
-                from ..observability import device as devtel
                 cap = devtel.current_capture()
                 if cap is not None:
                     cap.coverage_ratio = tally.ratio()

@@ -49,8 +49,11 @@ METRICS: Dict[str, Metric] = {
         'counter', 'Cluster client queries by verb/kind.'),
     # device-pipeline instruments (observability/device.py)
     'kyverno_tpu_scan_stage_duration_seconds': Metric(
-        'histogram', 'Batched-scan stage latency; stage=pack|encode|h2d|'
-        'compile|device_eval|d2h|report.'),
+        'histogram', 'Batched-scan stage latency; stage= one of '
+        'observability/device.py STAGES (the pipeline\'s pack|encode|'
+        'h2d|compile|device_eval|d2h|report, the consumer thread\'s '
+        'filter|chunk_wait|store|flush, per reconcile reconcile|unnamed, '
+        '...).'),
     'kyverno_tpu_compile_cache_requests_total': Metric(
         'counter', 'Evaluator executable lookups; result=hit|miss|'
         'aot_load|aot_store.'),
@@ -216,8 +219,8 @@ METRICS: Dict[str, Metric] = {
         'counter', 'Device dispatches served per executable '
         'acquisition source.'),
     'kyverno_tpu_executable_device_seconds_total': Metric(
-        'counter', 'Cumulative device-eval seconds spent per '
-        'executable acquisition source.'),
+        'counter', 'Cumulative seconds the dispatches took to '
+        'enqueue (not device time) per executable acquisition source.'),
     # pipeline critical-path observatory (observability/timeline.py)
     'kyverno_tpu_pipeline_blame_seconds_total': Metric(
         'counter', 'Exclusive critical-path blame per streaming-scan '
@@ -280,10 +283,27 @@ SPANS: Dict[str, str] = {
     'kyverno/device/encode': 'Host feature-extraction (encode) stage.',
     'kyverno/device/h2d': 'Host-to-device transfer stage.',
     'kyverno/device/compile': 'Executable lookup / XLA compile stage.',
-    'kyverno/device/device_eval': 'Device evaluation dispatch stage.',
+    'kyverno/device/device_eval': 'Device evaluation dispatch stage: '
+                                  'times the enqueue, not the device.',
     'kyverno/device/d2h': 'Device-to-host readback stage (stall-'
-                          'watchdog armed).',
+                          'watchdog armed): wait + copy.',
     'kyverno/device/report': 'Response/report assembly stage.',
+    'kyverno/device/match': 'Host match sieve over the policy axis '
+                            '(once a chunk or batch).',
+    'kyverno/device/encode_wait': 'The h2d thread blocked on the '
+                                  'encoder pool\'s result.',
+    'kyverno/device/device_wait': 'Blocked until the evaluator\'s '
+                                  'outputs are ready (inside d2h).',
+    'kyverno/device/expand': 'Compact readback expanded to status '
+                             'matrices; chunk buffers released.',
+    'kyverno/device/filter': 'Reconcile: pending rows + verdict-cache '
+                             'lookup pass, before the scan.',
+    'kyverno/device/flush': 'Reconcile: the verdict cache persisted, '
+                            'after the scan.',
+    'kyverno/device/prepare': 'Admission scan: resource wrapping and '
+                              'per-row admission lanes.',
+    'kyverno/device/resolve': 'Admission batch: provenance filled and '
+                              'every rider\'s ticket resolved.',
     'kyverno/mutate/patch_emit': 'Device mutate patch-emit stage: '
                                  'edit-site lane encode + kernel '
                                  'dispatch for one batch.',
@@ -306,7 +326,8 @@ SPANS: Dict[str, str] = {
 
 
 #: canonical streaming-pipeline stage labels — the single source of
-#: truth for every ``stage('<s>')`` timer, ``ChunkPipeline`` stage-list
+#: truth for every ``stage('<s>')`` timer, ``record_stage('<s>', ...)``
+#: sample, ``annotation('<s>')`` mark, ``ChunkPipeline`` stage-list
 #: entry, and backpressure attribution in the tree (ktpu-lint KTPU507:
 #: an unregistered label under ``compiler/`` or a dead registry entry
 #: is catalog drift).  The timeline recorder and its critical-path
@@ -319,7 +340,29 @@ PIPELINE_STAGES: Dict[str, str] = {
               'or forked worker).',
     'h2d': 'Host-to-device transfer (and forked-encode resolution).',
     'compile': 'Executable lookup / XLA compile.',
-    'device_eval': 'Device evaluation dispatch.',
-    'd2h': 'Device-to-host readback (stall-watchdog armed).',
+    'device_eval': 'Device evaluation dispatch (the enqueue).',
+    'd2h': 'Device-to-host readback (stall-watchdog armed): wait + copy.',
     'report': 'Report-row assembly / flush window.',
+    # leaf stages beside the pipeline's legs (not blamed by the
+    # timeline walk: the first four lie inside a leg's interval)
+    'match': 'Host match sieve (inside the encode leg on the scan path).',
+    'encode_wait': 'h2d leg blocked on the encoder pool\'s result.',
+    'device_wait': 'Blocked until the evaluator\'s outputs are ready '
+                   '(inside d2h).',
+    'expand': 'Compact readback expanded; chunk buffers released.',
+    'chunk_wait': 'Consumer thread in next(chunks): its wait for the '
+                  'pipeline (a single chunk runs inline inside it).',
+    'store': 'Consumer of the row stream holding the thread between '
+             'the rows of one report window.',
+    'filter': 'Reconcile: pending rows + verdict-cache lookup pass.',
+    'flush': 'Reconcile: the verdict cache persisted, after the scan.',
+    'reconcile': 'Wall of one reconcile() (histogram only).',
+    'unnamed': 'reconcile minus filter + chunk_wait + report + store + '
+               'flush (histogram only).',
+    'prepare': 'Admission scan: wrapping + per-row admission lanes.',
+    'resolve': 'Admission batch: every rider\'s ticket resolved.',
+    'handler_pre': 'validate(): entry to the batcher\'s submit '
+                   '(histogram only).',
+    'handler_post': 'validate(): resolved ticket to return '
+                    '(histogram only).',
 }

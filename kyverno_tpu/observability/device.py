@@ -25,6 +25,7 @@ import collections
 import contextvars
 import logging
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -42,9 +43,21 @@ BACKPRESSURE = 'kyverno_tpu_scan_backpressure_seconds_total'
 ENCODE_WORKER_CHUNKS = 'kyverno_tpu_encode_worker_chunks_total'
 STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 
-#: canonical stage labels, in pipeline order
-STAGES = ('pack', 'encode', 'h2d', 'compile', 'device_eval', 'd2h',
-          'report')
+#: canonical stage labels.  The pipeline's, in order: ``match`` (host
+#: match sieve), ``encode`` (in a worker process or inline),
+#: ``encode_wait`` (the h2d thread blocked on the encoder pool),
+#: ``pack``, ``h2d``, ``compile``, ``device_eval`` (the dispatch: it
+#: times the ENQUEUE), ``d2h`` (wait + copy), ``device_wait`` (nested in
+#: ``d2h``: blocked until the evaluator's outputs are ready),
+#: ``expand``.  The consumer thread's: ``filter``, ``chunk_wait``,
+#: ``report``, ``store``, ``flush``, and per reconcile ``reconcile``
+#: (its wall) and ``unnamed`` (that wall minus the five before it).
+#: The admission batch's own: ``prepare``, ``resolve``,
+#: ``handler_pre``, ``handler_post``.
+STAGES = ('match', 'encode', 'encode_wait', 'pack', 'h2d', 'compile',
+          'device_eval', 'd2h', 'device_wait', 'expand', 'filter',
+          'chunk_wait', 'report', 'store', 'flush', 'reconcile',
+          'unnamed', 'prepare', 'resolve', 'handler_pre', 'handler_post')
 
 _log = logging.getLogger('kyverno.device')
 
@@ -162,33 +175,50 @@ class ScanCapture:
             return self.stages.get(stage, 0.0)
 
 
-class _CaptureScope:
-    __slots__ = ('capture', '_token')
+class _VarScope:
+    """Sets one contextvar for a with-block (no-op for None)."""
 
-    def __init__(self, capture: Optional[ScanCapture]):
-        self.capture = capture
+    __slots__ = ('var', 'value', '_token')
+
+    def __init__(self, var: contextvars.ContextVar, value):
+        self.var = var
+        self.value = value
         self._token = None
 
-    def __enter__(self) -> Optional[ScanCapture]:
-        if self.capture is not None:
-            self._token = _capture_var.set(self.capture)
-        return self.capture
+    def __enter__(self):
+        if self.value is not None:
+            self._token = self.var.set(self.value)
+        return self.value
 
     def __exit__(self, *exc) -> None:
         if self._token is not None:
-            _capture_var.reset(self._token)
+            self.var.reset(self._token)
 
 
-def install_capture(capture: Optional[ScanCapture]) -> _CaptureScope:
+def install_capture(capture: Optional[ScanCapture]) -> _VarScope:
     """Context manager making ``capture`` the ambient scan accumulator
     (no-op for None).  The scan pipeline re-installs it on its worker
     threads (``compiler/scan.py`` encode/dispatch closures), the same
     way stage spans re-parent through ``tel_parent``."""
-    return _CaptureScope(capture)
+    return _VarScope(_capture_var, capture)
 
 
 def current_capture() -> Optional[ScanCapture]:
     return _capture_var.get()
+
+
+#: what the stages opened on this thread/context belong to: ``chunk``
+#: (the chunk's sequence number in its scan) on the scan path, ``batch``
+#: (the batcher's dispatch serial) and ``rows`` on the admission path
+_ids_var: contextvars.ContextVar[Optional[Dict[str, Any]]] = \
+    contextvars.ContextVar('ktpu_trace_ids', default=None)
+
+
+def trace_ids(**ids) -> _VarScope:
+    """Context manager adding ``ids`` to the identifiers every stage
+    opened inside it writes into the profiler's trace, so the spans of
+    one chunk or one batch can be joined across threads."""
+    return _VarScope(_ids_var, {**(_ids_var.get() or {}), **ids})
 
 
 def merge_worker_stages(stages: Dict[str, float]) -> None:
@@ -198,14 +228,8 @@ def merge_worker_stages(stages: Dict[str, float]) -> None:
     but their metric increments and contextvars die with them — the
     measured times ride home with the encoded tensors and are
     re-attributed here, on the pipeline thread that resolved them."""
-    if not stages:
-        return
-    capture = _capture_var.get()
     for name, seconds in stages.items():
-        if _registry is not None:
-            _registry.observe(SCAN_STAGE_DURATION, seconds, stage=name)
-        if capture is not None:
-            capture.add(name, seconds)
+        record_stage(name, seconds)
 
 
 # -- stage timers -----------------------------------------------------------
@@ -230,13 +254,13 @@ _NOOP_STAGE = _NoopStage()
 
 
 class _Stage:
-    __slots__ = ('stage', 'span', '_t0', '_capture')
+    __slots__ = ('stage', 'span', '_t0', '_mark')
 
-    def __init__(self, stage: str, span, t0: float, capture=None):
+    def __init__(self, stage: str, span, t0: float, mark):
         self.stage = stage
         self.span = span
         self._t0 = t0
-        self._capture = capture
+        self._mark = mark
 
     def set_attribute(self, key, value):
         self.span.set_attribute(key, value)
@@ -246,33 +270,73 @@ class _Stage:
 
     def __enter__(self):
         self.span.__enter__()
+        self._mark.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        self._mark.__exit__(exc_type, exc, tb)
         self.span.__exit__(exc_type, exc, tb)
-        elapsed = time.monotonic() - self._t0
-        if _registry is not None:
-            _registry.observe(SCAN_STAGE_DURATION, elapsed,
-                              stage=self.stage)
-        if self._capture is not None:
-            self._capture.add(self.stage, elapsed)
+        record_stage(self.stage, time.monotonic() - self._t0)
         return False
+
+
+def _off() -> bool:
+    """The one rule that keeps every stage primitive a no-op."""
+    return _registry is None and _capture_var.get() is None and \
+        not tracing.tracer().enabled
+
+
+#: ``jax.profiler.TraceAnnotation``, looked up once
+_annotation = None
+
+
+def _mark(name: str, ids: Optional[Dict[str, Any]]):
+    global _annotation
+    if _annotation is None:
+        # a process that has not loaded jax (an encoder worker) takes
+        # no trace, and must not load jax for this
+        jax = sys.modules.get('jax')
+        if jax is None:
+            return _NOOP_STAGE
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation('ktpu/' + name,
+                       **{**(_ids_var.get() or {}), **(ids or {})})
+
+
+def annotation(name: str, **ids):
+    """Context manager that writes a ``ktpu/<name>`` event, with ``ids``
+    and the ambient :func:`trace_ids`, into the profiler's own trace
+    while one is being taken: on the ``/host:CPU`` plane, on this
+    thread's line, on the device trace's clock."""
+    return _NOOP_STAGE if _off() else _mark(name, ids)
+
+
+def record_stage(name: str, seconds: float) -> None:
+    """One sample of ``name`` measured by the caller — a stage timed
+    row by row and observed once, or the wall of a whole reconcile —
+    into the stage histogram and the ambient ScanCapture; nothing goes
+    to the trace."""
+    if _registry is not None:
+        _registry.observe(SCAN_STAGE_DURATION, seconds, stage=name)
+    capture = _capture_var.get()
+    if capture is not None:
+        capture.add(name, seconds)
 
 
 def stage(name: str, attributes: Optional[Dict[str, Any]] = None,
           parent=None):
-    """Context manager timing one pipeline stage: a
+    """Context manager timing one leaf stage, with four sinks: a
     ``kyverno/device/<name>`` span (child of ``parent`` or the context
-    span) plus a stage-labelled histogram sample (and a line in the
-    active provenance ScanCapture, when one is installed).  Returns a
-    shared no-op when telemetry is unconfigured."""
-    capture = _capture_var.get()
-    if _registry is None and capture is None and \
-            not tracing.tracer().enabled:
+    span), a ``ktpu/<name>`` event carrying ``attributes`` in the
+    profiler's trace (:func:`annotation`), a histogram sample and a line
+    in the active ScanCapture (:func:`record_stage`).  Spans are wall
+    time: a wait for the GIL is inside them.  Returns a shared no-op
+    when telemetry is unconfigured."""
+    if _off():
         return _NOOP_STAGE
     span = tracing.tracer().start_span(f'kyverno/device/{name}',
                                        attributes, parent=parent)
-    return _Stage(name, span, time.monotonic(), capture)
+    return _Stage(name, span, time.monotonic(), _mark(name, attributes))
 
 
 # -- counters / gauges ------------------------------------------------------
@@ -462,11 +526,13 @@ class _D2HGuard:
 
 def d2h_guard(attributes: Optional[Dict[str, Any]] = None, parent=None):
     """``stage('d2h')`` with the stall watchdog armed for its duration."""
-    if _registry is None and _capture_var.get() is None and \
-            not tracing.tracer().enabled:
+    if _off():
         return _NOOP_STAGE
+    # the stage's clock starts first: arming wakes the monitor thread,
+    # and that hand-over is the readback's own cost
+    timer = stage('d2h', attributes, parent=parent)
     token = _watchdog.arm(attributes) if _watchdog is not None else -1
-    return _D2HGuard(stage('d2h', attributes, parent=parent), token)
+    return _D2HGuard(timer, token)
 
 
 def stage_breakdown() -> Dict[str, Dict[str, float]]:

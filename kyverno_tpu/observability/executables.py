@@ -19,7 +19,9 @@ registers an :class:`ExecutableRecord` at every acquisition site in
 Each record carries the policy-set fingerprint, the canonical row
 capacity, build/load duration, ``compiled.cost_analysis()`` flops and
 bytes where the backend reports them, cumulative dispatch count +
-device-eval seconds, and the last-used timestamp.  Evictions
+``device_s`` (the seconds its dispatches took to ENQUEUE: JAX returns
+before the device is done, so this is not device time), and the
+last-used timestamp.  Evictions
 (``execute_failed`` artifacts dropped by ``_evict_aot``) mark the
 record instead of silently removing it.
 
@@ -206,6 +208,8 @@ class ExecutableLedger:
 
     def record_dispatch(self, key: str, device_s: float,
                         outputs: Any = None) -> None:
+        """One dispatch of ``key``; ``device_s`` is the host's time to
+        enqueue it, not the device's time to run it."""
         with self._lock:
             rec = self._records.get(key)
             if rec is None:

@@ -28,6 +28,10 @@ from .types import (calculate_resource_hash, new_background_scan_report,
 
 ANNOTATION_LAST_SCAN_TIME = 'audit.kyverno.io/last-scan-time'
 
+#: the stages that run on the thread inside ``reconcile()``, one after
+#: the other: what they leave of the reconcile's wall is ``unnamed``
+_OWN_STAGES = ('filter', 'chunk_wait', 'report', 'store', 'flush')
+
 
 class MetadataCache:
     """Resource-metadata cache keyed by uid
@@ -297,6 +301,7 @@ class BackgroundScanController:
         BackgroundScanReport CRs; unchanged resources scanned after the
         last policy change are skipped.  ``now`` pins the scan
         timestamp (tests use it for bit-identity comparisons)."""
+        t_rec = time.monotonic()
         with self._lock:
             pending = list(self._pending)
             self._pending.clear()
@@ -366,43 +371,49 @@ class BackgroundScanController:
             # only needs the touched partitions re-evaluated
             scoped_ok = bool(self._scoped_pids) and hasattr(vc, 'partial')
             replayed = 0
-            if vc is not None:
-                for uid, resource, rhash, digest in rows:
-                    row = vc.lookup(digest)
-                    if row is None:
-                        if scoped_ok:
-                            cached = vc.partial(digest, self._scoped_pids)
-                            if cached is not None:
-                                scoped_uids.append(uid)
-                                scoped_work.append(resource)
-                                scoped_digests.append(digest)
-                                scoped_hashes.append(rhash)
-                                scoped_cached.append(cached)
-                                continue
+            # the reconcile's own capture: the stages of this thread
+            # (_OWN_STAGES) are summed from it when the reconcile ends
+            cap = devtel.ScanCapture()
+            own_s = 0.0
+            with devtel.install_capture(cap), devtel.stage('filter'):
+                if vc is not None:
+                    for uid, resource, rhash, digest in rows:
+                        row = vc.lookup(digest)
+                        if row is None:
+                            if scoped_ok:
+                                cached = vc.partial(digest,
+                                                    self._scoped_pids)
+                                if cached is not None:
+                                    scoped_uids.append(uid)
+                                    scoped_work.append(resource)
+                                    scoped_digests.append(digest)
+                                    scoped_hashes.append(rhash)
+                                    scoped_cached.append(cached)
+                                    continue
+                            miss_uids.append(uid)
+                            miss_work.append(resource)
+                            miss_digests.append(digest)
+                            miss_hashes.append(rhash)
+                            continue
+                        t_row = time.monotonic() if prov_on else 0.0
+                        report = self._store_fused_report(
+                            uid, resource,
+                            vc.replay(row, self.policies, ts), now, rhash)
+                        self._scanned[uid] = (rhash, now)
+                        if report is not None:
+                            reports.append(report)
+                        replayed += 1
+                        if prov_on:
+                            self._record_row(
+                                provenance, 'cache_replay', uid, resource,
+                                duration_s=time.monotonic() - t_row,
+                                verdict_digest=digest)
+                else:
+                    for uid, resource, rhash, digest in rows:
                         miss_uids.append(uid)
                         miss_work.append(resource)
                         miss_digests.append(digest)
                         miss_hashes.append(rhash)
-                        continue
-                    t_row = time.monotonic() if prov_on else 0.0
-                    report = self._store_fused_report(
-                        uid, resource, vc.replay(row, self.policies, ts),
-                        now, rhash)
-                    self._scanned[uid] = (rhash, now)
-                    if report is not None:
-                        reports.append(report)
-                    replayed += 1
-                    if prov_on:
-                        self._record_row(
-                            provenance, 'cache_replay', uid, resource,
-                            duration_s=time.monotonic() - t_row,
-                            verdict_digest=digest)
-            else:
-                for uid, resource, rhash, digest in rows:
-                    miss_uids.append(uid)
-                    miss_work.append(resource)
-                    miss_digests.append(digest)
-                    miss_hashes.append(rhash)
             # scoped rescan: partial-hit rows re-evaluate against ONLY
             # the touched partitions' policies; the unchanged subrows
             # come from the cache and merge_scoped composes + stores
@@ -430,6 +441,7 @@ class BackgroundScanController:
                         self._scanned[uid] = (rhash, now)
                         if report is not None:
                             reports.append(report)
+                own_s += sum(cap_s.stage_s(k) for k in _OWN_STAGES)
                 if prov_on:
                     n_scoped = len(scoped_work)
                     elapsed = time.monotonic() - t_scoped
@@ -450,7 +462,6 @@ class BackgroundScanController:
             if miss_work:
                 # the capture feeds both provenance (device-share
                 # amortization) and the tick's overlap attribution
-                cap = devtel.ScanCapture()
                 t_scan = time.monotonic()
                 with devtel.install_capture(cap):
                     for uid, resource, digest, rhash, row in zip(
@@ -472,7 +483,11 @@ class BackgroundScanController:
                 # pipeline legs genuinely overlapped this tick
                 scan_wall = time.monotonic() - t_scan
                 if scan_wall > 0:
-                    busy = sum(cap.stages.values())
+                    # busy: not the waits, not device_wait's second
+                    # count inside d2h, not the filter before the scan
+                    busy = sum(v for k, v in cap.stages.items()
+                               if not k.endswith('_wait')
+                               and k != 'filter')
                     span.set_attribute('overlap_ratio',
                                        round(busy / scan_wall, 4))
                 if cap.critical_path:
@@ -502,7 +517,16 @@ class BackgroundScanController:
                              scanned=len(miss_work) + len(scoped_work),
                              replayed=replayed, scoped=len(scoped_work))
         if vc is not None:
-            vc.flush()
+            with devtel.install_capture(cap), \
+                    devtel.stage('flush', parent=span):
+                vc.flush()
+        own_s += sum(cap.stage_s(k) for k in _OWN_STAGES)
+        # the reconcile's wall, and what of it no stage of this thread
+        # covers: the measure of how much of the pace-setting thread's
+        # time the stages name
+        wall = time.monotonic() - t_rec
+        devtel.record_stage('reconcile', wall)
+        devtel.record_stage('unnamed', wall - own_s)
         return reports
 
     def _record_row(self, provenance, path: str, uid: str,

@@ -91,6 +91,17 @@ WAIT_BUCKETS = (0.0005, 0.001, 0.002, 0.005, 0.01, 0.025, 0.05, 0.1,
 #: while a pathological failure storm stays O(depth * batch) dispatches
 QUARANTINE_MAX_DEPTH = 8
 
+#: the leaf stages of one dispatch on the batcher thread, in order,
+#: each beside the ``stats()`` field (``batch_<field>_ms``) that carries
+#: its mean.  They do not overlap, so with ``batch_unnamed_ms`` they sum
+#: to ``batch_ms``; ``device_wait`` is reported too but lies inside
+#: ``d2h``, and is not part of the sum.
+_BATCH_STAGES = (('prepare', 'prepare'), ('match', 'match'),
+                 ('encode', 'encode'), ('pack', 'pack'), ('h2d', 'h2d'),
+                 ('device_eval', 'dispatch'), ('d2h', 'd2h'),
+                 ('expand', 'expand'), ('report', 'report'),
+                 ('resolve', 'resolve'))
+
 #: consecutive all-failed dispatches of one key before poison-only
 #: evidence escalates to a breaker failure anyway: poison sheds are
 #: row-attributed (each row failed twice in isolation), so a single
@@ -179,6 +190,18 @@ class AdmissionBatcher:
         self._hetero_dispatches = 0
         self._requests = 0
         self._quarantine_dispatches = 0
+        # serial of the running dispatch (quarantine's sub-dispatches
+        # count): the id its stages carry into the profiler's trace;
+        # touched only by the batcher thread
+        self._serial = 0
+        # per-dispatch timing (seconds summed since reset_stats): the
+        # dispatches timed, their wall, their stages by name, and what
+        # the handlers report of their own time around the batcher
+        self._timed = 0
+        self._batch_s = 0.0
+        self._stage_s: Dict[str, float] = {}
+        self._handled = 0
+        self._handler_s = 0.0
         # consecutive all-failed dispatch count per key; touched only
         # by the batcher thread (dispatches are serialized), reset the
         # moment any rider of the key resolves on device
@@ -224,6 +247,14 @@ class AdmissionBatcher:
 
     def record_shed(self, reason: str) -> None:
         self.sheds.record(reason)
+
+    def record_handler(self, seconds: float) -> None:
+        """One request's time in its handler outside the batcher: from
+        the handler's entry to ``submit`` and from the resolved ticket
+        to its return (``handler_self_ms``)."""
+        with self._stats_lock:
+            self._handled += 1
+            self._handler_s += seconds
 
     # -- the coalescing loop ----------------------------------------------
 
@@ -286,6 +317,7 @@ class AdmissionBatcher:
         accounting.  Quarantine sub-dispatches re-enter here, so the
         fault-injection row check re-fires per sub-batch and bisection
         can isolate marker-poisoned rows."""
+        t_in = time.monotonic()
         lead = batch[0]
         scanner = lead.scanner
         resources = [t.resource for t in batch]
@@ -301,10 +333,13 @@ class AdmissionBatcher:
 
         from ..observability import device as devtel
         from ..observability import provenance
-        # per-dispatch provenance capture: device_eval time of THIS
-        # scan (not a registry-sum delta a concurrent rescan could
-        # contaminate) amortizes over the riders as their device share
-        cap = devtel.ScanCapture() if provenance.enabled() else None
+        # per-dispatch capture: the stage times of THIS scan (not a
+        # registry-sum delta a concurrent rescan could contaminate).
+        # Provenance amortizes its device_eval time over the riders as
+        # their device share; stats() splits the dispatch by stage
+        prov_on = provenance.enabled()
+        cap = devtel.ScanCapture() \
+            if prov_on or devtel.enabled() else None
         # UPDATE rows carry oldObject for the scanner's match retry; the
         # kwarg is only passed when present so CREATE-era scanner
         # doubles (and the mutate scanner) keep their signatures
@@ -316,39 +351,51 @@ class AdmissionBatcher:
         # key makes mixed tuples share this dispatch)
         if getattr(scanner, 'supports_row_admissions', False):
             extra['admissions'] = [t.admission for t in batch]
+        self._serial += 1
         with devtel.install_capture(cap), \
-                tracing.tracer().start_span(
+                devtel.trace_ids(batch=self._serial, rows=len(batch)):
+            with tracing.tracer().start_span(
                     'kyverno/serving/batch',
                     {'occupancy': len(batch),
                      'window_ms': self.window_s * 1000.0},
                     parent=lead.span) as bspan:
-            faults.check_rows(faults.SITE_BATCHER_DISPATCH, resources)
-            rows = scanner.scan(resources, contexts=contexts,
-                                admission=lead.admission,
-                                pctx_factory=pctx_factory, **extra)
-            if cap is not None and cap.critical_path is not None:
-                from ..observability import timeline as tlmod
-                bspan.set_attribute(
-                    'critical_path',
-                    tlmod.format_summary(cap.critical_path))
+                faults.check_rows(faults.SITE_BATCHER_DISPATCH, resources)
+                rows = scanner.scan(resources, contexts=contexts,
+                                    admission=lead.admission,
+                                    pctx_factory=pctx_factory, **extra)
+                if cap is not None and cap.critical_path is not None:
+                    from ..observability import timeline as tlmod
+                    bspan.set_attribute(
+                        'critical_path',
+                        tlmod.format_summary(cap.critical_path))
+            with devtel.stage('resolve', parent=lead.span):
+                if prov_on:
+                    device_eval_s = cap.stage_s('device_eval')
+                    share = device_eval_s / len(batch)
+                    batch_id = provenance.next_batch_id()
+                    for t in batch:
+                        # filled before resolve(): the waiting webhook
+                        # thread reads prov right after its future
+                        # resolves
+                        t.prov = {
+                            'batch_id': batch_id,
+                            'occupancy': len(batch),
+                            'queue_wait_s': t0 - t.enqueued_at,
+                            'device_share_s': share,
+                            'device_eval_s': device_eval_s,
+                            'aot_cache': cap.aot,
+                            'coverage_ratio': cap.coverage_ratio,
+                        }
+                for t, row in zip(batch, rows):
+                    t.resolve(row)
         if cap is not None:
-            device_eval_s = cap.stage_s('device_eval')
-            share = device_eval_s / len(batch)
-            batch_id = provenance.next_batch_id()
-            for t in batch:
-                # filled before resolve(): the waiting webhook thread
-                # reads prov right after its future resolves
-                t.prov = {
-                    'batch_id': batch_id,
-                    'occupancy': len(batch),
-                    'queue_wait_s': t0 - t.enqueued_at,
-                    'device_share_s': share,
-                    'device_eval_s': device_eval_s,
-                    'aot_cache': cap.aot,
-                    'coverage_ratio': cap.coverage_ratio,
-                }
-        for t, row in zip(batch, rows):
-            t.resolve(row)
+            wall = time.monotonic() - t_in
+            with self._stats_lock:
+                self._timed += 1
+                self._batch_s += wall
+                for name, seconds in cap.stages.items():
+                    self._stage_s[name] = \
+                        self._stage_s.get(name, 0.0) + seconds
 
     def _shed_batch(self, batch, reason: str) -> None:
         for t in batch:
@@ -465,7 +512,21 @@ class AdmissionBatcher:
             hetero = self._hetero_dispatches
             requests = self._requests
             quarantine = self._quarantine_dispatches
+            # mean milliseconds per timed dispatch, per handled request
+            per = 1000.0 / self._timed if self._timed else 0.0
+            batch_ms = self._batch_s * per
+            stage_ms = {name: seconds * per
+                        for name, seconds in self._stage_s.items()}
+            handler_ms = 1000.0 * self._handler_s / self._handled \
+                if self._handled else 0.0
+        timing = {f'batch_{field}_ms': stage_ms.get(name, 0.0)
+                  for name, field in _BATCH_STAGES}
         return {
+            'batch_ms': batch_ms,
+            **timing,
+            'batch_device_wait_ms': stage_ms.get('device_wait', 0.0),
+            'batch_unnamed_ms': batch_ms - sum(timing.values()),
+            'handler_self_ms': handler_ms,
             'dispatches': dispatches,
             'quarantine_dispatches': quarantine,
             'requests': requests,
@@ -489,6 +550,9 @@ class AdmissionBatcher:
             self._hetero_dispatches = 0
             self._requests = 0
             self._quarantine_dispatches = 0
+            self._timed = self._handled = 0
+            self._batch_s = self._handler_s = 0.0
+            self._stage_s.clear()
         self.sheds.reset()
 
     # -- lifecycle ---------------------------------------------------------

@@ -14,7 +14,7 @@ import collections
 import json
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import yaml
 
@@ -675,7 +675,11 @@ class ResourceHandlers:
         from ..observability import slo
         prov_on = provenance.enabled()
         slo_on = slo.enabled()
-        t_start = time.monotonic() if (prov_on or slo_on) else 0.0
+        t_start = time.monotonic()
+        # where the request rode a batch, the handler's own time around
+        # the batcher: (seconds from entry to submit, when the resolved
+        # ticket came back)
+        own: Optional[Tuple[float, float]] = None
         # decision provenance: which serving path answered this request
         # (batch | sync | shed:<reason> | host_fallback) plus the
         # batch/cache attribution that path produced
@@ -734,9 +738,12 @@ class ResourceHandlers:
                     # into one shared device dispatch
                     # (serving/batcher.py); a shed comes back as None
                     # and the host loop serves
+                    t_submit = time.monotonic()
                     batched, bprov = self._batched_scan(
                         scanner, policies, request, pctx,
                         old_resource=old_doc)
+                    if batched is not None:
+                        own = (t_submit - t_start, time.monotonic())
                     prov_path = bprov.pop('path')
                     prov_extra = bprov
                     prov_extra['fingerprint'] = getattr(
@@ -821,8 +828,8 @@ class ResourceHandlers:
             # GenerateEvents fed to the event controller
             self.event_sink(responses, blocked)
         if blocked:
-            return admission.response(uid, False,
-                                      get_blocked_messages(responses))
+            return self._answered(own, admission.response(
+                uid, False, get_blocked_messages(responses)))
         # async hand-offs: audit-mode policies and generate URs
         if self.audit_sink is not None:
             self.audit_sink(request, responses)
@@ -848,7 +855,24 @@ class ResourceHandlers:
                                              mutate_existing,
                                              ur_type='mutate')
         warnings = get_warning_messages(responses)
-        return admission.response(uid, True, '', warnings)
+        return self._answered(own, admission.response(uid, True, '',
+                                                      warnings))
+
+    def _answered(self, own: Optional[Tuple[float, float]],
+                  response: dict) -> dict:
+        """``response``, on its way out of ``validate``: a request that
+        rode a batch reports the handler's own time around the batcher
+        — ``handler_pre`` (entry to submit) and ``handler_post`` (from
+        the resolved ticket to here) in the stage histogram, their sum
+        to the batcher (``handler_self_ms``)."""
+        if own is not None:
+            from ..observability import device as devtel
+            pre_s, t_back = own
+            post_s = time.monotonic() - t_back
+            devtel.record_stage('handler_pre', pre_s)
+            devtel.record_stage('handler_post', post_s)
+            self._get_batcher().record_handler(pre_s + post_s)
+        return response
 
     def audit_responses(self, request: dict) -> List[EngineResponse]:
         """Audit-mode engine responses for report construction

@@ -214,6 +214,20 @@ class TestNoopWhenUnconfigured:
         after = {t for t in threading.enumerate() if t not in before}
         assert not any(t.name == 'ktpu-d2h-watchdog' for t in after)
         assert devtel.stage_breakdown() == {}
+        # ... and the reports of a scan are the same, bit for bit, with
+        # every sink of the stages on (histogram, spans, the profiler's
+        # marks) as with all of them off
+        docs = [pod(i) for i in range(8)]
+        off = list(scanner.scan_report_results(docs, now=1234.0))
+        tracing.configure()
+        devtel.configure(MetricsRegistry())
+        try:
+            on = list(scanner.scan_report_results(docs, now=1234.0))
+            assert devtel.stage_breakdown()['store']['count'] == 1
+        finally:
+            devtel.disable()
+            tracing.disable()
+        assert on == off
 
     def test_stage_returns_shared_noop(self):
         tracing.disable()
@@ -221,10 +235,34 @@ class TestNoopWhenUnconfigured:
         s1 = devtel.stage('pack')
         s2 = devtel.stage('d2h')
         g = devtel.d2h_guard()
-        assert s1 is s2 is g  # one shared no-op object, no allocation
+        mark = devtel.annotation('store', chunk=0)
+        assert s1 is s2 is g is mark  # one shared no-op, no allocation
         with s1:
             s1.set_attribute('k', 'v')
             s1.add_d2h_bytes(10)
+        devtel.record_stage('store', 1.0)  # no registry, no capture
+        assert devtel.stage_breakdown() == {}
+
+    def test_a_stage_never_loads_jax(self):
+        """The encoder workers' fork server imports compiler/encode.py
+        and nothing of jax; a stage opened there (a capture is
+        installed, so it is a real one) must not change that: the
+        profiler's mark is looked up only where jax is already loaded."""
+        import os
+        import subprocess
+        import sys
+        done = subprocess.run(
+            [sys.executable, '-c',
+             'import sys\n'
+             'import kyverno_tpu.compiler.encode\n'
+             'from kyverno_tpu.observability import device as d\n'
+             'with d.install_capture(d.ScanCapture()) as cap:\n'
+             '    with d.stage("encode"):\n'
+             '        pass\n'
+             'print("jax" in sys.modules, sorted(cap.stages))\n'],
+            capture_output=True, text=True, timeout=120,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+        assert done.stdout.strip() == "False ['encode']", done.stderr
 
     def test_tracing_only_emits_spans_not_series(self, scanner):
         devtel.disable()
@@ -236,3 +274,226 @@ class TestNoopWhenUnconfigured:
             assert devtel.registry() is None
         finally:
             tracing.disable()
+
+
+# -- the stages on the profiler's clock -------------------------------------
+
+CAP = 16     # rows a chunk, so a few dozen pods span several chunks
+WINDOW = 8   # rows a report window, so a chunk spans several windows
+
+#: the consumer thread's stages: with ``unnamed`` they are the reconcile
+OWN_STAGES = ('filter', 'chunk_wait', 'report', 'store', 'flush')
+#: (stage, the identifier its events carry) of a pipelined reconcile ...
+SCAN_MARKS = [('filter', None), ('flush', None), ('chunk_wait', 'chunk'),
+              ('report', 'chunk'), ('store', 'chunk'), ('match', 'chunk'),
+              ('encode_wait', 'chunk'), ('h2d', 'chunk'),
+              ('device_eval', 'chunk'), ('d2h', 'chunk'),
+              ('device_wait', 'chunk'), ('expand', 'chunk')]
+#: ... and of an admission dispatch, whose one chunk runs inline
+BATCH_MARKS = ['prepare', 'match', 'encode', 'h2d', 'device_eval', 'd2h',
+               'device_wait', 'expand', 'report', 'resolve']
+#: stats() fields that split batch_ms, and the two beside them
+SPLIT_FIELDS = ['batch_prepare_ms', 'batch_match_ms', 'batch_encode_ms',
+                'batch_pack_ms', 'batch_h2d_ms', 'batch_dispatch_ms',
+                'batch_d2h_ms', 'batch_expand_ms', 'batch_report_ms',
+                'batch_resolve_ms', 'batch_unnamed_ms']
+TIMING_FIELDS = ['batch_ms'] + SPLIT_FIELDS + ['batch_device_wait_ms',
+                                               'handler_self_ms']
+
+
+def review_bytes(resource, uid):
+    import json
+    return json.dumps({
+        'apiVersion': 'admission.k8s.io/v1', 'kind': 'AdmissionReview',
+        'request': {
+            'uid': uid, 'operation': 'CREATE',
+            'kind': {'group': '', 'version': 'v1', 'kind': 'Pod'},
+            'namespace': 'default', 'name': resource['metadata']['name'],
+            'object': resource,
+            'userInfo': {'username': 'alice', 'groups': []}}}).encode()
+
+
+def marks(path):
+    """The ``ktpu/`` events of a trace: ``(line, stage, start, end, ids)``
+    with ``line`` one thread of the ``/host:CPU`` plane."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for n, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith('ktpu/'):
+                    assert plane.name == '/host:CPU', plane.name
+                    out.append(((plane.name, n), ev.name[len('ktpu/'):],
+                                ev.start_ns, ev.start_ns + ev.duration_ns,
+                                {k: v for k, v in list(ev.stats)}))
+    return out
+
+
+@pytest.fixture(scope='module')
+def profiled(tmp_path_factory):
+    """A warmed multi-chunk reconcile and a few admission requests, with
+    the stage histogram on, then the same again under ``jax.profiler``
+    with the options ``benchmarks/run.py`` traces with.  Telemetry is off
+    again before the first test runs."""
+    import glob
+    import os
+
+    import jax
+
+    from kyverno_tpu.config.config import Configuration
+    from kyverno_tpu.dclient.client import FakeClient
+    from kyverno_tpu.policycache import cache as pcache
+    from kyverno_tpu.reports.controllers import BackgroundScanController
+    from kyverno_tpu.webhooks.handlers import ResourceHandlers
+    from kyverno_tpu.webhooks.server import WebhookServer
+    patch = pytest.MonkeyPatch()
+    patch.setenv('KTPU_VERDICT_CACHE_DIR',
+                 str(tmp_path_factory.mktemp('verdicts')))
+    patch.setenv('KTPU_ENCODE_PROCS', '2')
+    tracing.disable()
+    reg = devtel.configure(MetricsRegistry())
+    try:
+        docs = [pod(i) for i in range(12 * CAP + 5)]
+        for i, d in enumerate(docs):
+            d['metadata']['uid'] = f'uid-{i}'
+        ctrl = BackgroundScanController(FakeClient(), [Policy(POLICY)])
+        ctrl.scanner.CHUNK = CAP
+        ctrl.scanner.REPORT_FLUSH_ROWS = WINDOW
+        cache = pcache.Cache()
+        cache.warm_up([Policy(POLICY)])
+        handlers = ResourceHandlers(cache, configuration=Configuration(),
+                                    serving_mode='batch')
+        server = WebhookServer(handlers, configuration=Configuration())
+        enforce = cache.get_policies(pcache.VALIDATE_ENFORCE, 'Pod',
+                                     'default')
+        assert handlers.wait_device_ready(enforce, timeout=600)
+        batcher = handlers._get_batcher()
+        fresh = batcher.stats()
+
+        def reconcile(now):
+            # a new image on every pod: the verdict cache misses, so
+            # every pass scans
+            ctrl.reset_scan_state()
+            for d in docs:
+                d['spec']['containers'][0]['image'] = f'nginx:{now}'
+                ctrl.enqueue(d)
+            assert len(ctrl.reconcile(now=now)) == len(docs)
+            assert ctrl.rescan_stats['rows_scanned'] == len(docs)
+
+        def admit(n):
+            for i in range(n):
+                server.handle('/validate/fail',
+                              review_bytes(pod(i), f'u{i}'))
+
+        def histogram():
+            # stage_breakdown()'s numbers, unrounded
+            return {dict(key)['stage']: (count, total)
+                    for key, count, total in reg.histogram_series(
+                        devtel.SCAN_STAGE_DURATION)}
+
+        # warm: the executables, the encoder pool, the batcher's thread
+        reconcile(1000.0)
+        admit(2)
+        before = histogram()
+        batcher.reset_stats()
+        reconcile(2000.0)
+        stages = {name: {'count': count - before.get(name, (0, 0))[0],
+                         'total_s': total - before.get(name, (0, 0))[1]}
+                  for name, (count, total) in histogram().items()}
+        admit(6)
+        stats = batcher.stats()
+        batcher.reset_stats()
+        after_reset = batcher.stats()
+
+        out_dir = str(tmp_path_factory.mktemp('trace'))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(out_dir, profiler_options=options)
+        try:
+            reconcile(3000.0)
+            admit(3)
+        finally:
+            jax.profiler.stop_trace()
+        handlers.shutdown()
+        [path] = glob.glob(os.path.join(out_dir, '**', '*.xplane.pb'),
+                           recursive=True)
+        return {'marks': marks(path), 'stages': stages, 'fresh': fresh,
+                'stats': stats, 'after_reset': after_reset,
+                'chunks': -(-len(docs) // CAP), 'rows': len(docs)}
+    finally:
+        devtel.disable()
+        patch.undo()
+
+
+class TestStagesOnTheProfilersClock:
+    @pytest.mark.parametrize('stage, key', SCAN_MARKS)
+    def test_reconcile_stage_is_in_the_trace(self, profiled, stage, key):
+        """Each leaf stage of a pipelined reconcile is an event on a
+        host thread's line, carrying the chunk it belongs to."""
+        found = [m for m in profiled['marks']
+                 if m[1] == stage and 'batch' not in m[4]]
+        assert found, stage
+        if key is not None:
+            assert {m[4][key] for m in found} <= \
+                set(range(profiled['chunks'])), stage
+        # once a chunk or a window, never once a row
+        assert len(found) <= -(-profiled['rows'] // WINDOW) + \
+            profiled['chunks'], stage
+
+    @pytest.mark.parametrize('stage', BATCH_MARKS)
+    def test_admission_stage_is_in_the_trace(self, profiled, stage):
+        """Each leaf stage of a dispatch is an event on the batcher's
+        line, carrying the dispatch's serial and its rows."""
+        found = [m for m in profiled['marks']
+                 if m[1] == stage and 'batch' in m[4]]
+        assert found, stage
+        assert all(m[4]['rows'] >= 1 for m in found)
+        assert len({m[0] for m in found}) == 1  # one thread: the batcher
+        assert len({m[4]['batch'] for m in found}) == len(found)
+
+    def test_only_d2h_encloses_another_stage(self, profiled):
+        """Leaf stages only: ``label_gap`` names a gap by the one host
+        event that covers most of it, so an enclosing event would give
+        its name to every gap."""
+        by_line = {}
+        for line, stage, start, end, _ids in profiled['marks']:
+            by_line.setdefault(line, []).append((start, end, stage))
+        enclosing = {(a[2], b[2])
+                     for events in by_line.values()
+                     for a in events for b in events
+                     if a is not b and a[0] <= b[0] and b[1] <= a[1]}
+        assert enclosing == {('d2h', 'device_wait')}
+
+    def test_reconcile_wall_is_the_sum_of_this_threads_stages(
+            self, profiled):
+        stages = profiled['stages']
+        assert stages['reconcile']['count'] == 1
+        assert stages['store']['count'] == stages['report']['count'] \
+            == -(-CAP // WINDOW) * (profiled['chunks'] - 1) + 1
+        assert stages['chunk_wait']['count'] == profiled['chunks']
+        wall = stages['reconcile']['total_s']
+        parts = sum(stages[s]['total_s']
+                    for s in OWN_STAGES + ('unnamed',))
+        assert wall == pytest.approx(parts, rel=1e-9)
+        assert 0 <= stages['unnamed']['total_s'] < 0.10 * wall
+
+    @pytest.mark.parametrize('field', TIMING_FIELDS)
+    def test_batcher_stats_field(self, profiled, field):
+        """Flat numbers (the benchmark's webhook driver drops anything
+        else), 0.0 on a fresh batcher and after ``reset_stats``."""
+        assert profiled['fresh'][field] == 0.0
+        assert profiled['after_reset'][field] == 0.0
+        value = profiled['stats'][field]
+        assert isinstance(value, float) and value >= 0.0
+        if field not in ('batch_prepare_ms', 'batch_unnamed_ms'):
+            assert value > 0.0
+
+    def test_batcher_stats_split_the_dispatch(self, profiled):
+        stats = profiled['stats']
+        assert stats['dispatches'] >= 1
+        assert sum(stats[f] for f in SPLIT_FIELDS) == \
+            pytest.approx(stats['batch_ms'], rel=1e-9)
+        assert stats['batch_device_wait_ms'] <= stats['batch_d2h_ms']
+        # no share is asked of batch_unnamed_ms here: with one rule a
+        # dispatch takes a millisecond or two, most of it fixed cost
