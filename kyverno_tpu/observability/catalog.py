@@ -365,4 +365,6 @@ PIPELINE_STAGES: Dict[str, str] = {
                    '(histogram only).',
     'handler_post': 'validate(): resolved ticket to return '
                     '(histogram only).',
+    'deny_message': 'The denial message of one denied request, on its '
+                    'thread (inside handler_post; histogram only).',
 }

@@ -53,11 +53,13 @@ STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 #: ``report``, ``store``, ``flush``, and per reconcile ``reconcile``
 #: (its wall) and ``unnamed`` (that wall minus the five before it).
 #: The admission batch's own: ``prepare``, ``resolve``,
-#: ``handler_pre``, ``handler_post``.
+#: ``handler_pre``, ``handler_post``, and per denied request
+#: ``deny_message`` (inside ``handler_post`` where it rode a batch).
 STAGES = ('match', 'encode', 'encode_wait', 'pack', 'h2d', 'compile',
           'device_eval', 'd2h', 'device_wait', 'expand', 'filter',
           'chunk_wait', 'report', 'store', 'flush', 'reconcile',
-          'unnamed', 'prepare', 'resolve', 'handler_pre', 'handler_post')
+          'unnamed', 'prepare', 'resolve', 'handler_pre', 'handler_post',
+          'deny_message')
 
 _log = logging.getLogger('kyverno.device')
 

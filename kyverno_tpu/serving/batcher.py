@@ -202,6 +202,7 @@ class AdmissionBatcher:
         self._stage_s: Dict[str, float] = {}
         self._handled = 0
         self._handler_s = 0.0
+        self._message_s = 0.0
         # consecutive all-failed dispatch count per key; touched only
         # by the batcher thread (dispatches are serialized), reset the
         # moment any rider of the key resolves on device
@@ -248,13 +249,17 @@ class AdmissionBatcher:
     def record_shed(self, reason: str) -> None:
         self.sheds.record(reason)
 
-    def record_handler(self, seconds: float) -> None:
+    def record_handler(self, seconds: float,
+                       message_s: float = 0.0) -> None:
         """One request's time in its handler outside the batcher: from
         the handler's entry to ``submit`` and from the resolved ticket
-        to its return (``handler_self_ms``)."""
+        to its return (``handler_self_ms``).  Of it, where the request
+        was denied, ``message_s`` went to the denial message
+        (``handler_message_ms``)."""
         with self._stats_lock:
             self._handled += 1
             self._handler_s += seconds
+            self._message_s += message_s
 
     # -- the coalescing loop ----------------------------------------------
 
@@ -517,8 +522,9 @@ class AdmissionBatcher:
             batch_ms = self._batch_s * per
             stage_ms = {name: seconds * per
                         for name, seconds in self._stage_s.items()}
-            handler_ms = 1000.0 * self._handler_s / self._handled \
-                if self._handled else 0.0
+            per_handled = 1000.0 / self._handled if self._handled else 0.0
+            handler_ms = self._handler_s * per_handled
+            message_ms = self._message_s * per_handled
         timing = {f'batch_{field}_ms': stage_ms.get(name, 0.0)
                   for name, field in _BATCH_STAGES}
         return {
@@ -527,6 +533,7 @@ class AdmissionBatcher:
             'batch_device_wait_ms': stage_ms.get('device_wait', 0.0),
             'batch_unnamed_ms': batch_ms - sum(timing.values()),
             'handler_self_ms': handler_ms,
+            'handler_message_ms': message_ms,
             'dispatches': dispatches,
             'quarantine_dispatches': quarantine,
             'requests': requests,
@@ -551,7 +558,7 @@ class AdmissionBatcher:
             self._requests = 0
             self._quarantine_dispatches = 0
             self._timed = self._handled = 0
-            self._batch_s = self._handler_s = 0.0
+            self._batch_s = self._handler_s = self._message_s = 0.0
             self._stage_s.clear()
         self.sheds.reset()
 

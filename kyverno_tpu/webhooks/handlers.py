@@ -73,14 +73,171 @@ def _yaml_scalar(s: str) -> str:
 _CTRL_CHAR_RE = _re.compile(r'[\x00-\x1f]')
 
 
+# -- a map of strings as yaml.safe_dump writes it ----------------------------
+# PyYAML's emitter (yaml/emitter.py) walks every scalar a character at a
+# time, ~0.2ms a rule message; these are its rules for strings (block
+# context, 80 columns, ASCII output), a regex search where it has a loop.
+# tests/test_deny_message.py holds them to its bytes.
+
+_WIDTH = 80  # the emitter's best_width
+#: only a double-quoted scalar carries these: a character outside
+#: printable ASCII and ``\n``, a space before a break or after one
+#: (analyze_scalar: special_characters, space_break, break_space)
+_DOUBLE_ONLY_RE = _re.compile(r'[^\n\x20-\x7e]| \n|\n ')
+#: what keeps a scalar that may be single-quoted from being plain
+#: (analyze_scalar: block_indicators, leading and trailing spaces, breaks)
+_NOT_PLAIN_RE = _re.compile(
+    r"""\A(?:---|\.\.\.|[#,\[\]{}&*!|>'"%@`]|[?-](?: |\Z)| )"""
+    r"""|:(?: |\Z)| #| \Z|\n""")
+#: where a plain or single-quoted line may fold: one space between words
+_FOLD_RE = _re.compile(r'(?<=[^ ]) (?=[^ ])')
+#: in a double-quoted scalar, a space or what is written escaped
+_DQ_EVENT_RE = _re.compile(r'[^\x21-\x7e]|["\\]')
+_BREAK_RE = _re.compile('[\n\x85\u2028\u2029]')
+_ESCAPES = yaml.emitter.Emitter.ESCAPE_REPLACEMENTS
+_IMPLICIT = yaml.SafeDumper.yaml_implicit_resolvers
+
+
+def _reads_as_str(s: str) -> bool:
+    """Whether YAML 1.1 reads plain ``s`` back as a string (the
+    resolver's own table: not a bool, a number, a null, a date)."""
+    return not any(regexp.match(s) for _tag, regexp in
+                   _IMPLICIT.get(s[:1], []) + _IMPLICIT.get(None, []))
+
+
+def _is_simple_key(s: str) -> bool:
+    """check_simple_key: on one line, and short with its ``!!str``."""
+    return 0 < len(s) < 123 and not _BREAK_RE.search(s)
+
+
+def _fold(line: str, col: int, indent: int) -> str:
+    """``line`` (no break in it) from column ``col`` on, folded as
+    write_plain and write_single_quoted fold: at the first lone space
+    past the width, onto a line indented by ``indent``."""
+    if col + len(line) <= _WIDTH + 1:
+        return line
+    lines = []
+    pos = 0
+    while True:
+        space = _FOLD_RE.search(line, max(pos, pos + _WIDTH + 1 - col))
+        if space is None:
+            lines.append(line[pos:])
+            return ('\n' + ' ' * indent).join(lines)
+        lines.append(line[pos:space.start()])
+        pos = space.end()
+        col = indent
+
+
+def _double_quoted(text: str, col: int, indent: int, split: bool) -> str:
+    """write_double_quoted, with its loop's ``start``, ``end`` and
+    column; ``end`` skips what the loop passes over untouched."""
+    out = ['"']
+    col += 1
+    n = len(text)
+    start = end = 0
+    while end <= n:
+        ch = text[end] if end < n else None
+        if ch is None or ch in '"\\' or not ' ' <= ch <= '~':
+            if start < end:
+                out.append(text[start:end])
+                col += end - start
+                start = end
+            if ch is not None:
+                if ch in _ESCAPES:
+                    data = '\\' + _ESCAPES[ch]
+                elif ch <= '\xFF':
+                    data = '\\x%02X' % ord(ch)
+                elif ch <= '\uFFFF':
+                    data = '\\u%04X' % ord(ch)
+                else:
+                    data = '\\U%08X' % ord(ch)
+                out.append(data)
+                col += len(data)
+                start = end + 1
+        if 0 < end < n - 1 and (ch == ' ' or start >= end) \
+                and col + (end - start) > _WIDTH and split:
+            out.append(text[start:end] + '\\\n' + ' ' * indent)
+            if start < end:
+                start = end
+            col = indent
+            if text[start] == ' ':
+                out.append('\\')
+                col += 1
+        end += 1
+        if start < end < n:
+            # nothing is written before the next space, escape or the end
+            event = _DQ_EVENT_RE.search(text, end)
+            end = event.start() if event else n
+    out.append('"')
+    return ''.join(out)
+
+
+def _yaml_str(s: str, col: int, indent: int, split: bool = True) -> str:
+    """The string ``s`` as the emitter writes it from column ``col`` on:
+    plain if it may be (choose_scalar_style), else single-quoted, else
+    double-quoted; folded onto lines indented by ``indent`` unless it is
+    a simple key (``split`` false)."""
+    if _DOUBLE_ONLY_RE.search(s):
+        return _double_quoted(s, col, indent, split)
+    if not s:
+        return "''"
+    if not _NOT_PLAIN_RE.search(s) and _reads_as_str(s):
+        return _fold(s, col, indent) if split else s
+    s = s.replace("'", "''")
+    if not split:
+        return f"'{s}'"
+    col += 1
+    out = ["'"]
+    # write_single_quoted: a run of k breaks is written as k+1 and the
+    # indent (no space stands next to a break here)
+    for i, part in enumerate(_re.split('(\n+)', s)):
+        if i % 2:
+            out.append(part + '\n' + ' ' * indent)
+            col = indent
+        else:
+            out.append(_fold(part, col, indent))
+    out.append("'")
+    return ''.join(out)
+
+
+def _dump_as_emitter(failures: Dict[str, Dict[str, str]]) -> str:
+    """``yaml.safe_dump(failures, default_flow_style=False)``, byte for
+    byte, where every policy has a map of its own with a rule in it (a
+    map met twice would be written as an alias, an empty one as ``{}``).
+    A name that is no simple key (empty, of several lines, long) is
+    written ``? name`` with its value on the next line, after ``: ``."""
+    out = []
+    for pol in sorted(failures):
+        if _is_simple_key(pol):
+            out.append(_yaml_str(pol, 0, 2, split=False) + ':\n')
+            lead = '  '
+        else:
+            out.append(f'? {_yaml_str(pol, 2, 2)}\n')
+            lead = ': '
+        rules = failures[pol]
+        for rule in sorted(rules):
+            if _is_simple_key(rule):
+                key = _yaml_str(rule, 2, 4, split=False)
+                value = _yaml_str(rules[rule], len(key) + 4, 4)
+                out.append(f'{lead}{key}: {value}\n')
+            else:
+                out.append(f'{lead}? {_yaml_str(rule, 4, 4)}\n'
+                           f'  : {_yaml_str(rules[rule], 4, 4)}\n')
+            lead = '  '
+    return ''.join(out)
+
+
 def _dump_failures(failures: Dict[str, Dict[str, str]]) -> str:
-    # multi-line / control-character scalars need real YAML escaping —
-    # rare enough that the slow emitter handles the whole map then
+    # multi-line / control-character scalars need real YAML escaping:
+    # the whole map is written as the YAML emitter writes it then.  Every
+    # PSS message ends in a newline (pss/evaluate.py format_checks_print),
+    # so a pack with a podSecurity rule always comes this way.  The branch
+    # decides the bytes (only the emitter folds long lines), so it stays
     search = _CTRL_CHAR_RE.search
     for rules in failures.values():
         for k, v in rules.items():
             if search(k) or search(v):
-                return yaml.safe_dump(failures, default_flow_style=False)
+                return _dump_as_emitter(failures)
     lines = []
     for pol in sorted(failures):
         lines.append(f'{_yaml_scalar(pol)}:')
@@ -828,8 +985,7 @@ class ResourceHandlers:
             # GenerateEvents fed to the event controller
             self.event_sink(responses, blocked)
         if blocked:
-            return self._answered(own, admission.response(
-                uid, False, get_blocked_messages(responses)))
+            return self._answered(own, *self._denied(uid, responses))
         # async hand-offs: audit-mode policies and generate URs
         if self.audit_sink is not None:
             self.audit_sink(request, responses)
@@ -858,20 +1014,34 @@ class ResourceHandlers:
         return self._answered(own, admission.response(uid, True, '',
                                                       warnings))
 
-    def _answered(self, own: Optional[Tuple[float, float]],
-                  response: dict) -> dict:
+    @staticmethod
+    def _denied(uid: str,
+                responses: List[EngineResponse]) -> Tuple[dict, float]:
+        """The denial that ``responses`` block, and the seconds its
+        message took on this thread (stage ``deny_message``, one sample
+        a denied request)."""
+        from ..observability import device as devtel
+        t0 = time.monotonic()
+        message = get_blocked_messages(responses)
+        message_s = time.monotonic() - t0
+        devtel.record_stage('deny_message', message_s)
+        return admission.response(uid, False, message), message_s
+
+    def _answered(self, own: Optional[Tuple[float, float]], response: dict,
+                  message_s: float = 0.0) -> dict:
         """``response``, on its way out of ``validate``: a request that
         rode a batch reports the handler's own time around the batcher
         — ``handler_pre`` (entry to submit) and ``handler_post`` (from
         the resolved ticket to here) in the stage histogram, their sum
-        to the batcher (``handler_self_ms``)."""
+        to the batcher (``handler_self_ms``), and with it what a
+        denial's message took of it (``handler_message_ms``)."""
         if own is not None:
             from ..observability import device as devtel
             pre_s, t_back = own
             post_s = time.monotonic() - t_back
             devtel.record_stage('handler_pre', pre_s)
             devtel.record_stage('handler_post', post_s)
-            self._get_batcher().record_handler(pre_s + post_s)
+            self._get_batcher().record_handler(pre_s + post_s, message_s)
         return response
 
     def audit_responses(self, request: dict) -> List[EngineResponse]:
@@ -983,8 +1153,7 @@ class ResourceHandlers:
                         f'{e}')
         responses.append(er)
         if er.is_error() and failure_policy == 'Fail':
-            return admission.response(
-                uid, False, get_blocked_messages(responses))
+            return self._denied(uid, responses)[0]
         return None
 
     def _device_mutate_steps(self, request: dict, pctx,
@@ -1103,7 +1272,6 @@ class ResourceHandlers:
             patches.extend(iv_patches)
             responses.append(er)
             if er.is_failed():
-                return admission.response(
-                    uid, False, get_blocked_messages(responses))
+                return self._denied(uid, responses)[0]
         warnings = get_warning_messages(responses)
         return admission.mutation_response(uid, patches, warnings)

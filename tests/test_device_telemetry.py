@@ -298,7 +298,8 @@ SPLIT_FIELDS = ['batch_prepare_ms', 'batch_match_ms', 'batch_encode_ms',
                 'batch_d2h_ms', 'batch_expand_ms', 'batch_report_ms',
                 'batch_resolve_ms', 'batch_unnamed_ms']
 TIMING_FIELDS = ['batch_ms'] + SPLIT_FIELDS + ['batch_device_wait_ms',
-                                               'handler_self_ms']
+                                               'handler_self_ms',
+                                               'handler_message_ms']
 
 
 def review_bytes(resource, uid):
@@ -400,10 +401,16 @@ def profiled(tmp_path_factory):
         stages = {name: {'count': count - before.get(name, (0, 0))[0],
                          'total_s': total - before.get(name, (0, 0))[1]}
                   for name, (count, total) in histogram().items()}
+        denials = histogram().get('deny_message', (0, 0.0))[0]
         admit(6)
         stats = batcher.stats()
+        denials = histogram()['deny_message'][0] - denials
         batcher.reset_stats()
         after_reset = batcher.stats()
+        for i in (1, 3, 5):  # the pods with the label: all allowed
+            server.handle('/validate/fail', review_bytes(pod(i), f'a{i}'))
+        all_allowed = batcher.stats()
+        batcher.reset_stats()
 
         out_dir = str(tmp_path_factory.mktemp('trace'))
         options = jax.profiler.ProfileOptions()
@@ -420,6 +427,7 @@ def profiled(tmp_path_factory):
                            recursive=True)
         return {'marks': marks(path), 'stages': stages, 'fresh': fresh,
                 'stats': stats, 'after_reset': after_reset,
+                'denials': denials, 'all_allowed': all_allowed,
                 'chunks': -(-len(docs) // CAP), 'rows': len(docs)}
     finally:
         devtel.disable()
@@ -488,6 +496,21 @@ class TestStagesOnTheProfilersClock:
         assert isinstance(value, float) and value >= 0.0
         if field not in ('batch_prepare_ms', 'batch_unnamed_ms'):
             assert value > 0.0
+
+    def test_the_denial_message_is_part_of_the_handlers_own_time(
+            self, profiled):
+        """``handler_message_ms`` has ``handler_self_ms``'s denominator
+        (every handled request, denied or not), so the two subtract; a
+        window of allowed requests reads 0.0; the stage is sampled once
+        a denied request (of ``admit(6)``, the three pods without the
+        label), never once a policy."""
+        stats = profiled['stats']
+        assert 0.0 < stats['handler_message_ms'] <= stats['handler_self_ms']
+        allowed = profiled['all_allowed']
+        assert allowed['handler_self_ms'] > 0.0
+        assert allowed['handler_message_ms'] == 0.0
+        assert 'deny_message' in devtel.STAGES
+        assert profiled['denials'] == 3
 
     def test_batcher_stats_split_the_dispatch(self, profiled):
         stats = profiled['stats']
