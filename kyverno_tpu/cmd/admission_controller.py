@@ -93,8 +93,9 @@ class AdmissionController:
         span reports how many shapes came up."""
         from ..policycache import cache as pcache
         self.sync_policies()
-        enforce = self.cache.get_policies(pcache.VALIDATE_ENFORCE,
-                                          'Pod', '')
+        # the set the validate path compiles and keys its scanner on:
+        # cluster-wide policies and every namespace's own
+        enforce = self.cache.get_installed(pcache.VALIDATE_ENFORCE, 'Pod')
         if not enforce:
             return 'no enforce policies installed'
         if not self.handlers.device:

@@ -314,6 +314,23 @@ class BatchScanner:
         self._match_cache_max = 4096
         self._match_cache_lock = __import__('threading').Lock()
         self._rules = [Rule(p.rule_raw or {}) for p in self.cps.programs]
+        # which programs can match a resource of one namespace at all:
+        # those of the cluster-wide policies and of the namespace's own
+        # (policy_namespace_gate).  Built once, so that the sieve walks
+        # the programs that can apply to a row and not the whole set; a
+        # set without namespaced policies has no index and walks it all
+        self._ns_programs: Dict[str, List[int]] = {}
+        for j, prog in enumerate(self.cps.programs):
+            policy = policies[prog.policy_index]
+            if policy.is_namespaced:
+                self._ns_programs.setdefault(policy.namespace,
+                                             []).append(j)
+        self._candidate_masks: Dict[str, np.ndarray] = {}
+        if self._ns_programs:
+            cluster = np.ones(len(self.cps.programs), bool)
+            for js in self._ns_programs.values():
+                cluster[js] = False
+            self._candidate_masks[''] = cluster
         self._fail_msg_cache: Dict[Tuple, Optional[str]] = {}
         # encode workers only pay off with spare cores: on a
         # single-CPU host the ~150MB/chunk lane tensors pickled back
@@ -432,6 +449,29 @@ class BatchScanner:
     def _policy_gate(self, policy: Policy, res: Resource) -> bool:
         return policy_namespace_gate(policy, res)
 
+    def _candidates(self, namespace: str) -> Optional[np.ndarray]:
+        """bool[P]: the programs that can match a resource of
+        ``namespace`` (the cluster-wide policies' and that namespace's
+        own); None where the set has no namespaced policy and every
+        program can."""
+        masks = self._candidate_masks
+        if not masks:
+            return None
+        if namespace not in self._ns_programs:
+            return masks['']
+        mask = masks.get(namespace)
+        if mask is None:
+            mask = masks[''].copy()
+            mask[self._ns_programs[namespace]] = True
+            masks[namespace] = mask
+        return mask
+
+    def _of_namespace(self, js: np.ndarray, namespace: str) -> List[int]:
+        """Those of the program indexes ``js`` that can match a
+        resource of ``namespace``."""
+        can = self._candidates(namespace)
+        return (js if can is None else js[can[js]]).tolist()
+
     def _match_one(self, j: int, res: Resource,
                    admission: Optional[tuple] = None) -> bool:
         prog = self.cps.programs[j]
@@ -515,6 +555,7 @@ class BatchScanner:
         if p == 0:
             return match
         simple = np.asarray(self._simple_match)
+        simple_js = np.flatnonzero(simple)
         if adm_rows is None and admission is not None:
             adm_rows = [admission] * n
         if adm_rows is not None:
@@ -535,9 +576,9 @@ class BatchScanner:
             if cached is None:
                 rep = wrapped[idxs[0]]
                 rep_adm = adm3s[idxs[0]]
-                cached = np.array([
-                    self._match_one(j, rep, rep_adm) if simple[j] else False
-                    for j in range(p)])
+                cached = np.zeros(p, bool)
+                for j in self._of_namespace(simple_js, rep.namespace):
+                    cached[j] = self._match_one(j, rep, rep_adm)
                 self._mcache_put(key, cached)
             match[idxs, :] = cached
         # label-selector rules: the decision depends only on (group,
@@ -551,9 +592,11 @@ class BatchScanner:
                         tuple(sorted(labels.items())))
                 cached = self._mcache_get(lkey)
                 if cached is None:
-                    cached = np.array([
-                        self._match_one(int(j), wrapped[i], adm3s[i])
-                        for j in label_js])
+                    row = np.zeros(p, bool)
+                    for j in self._of_namespace(label_js,
+                                                wrapped[i].namespace):
+                        row[j] = self._match_one(j, wrapped[i], adm3s[i])
+                    cached = row[label_js]
                     self._mcache_put(lkey, cached)
                 match[i, label_js] = cached
         # remaining non-simple rules (names, annotations, wildcard
@@ -564,16 +607,13 @@ class BatchScanner:
         dev_cols: Dict[int, int] = {}
         if plan is not None and self._adm_cols is not None:
             dev_cols = {int(j): c for c, j in enumerate(self._adm_cols)}
-        for j in np.nonzero(rest)[0]:
-            j = int(j)
-            c = dev_cols.get(j)
-            if c is not None:
-                up = plan.upper[:, c]
-                for i in range(n):
-                    match[i, j] = up[i] if plan.valid[i] else \
-                        self._match_one(j, wrapped[i], adm3s[i])
-            else:
-                for i in range(n):
+        rest_js = np.flatnonzero(rest)
+        for i in range(n if rest_js.size else 0):
+            for j in self._of_namespace(rest_js, wrapped[i].namespace):
+                c = dev_cols.get(j)
+                if c is not None and plan.valid[i]:
+                    match[i, j] = plan.upper[i, c]
+                else:
                     match[i, j] = self._match_one(j, wrapped[i], adm3s[i])
         return match
 
@@ -600,16 +640,13 @@ class BatchScanner:
         match = match.copy()
         ridx = np.asarray(rows)
         match[ridx] |= om
-        progs = self.cps.programs
-        for j in range(len(progs)):
-            policy = self.policies[progs[j].policy_index]
-            if not policy.is_namespaced:
-                continue  # both-object gate is vacuous
+        if self._ns_programs:
+            # the both-object gate, vacuous for cluster-wide policies:
+            # a row keeps the programs both of its objects' namespaces
+            # admit, which are those of one namespace or of none
             for k, i in enumerate(rows):
-                if match[i, j] and not (
-                        self._policy_gate(policy, wrapped[i]) and
-                        self._policy_gate(policy, old_wrapped[k])):
-                    match[i, j] = False
+                match[i] &= self._candidates(wrapped[i].namespace) & \
+                    self._candidates(old_wrapped[k].namespace)
         return match
 
     # -- device evaluation --------------------------------------------------
@@ -1353,10 +1390,12 @@ class BatchScanner:
                                    None if rr is None or rr is _HOST
                                    else rr))
         else:
-            for j, prog in self.device_programs:
+            # the columns some row of the chunk matched: with namespaced
+            # policies most of the set matches nothing here
+            live = np.flatnonzero(sub_match.any(axis=0) & self._dev_mask)
+            for j in live.tolist():
+                prog = progs[j]
                 rows = np.flatnonzero(sub_match[:, j])
-                if rows.size == 0:
-                    continue
                 p_idx = prog.policy_index
                 if background_mode and not background_ok[j]:
                     # background-disabled policies contribute an empty

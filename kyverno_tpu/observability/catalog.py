@@ -361,6 +361,9 @@ PIPELINE_STAGES: Dict[str, str] = {
                'flush (histogram only).',
     'prepare': 'Admission scan: wrapping + per-row admission lanes.',
     'resolve': 'Admission batch: every rider\'s ticket resolved.',
+    'candidates': 'validate(): the policies that apply to the request '
+                  'and the installed set\'s key, before handler_pre '
+                  '(histogram only).',
     'handler_pre': 'validate(): entry to the batcher\'s submit '
                    '(histogram only).',
     'handler_post': 'validate(): resolved ticket to return '

@@ -52,14 +52,16 @@ STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 #: ``expand``.  The consumer thread's: ``filter``, ``chunk_wait``,
 #: ``report``, ``store``, ``flush``, and per reconcile ``reconcile``
 #: (its wall) and ``unnamed`` (that wall minus the five before it).
-#: The admission batch's own: ``prepare``, ``resolve``,
+#: The admission batch's own: ``prepare``, ``resolve``, and on the
+#: request's thread ``candidates`` (before ``handler_pre``: the
+#: policies that apply to the request and the installed set's key),
 #: ``handler_pre``, ``handler_post``, and per denied request
 #: ``deny_message`` (inside ``handler_post`` where it rode a batch).
 STAGES = ('match', 'encode', 'encode_wait', 'pack', 'h2d', 'compile',
           'device_eval', 'd2h', 'device_wait', 'expand', 'filter',
           'chunk_wait', 'report', 'store', 'flush', 'reconcile',
-          'unnamed', 'prepare', 'resolve', 'handler_pre', 'handler_post',
-          'deny_message')
+          'unnamed', 'prepare', 'resolve', 'candidates', 'handler_pre',
+          'handler_post', 'deny_message')
 
 _log = logging.getLogger('kyverno.device')
 

@@ -73,6 +73,8 @@ class Cache:
         self._policies: Dict[str, Policy] = {}
         # kind -> PolicyType -> set of policy keys
         self._kind_type: Dict[str, Dict[str, Set[str]]] = {}
+        # (PolicyType, kind) -> the installed list, until the next write
+        self._installed: Dict[tuple, List[Policy]] = {}
         self._on_change = on_change
 
     # -- writes --------------------------------------------------------------
@@ -143,6 +145,7 @@ class Cache:
             self._on_change()
 
     def _unset_locked(self, key: str) -> None:
+        self._installed.clear()
         self._policies.pop(key, None)
         for buckets in self._kind_type.values():
             for bucket in buckets.values():
@@ -168,9 +171,28 @@ class Cache:
                       if _check_overrides(enforce, namespace, p)]
         return result
 
-    def _get(self, policy_type: str, gvk: str, namespace: str
+    def get_installed(self, policy_type: str, kind: str) -> List[Policy]:
+        """Every policy of ``policy_type`` installed for ``kind``: the
+        cluster-wide ones, then every namespace's own, in the order
+        ``get_policies`` gives the part of them that applies to one
+        namespace.  What a compiled set serves whatever namespace a
+        request comes from; which of them apply to a request stays
+        ``get_policies``' to say.  The same list object until a policy
+        is set or unset, and not the caller's to change."""
+        with self._lock:
+            installed = self._installed.get((policy_type, kind))
+            if installed is None:
+                installed = self._get(policy_type, kind, '') + \
+                    self._get(policy_type, '*', '') + \
+                    self._get(policy_type, kind, None) + \
+                    self._get(policy_type, '*', None)
+                self._installed[(policy_type, kind)] = installed
+            return installed
+
+    def _get(self, policy_type: str, gvk: str, namespace: Optional[str]
              ) -> List[Policy]:
-        """reference: store.go:149 policyMap.get"""
+        """reference: store.go:149 policyMap.get; ``namespace`` None
+        gives the policies of every namespace"""
         kind = _compute_kind(gvk)
         out = []
         for key in sorted(self._kind_type.get(kind, {})
@@ -179,7 +201,10 @@ class Cache:
             policy = self._policies.get(key)
             if policy is None:
                 continue
-            if not ns and not namespace:
+            if namespace is None:
+                if ns:
+                    out.append(policy)
+            elif not ns and not namespace:
                 out.append(policy)
             elif ns == namespace:
                 out.append(policy)

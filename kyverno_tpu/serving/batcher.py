@@ -1,4 +1,4 @@
-"""The micro-batching loop: coalesce same-policy-set admission scans.
+"""The micro-batching loop: coalesce the admission scans of one scanner.
 
 One daemon thread watches the bounded queue.  It picks the oldest
 pending ticket, waits until that ticket's flush window expires
@@ -8,8 +8,13 @@ capacity, ``compiler/shapes.py``), then dispatches all claimed tickets
 of that key as ONE ``scanner.scan`` call and resolves their futures
 row by row.
 
-The coalescing key is the SCANNER ALONE (its monotonic serial): the
-scanner threads each rider's admission tuple through the compiled
+The coalescing key is the SCANNER ALONE (its monotonic serial).  The
+validate path compiles one scanner for the installed set of a kind —
+the cluster-wide policies and every namespace's own
+(``policycache.Cache.get_installed``) — so requests of different
+namespaces ride one dispatch, and the scanner's match sieve gives each
+row the policies of its own namespace.  The scanner threads each
+rider's admission tuple through the compiled
 pipeline as per-row lanes (``compiler/admission.py``), so mixed-user,
 mixed-role, mixed-verb bursts — the shape of real cluster traffic —
 share one dispatch instead of degenerating to batch-of-one.  Scanners
@@ -101,6 +106,11 @@ _BATCH_STAGES = (('prepare', 'prepare'), ('match', 'match'),
                  ('device_eval', 'dispatch'), ('d2h', 'd2h'),
                  ('expand', 'expand'), ('report', 'report'),
                  ('resolve', 'resolve'))
+
+#: why the host loop answered a request that asked for the compiled
+#: path: the set's scanner was still compiling, its breaker was not
+#: closed (or the scan raised in the handler), the batcher shed it
+HOST_LOOP_REASONS = ('building', 'breaker', 'shed')
 
 #: consecutive all-failed dispatches of one key before poison-only
 #: evidence escalates to a breaker failure anyway: poison sheds are
@@ -203,6 +213,19 @@ class AdmissionBatcher:
         self._handled = 0
         self._handler_s = 0.0
         self._message_s = 0.0
+        # the requests that asked for the compiled path since
+        # reset_stats: how many it answered, how many the host loop
+        # answered instead and why, and the sums of the policies that
+        # applied to them and of the installed set they were keyed on
+        # (keyed by the reason; None is the compiled path itself)
+        self._paths: Dict[Optional[str], int] = dict.fromkeys(
+            (None,) + HOST_LOOP_REASONS, 0)
+        self._candidate_policies = 0
+        self._installed_policies = 0
+        # validate scanners built since this batcher was made;
+        # reset_stats leaves it (a build belongs to set-up, and the
+        # count is there to show that none came after it)
+        self._scanner_builds = 0
         # consecutive all-failed dispatch count per key; touched only
         # by the batcher thread (dispatches are serialized), reset the
         # moment any rider of the key resolves on device
@@ -260,6 +283,22 @@ class AdmissionBatcher:
             self._handled += 1
             self._handler_s += seconds
             self._message_s += message_s
+
+    def record_path(self, host_reason: Optional[str], candidates: int,
+                    installed: int) -> None:
+        """One request that asked for the compiled path: answered by
+        it (``host_reason`` None) or by the host loop for one of
+        ``HOST_LOOP_REASONS``; ``candidates`` policies applied to it, of
+        the ``installed`` its scanner is compiled for."""
+        with self._stats_lock:
+            self._paths[host_reason] += 1
+            self._candidate_policies += candidates
+            self._installed_policies += installed
+
+    def record_build(self) -> None:
+        """One validate scanner built (and warmed) by the handler."""
+        with self._stats_lock:
+            self._scanner_builds += 1
 
     # -- the coalescing loop ----------------------------------------------
 
@@ -525,6 +564,12 @@ class AdmissionBatcher:
             per_handled = 1000.0 / self._handled if self._handled else 0.0
             handler_ms = self._handler_s * per_handled
             message_ms = self._message_s * per_handled
+            device_path = self._paths[None]
+            host_loop = {reason: self._paths[reason]
+                         for reason in HOST_LOOP_REASONS}
+            candidates = self._candidate_policies
+            installed = self._installed_policies
+            builds = self._scanner_builds
         timing = {f'batch_{field}_ms': stage_ms.get(name, 0.0)
                   for name, field in _BATCH_STAGES}
         return {
@@ -546,6 +591,12 @@ class AdmissionBatcher:
             'shed_total': self.sheds.total(),
             'shed': self.sheds.counts(),
             'queue_depth': self.queue.depth(),
+            'device_path_requests': device_path,
+            'host_loop_requests': sum(host_loop.values()),
+            'host_loop': host_loop,
+            'candidate_policies': candidates,
+            'installed_policies': installed,
+            'scanner_builds': builds,
         }
 
     def reset_stats(self) -> None:
@@ -560,6 +611,8 @@ class AdmissionBatcher:
             self._timed = self._handled = 0
             self._batch_s = self._handler_s = self._message_s = 0.0
             self._stage_s.clear()
+            self._paths = dict.fromkeys(self._paths, 0)
+            self._candidate_policies = self._installed_policies = 0
         self.sheds.reset()
 
     # -- lifecycle ---------------------------------------------------------

@@ -472,11 +472,15 @@ class ResourceHandlers:
         self._scanners: 'collections.OrderedDict[tuple, Any]' = \
             collections.OrderedDict()
         self._scanners_max = 8
-        # (namespace, name) identity sets per cached scanner key: policy
-        # churn replaces the Policy OBJECTS (so the id()-tuple key never
-        # matches), but the logical set persists — the hot-swap
-        # predecessor search matches on identity overlap
-        self._scanner_ident: Dict[tuple, frozenset] = {}
+        # per cached scanner key, what it was compiled for: the
+        # (namespace, name) identity set, the policy list and the ids of
+        # its objects.  Policy churn replaces the Policy OBJECTS (so the
+        # id()-tuple key never matches), but the logical set persists —
+        # the hot-swap predecessor search matches on identity overlap;
+        # and while a successor compiles, the predecessor serves the
+        # requests whose own policies it holds (_serving_predecessor)
+        self._scanner_sets: Dict[tuple, Tuple[frozenset, Any,
+                                              frozenset]] = {}
         self._building: set = set()
         # per-policy-set circuit breakers (serving/breaker.py): a set
         # that keeps failing (build or scan) opens and serves the host
@@ -510,23 +514,39 @@ class ResourceHandlers:
             'KTPU_MUTATE_DEVICE', '1') not in ('0', 'false', 'off')
         self._batcher = None
         self._batcher_lock = threading.Lock()
+        self._key_memo: Tuple[Any, tuple] = (None, ())
 
-    @staticmethod
-    def _policy_key(policies):
-        return tuple(id(p) for p in policies)
+    def _policy_key(self, policies):
+        """The identity of a policy set: the ids of its objects, in
+        order.  ``Cache.get_installed`` hands out one list object until
+        the set changes, so the last key is kept beside its list (a
+        request asks for it several times over a thousand policies)."""
+        memo = self._key_memo
+        if memo[0] is policies:
+            return memo[1]
+        key = tuple(id(p) for p in policies)
+        self._key_memo = (policies, key)
+        return key
 
     def _device_scanner(self, policies, kind: str = 'validate'):
         """Scanner for ``policies``, or None while one is still compiling.
 
-        ``kind`` selects the program: ``validate`` builds a
+        The validate path passes the installed set of the request's
+        kind (``Cache.get_installed``: cluster-wide policies and every
+        namespace's own), so one scanner serves every namespace and the
+        scanner, the breaker and the batch are keyed on that set, not
+        on the list that applies to one request.  ``kind`` selects the
+        program: ``validate`` builds a
         ``BatchScanner``, ``mutate`` a ``MutateScanner`` (a mutate set
         that does not lower is cached too — callers check ``.ok`` — so
         the lowering never re-runs per request).  Building pays jax
         trace + XLA compile (seconds to minutes on a policy-set change);
         doing that on the request path would blow the webhook timeout
         (reference: 10s cap, spec_types.go:95).  The build runs on a
-        background thread and requests serve the host engine loop —
-        identical verdicts — until the compiled path is ready.  The
+        background thread; until the compiled path is ready the validate
+        path asks the predecessor (``_serving_predecessor``) and, where
+        that does not hold the request's policies, serves the host
+        engine loop — identical verdicts.  The
         circuit breaker is keyed per policy set (kindless): a backend
         broken for one program kind is broken for the other."""
         from ..observability import coverage
@@ -576,6 +596,8 @@ class ResourceHandlers:
                     # hits (AOT-loads from the persistent executable store
                     # when a prior process already compiled this set)
                     scanner.warmup()
+                    if self.serving_mode == 'batch':
+                        self._get_batcher().record_build()
                 self._install_scanner(key, base, kind, policies,
                                       scanner)
             except Exception as e:  # noqa: BLE001
@@ -622,7 +644,7 @@ class ResourceHandlers:
             for k in self._scanners:
                 if k[0] != key[0] or k == key:
                     continue
-                prev = self._scanner_ident.get(k)
+                prev = self._scanner_sets.get(k, ((),))[0]
                 if not prev:
                     continue
                 ratio = len(ident & prev) / max(len(ident), len(prev), 1)
@@ -630,15 +652,15 @@ class ResourceHandlers:
                     best, best_ratio = k, ratio
             if best is not None and best_ratio >= 0.5:
                 old = self._scanners.pop(best)
-                self._scanner_ident.pop(best, None)
+                self._scanner_sets.pop(best, None)
                 state = self._breakers.migrate(best[1:], base,
                                                policies=policies)
                 swapped = (old, state)
             while len(self._scanners) >= self._scanners_max:
                 evicted, _ = self._scanners.popitem(last=False)
-                self._scanner_ident.pop(evicted, None)
+                self._scanner_sets.pop(evicted, None)
             self._scanners[key] = scanner
-            self._scanner_ident[key] = ident
+            self._scanner_sets[key] = (ident, policies, frozenset(base))
         if swapped is None:
             return
         old, state = swapped
@@ -664,6 +686,30 @@ class ResourceHandlers:
                     old_serial=getattr(old, 'serial', None),
                     new_serial=getattr(scanner, 'serial', None),
                     breaker_state=state)
+
+    def _serving_predecessor(self, policies):
+        """While the installed set's successor compiles: ``(scanner, its
+        policy list, its key)`` of a validate scanner that holds every
+        policy of ``policies`` (a request's own list), the very objects,
+        and whose breaker is closed; None where there is none.
+
+        A change to one namespace's policies changes the installed set
+        and so the key every request asks for, but the objects of every
+        other namespace stay the ones the predecessor compiled.  Its
+        answers, kept to the request's own list, are right for each of
+        them, and for the changed namespace after a removal; a request
+        whose list holds an added or edited policy finds no such scanner
+        and is answered by the host loop until the swap."""
+        from ..serving import breaker as breaker_mod
+        want = set(map(id, policies))
+        with self._scanner_lock:
+            for key in reversed(self._scanners):
+                held = self._scanner_sets.get(key)
+                if key[0] == 'validate' and held is not None and \
+                        want <= held[2] and self._breakers.state(
+                            key[1:]) == breaker_mod.CLOSED:
+                    return self._scanners[key], held[1], key[1:]
+        return None
 
     def _record_key_failure(self, key: tuple, policies, reason: str) -> None:
         import logging
@@ -746,6 +792,7 @@ class ResourceHandlers:
         with self._scanner_lock:
             for kind in ('validate', 'mutate'):
                 self._scanners.pop((kind,) + base, None)
+                self._scanner_sets.pop((kind,) + base, None)
         self._record_key_failure(
             base, policies,
             f'batched scan failed, shedding to host engine: {error}')
@@ -826,13 +873,28 @@ class ResourceHandlers:
         uid = request.get('uid', '')
         kind = (request.get('kind') or {}).get('kind', '')
         ns = request.get('namespace', '')
-        policies = self.cache.get_policies(pcache.VALIDATE_ENFORCE, kind, ns)
-        generate_policies = self.cache.get_policies(pcache.GENERATE, kind, ns)
+        from ..observability import device as devtel
         from ..observability import provenance
         from ..observability import slo
+        t_candidates = time.monotonic()
+        # the policies that apply to this request decide which responses
+        # exist for it; the installed set of the kind (they are part of
+        # it, in its order) is what is compiled and what the scanner,
+        # the breaker and the batch are keyed on
+        policies = self.cache.get_policies(pcache.VALIDATE_ENFORCE, kind, ns)
+        installed = self.cache.get_installed(pcache.VALIDATE_ENFORCE, kind)
+        installed_key = self._policy_key(installed)
+        # the set whose scanner answers: the installed one, or its
+        # predecessor while the installed one compiles
+        served, served_key = installed, installed_key
+        devtel.record_stage('candidates', time.monotonic() - t_candidates)
+        generate_policies = self.cache.get_policies(pcache.GENERATE, kind, ns)
         prov_on = provenance.enabled()
         slo_on = slo.enabled()
         t_start = time.monotonic()
+        # where the compiled path was asked and the host loop answered
+        # (batch mode counts it: AdmissionBatcher.stats)
+        host_reason: Optional[str] = None
         # where the request rode a batch, the handler's own time around
         # the batcher: (seconds from entry to submit, when the resolved
         # ticket came back)
@@ -875,14 +937,23 @@ class ResourceHandlers:
             try:
                 from .. import faults
                 faults.check(faults.SITE_WEBHOOK_HANDLER)
-                scanner = self._device_scanner(policies)
+                scanner = self._device_scanner(installed)
                 if scanner is None:
-                    # compiled path still building — or the set's
-                    # circuit breaker is open: host loop this request
                     from ..serving import breaker as breaker_mod
-                    if self._breakers.state(self._policy_key(
-                            policies)) != breaker_mod.CLOSED:
+                    building = self._breakers.state(
+                        installed_key) == breaker_mod.CLOSED
+                    before = self._serving_predecessor(policies) \
+                        if building else None
+                    if before is not None:
+                        scanner, served, served_key = before
+                if scanner is None:
+                    # compiled path still building and no predecessor
+                    # holds this request's policies — or the set's
+                    # circuit breaker is open: host loop this request
+                    host_reason = 'building'
+                    if not building:
                         from ..serving import shed as shed_policy
+                        host_reason = 'breaker'
                         prov_path = \
                             f'shed:{shed_policy.REASON_BREAKER_OPEN}'
                         if self.serving_mode == 'batch':
@@ -891,13 +962,14 @@ class ResourceHandlers:
                     use_device = False
                 elif self.serving_mode == 'batch':
                     # micro-batching scheduler: this request coalesces
-                    # with concurrent same-policy-set same-verb requests
-                    # into one shared device dispatch
+                    # with the concurrent requests of its kind, whatever
+                    # their namespace, user or verb, into one shared
+                    # device dispatch of the installed set's scanner
                     # (serving/batcher.py); a shed comes back as None
                     # and the host loop serves
                     t_submit = time.monotonic()
                     batched, bprov = self._batched_scan(
-                        scanner, policies, request, pctx,
+                        scanner, served, request, pctx,
                         old_resource=old_doc)
                     if batched is not None:
                         own = (t_submit - t_start, time.monotonic())
@@ -906,11 +978,11 @@ class ResourceHandlers:
                     prov_extra['fingerprint'] = getattr(
                         scanner, 'fingerprint', '')
                     if batched is None:
+                        host_reason = 'shed'
                         use_device = False
                     else:
                         responses = batched
                 else:
-                    from ..observability import device as devtel
                     resource = admission.request_resource(request)
                     cap = devtel.ScanCapture() if prov_on else None
                     with devtel.install_capture(cap):
@@ -936,8 +1008,16 @@ class ResourceHandlers:
                         }
                     # success closes the set's breaker (recovery) or
                     # forgets its consecutive-failure count
-                    self._breakers.record_success(
-                        self._policy_key(policies))
+                    self._breakers.record_success(served_key)
+                if use_device and len(policies) != len(served):
+                    # the compiled set answers for every policy whose
+                    # rules match the resource; the responses that exist
+                    # for this request are those of its own list
+                    # (another namespace's never match; an override can
+                    # take an installed policy out of Enforce here)
+                    applies = set(map(id, policies))
+                    responses = [r for r in responses
+                                 if id(r.policy) in applies]
             except Exception as e:  # noqa: BLE001
                 # device failure must not turn into a 500: drop to the
                 # host engine loop and discard the broken scanner so the
@@ -945,17 +1025,22 @@ class ResourceHandlers:
                 # Repeated failures trip the per-set circuit breaker —
                 # otherwise every request would pay a full policy-set
                 # recompile before falling back.
-                base = self._policy_key(policies)
                 with self._scanner_lock:
-                    self._scanners.pop(('validate',) + base, None)
+                    self._scanners.pop(('validate',) + served_key, None)
+                    self._scanner_sets.pop(('validate',) + served_key,
+                                           None)
                 self._record_key_failure(
-                    base, policies,
+                    served_key, served,
                     f'scan failed, falling back to host engine: {e}')
                 provenance.notify_scan_error(e)
+                host_reason = 'breaker'
                 use_device = False
                 responses = []
                 prov_path = 'host_fallback'
                 prov_extra = {'error': f'scan failed: {e}'}
+        if self.serving_mode == 'batch' and (use_device or host_reason):
+            self._get_batcher().record_path(
+                host_reason, len(policies), len(served))
         if not use_device:
             for policy in policies:
                 ctx = pctx.copy()
@@ -1196,6 +1281,7 @@ class ResourceHandlers:
             base = self._policy_key(mutate_policies)
             with self._scanner_lock:
                 self._scanners.pop(('mutate',) + base, None)
+                self._scanner_sets.pop(('mutate',) + base, None)
             self._record_key_failure(
                 base, mutate_policies,
                 f'mutate scan failed, falling back to host engine: {e}')
