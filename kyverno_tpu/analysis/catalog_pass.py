@@ -662,7 +662,9 @@ def _is_str_expr(expr: ast.AST) -> bool:
             return True
         if isinstance(f, ast.Attribute) and \
                 f.attr in ('decode', 'dumps', 'format', 'join'):
-            return True
+            # json.dumps gives a str, pickle.dumps bytes
+            return not (isinstance(f.value, ast.Name) and
+                        f.value.id == 'pickle')
     return False
 
 

@@ -119,11 +119,12 @@ class Lanes:
     """numpy lane arrays for one slot or gather at a given shape, sized to
     exactly the lanes (and head byte width) its comparisons read."""
 
-    def __init__(self, shape: Tuple[int, ...], needs: LaneNeeds):
+    def __init__(self, shape: Tuple[int, ...], needs: LaneNeeds,
+                 zeros=np.zeros):
         self.needs = needs
-        self.tag = np.zeros(shape, np.int8)
-        z64 = lambda: np.zeros(shape, np.int64)  # noqa: E731
-        zb = lambda: np.zeros(shape, bool)       # noqa: E731
+        self.tag = zeros(shape, np.int8)
+        z64 = lambda: zeros(shape, np.int64)  # noqa: E731
+        zb = lambda: zeros(shape, bool)       # noqa: E731
         self.milli = z64() if needs.milli else None
         self.milli_ok = zb() if needs.milli else None
         self.nanos = z64() if needs.nanos else None
@@ -136,16 +137,16 @@ class Lanes:
         self.str_is_dur = zb() if needs.nanos else None
         self.lit_zero = zb() if needs.lit_zero else None
         if needs.length or needs.head or needs.tail:
-            self.str_len = np.zeros(shape, np.int32)
+            self.str_len = zeros(shape, np.int32)
         else:
             self.str_len = None
         if needs.head:
             # round the head window up for alignment / fewer pack groups
             w = min(STR_LEN, (needs.head + 7) & ~7)
-            self.str_head = np.zeros(shape + (w,), np.uint8)
+            self.str_head = zeros(shape + (w,), np.uint8)
         else:
             self.str_head = None
-        self.str_tail = np.zeros(shape + (TAIL_LEN,), np.uint8) \
+        self.str_tail = zeros(shape + (TAIL_LEN,), np.uint8) \
             if needs.tail else None
         self.has_wild = zb() if needs.wild else None
 
@@ -424,9 +425,9 @@ class LaneArena:
     recycled buffer."""
 
     def __init__(self, max_pool: int = 4):
-        #: buffers kept per shape key; 0 = palettes only (forked encode
-        #: workers pickle tensors after return, so recycling there could
-        #: zero a buffer mid-serialization)
+        #: buffers kept per shape key; 0 = palettes only (an encoder
+        #: worker lays each chunk's lanes over a block of the parent's:
+        #: :class:`BlockArena`)
         self.max_pool = max_pool
         self._lock = __import__('threading').Lock()
         self._free: Dict[tuple, List['Batch']] = {}
@@ -446,6 +447,11 @@ class LaneArena:
             if pool:
                 return pool.pop()
         return None
+
+    def build(self, make) -> 'Batch':
+        """A fresh batch: ``make(zeros)`` allocates every lane through
+        ``zeros(shape, dtype)``; here that is plain numpy."""
+        return make(np.zeros)
 
     def release(self, batch: 'Batch') -> None:
         key = getattr(batch, 'arena_key', None)
@@ -707,6 +713,9 @@ class Batch:
         self.row_count = n if row_count is None else row_count
         #: set when the batch came from a LaneArena pool (recycle key)
         self.arena_key: Optional[tuple] = None
+        #: the ``__rowvalid__`` lane (``_build_batch`` allocates it with
+        #: the rest, ``encode_batch`` writes it)
+        self.rowvalid: Optional[np.ndarray] = None
         self.slot_lanes: Dict[Slot, Lanes] = {}
         self.array_meta: Dict[Tuple[str, ...], Dict[str, np.ndarray]] = {}
         self.gather_lanes: Dict[GatherSlot, Lanes] = {}
@@ -738,9 +747,7 @@ class Batch:
         # jitted program (cross-row reductions — the mesh verdict
         # summary, the compact fail-detail selection — must never read
         # them), so one compiled capacity serves every occupancy
-        out: Dict[str, np.ndarray] = {
-            '__rowvalid__':
-                (np.arange(self.n) < self.row_count).astype(np.int8)}
+        out: Dict[str, np.ndarray] = {'__rowvalid__': self.rowvalid}
         for i, (slot, lanes) in enumerate(self.slot_lanes.items()):
             out.update(lanes.tensors(f's{i}'))
         for j, (path, meta) in enumerate(self.array_meta.items()):
@@ -949,13 +956,15 @@ def encode_batch(resources: List[dict], cps: CompiledPolicySet,
     key = (n, elems, gwidth, egwidth)
     batch = arena.acquire(key) if pooled else None
     if batch is None:
-        batch = _build_batch(cps, n, elems, gwidth, egwidth, slot_needs,
-                             gather_needs, elem_needs, array_paths)
+        batch = arena.build(lambda zeros: _build_batch(
+            cps, n, elems, gwidth, egwidth, slot_needs, gather_needs,
+            elem_needs, array_paths, zeros))
         if pooled:
             batch.arena_key = key
     else:
         batch.clear()
     batch.row_count = n_rows
+    batch.rowvalid[:] = np.arange(n) < n_rows
     batch.elems = elems
     batch.gather_width = gwidth
     batch.elem_gather_width = egwidth
@@ -1066,42 +1075,47 @@ def encode_batch(resources: List[dict], cps: CompiledPolicySet,
 
 def _build_batch(cps: CompiledPolicySet, n: int, elems: int, gwidth: int,
                  egwidth: int, slot_needs, gather_needs, elem_needs,
-                 array_paths) -> Batch:
+                 array_paths, zeros=np.zeros) -> Batch:
     """Allocate the full lane tensor set for one batch shape (reused
-    across chunks via the LaneArena)."""
+    across chunks via the LaneArena).  Every lane comes from
+    ``zeros(shape, dtype)``: numpy's in this process, a
+    :class:`_BlockZeros` over a shared-memory block in an encoder
+    worker."""
     batch = Batch(n)
+    batch.rowvalid = zeros(n, np.int8)
     for path in array_paths:
         depth = sum(1 for p in path if p == '*')
         shape = (n,) + (elems,) * depth
         # ktpu: noqa[KTPU205] -- per-SLOT lane allocation (runs once per
         # batch shape, then recycles through the arena), not per row
         batch.array_meta[path] = {
-            'count': np.zeros(shape, np.int32),
-            'overflow': np.zeros(shape, bool),
-            'tag': np.zeros(shape, np.int8),
+            'count': zeros(shape, np.int32),
+            'overflow': zeros(shape, bool),
+            'tag': zeros(shape, np.int8),
         }
     for slot in cps.slots:
         shape = (n,) + (elems,) * slot.depth
-        batch.slot_lanes[slot] = Lanes(shape, slot_needs[slot])
+        batch.slot_lanes[slot] = Lanes(shape, slot_needs[slot], zeros)
     for g in cps.gathers:
-        batch.gather_lanes[g] = Lanes((n, gwidth), gather_needs[g])
+        batch.gather_lanes[g] = Lanes((n, gwidth), gather_needs[g], zeros)
         # ktpu: noqa[KTPU205] -- per-GATHER metadata allocation (arena-
         # recycled), not per row
         batch.gather_meta[g] = {
-            'kind': np.zeros(n, np.int8),
-            'count': np.zeros(n, np.int32),
-            'overflow': np.zeros(n, bool),
-            'notfound': np.zeros(n, bool),
+            'kind': zeros(n, np.int8),
+            'count': zeros(n, np.int32),
+            'overflow': zeros(n, bool),
+            'notfound': zeros(n, bool),
         }
     for eg in cps.elem_gathers:
-        batch.elem_lanes[eg] = Lanes((n, gwidth, egwidth), elem_needs[eg])
+        batch.elem_lanes[eg] = Lanes((n, gwidth, egwidth), elem_needs[eg],
+                                     zeros)
         # ktpu: noqa[KTPU205] -- per-GATHER metadata allocation (arena-
         # recycled), not per row
         batch.elem_meta[eg] = {
-            'kind': np.zeros((n, gwidth), np.int8),
-            'count': np.zeros((n, gwidth), np.int32),
-            'overflow': np.zeros((n, gwidth), bool),
-            'notfound': np.zeros((n, gwidth), bool),
+            'kind': zeros((n, gwidth), np.int8),
+            'count': zeros((n, gwidth), np.int32),
+            'overflow': zeros((n, gwidth), bool),
+            'notfound': zeros((n, gwidth), bool),
         }
     return batch
 
@@ -1336,13 +1350,109 @@ def _fill_elem_gather_column(rows: list, lanes: Lanes, meta, egwidth: int,
 # The encoder worker process (compiler/scan.py _EncoderPool).  It lives in
 # this module because this module imports no jax: a worker, and the fork
 # server it comes from, import nothing else.
+#
+# A chunk's lanes do not travel home through the pool's result pipe (some
+# hundreds of arrays, 272 MB at capacity 16,384: the parent's result
+# thread reads a pipe 64 kB at a time and retakes the interpreter lock
+# after every read).  The worker encodes them in place into a
+# shared-memory block and returns what names them: lane name, dtype,
+# shape and offset.  The parent owns every block and chooses every name
+# (scan.py _Block): with a task it offers the block it has and a spare
+# name, and a worker whose batch does not fit creates a block of the
+# right size under the spare name, which the parent then adopts.  (One
+# fresh block a chunk needs none of this and was tried: it cost 1.3 s a
+# reconcile on the chip, PERF.md section 6, PR 29.)
+
+#: lanes in a block start on a multiple of this many bytes
+_BLOCK_ALIGN = 64
+
+
+class _BlockZeros:
+    """``np.zeros`` over one buffer: lanes laid one after the other,
+    each start aligned.  Without a buffer it lays nothing and only adds
+    the sizes up: ``offset`` is then the bytes a block needs."""
+
+    def __init__(self, buf=None):
+        self.buf = buf
+        self.offset = 0
+
+    def __call__(self, shape, dtype):
+        dtype = np.dtype(dtype)
+        shape = (shape,) if isinstance(shape, int) else tuple(shape)
+        at = self.offset
+        end = at + dtype.itemsize * math.prod(shape)
+        self.offset = -(-end // _BLOCK_ALIGN) * _BLOCK_ALIGN
+        if self.buf is None:
+            return None
+        lane = np.ndarray(shape, dtype, buffer=self.buf, offset=at)
+        lane.fill(0)  # a block comes back with its last chunk's lanes
+        return lane
+
+
+def open_block(offer, need: int):
+    """The block a worker lays ``need`` bytes of lanes over.  ``offer``
+    is ``(name, size, spare)``: the parent's block (``None, 0`` when it
+    has none yet) and the name to create a larger one under.  A new
+    block has every page reserved at once: a segment is sparse until
+    written, and a write that tmpfs cannot back kills the worker, which
+    the parent would only learn by waiting out its timeout.  Raises
+    ``OSError`` where a block cannot be had (no ``/dev/shm``, or too
+    little room in it)."""
+    import os
+    from multiprocessing import shared_memory
+    name, size, spare = offer
+    if name is not None and need <= size:
+        return shared_memory.SharedMemory(name=name)
+    shm = shared_memory.SharedMemory(name=spare, create=True, size=need)
+    try:
+        os.posix_fallocate(shm._fd, 0, need)
+    except OSError:
+        shm.close()
+        shm.unlink()
+        raise
+    return shm
+
+
+def block_layout(tensors: Dict[str, np.ndarray], buf) -> list:
+    """``(name, dtype, shape, offset)`` of each lane within ``buf``."""
+    whole = np.frombuffer(buf, np.uint8)
+    layout = []
+    for name, lane in tensors.items():
+        at = lane.ctypes.data - whole.ctypes.data
+        if not lane.flags.c_contiguous or at < 0 \
+                or at + lane.nbytes > whole.nbytes:
+            raise ValueError(f'lane {name} does not lie in the block')
+        layout.append((name, lane.dtype.str, lane.shape, at))
+    return layout
+
+
+def block_lanes(buf, layout) -> Dict[str, np.ndarray]:
+    """The lanes ``layout`` names, as views over ``buf``."""
+    return {name: np.ndarray(shape, dtype, buffer=buf, offset=at)
+            for name, dtype, shape, at in layout}
+
+
+class BlockArena(LaneArena):
+    """An encoder worker's arena.  The columnar value palettes stay
+    warm across the chunks one worker serves; no buffer is pooled,
+    because a chunk's lanes are laid over the block offered with its
+    task (``offer``).  ``shm`` is the block in use until the worker has
+    handed it back."""
+
+    def __init__(self):
+        super().__init__(max_pool=0)
+        self.offer = None
+        self.shm = None
+
+    def build(self, make) -> 'Batch':
+        sizes = _BlockZeros()
+        make(sizes)
+        self.shm = open_block(self.offer, sizes.offset)
+        return make(_BlockZeros(self.shm.buf))
+
 
 _WORKER_CPS = None
-#: per-worker-process arena: keeps the columnar value palettes warm
-#: across the chunks one encoder worker serves (buffer pooling stays off
-#: in workers — tensors are pickled back after return, so a recycled
-#: buffer could be zeroed mid-serialization)
-_WORKER_PALETTES = None
+_WORKER_ARENA: Optional[BlockArena] = None
 
 
 def encode_worker_init(cps) -> None:
@@ -1351,25 +1461,38 @@ def encode_worker_init(cps) -> None:
 
 
 def encode_worker(args):
-    global _WORKER_PALETTES
+    """Encode one chunk into a block; returns ``(block name, layout,
+    stage seconds, (t0, t1, pid))``."""
+    global _WORKER_ARENA
     import os
     import time
-    docs, contexts, padded_n = args
-    if _WORKER_PALETTES is None:
-        _WORKER_PALETTES = LaneArena(max_pool=0)
+    docs, contexts, padded_n, offer = args
+    if _WORKER_ARENA is None:
+        _WORKER_ARENA = BlockArena()
+    arena = _WORKER_ARENA
+    arena.offer = offer
     # the worker's metric increments and contextvars die with the
     # process — the pipeline threads re-install the scan's ScanCapture,
     # and this is the process-side analogue: measure into a fresh local
     # capture and ship the stage seconds (plus the wall interval, for
-    # the timeline) home with the tensors; the resolving pipeline
+    # the timeline) home with the layout; the resolving pipeline
     # thread re-attributes them via devtel.merge_worker_stages.
     from ..observability import device as devtel
     cap = devtel.ScanCapture()
     t0 = time.monotonic()
-    with devtel.install_capture(cap):
-        batch = encode_batch(docs, _WORKER_CPS, padded_n=padded_n,
-                             contexts=contexts, arena=_WORKER_PALETTES)
-    t1 = time.monotonic()
-    cap.add('encode', t1 - t0)
-    return batch.tensors(), dict(cap.stages), (t0, t1, os.getpid())
-
+    try:
+        with devtel.install_capture(cap):
+            batch = encode_batch(docs, _WORKER_CPS, padded_n=padded_n,
+                                 contexts=contexts, arena=arena)
+        t1 = time.monotonic()
+        cap.add('encode', t1 - t0)
+        layout = block_layout(batch.tensors(), arena.shm.buf)
+    except BaseException:
+        # the error's traceback may still hold lanes, and a mapping
+        # cannot close under them: it goes when they do
+        arena.shm = None
+        raise
+    shm, arena.shm = arena.shm, None
+    del batch  # its lanes are views of the mapping about to close
+    shm.close()
+    return shm.name, layout, dict(cap.stages), (t0, t1, os.getpid())

@@ -20,6 +20,7 @@ pair (reference match semantics: pkg/engine/utils.go:185).
 
 from __future__ import annotations
 
+import multiprocessing
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -91,18 +92,177 @@ _stop_at_exit = False  # stop_encoder_processes is registered with atexit
 
 
 def stop_encoder_processes() -> None:
-    """Terminate every encoder pool, then stop the fork server and
-    multiprocessing's resource tracker, and wait for each.  Left to
-    themselves the two helpers only notice that this process is gone
-    after it has exited, so whoever waited for it finds them still
-    running; registered with ``atexit`` by the first pool, so a process
-    that has scanned leaves none behind.  A later scan starts them
-    again."""
+    """Terminate every encoder pool and unlink its blocks, then stop
+    the fork server and multiprocessing's resource tracker, and wait for
+    each.  Left to themselves the two helpers only notice that this
+    process is gone after it has exited, so whoever waited for it finds
+    them still running; registered with ``atexit`` by the first pool, so
+    a process that has scanned leaves none behind.  A later scan starts
+    them again."""
     from multiprocessing import forkserver, resource_tracker
     for pool in list(_LIVE_POOLS):
         pool.close()
+    # a pool shut with a task unanswered is in a cycle with that task:
+    # collect it now, while the tracker still knows its semaphores
+    __import__('gc').collect()
     forkserver._forkserver._stop()
     resource_tracker._resource_tracker._stop()
+
+
+# A worker's lanes come home in a shared-memory block (compiler/encode.py
+# has the worker's half and the reason).  This process owns every block:
+# it chooses each name before a worker can create anything under it, so
+# whatever becomes of the worker, the names to unlink are known here.
+
+_BLOCK_SERIALS = __import__('itertools').count(1)
+
+
+def _unlink_segment(name: str) -> None:
+    """Unlink segment ``name`` if a worker got as far as creating it.
+    The attach is what lets ``unlink()`` take it off the resource
+    tracker's list as well, where its creator put it."""
+    from multiprocessing import shared_memory
+    try:
+        made = shared_memory.SharedMemory(name=name)
+    except (FileNotFoundError, ValueError):  # never, or empty
+        return
+    made.unlink()
+    made.close()
+
+
+class _Block:
+    """One block of a chunk's encoded lanes.  ``shm`` is this process's
+    mapping of the segment it holds (None before the first chunk);
+    ``spare`` is the name a worker may create a larger one under and
+    ``task`` the pool's handle on that worker's answer, both set for as
+    long as the block is out."""
+
+    __slots__ = ('shm', 'spare', 'task')
+
+    def __init__(self):
+        self.shm = None
+        self.spare: Optional[str] = None
+        self.task = None
+
+    def offer(self) -> tuple:
+        """What a worker is told: ``(name, size, spare)``."""
+        self.spare = 'ktpu-enc-%d-%d' % (__import__('os').getpid(),
+                                         next(_BLOCK_SERIALS))
+        if self.shm is None:
+            return None, 0, self.spare
+        return self.shm.name, self.shm.size, self.spare
+
+
+class _Blocks:
+    """The blocks of one encoder pool: as many as chunks were ever in
+    flight at once, which the pipeline's depth bounds."""
+
+    def __init__(self):
+        self._lock = __import__('threading').Lock()
+        self._free: List[_Block] = []
+        self._all: List[_Block] = []
+        #: spare name -> task of blocks given up while their worker was
+        #: still at work: unlinked once it has answered, by a later sweep
+        self._lost: Dict[str, Any] = {}
+        #: mappings unlinked while lanes over them were still referenced
+        #: (a chunk that died in flight): closed once those are gone, by
+        #: a later sweep
+        self._unmap_later: List[Any] = []
+
+    def acquire(self) -> _Block:
+        self._sweep()
+        with self._lock:
+            if self._free:
+                return self._free.pop()
+            block = _Block()
+            self._all.append(block)
+            return block
+
+    def lanes(self, block: _Block, name: str,
+              layout) -> Dict[str, np.ndarray]:
+        """The lanes a worker left in segment ``name``: the one offered,
+        or the spare it had to create, which replaces it."""
+        from multiprocessing import shared_memory
+        from .encode import block_lanes
+        if name == block.spare:
+            old, block.shm = block.shm, shared_memory.SharedMemory(name=name)
+            if old is not None:
+                old.unlink()
+                self._unmap(old)
+        elif block.shm is None or name != block.shm.name:
+            raise ValueError(f'a worker answered from block {name!r}, '
+                             f'which it was not offered')
+        block.spare = block.task = None
+        return block_lanes(block.shm.buf, layout)
+
+    def release(self, block: _Block) -> None:
+        """Free for the next chunk, if its lanes had come home.  A block
+        still out with a worker (its chunk died first) is given up: the
+        worker may yet write into it, so no other chunk may have it.  So
+        is one whose pool was given up meanwhile."""
+        with self._lock:
+            mine = any(block is b for b in self._all)
+            if mine and block.spare is None:
+                self._free.append(block)
+                return
+            if mine:
+                self._all.remove(block)
+        self._drop(block)
+
+    def drop_all(self) -> None:
+        """No worker is left: unlink whatever one holds or created."""
+        with self._lock:
+            blocks, self._all, self._free = self._all, [], []
+        for block in blocks:
+            self._drop(block)
+        self._sweep(final=True)
+
+    def _drop(self, block: _Block) -> None:
+        """Unlink what ``block`` holds and may hold; idempotent."""
+        shm, block.shm = block.shm, None
+        spare, block.spare = block.spare, None
+        task, block.task = block.task, None
+        if shm is not None:
+            try:
+                shm.unlink()
+            except FileNotFoundError:
+                pass
+        self._unmap(shm)
+        if spare is not None:
+            with self._lock:
+                self._lost[spare] = task
+            self._sweep()
+
+    def _sweep(self, final: bool = False) -> None:
+        """Unlink the spare segments of the blocks given up, each once
+        its worker has answered and so creates nothing any more, or, at
+        the ``final`` sweep, is gone."""
+        with self._lock:
+            for name, task in list(self._lost.items()):
+                if final or task is None or task.ready():
+                    _unlink_segment(name)
+                    del self._lost[name]
+
+    def _unmap(self, shm=None) -> None:
+        """Close ``shm``'s mapping, and every earlier one that had to
+        wait: a mapping cannot close under a numpy view of it."""
+        with self._lock:
+            todo, self._unmap_later = self._unmap_later, []
+        if shm is not None:
+            todo.append(shm)
+        for m in todo:
+            try:
+                m.close()
+            except BufferError:
+                with self._lock:
+                    self._unmap_later.append(m)
+
+
+def _shut_pool(pool, blocks: _Blocks) -> None:
+    """Workers first, so that none is left to create a block after the
+    names were unlinked."""
+    pool.terminate()
+    blocks.drop_all()
 
 
 class _EncoderPool:
@@ -114,24 +274,25 @@ class _EncoderPool:
         self.procs = procs
         self._pool = None
         self._broken = False
+        self.blocks = _Blocks()
 
     def start(self) -> bool:
         if self._broken or self.procs <= 0:
             return False
         if self._pool is None:
             try:
-                import multiprocessing as mp
                 import weakref
-                ctx = mp.get_context('forkserver')
+                ctx = multiprocessing.get_context('forkserver')
                 ctx.set_forkserver_preload([encode_worker.__module__])
                 pool = ctx.Pool(self.procs, initializer=encode_worker_init,
                                 initargs=(self.cps,))
-                self._pool = pool
                 # weakref.finalize runs at collection OR interpreter exit
-                # (atexit=True default), so workers are reaped when the
-                # scanner is dropped and mp.Pool.__del__ never races the
-                # shutdown pickler
-                self._finalizer = weakref.finalize(self, pool.terminate)
+                # (atexit=True default), so workers are reaped and blocks
+                # unlinked when the scanner is dropped and
+                # mp.Pool.__del__ never races the shutdown pickler
+                self._finalizer = weakref.finalize(self, _shut_pool, pool,
+                                                   self.blocks)
+                self._pool = pool
                 _LIVE_POOLS.add(self)
                 global _stop_at_exit
                 if not _stop_at_exit:
@@ -149,17 +310,14 @@ class _EncoderPool:
         self.close()
         self._broken = True
 
-    def submit(self, docs, contexts, padded_n):
-        return self._pool.apply_async(encode_worker,
-                                      ((docs, contexts, padded_n),))
+    def submit(self, docs, contexts, padded_n, block: _Block):
+        block.task = self._pool.apply_async(
+            encode_worker, ((docs, contexts, padded_n, block.offer()),))
+        return block.task
 
     def close(self) -> None:
         if self._pool is not None:
-            fin = getattr(self, '_finalizer', None)
-            if fin is not None:
-                fin()  # idempotent: terminates the pool once
-            else:
-                self._pool.terminate()
+            self._finalizer()  # idempotent: shuts the pool once
             self._pool = None
             _LIVE_POOLS.discard(self)
 
@@ -332,9 +490,9 @@ class BatchScanner:
                 cluster[js] = False
             self._candidate_masks[''] = cluster
         self._fail_msg_cache: Dict[Tuple, Optional[str]] = {}
-        # encode workers only pay off with spare cores: on a
-        # single-CPU host the ~150MB/chunk lane tensors pickled back
-        # through the pipe cost more CPU than the encode they offload
+        # encode workers only pay off with spare cores: on a host of
+        # one or two the worker's encode and this process's report
+        # assembly take turns on the same core
         _os = __import__('os')
         _default_procs = '2' if (_os.cpu_count() or 1) > 2 else '0'
         self._encoder_pool = _EncoderPool(
@@ -697,9 +855,10 @@ class BatchScanner:
         ``KTPU_PIPELINE_DEPTH`` chunks in flight, so end-to-end rate ≈
         max(stage) instead of sum(stage) and a slow leg backpressures
         intake instead of buffering.  Encode lane tensors are recycled
-        through the scanner's :class:`LaneArena` — a chunk's buffers
-        return to the pool when its d2h lands, so RSS stays flat in
-        ``n_resources``.
+        through the scanner's :class:`LaneArena`, or where worker
+        processes encode through the pool's shared-memory blocks — a
+        chunk's buffers return to either when its d2h lands, so RSS
+        stays flat in ``n_resources``.
 
         ``match`` (the host-side [R, P] match mask) rides to the device
         with each chunk so fail details compact to the (matched, FAIL)
@@ -745,12 +904,13 @@ class BatchScanner:
                 return batch.tensors(), batch
 
         def release_chunk(p):
-            """Return a chunk's encode buffers to the arena exactly
-            once — after d2h frees its device inputs on the success
-            path, or via the pipeline's cleanup hook when the chunk
-            dies mid-flight (stage crash, aborted stream).  Device
-            references are dropped first so a zero-copy h2d path never
-            sees its backing buffer recycled while still reachable."""
+            """Return a chunk's encode buffers — the arena's batch, or
+            the encoder pool's block — exactly once: after d2h frees its
+            device inputs on the success path, or via the pipeline's
+            cleanup hook when the chunk dies mid-flight (stage crash,
+            aborted stream).  Device references are dropped first so a
+            zero-copy h2d path never sees its backing buffer recycled
+            while still reachable."""
             if not isinstance(p, dict):
                 return
             p['t'] = p['out'] = p['enc'] = None
@@ -758,6 +918,10 @@ class BatchScanner:
             p['batch'] = None
             if arena is not None and batch is not None:
                 arena.release(batch)
+            block = p.get('block')
+            p['block'] = None
+            if block is not None:
+                self._encoder_pool.blocks.release(block)
 
         def stage_encode(start):
             faults.check(faults.SITE_ENCODE)
@@ -776,19 +940,23 @@ class BatchScanner:
             # second shape to a bulk scan.
             bucket = chunk if n > chunk else canonical_capacity(
                 len(part), chunk=chunk, small=self.SMALL_BATCH)
-            enc = batch = None
+            enc = batch = block = None
             if use_procs and not self._encoder_pool._broken:
+                # the worker lays the chunk's lanes over this block; it
+                # is the chunk's until release_chunk
+                block = self._encoder_pool.blocks.acquire()
                 try:
                     enc = self._encoder_pool.submit(part, part_ctx,
-                                                    bucket)
+                                                    bucket, block)
                 except Exception:  # noqa: BLE001 - fall back in-process
+                    # giving the pool up drops its blocks, this one too
                     self._encoder_pool.mark_broken('pool_failed')
-                    enc = None
+                    enc = block = None
             if enc is None:
                 enc, batch = inline_encode(part, part_ctx, bucket)
             return {'start': start, 'ln': len(part), 'part': part,
                     'part_ctx': part_ctx, 'bucket': bucket, 'enc': enc,
-                    'batch': batch, 'cm': cm}
+                    'batch': batch, 'block': block, 'cm': cm}
 
         def stage_h2d(p):
             faults.check(faults.SITE_H2D)
@@ -808,13 +976,23 @@ class BatchScanner:
                 else:
                     try:
                         with devtel.stage('encode_wait'):
-                            tensors, wstages, wspan = tensors.get(
+                            home = tensors.get(
                                 timeout=self.ENCODE_TIMEOUT_S)
-                    except Exception:  # noqa: BLE001 - worker death
-                        self._encoder_pool.mark_broken('presumed_dead')
+                        name, lanes_at, wstages, wspan = home
+                        tensors = self._encoder_pool.blocks.lanes(
+                            p['block'], name, lanes_at)
+                    except Exception as e:  # noqa: BLE001 - no answer
+                        # inside the timeout: the worker is presumed
+                        # dead; an answer that is an error, or lanes
+                        # that cannot be mapped: no block could be had
+                        self._encoder_pool.mark_broken(
+                            'presumed_dead' if isinstance(
+                                e, multiprocessing.TimeoutError)
+                            else 'pool_failed')
                         tensors, p['batch'] = inline_encode(
                             p['part'], p['part_ctx'], p['bucket'])
                     else:
+                        devtel.record_encode_result_bytes(tensors, home)
                         # stage seconds measured inside the worker:
                         # fold into the parent's histogram and the
                         # ambient ScanCapture (installed on this
@@ -829,6 +1007,8 @@ class BatchScanner:
                                 'encode', start // chunk, wspan[0],
                                 wspan[1],
                                 thread='ktpu-encproc-%d' % wspan[2])
+                # home once: a retry of this stage starts from the lanes
+                p['enc'] = tensors
             cm = p['cm']
             if cm is not None and self.mesh is None and tensors:
                 from ..ops.eval import fold_match_unique

@@ -76,11 +76,18 @@ METRICS: Dict[str, Metric] = {
         'counter', 'Scan-pipeline stage attempts that raised and were '
         're-run on the same chunk (KTPU_STAGE_RETRIES), by stage.'),
     'kyverno_tpu_encode_worker_chunks_total': Metric(
-        'counter', 'Encoder worker-pool outcomes; result=ok (a chunk a '
-        'worker encoded)|presumed_dead (no answer inside '
-        'KTPU_ENCODE_TIMEOUT)|pool_failed (pool would not start or take '
-        'a task). Anything but ok drops the scanner to in-process '
+        'counter', 'Encoder worker-pool outcomes; result=ok (a chunk '
+        'whose lanes a worker left in a shared-memory block)|'
+        'presumed_dead (no answer inside KTPU_ENCODE_TIMEOUT)|'
+        'pool_failed (pool would not start or take a task, or no block '
+        'could be had: /dev/shm needs room for KTPU_PIPELINE_DEPTH '
+        'blocks). Anything but ok drops the scanner to in-process '
         'encoding.'),
+    'kyverno_tpu_encode_result_bytes_total': Metric(
+        'counter', 'Bytes a chunk\'s encode brought home from its '
+        'worker; via=block (the lanes, in a shared-memory block this '
+        'process maps)|pipe (the pickled answer that names them: a few '
+        'kB a chunk).'),
     # device-coverage ledger (observability/coverage.py)
     'kyverno_tpu_rule_placement_info': Metric(
         'gauge', '1 per compiled (policy, rule, path); placement=device|'
@@ -290,8 +297,10 @@ SPANS: Dict[str, str] = {
     'kyverno/device/report': 'Response/report assembly stage.',
     'kyverno/device/match': 'Host match sieve over the policy axis '
                             '(once a chunk or batch).',
-    'kyverno/device/encode_wait': 'The h2d thread blocked on the '
-                                  'encoder pool\'s result.',
+    'kyverno/device/encode_wait': 'The h2d thread waiting out a '
+                                  'worker\'s encode of the chunk (its '
+                                  'lanes come home in a block, not '
+                                  'through the pipe).',
     'kyverno/device/device_wait': 'Blocked until the evaluator\'s '
                                   'outputs are ready (inside d2h).',
     'kyverno/device/expand': 'Compact readback expanded to status '
@@ -346,7 +355,8 @@ PIPELINE_STAGES: Dict[str, str] = {
     # leaf stages beside the pipeline's legs (not blamed by the
     # timeline walk: the first four lie inside a leg's interval)
     'match': 'Host match sieve (inside the encode leg on the scan path).',
-    'encode_wait': 'h2d leg blocked on the encoder pool\'s result.',
+    'encode_wait': 'h2d leg waiting out a worker\'s encode of the '
+                   'chunk (no transfer: the lanes are in a block).',
     'device_wait': 'Blocked until the evaluator\'s outputs are ready '
                    '(inside d2h).',
     'expand': 'Compact readback expanded; chunk buffers released.',

@@ -25,6 +25,7 @@ import collections
 import contextvars
 import logging
 import os
+import pickle
 import sys
 import threading
 import time
@@ -41,11 +42,12 @@ D2H_STALLS = 'kyverno_tpu_d2h_stalls_total'
 PIPELINE_INFLIGHT = 'kyverno_tpu_scan_pipeline_inflight_chunks'
 BACKPRESSURE = 'kyverno_tpu_scan_backpressure_seconds_total'
 ENCODE_WORKER_CHUNKS = 'kyverno_tpu_encode_worker_chunks_total'
+ENCODE_RESULT_BYTES = 'kyverno_tpu_encode_result_bytes_total'
 STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 
 #: canonical stage labels.  The pipeline's, in order: ``match`` (host
 #: match sieve), ``encode`` (in a worker process or inline),
-#: ``encode_wait`` (the h2d thread blocked on the encoder pool),
+#: ``encode_wait`` (the h2d thread waiting out a worker's encode),
 #: ``pack``, ``h2d``, ``compile``, ``device_eval`` (the dispatch: it
 #: times the ENQUEUE), ``d2h`` (wait + copy), ``device_wait`` (nested in
 #: ``d2h``: blocked until the evaluator's outputs are ready),
@@ -394,13 +396,27 @@ def record_stage_retry(stage: str) -> None:
 
 
 def record_encode_worker(result: str) -> None:
-    """One outcome of the encoder worker pool: ``ok`` per chunk a
-    worker encoded, ``presumed_dead`` when a chunk's worker did not
-    answer inside ENCODE_TIMEOUT_S, ``pool_failed`` when the pool could
-    not start or take a task.  Anything but ``ok`` means the scanner
+    """One outcome of the encoder worker pool: ``ok`` per chunk whose
+    lanes a worker left in a shared-memory block, ``presumed_dead`` when
+    a chunk's worker did not answer inside ENCODE_TIMEOUT_S,
+    ``pool_failed`` when the pool could not start or take a task, or a
+    block could not be had.  Anything but ``ok`` means the scanner
     dropped to in-process encoding."""
     if _registry is not None:
         _registry.inc(ENCODE_WORKER_CHUNKS, result=result)
+
+
+def record_encode_result_bytes(lanes, answer) -> None:
+    """What one chunk's encode brought home from its worker, by the way
+    it came: the bytes of ``lanes`` in the shared-memory block, and
+    through the pipe ``answer``, which names them (a few kB pickled).
+    Both are sized here, so only where metrics are on."""
+    if _registry is not None:
+        _registry.inc(ENCODE_RESULT_BYTES,
+                      float(sum(v.nbytes for v in lanes.values())),
+                      via='block')
+        _registry.inc(ENCODE_RESULT_BYTES,
+                      float(len(pickle.dumps(answer))), via='pipe')
 
 
 # -- d2h stall watchdog -----------------------------------------------------

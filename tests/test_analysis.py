@@ -569,6 +569,16 @@ def test_ktpu506_len_of_str_into_bytes_metric(tmp_path):
         reg.inc('kyverno_tpu_response_bytes_total', len(payload))
     """}, rules=['KTPU506'])
     assert not rep.active
+    # json.dumps gives a str, pickle.dumps gives bytes
+    for module, flagged in (('json', True), ('pickle', False)):
+        rep = run(tmp_path, {'a.py': f"""\
+        import {module}
+
+        def emit(reg, answer):
+            reg.inc('kyverno_tpu_response_bytes_total',
+                    len({module}.dumps(answer)))
+        """}, rules=['KTPU506'])
+        assert bool(rep.active) == flagged, module
 
 
 def test_ktpu506_ignores_unitless_metrics_and_bucket_args(tmp_path):
