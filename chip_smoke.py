@@ -14,11 +14,15 @@ answer to the host engine (``kyverno_tpu/engine``, the plain reference):
       ``WebhookServer.handle``;
   (d) one batch through the device mutate scanner.
 
-The policies are the pack this repo commits (``bench.load_policy_pack``) and
-the cluster is generated from ``--seed``; nothing outside the checkout is
-read.  Timings, counts, compile seconds and the compile-cache directory go
-on earlier lines; the last line of standard output is the one JSON object
-the driver reads.  Any failed phase raises: the exit code is then non-zero
+The policies are the pack this repo commits (``benchmarks/packs/``), the
+cluster and the requests come from the benchmark's generators and
+``--seed``, and the report store, the comparison with the host engine and the
+instruments are the benchmark's too (``benchmarks/benchlib.py``,
+``benchmarks/drivers/reports_controller.py``): this program keeps the
+phases and what only it runs on the chip (the rescan after churn, the device
+mutate batch, ``--mesh``).  Nothing outside the checkout is read.  Timings,
+counts, compile seconds and the compile-cache directory go on earlier lines;
+the last line of standard output is the one JSON object the driver reads.  Any failed phase raises: the exit code is then non-zero
 and no result line is printed.  The encoder workers and multiprocessing's
 two helpers are the only processes the program starts; they are stopped
 and waited for before the result line, which is refused while any
@@ -32,13 +36,24 @@ from __future__ import annotations
 
 import argparse
 import json
-import logging
 import os
 import sys
 import time
 
 _T0 = time.monotonic()
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(os.path.abspath(__file__))
+for _path in (os.path.join(ROOT, 'benchmarks'), ROOT):
+    if _path not in sys.path:
+        sys.path.insert(0, _path)
+
+import benchlib  # noqa: E402
+
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
+admission_reviews = benchlib.load_module('generators', 'admission_reviews')
+reports_driver = benchlib.load_module('drivers', 'reports_controller')
+
+#: the committed pack, in ``benchmarks/packs/``
+PACKS = ['pss', 'pack', 'config4']
 
 #: BASELINE.json configuration 3
 N_RESOURCES = 100_000
@@ -62,112 +77,12 @@ def require(ok, what: str) -> None:
         raise AssertionError(what)
 
 
-# -- the cluster -------------------------------------------------------------
-
-def make_cluster(seed: int, n: int) -> list:
-    """``n`` mixed resources: bare Pods and Deployments whose template is
-    such a Pod (the PSS policies reach those through their autogen rules;
-    the Pod-only policies do not match them at all)."""
-    import random
-    import bench
-    rng = random.Random(seed)
-    out = []
-    for i in range(n):
-        pod = bench.make_config4_pod(rng, i)
-        if rng.random() < 0.3:
-            meta = pod['metadata']
-            out.append({
-                'apiVersion': 'apps/v1', 'kind': 'Deployment',
-                'metadata': {'name': f'deploy-{i}',
-                             'namespace': meta['namespace'],
-                             'labels': dict(meta['labels'])},
-                'spec': {'replicas': 1 + i % 3,
-                         'selector': {'matchLabels':
-                                      {'app': meta['labels']['app']}},
-                         'template': {
-                             'metadata': {'labels': dict(meta['labels'])},
-                             'spec': pod['spec']}}})
-        else:
-            out.append(pod)
-    return out
-
-
-class ReportStore:
-    """The report sink the controller writes through: the client verbs it
-    calls, kept in one dict so every report can be read back."""
-
-    def __init__(self):
-        self.reports = {}
-
-    def get_resource(self, api_version, kind, ns, name):
-        return self.reports[(kind, ns, name)]
-
-    def create_resource(self, api_version, kind, ns, obj):
-        self.reports[(kind, ns, obj['metadata']['name'])] = obj
-        return obj
-
-    def update_resource(self, api_version, kind, ns, obj):
-        self.reports[(kind, ns, obj['metadata']['name'])] = obj
-        return obj
-
-    def delete_resource(self, api_version, kind, ns, name):
-        self.reports.pop((kind, ns, name), None)
-
-    def list_resource(self, *a, **k):
-        return []  # no PolicyExceptions in this cluster
-
-
-# -- the reference -----------------------------------------------------------
-
-def host_report(engine, policies, resource):
-    """The BackgroundScanReport spec the host engine gives one resource."""
-    from kyverno_tpu.engine.api import PolicyContext
-    from kyverno_tpu.reports.results import set_responses
-    from kyverno_tpu.reports.types import new_background_scan_report
-    responses = [engine.apply_background_checks(
-        PolicyContext(p, new_resource=resource)) for p in policies]
-    report = new_background_scan_report(resource)
-    set_responses(report, *[r for r in responses
-                            if r.policy_response.rules])
-    return report
-
-
-def _sans_timestamp(results):
-    return [{k: v for k, v in r.items() if k != 'timestamp'}
-            for r in results or []]
-
-
-def compare_reports(store, engine, policies, resources, what: str) -> None:
-    for resource in resources:
-        want = host_report(engine, policies, resource)
-        name = want['metadata']['name']
-        ns = resource['metadata'].get('namespace', '')
-        got = store.reports.get((want['kind'], ns, name))
-        require(got is not None, f'{what}: no report for {name}')
-        require(got['spec']['summary'] == want['spec']['summary'] and
-                _sans_timestamp(got['spec']['results']) ==
-                _sans_timestamp(want['spec']['results']),
-                f'{what}: report rows of {resource["kind"]} {name} differ '
-                f'from the host engine\'s')
+def require_none(problems: list, what: str) -> None:
+    """The benchmark's checks return what is wrong, one line each."""
+    require(not problems, f'{what}: ' + '; '.join(problems[:5]))
 
 
 # -- instruments -------------------------------------------------------------
-
-class CacheEvents:
-    """JAX's own persistent-compilation-cache events, counted."""
-
-    PREFIX = '/jax/compilation_cache/'
-
-    def __init__(self):
-        import jax.monitoring
-        self.counts = {}
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event.startswith(self.PREFIX):
-            key = event[len(self.PREFIX):]
-            self.counts[key] = self.counts.get(key, 0) + 1
-
 
 def cache_entries(cache_dir, prefix: str) -> int:
     """Entries of one jitted function in the compile cache directory."""
@@ -176,59 +91,6 @@ def cache_entries(cache_dir, prefix: str) -> int:
                    if f.startswith(prefix) and f.endswith('-cache'))
     except OSError:
         return 0
-
-
-class FailureLog(logging.Handler):
-    """Keeps the webhook's ERROR log lines ('device path failure')."""
-
-    def __init__(self):
-        super().__init__(logging.ERROR)
-        self.lines = []
-        logging.getLogger('kyverno.webhooks').addHandler(self)
-
-    def emit(self, record):
-        self.lines.append(record.getMessage())
-
-    def count(self, needle: str) -> int:
-        return sum(needle in line for line in self.lines)
-
-
-def executables_on(platform: str, fingerprint: str, what: str) -> list:
-    """The ledger's records for one policy set, each required to have
-    run and to keep its outputs on ``platform``."""
-    from kyverno_tpu.observability import executables
-    records = [r for r in executables.ledger().records()
-               if r.fingerprint == fingerprint]
-    require(records, f'{what}: no executable was registered')
-    for r in records:
-        say(f'{what}: executable capacity={r.capacity} source={r.source} '
-            f'build_s={r.build_s:.2f} dispatches={r.dispatches} '
-            f'device_s={r.device_s:.3f} outputs_on={r.platform or "?"}')
-        require(r.platform == platform,
-                f'{what}: outputs of the capacity-{r.capacity} executable '
-                f'live on {r.platform!r}, not on {platform!r}')
-    return records
-
-
-def descendants() -> list:
-    """Every live process below this one, as ``(pid, command line)``."""
-    parent_of, cmd = {}, {}
-    for pid in filter(str.isdigit, os.listdir('/proc')):
-        try:
-            with open(f'/proc/{pid}/stat') as f:
-                state, ppid = f.read().rsplit(')', 1)[1].split()[:2]
-            with open(f'/proc/{pid}/cmdline') as f:
-                cmd[int(pid)] = f.read().replace('\0', ' ').strip()
-        except OSError:
-            continue  # gone between the listing and the read
-        if state != 'Z':
-            parent_of[int(pid)] = int(ppid)
-    below = {os.getpid()}
-    while True:
-        more = {p for p, pp in parent_of.items() if pp in below} - below
-        if not more:
-            return sorted((p, cmd[p]) for p in below - {os.getpid()})
-        below |= more
 
 
 def worker_counts(registry) -> dict:
@@ -256,7 +118,7 @@ def scan_phase(policies, cluster, platform: str, seed: int, registry,
     # script must scan on the device again, not replay the first run
     vdir = tempfile.mkdtemp(prefix='ktpu-smoke-verdicts-')
     os.environ['KTPU_VERDICT_CACHE_DIR'] = vdir
-    store = ReportStore()
+    store = reports_driver.ReportStore()
     t0 = time.monotonic()
     cache = MetadataCache()
     ctrl = BackgroundScanController(store, policies, cache=cache)
@@ -280,17 +142,16 @@ def scan_phase(policies, cluster, platform: str, seed: int, registry,
         engine = Engine()
         t0 = time.monotonic()
         sample = rng.sample(range(n), min(n_sample, n))
-        compare_reports(store, engine, policies,
-                        [cluster[i] for i in sample], 'cold scan')
+        require_none(reports_driver.compare_reports(
+            store, engine, policies, [cluster[i] for i in sample]),
+            'cold scan')
         say(f'scan: {len(sample)} sampled reports equal the host '
             f'engine\'s ({time.monotonic() - t0:.1f}s)')
 
         touched = rng.sample(range(n), max(1, int(n * CHURN)))
         for i in touched:
-            spec = cluster[i]['spec']
-            pod_spec = spec['template']['spec'] \
-                if cluster[i]['kind'] == 'Deployment' else spec
-            pod_spec['containers'][0]['image'] = f'registry/churn:{i}'
+            mixed_cluster.pod_spec(cluster[i])['containers'][0]['image'] = \
+                f'registry/churn:{i}'
             ctrl.enqueue(cluster[i])
         t0 = time.monotonic()
         reports = ctrl.reconcile()
@@ -301,9 +162,10 @@ def scan_phase(policies, cluster, platform: str, seed: int, registry,
         require(len(reports) == len(touched) and len(store.reports) == n,
                 f'second reconcile wrote {len(reports)} reports for '
                 f'{len(touched)} touched resources')
-        compare_reports(store, engine, policies,
-                        [cluster[i] for i in
-                         touched[:max(1, n_sample // 4)]], 'rescan')
+        require_none(reports_driver.compare_reports(
+            store, engine, policies,
+            [cluster[i] for i in touched[:max(1, n_sample // 4)]]),
+            'rescan')
 
         cov = coverage.bench_block()
         require(cov['device_rows'] + cov['host_rows'] == cov['total_rows']
@@ -314,8 +176,9 @@ def scan_phase(policies, cluster, platform: str, seed: int, registry,
             f'total_rows={cov["total_rows"]} device_share='
             f'{cov["device_rows"] / cov["total_rows"]:.4f} '
             f'by_reason={json.dumps(cov.get("by_reason", {}))}')
-        executables_on(platform, ctrl.scanner.fingerprint,
-                       'scan')
+        require_none(benchlib.executables_problems(
+            benchlib.executables(ctrl.scanner.fingerprint), platform,
+            'scan'), 'the executable ledger')
         workers = worker_counts(registry)
         retries = int(registry.counter_total(devtel.STAGE_RETRIES))
         say(f'scan: encoder workers {workers} '
@@ -336,70 +199,22 @@ def scan_phase(policies, cluster, platform: str, seed: int, registry,
 
 # -- phase (c): the admission webhook ----------------------------------------
 
-def compliant_pod(i: int) -> dict:
-    """A Pod every policy of the pack admits (restricted PSS included)."""
-    container = {
-        'name': 'c0', 'image': 'ghcr.io/org/app:v2.1',
-        'resources': {'requests': {'memory': '64Mi', 'cpu': '100m'},
-                      'limits': {'memory': '128Mi'}},
-        'livenessProbe': {'httpGet': {'path': '/healthz', 'port': 8080}},
-        'securityContext': {'allowPrivilegeEscalation': False,
-                            'runAsNonRoot': True,
-                            'capabilities': {'drop': ['ALL']}}}
-    return {'apiVersion': 'v1', 'kind': 'Pod',
-            'metadata': {'name': f'ok-{i}', 'namespace': f'ns-{i % 7}',
-                         'labels': {'app': f'app-{i % 11}', 'tier': 'web'}},
-            'spec': {'securityContext': {
-                         'runAsNonRoot': True,
-                         'seccompProfile': {'type': 'RuntimeDefault'}},
-                     'containers': [container]}}
-
-
-def admission_request(i: int, doc: dict, users) -> dict:
-    """The i-th request: three in four CREATE, the rest UPDATE with an
-    oldObject that differs; the user changes with every request."""
-    user = users.users[(i * 7) % len(users.users)]
-    request = {
-        'uid': f'smoke-{i}',
-        'operation': 'UPDATE' if i % 4 == 3 else 'CREATE',
-        'kind': {'group': '', 'version': 'v1', 'kind': 'Pod'},
-        'namespace': doc['metadata']['namespace'],
-        'name': doc['metadata']['name'],
-        'object': doc,
-        'userInfo': users.user_info(user),
-    }
-    if request['operation'] == 'UPDATE':
-        old = json.loads(json.dumps(doc))
-        old['metadata'].setdefault('labels', {})['rev'] = 'old'
-        request['oldObject'] = old
-    return request
-
-
 def admission_phase(policies, cluster, platform: str, seed: int,
                     n_policies: int, n_requests: int) -> dict:
     import statistics
     import threading
-    import bench
-    from kyverno_tpu.conformance.loadgen import SyntheticCluster
     from kyverno_tpu.policycache import cache as pcache
     from kyverno_tpu.serving import breaker
     from kyverno_tpu.webhooks.handlers import ResourceHandlers
     from kyverno_tpu.webhooks.server import WebhookServer
 
-    replicated = bench.replicate_enforce(policies, n_policies)
+    replicated = benchlib.replicate_enforce(policies, n_policies)
     # the cluster's own Pods nearly all break some enforce policy, so
-    # every third request carries one that must be admitted
-    pods = [r for r in cluster if r['kind'] == 'Pod'][:n_requests]
-    pods = [compliant_pod(i) if i % 3 == 2 else doc
-            for i, doc in enumerate(pods)]
-    users = SyntheticCluster(seed=seed)
-    bodies = [json.dumps({'apiVersion': 'admission.k8s.io/v1',
-                          'kind': 'AdmissionReview',
-                          'request': admission_request(i, doc, users)}
-                         ).encode()
-              for i, doc in enumerate(pods)]
+    # every third request carries one that must be admitted; three in
+    # four CREATE, the rest UPDATE, the user drawn by Zipf from the seed
+    bodies = admission_reviews.generate(seed, cluster, n_requests)
 
-    failures = FailureLog()
+    failures = benchlib.FailureLog()
     cache = pcache.Cache()
     cache.warm_up(replicated)
     # a request leaves its batch for the host loop once it has waited
@@ -410,8 +225,9 @@ def admission_phase(policies, cluster, platform: str, seed: int,
     os.environ['KTPU_SHED_DEADLINE_MS'] = '5000'
     handlers = ResourceHandlers(cache, serving_mode='batch')
     server = WebhookServer(handlers)
-    enforce = cache.get_policies(pcache.VALIDATE_ENFORCE, 'Pod',
-                                 pods[0]['metadata']['namespace'])
+    enforce = cache.get_policies(
+        pcache.VALIDATE_ENFORCE, 'Pod',
+        json.loads(bodies[0])['request']['namespace'])
     require(len(enforce) == n_policies,
             f'{len(enforce)} enforce policies apply to a Pod, '
             f'not {n_policies}')
@@ -487,9 +303,11 @@ def admission_phase(policies, cluster, platform: str, seed: int,
             all(b['state'] == breaker.CLOSED for b in report['breakers']),
             f'the device path failed: {report}')
     require(handlers.device, 'the device path was switched off')
-    records = executables_on(platform, scanner.fingerprint,
-                             'admission')
-    require(any(r.capacity == scanner.SMALL_BATCH and r.dispatches > 0
+    records = benchlib.executables(scanner.fingerprint)
+    require_none(benchlib.executables_problems(records, platform,
+                                               'admission'),
+                 'the executable ledger')
+    require(any(r['capacity'] == scanner.SMALL_BATCH and r['dispatches'] > 0
                 for r in records),
             'the admission batch executable was never dispatched')
     return {'p50_ms': p50, 'p99_ms': p99}
@@ -499,12 +317,12 @@ def admission_phase(policies, cluster, platform: str, seed: int,
 
 def mutate_phase(seed: int, n_rows: int) -> None:
     import random
-    import bench
+    from kyverno_tpu.conformance import corpus
     from kyverno_tpu.engine.engine import Engine
     from kyverno_tpu.mutate import MutateScanner
-    policies = bench.load_mutate_pack()
+    policies = corpus.load_mutate_pack()
     rng = random.Random(seed + 2)
-    pods = [bench.make_mutate_pod(rng, i) for i in range(n_rows)]
+    pods = [corpus.make_mutate_pod(rng, i) for i in range(n_rows)]
     scanner = MutateScanner(policies)
     require(scanner.ok, 'the mutate pack did not lower to the device')
     t0 = time.monotonic()
@@ -512,8 +330,8 @@ def mutate_phase(seed: int, n_rows: int) -> None:
     scan_s = time.monotonic() - t0
     engine = Engine()
     for i, pod in enumerate(pods):
-        bench.check_mutate_row(engine, policies, pod, rows[i],
-                               f'mutate row {i}')
+        corpus.check_mutate_row(engine, policies, pod, rows[i],
+                                f'mutate row {i}')
     edited = sum(patched != pod for pod, (_steps, patched)
                  in zip(pods, rows))
     say(f'mutate: one batch of {n_rows} rows in {scan_s:.2f}s (compile '
@@ -594,15 +412,10 @@ def run(seed: int = 0, mesh: bool = False, platform: str = 'tpu',
     say(f'(a) {len(jax.devices())} x {device.device_kind} '
         f'({device.platform}), jax {jax.__version__}')
 
-    import bench
     from kyverno_tpu.aotcache import enable_persistent_compilation_cache
     from kyverno_tpu.compiler.scan import stop_encoder_processes
-    from kyverno_tpu.observability import coverage
     from kyverno_tpu.observability import device as devtel
-    from kyverno_tpu.observability import executables
-    from kyverno_tpu.observability.metrics import (MetricsRegistry,
-                                                   set_global_registry)
-    events = CacheEvents()
+    events = benchlib.CacheEvents()
     cache_dir = enable_persistent_compilation_cache()
     require(cache_dir and jax.config.jax_compilation_cache_dir == cache_dir,
             f'the compile cache is at '
@@ -611,15 +424,11 @@ def run(seed: int = 0, mesh: bool = False, platform: str = 'tpu',
     say(f'compile cache: {cache_dir} (JAX_COMPILATION_CACHE_DIR '
         f'{"set" if os.environ.get("JAX_COMPILATION_CACHE_DIR") else "unset"}'
         f'), {before} evaluator entries before this run')
-    registry = MetricsRegistry()
-    set_global_registry(registry)
-    devtel.configure(registry)
-    coverage.configure(registry)
-    executables.configure(registry)
+    registry = benchlib.program_telemetry()
 
-    policies = bench.load_policy_pack()
+    policies = benchlib.load_policies(PACKS)
     t0 = time.monotonic()
-    cluster = make_cluster(seed, n_resources)
+    cluster = mixed_cluster.generate(seed, n_resources)
     kinds = {}
     for r in cluster:
         kinds[r['kind']] = kinds.get(r['kind'], 0) + 1
@@ -643,13 +452,13 @@ def run(seed: int = 0, mesh: bool = False, platform: str = 'tpu',
         # the encoder pool, its fork server and the resource tracker are
         # the only processes this program starts
         stop_encoder_processes()
-    left = descendants()
+    left = benchlib.descendants()
     require(not left, f'processes this run started are still alive: {left}')
     say('processes: encoder workers, fork server and resource tracker '
         'stopped and waited for; no descendant of this process is alive')
     after = cache_entries(cache_dir, 'jit_evaluate_packed-')
-    say(f'compile cache: persistent hits={events.counts.get("cache_hits", 0)}'
-        f' of {events.counts.get("compile_requests_use_cache", 0)} '
+    say(f'compile cache: persistent hits={events.count(events.HIT)}'
+        f' of {events.count(events.REQUEST)} '
         f'compile requests; evaluator entries {before} -> {after} '
         f'({after - before} compiled fresh)')
     say(f'all phases passed in {time.monotonic() - _T0:.1f}s')

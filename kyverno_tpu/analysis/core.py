@@ -193,7 +193,7 @@ EXCLUDE_DIRS = frozenset({
 #: the default analyzed file set, shared by the driver and the
 #: standalone checker shims (``scripts/`` is *included* by intent —
 #: the lint tooling lints itself; ``tests/`` is excluded above)
-DEFAULT_SOURCE_PATHS = ('kyverno_tpu', 'scripts', 'bench.py')
+DEFAULT_SOURCE_PATHS = ('kyverno_tpu', 'scripts')
 
 
 def collect_files(paths: List[str], root: str) -> List[SourceFile]:

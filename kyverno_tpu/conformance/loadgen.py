@@ -9,23 +9,23 @@ ride the host engine loop, and bursty/trickling arrival.  The
 generator is fully deterministic for a seed, so bench numbers and
 tests reproduce.
 
-Consumers:
+Consumers, tests only since ``bench.py`` went (the benchmark's cells
+draw their requests from ``benchmarks/generators/``, which copied this
+module's user model):
 
-* ``bench.py`` drives the admission-concurrency bench with
-  :meth:`SyntheticCluster.review_bytes` and ratchets mean batch
-  occupancy under this traffic (``HET_OCCUPANCY_FLOOR``);
-* tests use small instances to pin batched-vs-sync bit-identity under
-  mixed admission tuples;
-* the chaos drills (``bench.py --admission-chaos``,
-  ``tests/test_faults.py``) mark a deterministic slice of rows as
-  *poison* — their ``chaos`` label is what a marker-armed
-  ``KTPU_FAULTS`` clause keys on — and pair the traffic with a fault
-  schedule, so a run under injected failures replays against its own
-  fault-free oracle;
-* the policy-churn bench (``bench.py --policy-churn``) and churn
-  tests share :meth:`SyntheticCluster.churn_schedule` /
-  :func:`apply_churn` — deterministic mid-burst policy edit/add/delete
-  events at fixed request ticks.
+* tests use small instances (:meth:`SyntheticCluster.review_bytes`) to
+  pin batched-vs-sync bit-identity under mixed admission tuples;
+* the chaos drills (``tests/test_faults.py``) mark a deterministic
+  slice of rows as *poison* — their ``chaos`` label is what a
+  marker-armed ``KTPU_FAULTS`` clause keys on — and pair the traffic
+  with a fault schedule, so a run under injected failures replays
+  against its own fault-free oracle;
+* :meth:`SyntheticCluster.churn_schedule` / :func:`apply_churn`
+  (deterministic mid-burst policy edit/add/delete events at fixed
+  request ticks), :meth:`SyntheticCluster.arrivals` and
+  :meth:`SyntheticCluster.exception_docs` have no caller left; the cell
+  ``rescan_policy_edit`` (ROADMAP R4b) is the churn schedule's intended
+  one, and ROADMAP D5 lists them.
 
 Layered beside the kuttl/scenario harness (this package): scenarios
 replay *recorded* cases, the generator synthesizes *load*.

@@ -16,7 +16,8 @@ of those falls *attributed*, never silent:
   server, ``scripts/coverage_report.py``);
 * runtime counters ``kyverno_tpu_host_fallback_total{path, reason}``
   and a per-scan ``kyverno_tpu_device_coverage_ratio`` gauge, plus the
-  ``coverage`` block ``bench.py`` embeds in its JSON line.
+  ``coverage`` block (:func:`bench_block`) that the benchmark's reports
+  driver and ``chip_smoke.py`` read.
 
 Everything is a no-op until :func:`configure` runs (the established
 ``observability/device.py`` contract): an unconfigured process records
@@ -419,7 +420,9 @@ class CoverageLedger:
         }
 
     def totals(self) -> dict:
-        """The ``coverage`` block bench.py embeds in its JSON line."""
+        """The ``coverage`` block (:func:`bench_block`): read by
+        ``benchmarks/drivers/reports_controller.py`` (``host_rows_share``)
+        and by ``chip_smoke.py``."""
         with self._lock:
             out = self._totals_locked()
             by_reason: Dict[str, Dict[str, int]] = {}

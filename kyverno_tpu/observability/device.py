@@ -557,7 +557,9 @@ def d2h_guard(attributes: Optional[Dict[str, Any]] = None, parent=None):
 
 def stage_breakdown() -> Dict[str, Dict[str, float]]:
     """Per-stage {total_s, count, mean_s} from the stage histogram —
-    the ``stage_breakdown`` block bench.py embeds in its JSON line."""
+    tests only; the benchmark's reports driver reads the same histogram
+    unrounded (``benchmarks/drivers/reports_controller.py``
+    ``_snapshot``)."""
     if _registry is None:
         return {}
     out: Dict[str, Dict[str, float]] = {}

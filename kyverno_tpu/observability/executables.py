@@ -281,8 +281,10 @@ class ExecutableLedger:
             return list(self._records.values())
 
     def census(self) -> Dict[str, Any]:
-        """The compact summary bench.py embeds: live counts by source +
-        cumulative dispatch/device totals."""
+        """The compact summary inside :meth:`report` (``GET
+        /debug/executables``): live counts by source + cumulative
+        dispatch/device totals.  The module-level :func:`census` is read
+        by tests only."""
         with self._lock:
             recs = list(self._records.values())
         by_source: Dict[str, int] = {}

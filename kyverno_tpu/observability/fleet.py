@@ -28,8 +28,8 @@ emitted no per-shard attribution at all.  Three pieces close that gap:
   and merges snapshots: counters sum, histograms merge bucket-wise,
   gauges follow residency rules (occupancy gauges marked
   ``reset_on_close`` sum across the fleet; state gauges take the max).
-  Snapshots arrive by pull (``GET /debug/fleet``), by JSONL files from
-  a bench run (``scripts/fleet_report.py``), or programmatically
+  Snapshots arrive by pull (``GET /debug/fleet``), by JSONL files
+  (``scripts/fleet_report.py``), or programmatically
   (:meth:`FleetRegistry.add_snapshot` — keyed by identity, so re-adding
   a host's snapshot replaces it and the merge stays idempotent).
 
@@ -396,13 +396,14 @@ class FleetRegistry:
         return '\n'.join(lines) + '\n'
 
 
-# -- snapshot files (offline bench merge) ------------------------------------
+# -- snapshot files (offline merge) ------------------------------------------
 
 
 def write_snapshot(path: str,
                    registry: Optional[MetricsRegistry] = None) -> Dict:
     """Append this process's snapshot as one JSONL line (the per-host
-    artifact a bench run leaves behind for offline federation)."""
+    artifact for offline federation; tests only since ``bench.py``
+    went)."""
     reg = registry or (_fleet._registry if _fleet is not None else None)
     if reg is None:
         raise RuntimeError('fleet snapshot needs a configured registry')

@@ -370,7 +370,7 @@ def record_decision(path: str, source: str = 'admission', uid: str = '',
     return rec
 
 
-# -- bench / endpoint views --------------------------------------------------
+# -- summary / endpoint views ------------------------------------------------
 
 def _pctl(sorted_vals: List[float], q: float) -> float:
     if not sorted_vals:
@@ -380,8 +380,8 @@ def _pctl(sorted_vals: List[float], q: float) -> float:
 
 
 def breakdown() -> Dict[str, Any]:
-    """The ``decision_breakdown`` block ``bench.py`` embeds: per-path
-    decision counts + p50/p95 latency, and the device-share histogram
+    """Tests only (``tests/test_provenance.py``) since ``bench.py`` went:
+    per-path decision counts + p50/p95 latency, and the device-share histogram
     over batch/sync decisions — the homogeneous-vs-heterogeneous
     occupancy gap as a tracked number."""
     r = _recorder

@@ -153,13 +153,12 @@ class InMemoryExporter:
 class JsonlExporter:
     """Append each finished span as one OTLP-shaped JSON line.
 
-    Serves the bench path: a scan run leaves a machine-readable
-    per-stage record on disk (``stage_breakdown`` assembly) without a
-    collector.  Writes are line-buffered and locked.  The file rotates
-    by size (``KTPU_TRACE_JSONL_MAX_BYTES``; 0 disables): when the next
+    A scan run leaves a machine-readable per-stage record on disk
+    without a collector.  Writes are line-buffered and locked.  The file
+    rotates by size (``KTPU_TRACE_JSONL_MAX_BYTES``; 0 disables): when the next
     line would exceed the budget, the current file moves to
     ``<path>.1`` (one rotated generation kept) and a fresh file opens —
-    long benches no longer grow the trace file without bound.  A write
+    long runs no longer grow the trace file without bound.  A write
     failure closes the exporter and re-raises so ``Tracer._export``
     counts it on ``kyverno_tpu_trace_export_errors_total``."""
 

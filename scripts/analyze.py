@@ -11,7 +11,7 @@
     python scripts/analyze.py --knob-table     # README KTPU_* table
     python scripts/analyze.py --list-rules     # rule id reference
 
-Default file set: ``kyverno_tpu/``, ``scripts/``, and ``bench.py``.
+Default file set: ``kyverno_tpu/`` and ``scripts/``.
 The committed baseline lives at ``.ktpu-baseline.json``; every entry
 must carry a ``reason`` (``--strict`` refuses unjustified entries).
 Per-line suppressions: ``# ktpu: noqa[KTPU101] -- reason``.
@@ -38,7 +38,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument('paths', nargs='*', default=None,
                     help='files/dirs to analyze (default: '
-                         'kyverno_tpu scripts bench.py)')
+                         'kyverno_tpu scripts)')
     ap.add_argument('--json', action='store_true', dest='as_json')
     ap.add_argument('--strict', action='store_true',
                     help='exit nonzero on non-baseline findings, '

@@ -7,7 +7,8 @@ twin of a live process's ``GET /debug/fleet``.  Two modes:
   scripts/fleet_report.py host1.jsonl host2.jsonl ...
       merge per-host JSONL snapshot files (written by
       ``kyverno_tpu.observability.fleet.write_snapshot`` — one line
-      per snapshot; ``bench.py --multichip`` leaves these behind) with
+      per snapshot; no program writes them since ``bench.py`` went,
+      ``tests/test_fleet.py`` does) with
       the exact merge the live endpoint uses, so the CLI and a running
       process can never disagree on the math.
 

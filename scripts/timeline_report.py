@@ -11,13 +11,13 @@ offline half).
                                                            # only
 
 The trace comes from ``GET /debug/timeline?format=chrome`` on a live
-process, or from the file a northstar ``bench.py`` run drops (path in
-its ``critical_path.trace_file`` field); Perfetto
+process (``observability/timeline.py`` ``dump_chrome_trace`` writes the
+same file, but no program calls it since ``bench.py`` went); Perfetto
 (https://ui.perfetto.dev) loads the same file directly.  ``--check``
 validates against the trace-event schema subset we emit (complete 'X'
 events with numeric non-negative ts/dur, matched 'B'/'E' pairs with
 per-(pid,tid) monotonic timestamps) and exits nonzero on any violation
-— the bench harness runs it over every trace it writes.
+(``tests/test_timeline.py`` runs it over a trace it records).
 """
 
 from __future__ import annotations

@@ -14,4 +14,10 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# The checkout for ``kyverno_tpu`` and ``chip_smoke``; ``benchmarks/`` for
+# ``benchlib``, through which the tests read the packs and generators the
+# benchmark's cells run (``benchlib.load_policies``, ``benchlib.load_module``).
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for _path in (os.path.join(ROOT, 'benchmarks'), ROOT):
+    if _path not in sys.path:
+        sys.path.insert(0, _path)

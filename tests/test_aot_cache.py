@@ -298,7 +298,8 @@ def _fresh_python(script, timeout=240, **extra_env):
     env = {k: v for k, v in os.environ.items()
            if k not in ('XLA_FLAGS', 'JAX_PLATFORMS',
                         'JAX_COMPILATION_CACHE_DIR')}
-    env.update({'JAX_PLATFORMS': 'cpu', 'PYTHONPATH': REPO}, **extra_env)
+    env.update({'JAX_PLATFORMS': 'cpu', 'PYTHONPATH': os.pathsep.join(
+        (REPO, os.path.join(REPO, 'benchmarks')))}, **extra_env)
     out = subprocess.run([sys.executable, '-c', script],
                          env=env, cwd=REPO, capture_output=True,
                          text=True, timeout=timeout)
@@ -456,16 +457,16 @@ def test_one_row_batch_asks_for_no_cpu_device(monkeypatch):
 
 _XLA_THEN_AOT_SCRIPT = r'''
 import json, os, random, sys
-import bench
-from kyverno_tpu.api.policy import load_policies_from_yaml
+import benchlib
 from kyverno_tpu.observability import device as devtel
 from kyverno_tpu.observability.metrics import MetricsRegistry
 reg = devtel.configure(MetricsRegistry())
 from kyverno_tpu.compiler.scan import BatchScanner
-scanner = BatchScanner(load_policies_from_yaml(bench.PACK))
+scanner = BatchScanner(benchlib.load_policies(['pack']))
+make_pod = benchlib.load_module('generators', 'mixed_cluster').make_pod
 rng = random.Random(0)
 status, detail, match = scanner.scan_statuses(
-    [bench.make_pod(rng, i) for i in range(4)])
+    [make_pod(rng, i) for i in range(4)])
 from kyverno_tpu.compiler import aot
 aot.flush_stores()
 C = 'kyverno_tpu_compile_cache_requests_total'

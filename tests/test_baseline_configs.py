@@ -14,23 +14,26 @@ import random
 
 import pytest
 
-import bench
+import benchlib
 from kyverno_tpu.api.policy import load_policies_from_yaml
 from kyverno_tpu.compiler.apply import BatchApplier
 from kyverno_tpu.compiler.scan import BatchScanner
+from kyverno_tpu.conformance import corpus
 from kyverno_tpu.engine.api import PolicyContext
 from kyverno_tpu.engine.engine import Engine
+
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
 
 
 class TestConfig4JMESPathHeavy:
     @pytest.fixture(scope='class')
     def policies(self):
-        return load_policies_from_yaml(bench.CONFIG4_PACK)
+        return benchlib.load_policies(['config4'])
 
     @pytest.fixture(scope='class')
     def pods(self):
         rng = random.Random(7)
-        return [bench.make_config4_pod(rng, i) for i in range(160)]
+        return [mixed_cluster.make_config4_pod(rng, i) for i in range(160)]
 
     def test_pack_mostly_compiles(self, policies):
         scanner = BatchScanner(policies)
@@ -73,12 +76,13 @@ class TestConfig4JMESPathHeavy:
 class TestConfig5MutateGenerate:
     @pytest.fixture(scope='class')
     def policies(self):
-        return load_policies_from_yaml(bench.CONFIG5_PACK)
+        return load_policies_from_yaml(corpus.CONFIG5_PACK)
 
     @pytest.fixture(scope='class')
     def dump(self):
         rng = random.Random(11)
-        return [bench.make_config5_resource(rng, i) for i in range(300)]
+        return [corpus.make_config5_resource(rng, i, mixed_cluster.make_pod)
+                for i in range(300)]
 
     def test_applier_matches_engine_loop(self, policies, dump):
         applier = BatchApplier(policies, processes=0)

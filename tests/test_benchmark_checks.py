@@ -1,0 +1,37 @@
+"""The benchmark's own fast checks, run by tier-1.
+
+``benchmarks/tests/`` is outside ``pytest tests/``, so nothing held
+``BENCHMARK.json``, the layer files, the open-loop schedule, the bytes table
+or the trace reduction until a chip run failed.  This file takes the tests
+and fixtures of the five files that need no subprocess run of a cell (about a
+second together) into its own namespace, as they are; ``tests/conftest.py``
+has put ``benchmarks/`` on ``sys.path``, which is all their own
+``conftest.py`` does.  ``test_rehearse.py`` (two minutes of CPU rehearsals)
+stays outside: ``python -m pytest benchmarks/tests -q`` runs everything,
+and rewrites the asserts of a failing check, which this file's import does
+not.
+"""
+
+import importlib.util
+
+import benchlib
+
+FILES = ['test_bytes', 'test_files', 'test_layer_sources', 'test_schedule',
+         'test_trace_reduce']
+
+
+def _collect(name: str) -> dict:
+    path = benchlib.data_path('tests', name, '.py')
+    spec = importlib.util.spec_from_file_location(f'bench_tests_{name}', path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return {key: value for key, value in vars(module).items()
+            if key.startswith('test_')
+            or hasattr(value, '_fixture_function_marker')}  # a fixture
+
+
+for _name in FILES:
+    _found = _collect(_name)
+    _twice = sorted(set(_found) & set(globals()))
+    assert not _twice, f'{_name}.py defines {_twice} a second time'
+    globals().update(_found)

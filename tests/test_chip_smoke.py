@@ -1,17 +1,21 @@
 """chip_smoke.py off the chip: it refuses to run, and its traffic is what
 its checks assume.  The phases themselves only mean something on the chip
-(``python chip_smoke.py`` through the chip tool); nothing here runs them."""
+(``python chip_smoke.py`` through the chip tool); nothing here runs them.
+The cluster, the requests and ``descendants`` are the benchmark's
+(``benchmarks/generators/``, ``benchmarks/benchlib.py``), which the smoke
+and the cells both run."""
 
 import json
 import os
 import subprocess
 import sys
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+import benchlib
+import chip_smoke
 
-import bench  # noqa: E402
-import chip_smoke  # noqa: E402
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+mixed_cluster = chip_smoke.mixed_cluster
+admission_reviews = chip_smoke.admission_reviews
 
 
 def test_no_accelerator_no_result_line():
@@ -26,9 +30,9 @@ def test_no_accelerator_no_result_line():
 
 
 def test_cluster_is_seeded_and_mixed():
-    a = chip_smoke.make_cluster(0, 400)
-    assert a == chip_smoke.make_cluster(0, 400)
-    assert a != chip_smoke.make_cluster(1, 400)
+    a = mixed_cluster.generate(0, 400)
+    assert a == mixed_cluster.generate(0, 400)
+    assert a != mixed_cluster.generate(1, 400)
     kinds = {r['kind'] for r in a}
     assert kinds == {'Pod', 'Deployment'}
     names = {(r['kind'], r['metadata']['namespace'], r['metadata']['name'])
@@ -41,11 +45,10 @@ def test_cluster_is_seeded_and_mixed():
 def test_admission_traffic_has_both_answers_and_both_verbs():
     """Every enforce policy of the pack admits the compliant Pod, and
     the host engine refuses at least one of the cluster's own."""
-    from kyverno_tpu.conformance.loadgen import SyntheticCluster
     from kyverno_tpu.engine.api import PolicyContext
     from kyverno_tpu.engine.engine import Engine
     engine = Engine()
-    policies = bench.load_policy_pack()
+    policies = benchlib.load_policies(chip_smoke.PACKS)
 
     def failures(doc):
         return [r.name for p in policies
@@ -53,37 +56,37 @@ def test_admission_traffic_has_both_answers_and_both_verbs():
                     p, new_resource=doc)).policy_response.rules
                 if str(r.status) == 'fail']
 
-    assert failures(chip_smoke.compliant_pod(5)) == []
-    pods = [r for r in chip_smoke.make_cluster(0, 40) if r['kind'] == 'Pod']
-    assert any(failures(p) for p in pods)
-    users = SyntheticCluster(seed=0)
-    requests = [chip_smoke.admission_request(i, pods[i % len(pods)], users)
-                for i in range(8)]
+    assert failures(admission_reviews.compliant_pod(5)) == []
+    cluster = mixed_cluster.generate(0, 40)
+    assert any(failures(r) for r in cluster if r['kind'] == 'Pod')
+    bodies = admission_reviews.generate(0, cluster, 8)
+    assert bodies == admission_reviews.generate(0, cluster, 8)
+    requests = [json.loads(body)['request'] for body in bodies]
+    assert [r['name'].startswith('ok-') for r in requests] == \
+        [i % 3 == 2 for i in range(8)]
     assert {r['operation'] for r in requests} == {'CREATE', 'UPDATE'}
     assert all('oldObject' in r for r in requests
                if r['operation'] == 'UPDATE')
     assert len({r['userInfo']['username'] for r in requests}) > 1
-    json.dumps(requests)  # what goes on the wire
 
 
 def test_descendants_lists_live_children_only():
-    # an earlier test of this worker may have left the fork server up
-    before = chip_smoke.descendants()
+    # an earlier test of this worker may have left a fork server up, which
+    # may exit at any moment: only the child started here is asked about
     child = subprocess.Popen(['sleep', '60'])
     try:
-        assert [d for d in chip_smoke.descendants() if d not in before] \
-            == [(child.pid, 'sleep 60')]
+        assert (child.pid, 'sleep 60') in benchlib.descendants()
     finally:
         child.kill()
         child.wait()
-    assert chip_smoke.descendants() == before
+    assert child.pid not in [pid for pid, _cmd in benchlib.descendants()]
 
 
 SCAN_WITH_WORKERS = '''
-import random, sys
+import os, random, sys
 sys.path.insert(0, {repo!r})
-import bench
-from kyverno_tpu.api.policy import load_policies_from_yaml
+sys.path.insert(0, os.path.join({repo!r}, 'benchmarks'))
+import benchlib
 from kyverno_tpu.compiler.scan import BatchScanner
 from kyverno_tpu.observability import device as devtel
 from kyverno_tpu.observability.metrics import MetricsRegistry
@@ -91,11 +94,12 @@ from kyverno_tpu.observability.metrics import MetricsRegistry
 if __name__ == '__main__':
     registry = MetricsRegistry()
     devtel.configure(registry)
-    scanner = BatchScanner(load_policies_from_yaml(bench.PACK))
+    scanner = BatchScanner(benchlib.load_policies(['pack']))
+    make_pod = benchlib.load_module('generators', 'mixed_cluster').make_pod
     scanner.CHUNK = 16
     scanner._encoder_pool.procs = 2
     rng = random.Random(3)
-    docs = [bench.make_pod(rng, i) for i in range(48)]
+    docs = [make_pod(rng, i) for i in range(48)]
     assert len(list(scanner.scan_report_results(docs))) == len(docs)
     print('worker chunks',
           int(registry.counter_value(devtel.ENCODE_WORKER_CHUNKS,

@@ -46,11 +46,11 @@ def pod(name, ns, image='nginx:1.25'):
 
 
 def test_pack_fully_compiles():
-    """The committed pack (bench.load_policy_pack: PSS baseline +
-    restricted with their autogen rules, PACK, CONFIG4_PACK): 11
+    """The committed pack (benchmarks/packs: pss — baseline +
+    restricted with their autogen rules —, pack, config4): 11
     policies, every rule compiled for the device."""
-    import bench
-    policies = bench.load_policy_pack()
+    import benchlib
+    policies = benchlib.load_policies(['pss', 'pack', 'config4'])
     cps = compile_policies(policies)
     assert len(policies) == 11
     assert len(cps.host_rules) == 0

@@ -143,7 +143,7 @@ class TestMixedScan:
         for (path, reason), _rows in led._fallbacks.items():
             assert reason in coverage.REASONS, (path, reason)
         assert 'kyverno_tpu_host_fallback_total' in METRICS
-        # ledger invariant (what bench.py asserts before writing output)
+        # ledger invariant (what chip_smoke.py requires of its scan)
         totals = led.totals()
         assert totals['device_rows'] + totals['host_rows'] == \
             totals['total_rows']

@@ -22,6 +22,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = r'''
 import json, os, sys
 sys.path.insert(0, %(repo)r)
+sys.path.insert(0, os.path.join(%(repo)r, 'benchmarks'))
 import jax
 jax.config.update('jax_platforms', 'cpu')
 jax.distributed.initialize(coordinator_address=%(coord)r,
@@ -29,17 +30,17 @@ jax.distributed.initialize(coordinator_address=%(coord)r,
                            process_id=int(sys.argv[1]))
 assert jax.process_count() == 2
 import numpy as np
-import bench
-from kyverno_tpu.api.policy import load_policies_from_yaml
+import benchlib
 from kyverno_tpu.compiler.compile import compile_policies
 from kyverno_tpu.parallel.mesh import distributed_scan_step, make_mesh
 from kyverno_tpu.controllers.leaderelection import mesh_is_leader
 
-policies = load_policies_from_yaml(bench.PACK)
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
+policies = benchlib.load_policies(['pack'])
 cps = compile_policies(policies)
 import random
 rng = random.Random(0)
-resources = [bench.make_pod(rng, i) for i in range(24)]
+resources = [mixed_cluster.make_pod(rng, i) for i in range(24)]
 mesh = make_mesh()   # global devices across both processes
 assert mesh.devices.size == jax.device_count() == 4  # 2 per process
 statuses, summary = distributed_scan_step(cps, mesh, resources)
@@ -50,7 +51,7 @@ statuses, summary = distributed_scan_step(cps, mesh, resources)
 from kyverno_tpu.compiler.scan import BatchScanner
 from kyverno_tpu.reports.results import set_responses
 from kyverno_tpu.reports.types import new_background_scan_report
-stream_resources = [bench.make_pod(rng, 1000 + i) for i in range(40)]
+stream_resources = [mixed_cluster.make_pod(rng, 1000 + i) for i in range(40)]
 scanner = BatchScanner(policies, mesh=mesh)
 report_dump = []
 for resource, responses in zip(stream_resources,
@@ -126,16 +127,16 @@ def test_two_process_distributed_scan_agrees():
 
     import numpy as np
 
-    import bench
-    from kyverno_tpu.api.policy import load_policies_from_yaml
+    import benchlib
     from kyverno_tpu.compiler.compile import compile_policies
     from kyverno_tpu.compiler.encode import encode_batch
     from kyverno_tpu.ops.eval import build_evaluator, shard_batch
 
-    policies = load_policies_from_yaml(bench.PACK)
+    mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
+    policies = benchlib.load_policies(['pack'])
     cps = compile_policies(policies)
     rng = random.Random(0)
-    resources = [bench.make_pod(rng, i) for i in range(24)]
+    resources = [mixed_cluster.make_pod(rng, i) for i in range(24)]
     batch = encode_batch(resources, cps, padded_n=24)
     t, layout = shard_batch(batch.tensors(), None)
     evaluator = build_evaluator(cps)
@@ -150,7 +151,8 @@ def test_two_process_distributed_scan_agrees():
     from kyverno_tpu.reports.results import set_responses
     from kyverno_tpu.reports.types import new_background_scan_report
 
-    stream_resources = [bench.make_pod(rng, 1000 + i) for i in range(40)]
+    stream_resources = [mixed_cluster.make_pod(rng, 1000 + i)
+                        for i in range(40)]
     scanner = BatchScanner(policies)
     dump = []
     for resource, responses in zip(stream_resources,

@@ -35,10 +35,9 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-import bench  # noqa: E402
+import benchlib  # noqa: E402
 import encode_block_helpers as helpers  # noqa: E402
 import test_foreach_compile as foreach  # noqa: E402
-from kyverno_tpu.api.policy import load_policies_from_yaml  # noqa: E402
 from kyverno_tpu.compiler import encode as encode_mod  # noqa: E402
 from kyverno_tpu.compiler import scan as scan_mod  # noqa: E402
 from kyverno_tpu.compiler.compile import compile_policies  # noqa: E402
@@ -51,6 +50,7 @@ from kyverno_tpu.reports.types import build_fused_report  # noqa: E402
 
 CAP = 16  # rows a chunk, so a few dozen pods span several chunks
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
 
 
 def segments():
@@ -67,7 +67,7 @@ def pods(n, seed=5, containers=None):
     rng = random.Random(seed)
     out = []
     for i in range(n):
-        pod = bench.make_config4_pod(rng, i)
+        pod = mixed_cluster.make_config4_pod(rng, i)
         donor = foreach.make_pod(rng)['spec'].get('containers') or []
         for c, d in zip(pod['spec']['containers'], donor):
             if 'securityContext' in d:
@@ -85,8 +85,7 @@ def pods(n, seed=5, containers=None):
 def wide_cps():
     """Every lane family: slots with string heads, array metadata,
     gathers and element gathers."""
-    cps = compile_policies(load_policies_from_yaml(bench.PSS_PACK)
-                           + load_policies_from_yaml(bench.CONFIG4_PACK)
+    cps = compile_policies(benchlib.load_policies(['pss', 'config4'])
                            + foreach.load_pack())
     assert cps.slots and cps.gathers and cps.elem_gathers
     return cps
@@ -94,7 +93,7 @@ def wide_cps():
 
 @pytest.fixture(scope='module')
 def policies():
-    return load_policies_from_yaml(bench.PACK) + foreach.load_pack()
+    return benchlib.load_policies(['pack']) + foreach.load_pack()
 
 
 @pytest.fixture(scope='module', autouse=True)
@@ -483,14 +482,14 @@ A_WORKER_EXITS = '''
 import glob, os, sys
 sys.path.insert(0, {repo!r})
 sys.path.insert(0, os.path.join({repo!r}, 'tests'))
-import bench
+sys.path.insert(0, os.path.join({repo!r}, 'benchmarks'))
+import benchlib
 import test_encode_blocks as t
-from kyverno_tpu.api.policy import load_policies_from_yaml
 from kyverno_tpu.compiler.compile import compile_policies
 from kyverno_tpu.compiler.scan import _EncoderPool
 
 if __name__ == '__main__':
-    cps = compile_policies(load_policies_from_yaml(bench.PACK))
+    cps = compile_policies(benchlib.load_policies(['pack']))
     pool = _EncoderPool(cps, 1)
     assert pool.start()
     block = pool.blocks.acquire()

@@ -6,14 +6,17 @@ import random
 
 import pytest
 
-import bench
+import benchlib
 from kyverno_tpu.api.policy import load_policies_from_yaml
 from kyverno_tpu.compiler.apply import BatchApplier
+from kyverno_tpu.conformance import corpus
+
+make_pod = benchlib.load_module('generators', 'mixed_cluster').make_pod
 
 
 @pytest.fixture(scope='module')
 def policies():
-    return load_policies_from_yaml(bench.CONFIG5_PACK)
+    return load_policies_from_yaml(corpus.CONFIG5_PACK)
 
 
 def _run(policies, resources, fast, monkey):
@@ -33,7 +36,8 @@ def test_config5_pack_compiles_fast(policies, monkeypatch):
 
 def test_fast_matches_engine_bit_identical(policies, monkeypatch):
     rng = random.Random(23)
-    resources = [bench.make_config5_resource(rng, i) for i in range(400)]
+    resources = [corpus.make_config5_resource(rng, i, make_pod)
+                 for i in range(400)]
     # shape escapes: labels as non-dict, containers missing
     resources.append({'apiVersion': 'v1', 'kind': 'Pod',
                       'metadata': {'name': 'weird', 'namespace': 'x',
@@ -64,7 +68,8 @@ def test_fast_matches_engine_bit_identical(policies, monkeypatch):
 def test_fast_rate_improvement(policies, monkeypatch):
     import time
     rng = random.Random(7)
-    resources = [bench.make_config5_resource(rng, i) for i in range(1500)]
+    resources = [corpus.make_config5_resource(rng, i, make_pod)
+                 for i in range(1500)]
     monkeypatch.setenv('KTPU_FAST_MUTATE', '1')
     applier = BatchApplier(policies, processes=0)
     applier.apply(resources[:32], parallel=False)

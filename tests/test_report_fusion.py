@@ -7,13 +7,16 @@ import random
 
 import pytest
 
-import bench
+import benchlib
 from kyverno_tpu.api.policy import load_policies_from_yaml
 from kyverno_tpu.compiler.scan import BatchScanner
 from kyverno_tpu.reports.results import set_fused_results, set_responses
 from kyverno_tpu.reports.types import new_background_scan_report
 
-PACK = bench.PACK + """
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
+
+with open(benchlib.data_path('packs', 'pack', '.yaml')) as _f:
+    PACK = _f.read() + """
 ---
 apiVersion: kyverno.io/v1
 kind: ClusterPolicy
@@ -76,7 +79,7 @@ def scanner():
 
 def test_fused_matches_unfused(scanner):
     rng = random.Random(3)
-    pods = [bench.make_pod(rng, i) for i in range(96)]
+    pods = [mixed_cluster.make_pod(rng, i) for i in range(96)]
 
     unfused = []
     for pod, responses in zip(pods, scanner.scan_stream(pods)):
@@ -102,7 +105,7 @@ def test_fused_matches_unfused(scanner):
 
 def test_fused_results_are_sorted(scanner):
     rng = random.Random(5)
-    pods = [bench.make_pod(rng, i) for i in range(8)]
+    pods = [mixed_cluster.make_pod(rng, i) for i in range(8)]
     for results, _summary, _p in scanner.scan_report_results(pods):
         keys = [(r.get('policy', ''), r.get('rule', '')) for r in results]
         assert keys == sorted(keys)

@@ -54,8 +54,8 @@ def test_baseline_is_minimal_and_justified():
         reason = str(e.get('reason', '')).strip()
         assert reason and not reason.startswith('TODO'), \
             f'unjustified baseline entry: {e}'
-    analyzer = Analyzer(['kyverno_tpu', 'scripts', 'bench.py'],
-                        REPO_ROOT, baseline_path=BASELINE)
+    analyzer = Analyzer(['kyverno_tpu', 'scripts'], REPO_ROOT,
+                        baseline_path=BASELINE)
     report = analyzer.run()
     assert not report.stale_baseline, report.stale_baseline
     assert not report.active, [f.render() for f in report.active]

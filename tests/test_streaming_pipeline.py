@@ -28,8 +28,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-import bench  # noqa: E402
-from kyverno_tpu.api.policy import Policy, load_policies_from_yaml  # noqa: E402
+import benchlib  # noqa: E402
 from kyverno_tpu.compiler.scan import BatchScanner  # noqa: E402
 from kyverno_tpu.observability import device as devtel  # noqa: E402
 from kyverno_tpu.observability import provenance  # noqa: E402
@@ -37,16 +36,17 @@ from kyverno_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from kyverno_tpu.reports.types import build_fused_report  # noqa: E402
 
 CAP = 16  # tiny chunk capacity so a handful of pods spans many chunks
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
 
 
 def pods(n, seed=5):
     rng = random.Random(seed)
-    return [bench.make_pod(rng, i) for i in range(n)]
+    return [mixed_cluster.make_pod(rng, i) for i in range(n)]
 
 
 @pytest.fixture(scope='module')
 def policies():
-    return load_policies_from_yaml(bench.PACK)
+    return benchlib.load_policies(['pack'])
 
 
 @pytest.fixture()
@@ -240,7 +240,7 @@ class TestReplayInterleavedWithMisses:
         from kyverno_tpu.reports.controllers import (
             BackgroundScanController)
         monkeypatch.setenv('KTPU_VERDICT_CACHE_DIR', str(tmp_path / 'vc'))
-        policies = load_policies_from_yaml(bench.PACK)
+        policies = benchlib.load_policies(['pack'])
         docs = pods(3 * CAP + 5, seed=17)
         for i, d in enumerate(docs):
             d['metadata']['uid'] = f'uid-{i}'

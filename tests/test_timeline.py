@@ -32,9 +32,8 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-import bench  # noqa: E402
+import benchlib  # noqa: E402
 from kyverno_tpu import faults  # noqa: E402
-from kyverno_tpu.api.policy import load_policies_from_yaml  # noqa: E402
 from kyverno_tpu.compiler.scan import BatchScanner  # noqa: E402
 from kyverno_tpu.observability import device as devtel  # noqa: E402
 from kyverno_tpu.observability import timeline as tlmod  # noqa: E402
@@ -44,16 +43,17 @@ from kyverno_tpu.reports.types import build_fused_report  # noqa: E402
 
 CAP = 16  # tiny chunk capacity so a handful of pods spans many chunks
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
 
 
 def pods(n, seed=5):
     rng = random.Random(seed)
-    return [bench.make_pod(rng, i) for i in range(n)]
+    return [mixed_cluster.make_pod(rng, i) for i in range(n)]
 
 
 @pytest.fixture(scope='module')
 def policies():
-    return load_policies_from_yaml(bench.PACK)
+    return benchlib.load_policies(['pack'])
 
 
 @pytest.fixture()

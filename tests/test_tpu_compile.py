@@ -65,13 +65,14 @@ def pack():
     """The smoke's pack and one encoded admission-capacity batch of it:
     ``(cps, evaluator, packed, layout)``.  The packed layout does not depend on the
     capacity, so other capacities only change the leading dimension."""
-    import bench
+    import benchlib
     from kyverno_tpu.compiler import admission
     from kyverno_tpu.compiler.compile import compile_policies
     from kyverno_tpu.compiler.encode import encode_batch
     from kyverno_tpu.compiler.scan import WARM_POD
     from kyverno_tpu.ops.eval import build_evaluator, pack_batch
-    cps = compile_policies(bench.load_policy_pack())
+    cps = compile_policies(
+        benchlib.load_policies(['pss', 'pack', 'config4']))
     assert len(cps.programs) == 15 and not cps.host_rules
     evaluator = build_evaluator(cps)
     cap = 64
@@ -124,13 +125,13 @@ def test_evaluator_compiles_for_one_chip(pack, one_chip,
 def test_mutate_kernel_compiles_for_one_chip(one_chip,
                                              no_persistent_cache):
     import jax
-    import bench
     from kyverno_tpu.compiler.scan import WARM_POD
+    from kyverno_tpu.conformance import corpus
     from kyverno_tpu.mutate.encode import (encode_mutate_batch,
                                            string_window)
     from kyverno_tpu.mutate.kernel import MutateKernel
     from kyverno_tpu.mutate.plan import compile_mutate_set
-    program = compile_mutate_set(bench.load_mutate_pack())
+    program = compile_mutate_set(corpus.load_mutate_pack())
     assert program.device_ok and program.programs
     kernel = MutateKernel(program)
     lanes = encode_mutate_batch([WARM_POD], program, padded_n=64,

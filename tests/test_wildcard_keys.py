@@ -108,8 +108,9 @@ class TestWildcardKeyCompile:
     def test_full_pack_zero_host_rules(self):
         """The committed pack compiles with no apparmor rule left on
         the host."""
-        import bench
-        cps = compile_policies(bench.load_policy_pack())
+        import benchlib
+        cps = compile_policies(
+            benchlib.load_policies(['pss', 'pack', 'config4']))
         names = {r.get('name') for _, r, _ in cps.host_rules}
         assert all('app-armor' not in (n or '') for n in names), \
             f'apparmor rules still host-bound: {names}'
