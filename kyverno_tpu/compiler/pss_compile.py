@@ -5,8 +5,13 @@ the native check set (kyverno_tpu/pss/checks.py, reference:
 pkg/pss/evaluate.go:17 + k8s.io/pod-security-admission DefaultChecks).
 Each check becomes a BoolExpr whose truth means "check passes"; the rule
 status is the conjunction walked in DEFAULT_CHECKS order, so the first
-failing check decides (messages for failures are materialized by the
-host engine — only the PASS verdict is synthesized on device).
+failing check decides.  Only the PASS response is synthesized from the
+device's verdict: a failure's message prints every failing check with the
+resource's own container names, capabilities, ports and sysctls, so the
+scanner has the native check library word it from the document
+(engine.pod_security_response, called by BatchScanner._materialize with
+no Validator around it; a rule with context or preconditions goes through
+the Validator).
 
 The pod spec prefix is derived from the rule's matched kinds
 (pss/evaluate.py extract_pod_spec, reference: pkg/engine/validation.go:481):
@@ -94,8 +99,8 @@ def compile_pod_security(cps: CompiledPolicySet, pod_security: dict,
             ('seccompProfile_restricted', b.seccomp_restricted()),
             ('capabilities_restricted', b.capabilities_restricted()),
         ]
-    # DEFAULT_CHECKS order: first failing check decides; the host
-    # materializes the exact forbidden-reason message on any non-pass
+    # DEFAULT_CHECKS order: first failing check decides; the check
+    # library words the exact forbidden-reason message on any non-pass
     return StatusExpr.seq(
         [StatusExpr('leaf', expr=e) for _, e in checks])
 

@@ -30,7 +30,7 @@ from ..api.policy import Policy, Rule
 from ..api.unstructured import Resource
 from ..engine.api import (EngineResponse, PolicyContext, RuleResponse,
                           RuleStatus, RuleType)
-from ..engine.engine import Engine
+from ..engine.engine import Engine, Validator, pod_security_response
 from ..engine.match import matches_resource_description
 from ..observability import coverage
 from .. import faults
@@ -458,6 +458,20 @@ class BatchScanner:
                 self._adm_cols = None
                 _census.record_plan(self.fingerprint, _pset.plan,
                                     serial=self.serial)
+        # what host materialization needs of each program, worked out
+        # once a scanner: its Rule, and for a podSecurity rule that
+        # reads nothing but the resource (no context, no
+        # preconditions) the block the check library takes — such a
+        # cell is phrased by engine.pod_security_response alone, with
+        # no PolicyContext and no Validator built around it
+        self._host_rule: Dict[RuleProgram, Tuple[Rule, Optional[dict]]] = {}
+        for _prog in self.cps.programs:
+            _rule = Rule(_prog.rule_raw or {})
+            _direct = None
+            if _prog.pss is not None and not _rule.context and \
+                    _rule.preconditions is None:
+                _direct = _rule.validation.get('podSecurity')
+            self._host_rule[_prog] = (_rule, _direct)
         from collections import OrderedDict
         self._simple_match = [
             _rule_match_is_simple(p.rule_raw or {}) for p in self.cps.programs]
@@ -1755,7 +1769,7 @@ class BatchScanner:
                         tally.fallback_n(
                             prog, coverage.REASON_STATUS_HOST
                             if st == STATUS_HOST
-                            else coverage.REASON_UNSYNTHESIZABLE,
+                            else self._message_reason(prog),
                             int(sub.size))
                     for k in sub.tolist():
                         rr = self._materialize(prog, resources[base + k])
@@ -1800,7 +1814,7 @@ class BatchScanner:
             msg = self._fail_message_cached(prog, j, fdet[sg[0]])
             if msg is None:
                 if tally is not None:
-                    tally.fallback_n(prog, coverage.REASON_UNSYNTHESIZABLE,
+                    tally.fallback_n(prog, self._message_reason(prog),
                                      int(sg.size))
                 for k in sg.tolist():
                     rr = self._materialize(prog, resources[base + k])
@@ -2040,7 +2054,7 @@ class BatchScanner:
             msg = self._fail_message_cached(prog, j, fdet_row)
             if msg is None:
                 if tally is not None:
-                    tally.fallback(prog, coverage.REASON_UNSYNTHESIZABLE)
+                    tally.fallback(prog, self._message_reason(prog))
                 return _HOST_MARKER
             key = (j, STATUS_FAIL, msg)
             rr = fly.get(key)
@@ -2062,7 +2076,7 @@ class BatchScanner:
                 tally.fallback(
                     prog, coverage.REASON_STATUS_HOST
                     if st == STATUS_HOST
-                    else coverage.REASON_UNSYNTHESIZABLE)
+                    else self._message_reason(prog))
             else:
                 tally.device(prog)
         return rr
@@ -2277,11 +2291,37 @@ class BatchScanner:
 
     def _materialize(self, prog: RuleProgram,
                      resource: dict) -> Optional[RuleResponse]:
-        """Produce the exact host-engine rule response for one rule."""
-        from ..engine.engine import Validator
+        """Produce the exact host-engine rule response for one rule.
+
+        A podSecurity rule with neither context nor preconditions reads
+        the resource and nothing else (Validator.validate loads an
+        empty context, passes an empty precondition and calls
+        _validate_pod_security), so its answer is one call of the
+        function the Validator itself answers with.  Nothing of it is
+        kept: every cell pays its own call.  An empty document (a
+        DELETE, which the Validator answers with None) and every other
+        rule go through the Validator."""
+        rule, pod_security = self._host_rule[prog]
+        if pod_security is not None:
+            # the Validator reads pctx.new_resource: with a factory
+            # that is the request's own context, handed over as it is
+            factory = getattr(self, '_pctx_factory', None)
+            doc = resource if factory is None \
+                else factory(resource).new_resource
+            if doc:
+                return pod_security_response(
+                    rule.name, pod_security, doc,
+                    self.engine.pss_evaluator)
         pctx = self._pctx(self.policies[prog.policy_index], resource)
-        rule = Rule(prog.rule_raw or {})
         return Validator(self.engine, pctx, rule).validate()
+
+    def _message_reason(self, prog: RuleProgram) -> str:
+        """The ledger's reason for a cell whose verdict the device
+        decided and whose message the host words: the check library
+        called directly, or the Validator."""
+        if self._host_rule[prog][1] is not None:
+            return coverage.REASON_PSS_DIRECT
+        return coverage.REASON_UNSYNTHESIZABLE
 
     def _new_response(self, policy_index: int, resource: dict,
                       now: float,

@@ -364,6 +364,44 @@ def _rule_error(rule: Rule, rule_type: str, message: str,
                         RuleStatus.ERROR)
 
 
+def pod_security_response(rule_name: str, pod_security: dict,
+                          resource: dict,
+                          evaluator: Callable) -> RuleResponse:
+    """The response of one ``validate.podSecurity`` rule on one resource
+    (reference: pkg/engine/validation.go:535 validatePodSecurity).  The
+    one wording of it: ``Validator`` answers with it, and so does the
+    scanner for a cell whose rule reads nothing but the resource
+    (compiler/scan.py ``_materialize``)."""
+    from ..pss.evaluate import extract_pod_spec, format_checks_print
+    try:
+        pod = extract_pod_spec(resource)
+    except ValueError as e:
+        return RuleResponse(rule_name, RuleType.VALIDATION,
+                            f'Error while getting new resource: {e}',
+                            RuleStatus.ERROR)
+    try:
+        allowed, checks = evaluator(pod_security, pod)
+    except ValueError as e:
+        return RuleResponse(rule_name, RuleType.VALIDATION,
+                            f'failed to parse pod security api version: {e}',
+                            RuleStatus.ERROR)
+    level = pod_security.get('level', '')
+    version = pod_security.get('version', '')
+    psc = {'level': level, 'version': version, 'checks': checks}
+    if allowed:
+        r = RuleResponse(rule_name, RuleType.VALIDATION,
+                         f"Validation rule '{rule_name}' passed.",
+                         RuleStatus.PASS)
+    else:
+        r = RuleResponse(
+            rule_name, RuleType.VALIDATION,
+            f"Validation rule '{rule_name}' failed. It violates "
+            f'PodSecurity "{level}:{version}": '
+            f'{format_checks_print(checks)}', RuleStatus.FAIL)
+    r.pod_security_checks = psc
+    return r
+
+
 class Validator:
     """Per-rule validator (reference: pkg/engine/validation.go:210)."""
 
@@ -597,35 +635,9 @@ class Validator:
     # -- pod security --------------------------------------------------------
 
     def _validate_pod_security(self) -> RuleResponse:
-        # reference: pkg/engine/validation.go:535 validatePodSecurity
-        from ..pss.evaluate import extract_pod_spec
-        rule = self.rule
-        try:
-            pod = extract_pod_spec(self.pctx.new_resource)
-        except ValueError as e:
-            return _rule_error(rule, RuleType.VALIDATION,
-                               'Error while getting new resource', e)
-        try:
-            allowed, checks = self.engine.pss_evaluator(self.pod_security, pod)
-        except ValueError as e:
-            return _rule_error(rule, RuleType.VALIDATION,
-                               'failed to parse pod security api version', e)
-        level = self.pod_security.get('level', '')
-        version = self.pod_security.get('version', '')
-        psc = {'level': level, 'version': version, 'checks': checks}
-        if allowed:
-            r = _rule_response(rule, RuleType.VALIDATION,
-                               f"Validation rule '{rule.name}' passed.",
-                               RuleStatus.PASS)
-        else:
-            from ..pss.evaluate import format_checks_print
-            r = _rule_response(
-                rule, RuleType.VALIDATION,
-                f"Validation rule '{rule.name}' failed. It violates "
-                f'PodSecurity "{level}:{version}": '
-                f'{format_checks_print(checks)}', RuleStatus.FAIL)
-        r.pod_security_checks = psc
-        return r
+        return pod_security_response(self.rule.name, self.pod_security,
+                                     self.pctx.new_resource,
+                                     self.engine.pss_evaluator)
 
     # -- foreach -------------------------------------------------------------
 

@@ -61,6 +61,14 @@ REASON_POLICY_COUPLING = 'policy_coupling'  # rule compiled, but a
 REASON_STATUS_HOST = 'status_host'        # device verdict undecidable
 REASON_UNSYNTHESIZABLE = 'unsynthesizable_message'  # verdict known but
 #   the host's exact message cannot be synthesized from templates
+REASON_PSS_DIRECT = 'pss_direct_message'  # verdict the device's; the
+#   message lists every failing check with the resource's own container
+#   names, capabilities, ports and sysctls, which the device does not
+#   hold, so the check library writes it from the document, called
+#   directly (engine.pod_security_response): a host row, but no
+#   Validator and no PolicyContext.  A podSecurity rule with context or
+#   preconditions still goes through the Validator and keeps
+#   unsynthesizable_message
 REASON_CONTEXT_LOAD = 'context_load_failed'  # rule context load failed;
 #   host materialization produces the exact error response
 # Runtime (mutate fast-path escapes):
@@ -100,8 +108,9 @@ REASON_STAGE_RETRY_EXHAUSTED = 'stage_retry_exhausted'  # a scan
 REASONS = frozenset({
     REASON_UNSUPPORTED_OPERATOR, REASON_HOST_CLOSURE, REASON_API_CALL,
     REASON_POLICY_COUPLING, REASON_STATUS_HOST, REASON_UNSYNTHESIZABLE,
-    REASON_CONTEXT_LOAD, REASON_NON_DICT, REASON_DUP_ELEMENT_NAMES,
-    REASON_REPLACE_PATH_MISSING, REASON_PRECONDITION_ESCAPE,
+    REASON_PSS_DIRECT, REASON_CONTEXT_LOAD, REASON_NON_DICT,
+    REASON_DUP_ELEMENT_NAMES, REASON_REPLACE_PATH_MISSING,
+    REASON_PRECONDITION_ESCAPE,
     REASON_SITE_CONFLICT, REASON_PATCH_UNDECIDABLE,
     REASON_ADMISSION_UNENCODABLE, REASON_POISON_ROW,
     REASON_BREAKER_OPEN, REASON_STAGE_RETRY_EXHAUSTED,

@@ -234,6 +234,67 @@ class TestMixedScan:
         assert with_ledger == baseline
 
 
+class TestPssDirectMessage:
+    """A podSecurity FAIL is a host row (the host words it), booked
+    under ``pss_direct_message`` where the check library is called
+    directly and under ``unsynthesizable_message`` where the rule still
+    needs the Validator (preconditions)."""
+
+    GATED = {
+        'apiVersion': 'kyverno.io/v1', 'kind': 'ClusterPolicy',
+        'metadata': {'name': 'pss-gated', 'annotations': dict(NO_AUTOGEN)},
+        'spec': {'rules': [{
+            'name': 'gated',
+            'match': {'any': [{'resources': {'kinds': ['Pod']}}]},
+            'preconditions': {'all': [{
+                'key': '{{request.object.metadata.name}}',
+                'operator': 'NotEquals', 'value': 'skipme'}]},
+            'validate': {'podSecurity': {'level': 'restricted',
+                                         'version': 'latest'}}}]}}
+
+    def test_reason_is_in_the_taxonomy(self):
+        assert coverage.REASON_PSS_DIRECT == 'pss_direct_message'
+        assert coverage.REASON_PSS_DIRECT in coverage.REASONS
+
+    @pytest.mark.parametrize('how', ['rows', 'columns', 'fused'])
+    def test_failing_pods_are_host_rows_under_the_new_reason(self, ledger,
+                                                             how):
+        import benchlib
+        from kyverno_tpu.compiler.scan import BatchScanner
+        led, reg = ledger
+        scanner = BatchScanner(benchlib.load_policies(['pss']) +
+                               [Policy(self.GATED)])
+        # no securityContext at all: every Pod fails restricted, in the
+        # pack's rule and in the gated one; baseline passes on the device
+        pods = [pod(i) for i in range(
+            4 if how == 'rows' else scanner.SMALL_BATCH + 8)]
+        if how == 'fused':
+            list(scanner.scan_report_results(pods))
+        else:
+            scanner.scan(pods)
+        n = len(pods)
+        by_reason = led.totals()['by_reason']['pss']
+        assert by_reason == {'pss_direct_message': n,
+                             'unsynthesizable_message': n}
+        assert reg.counter_value(
+            'kyverno_tpu_host_fallback_total', path='pss',
+            reason='pss_direct_message') == n
+        rules = {(r['policy'], r['rule']): r
+                 for r in led.report()['rules']}
+        direct = rules[('podsecurity-restricted', 'restricted')]
+        assert (direct['host_rows'], direct['device_rows']) == (n, 0)
+        assert direct['effective'] == 'partial'
+        gated = rules[('pss-gated', 'gated')]
+        assert (gated['host_rows'], gated['device_rows']) == (n, 0)
+        passed = rules[('podsecurity-baseline', 'baseline')]
+        assert (passed['host_rows'], passed['device_rows']) == (0, n)
+        totals = led.totals()
+        assert totals['host_rows'] == 2 * n
+        assert totals['device_rows'] + totals['host_rows'] == \
+            totals['total_rows'] == 3 * n
+        assert 'reason="unknown"' not in reg.render()
+
+
 class TestMutateFallbacks:
     def test_attributed_reasons(self, ledger):
         led, reg = ledger

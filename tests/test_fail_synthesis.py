@@ -289,3 +289,58 @@ class TestFailSynthesis:
         # only the variable-message rule's fails need the host
         assert calls[0] < fails / 2, \
             f'{calls[0]} materializations for {fails} fails: synthesis idle'
+
+    def test_pss_pack_messages_equal_the_host_engines(self):
+        """A podSecurity FAIL has no site to synthesize from (fd -1): the
+        scanner words it with the check library, called directly, and the
+        message, status and checks are the host engine's, in the
+        responses and in the reports."""
+        import benchlib
+        from kyverno_tpu.reports.results import set_fused_results
+        from kyverno_tpu.reports.types import new_background_scan_report
+        reports_driver = benchlib.load_module('drivers',
+                                              'reports_controller')
+        mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
+        policies = benchlib.load_policies(['pss'])
+        engine = Engine()
+        resources = mixed_cluster.generate(13, 200)
+        scanner = BatchScanner(policies)
+        direct = [0]
+        inner = scanner._materialize
+
+        def counting(prog, doc):
+            direct[0] += 1
+            return inner(prog, doc)
+        scanner._materialize = counting
+        fails = 0
+        for resource, responses in zip(resources,
+                                       scanner.scan(resources)):
+            got = {}
+            for er in responses:
+                if er.policy_response.rules:
+                    got[er.policy_response.policy_name] = {
+                        r.name: (r.status, r.message)
+                        for r in er.policy_response.rules}
+                    checks = {r.name: r.pod_security_checks
+                              for r in er.policy_response.rules}
+                    want = engine.apply_background_checks(PolicyContext(
+                        er.policy, new_resource=resource))
+                    assert checks == {
+                        r.name: r.pod_security_checks
+                        for r in want.policy_response.rules}
+            assert got == host_results(engine, policies, resource)
+            fails += sum(1 for rules in got.values()
+                         for st, _ in rules.values() if st == 'fail')
+        assert fails > 200
+        # every FAIL was worded on the host, none twice
+        assert direct[0] == fails
+        for resource, (results, summary, pols) in zip(
+                resources, scanner.scan_report_results(resources)):
+            report = new_background_scan_report(resource)
+            set_fused_results(report, results, summary, pols)
+            want = reports_driver.host_report(engine, policies, resource)
+            strip = reports_driver._sans_timestamp
+            assert strip(report['spec']['results']) == \
+                strip(want['spec']['results'])
+            assert report['spec']['summary'] == want['spec']['summary']
+        assert direct[0] == 2 * fails

@@ -109,3 +109,42 @@ def test_fused_results_are_sorted(scanner):
     for results, _summary, _p in scanner.scan_report_results(pods):
         keys = [(r.get('policy', ''), r.get('rule', '')) for r in results]
         assert keys == sorted(keys)
+
+
+def test_pss_pack_fused_unfused_and_host():
+    """The cell's PSS pack over its own cluster: every podSecurity FAIL
+    is worded by the check library called directly
+    (BatchScanner._materialize), and the fused report, the unfused one
+    and the host engine's still agree to the byte."""
+    reports_driver = benchlib.load_module('drivers', 'reports_controller')
+    policies = benchlib.load_policies(['pss'])
+    scanner = BatchScanner(policies)
+    docs = mixed_cluster.generate(11, 160)
+    assert {d['kind'] for d in docs} == {'Pod', 'Deployment'}
+
+    unfused = []
+    for doc, responses in zip(docs, scanner.scan_stream(docs)):
+        report = new_background_scan_report(doc)
+        set_responses(report, *[r for r in responses
+                                if r.policy_response.rules])
+        unfused.append(report)
+    fused = []
+    for doc, (results, summary, pols) in zip(
+            docs, scanner.scan_report_results(docs)):
+        report = new_background_scan_report(doc)
+        set_fused_results(report, results, summary, pols)
+        fused.append(report)
+
+    pss_fails = 0
+    for doc, f, u in zip(docs, fused, unfused):
+        h = reports_driver.host_report(scanner.engine, policies, doc)
+        for other in (u, h):
+            assert f['metadata'].get('labels') == \
+                other['metadata'].get('labels')
+            assert f['spec']['summary'] == other['spec']['summary']
+            assert _strip_ts(f['spec']['results']) == \
+                _strip_ts(other['spec']['results'])
+        pss_fails += sum(1 for r in f['spec']['results']
+                         if r['policy'].startswith('podsecurity-')
+                         and r['result'] == 'fail')
+    assert pss_fails > 160
