@@ -50,6 +50,7 @@ import benchlib  # noqa: E402
 
 mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
 admission_reviews = benchlib.load_module('generators', 'admission_reviews')
+mutate_reviews = benchlib.load_module('generators', 'mutate_reviews')
 reports_driver = benchlib.load_module('drivers', 'reports_controller')
 
 #: the committed pack, in ``benchmarks/packs/``
@@ -316,13 +317,13 @@ def admission_phase(policies, cluster, platform: str, seed: int,
 # -- phase (d): device mutate ------------------------------------------------
 
 def mutate_phase(seed: int, n_rows: int) -> None:
-    import random
     from kyverno_tpu.conformance import corpus
     from kyverno_tpu.engine.engine import Engine
     from kyverno_tpu.mutate import MutateScanner
-    policies = corpus.load_mutate_pack()
-    rng = random.Random(seed + 2)
-    pods = [corpus.make_mutate_pod(rng, i) for i in range(n_rows)]
+    policies = benchlib.load_policies(['mutate-defaults'])
+    pods = [json.loads(body)['request']['object']
+            for body in mutate_reviews.generate(
+                seed + 2, mixed_cluster.generate(seed + 2, n=512), n_rows)]
     scanner = MutateScanner(policies)
     require(scanner.ok, 'the mutate pack did not lower to the device')
     t0 = time.monotonic()

@@ -73,15 +73,27 @@ class CompiledMutation:
 
 # -- static strategic merge (dict paths) ------------------------------------
 
-def _compile_overlay(overlay: Any) -> Optional[List[Tuple[Tuple[str, ...],
-                                                          bool, Any]]]:
+#: the one list shape in the device vocabulary: a list holding a single
+#: map whose only anchor is ``(name)`` with one of these values
+_ELEMENT_NAME_PATTERNS = ('*', '?*')
+
+
+def _compile_overlay(overlay: Any, max_elements: int = 0
+                     ) -> Optional[List[Tuple[tuple, bool, Any]]]:
     """Flatten a static dict overlay into (path, add_only, value) sets;
-    None when the shape is outside the fast vocabulary."""
+    None when the shape is outside the fast vocabulary.
+
+    With ``max_elements`` (the device lowering passes its slot count;
+    the host appliers below pass none, so a list keeps the engine loop
+    there) one list shape lowers too: ``[{(name): "*" | "?*", ...}]``
+    with scalar and ``+(key)`` scalar leaves under plain maps.  Each of
+    its leaves becomes ``max_elements`` sets whose path carries the
+    element slot as an int, ``list path + (i,) + leaf path``."""
     if not isinstance(overlay, dict) or not _static(overlay):
         return None
-    out: List[Tuple[Tuple[str, ...], bool, Any]] = []
+    out: List[Tuple[tuple, bool, Any]] = []
 
-    def walk(node: dict, path: Tuple[str, ...]) -> bool:
+    def walk(node: dict, path: tuple, in_element: bool) -> bool:
         for key, value in node.items():
             if not isinstance(key, str):
                 return False
@@ -95,15 +107,36 @@ def _compile_overlay(overlay: Any) -> Optional[List[Tuple[Tuple[str, ...],
             if isinstance(value, dict):
                 if add_only:
                     return False  # +() on maps: engine semantics differ
-                if not walk(value, path + (key,)):
+                if not walk(value, path + (key,), in_element):
                     return False
-            elif isinstance(value, (list,)):
-                return False
+            elif isinstance(value, list):
+                if add_only or in_element or \
+                        not element_list(value, path + (key,)):
+                    return False
             else:
                 out.append((path + (key,), add_only, value))
         return True
 
-    if not walk(overlay, ()):
+    def element_list(items: list, path: tuple) -> bool:
+        if not max_elements or len(items) != 1 or \
+                not isinstance(items[0], dict):
+            return False
+        element = dict(items[0])
+        # the merge key is the resource element's own name: a plain
+        # ``name`` leaf beside the anchor would be overwritten by it
+        if element.pop('(name)', None) not in _ELEMENT_NAME_PATTERNS \
+                or 'name' in element or '+(name)' in element:
+            return False
+        first = len(out)
+        if not walk(element, (), True) or len(out) == first:
+            return False
+        leaves = out[first:]
+        out[first:] = [(path + (i,) + leaf, add_only, value)
+                       for i in range(max_elements)
+                       for leaf, add_only, value in leaves]
+        return True
+
+    if not walk(overlay, (), False):
         return None
     return out
 
@@ -154,28 +187,40 @@ def apply_edit_list(doc: dict,
     decode (``kyverno_tpu/mutate/scanner.py``, which reads the edit
     bitmask back from the device and materializes it here).  Returns
     the patched document, or None when a non-map parent appears while
-    rebuilding a path (callers attribute the escape)."""
+    rebuilding a path (callers attribute the escape).  A path may carry
+    one element slot (an int, see ``_compile_overlay``): the list it
+    indexes is copied on write as a map is."""
     if not changes:
         return doc
     patched = dict(doc)
-    copied: Dict[Tuple[str, ...], dict] = {(): patched}
+    copied: Dict[tuple, Any] = {(): patched}
 
-    def cow(path: Tuple[str, ...]) -> Any:
+    def cow(path: tuple) -> Any:
         node = copied.get(path)
         if node is not None:
             return node
         parent = cow(path[:-1])
-        if not isinstance(parent, dict):
+        key = path[-1]
+        if isinstance(key, int):
+            # an element slot: the list was copied on the way here, as
+            # a map is, and the element has to be a map that is there
+            if not isinstance(parent, list) or key >= len(parent) or \
+                    not isinstance(parent[key], dict):
+                return None
+            child: Any = dict(parent[key])
+        elif not isinstance(parent, dict):
             return None
-        child = parent.get(path[-1])
-        child = dict(child) if isinstance(child, dict) else {}
-        parent[path[-1]] = child
+        else:
+            child = parent.get(key)
+            child = dict(child) if isinstance(child, dict) else \
+                list(child) if isinstance(child, list) else {}
+        parent[key] = child
         copied[path] = child
         return child
 
     for path, value in changes:
         parent = cow(path[:-1])
-        if parent is None:
+        if not isinstance(parent, dict):
             return None
         parent[path[-1]] = value
     return patched

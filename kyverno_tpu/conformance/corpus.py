@@ -1,77 +1,21 @@
-"""Packs and generators that no cell of the benchmark runs yet.
+"""Packs and generators that no cell of the benchmark runs yet, and the
+host-chain oracle of a device mutate row.
 
-The mutate pack and its Pods are what ``chip_smoke.py``'s device mutate
-phase and ``tests/test_tpu_compile.py`` run; the config-5 pack and its
-resource dump (``BASELINE.json`` ``configs[4]``) are what
-``tests/test_baseline_configs.py`` and ``tests/test_mutate_compile.py``
-run.  The packs a cell runs live under ``benchmarks/packs/`` as data and
-their generators under ``benchmarks/generators/``; this module imports
-nothing from there (the package never reaches up).  The ``model_config``
-PR that adds the cell of one of these (ROADMAP R3d, R7) moves it there
-and deletes it here.
+The config-5 pack and its resource dump (``BASELINE.json`` ``configs[4]``)
+are what ``tests/test_baseline_configs.py`` and
+``tests/test_mutate_compile.py`` run.  The packs a cell runs live under
+``benchmarks/packs/`` as data and their generators under
+``benchmarks/generators/`` (the mutate pack and its Pods moved there with
+the cell ``admission_mutate_open``); this module imports nothing from
+there (the package never reaches up).  The ``model_config`` PR that adds
+the cell of the config-5 pack (ROADMAP R7) moves it there and deletes it
+here.  ``check_mutate_row`` is what ``chip_smoke.py``'s device mutate phase
+and ``tests/test_device_mutate.py`` hold a ``MutateScanner`` row to.
 """
 
 from __future__ import annotations
 
 import json
-
-# mutate-heavy pack for the device-side mutate path
-# (kyverno_tpu/mutate/): every policy lowers to edit-site programs —
-# the set is all-or-nothing (plan.py), so one unlowerable rule would
-# zero the ratio — while a fraction of the generated pods trips the
-# per-row FALLBACK paths (json6902 replace on a missing path, non-map
-# intermediates), keeping the attributed-host machinery honest.
-MUTATE_PACK = """
-apiVersion: kyverno.io/v1
-kind: ClusterPolicy
-metadata:
-  name: add-default-labels
-  annotations: {pod-policies.kyverno.io/autogen-controllers: none}
-spec:
-  rules:
-    - name: add-team
-      match: {any: [{resources: {kinds: [Pod]}}]}
-      mutate:
-        patchStrategicMerge:
-          metadata:
-            labels:
-              "+(team)": platform
-              "+(cost-center)": eng-42
----
-apiVersion: kyverno.io/v1
-kind: ClusterPolicy
-metadata:
-  name: set-dns-policy
-  annotations: {pod-policies.kyverno.io/autogen-controllers: none}
-spec:
-  rules:
-    - name: dns
-      match: {any: [{resources: {kinds: [Pod]}}]}
-      mutate:
-        patchStrategicMerge:
-          spec:
-            dnsPolicy: ClusterFirst
-            "+(enableServiceLinks)": false
----
-apiVersion: kyverno.io/v1
-kind: ClusterPolicy
-metadata:
-  name: stamp-annotations
-  annotations: {pod-policies.kyverno.io/autogen-controllers: none}
-spec:
-  rules:
-    - name: stamp
-      match: {any: [{resources: {kinds: [Pod]}}]}
-      mutate:
-        patchesJson6902: |-
-          - op: add
-            path: /metadata/annotations/managed-by
-            value: kyverno-tpu
-          - op: replace
-            path: /metadata/annotations/tier
-            value: gold
-"""
-
 
 # --------------------------------------------------------------------------
 # BASELINE config 5: mutate + generate with foreach over a resource dump.
@@ -165,32 +109,6 @@ def make_config5_resource(rng, i: int, make_pod) -> dict:
         for cont in pod['spec']['containers']:
             cont['imagePullPolicy'] = 'Always'
     return pod
-
-
-def make_mutate_pod(rng, i: int) -> dict:
-    """Pods for the mutate-heavy pack: ~90% carry the ``tier``
-    annotation the json6902 replace needs (the rest FALLBACK per row,
-    attributed ``replace_path_missing``), half already carry a ``team``
-    label (the add-only anchor skips), and dnsPolicy varies so the
-    strategic merge sometimes edits, sometimes SKIPs."""
-    meta = {'name': f'pod-{i}', 'namespace': f'ns-{i % 7}'}
-    annotations = {'owner': f'team-{i % 5}'}
-    if rng.random() < 0.9:
-        annotations['tier'] = rng.choice(['bronze', 'silver', 'gold'])
-    meta['annotations'] = annotations
-    if rng.random() < 0.5:
-        meta['labels'] = {'team': rng.choice(['red', 'blue'])}
-    spec = {'containers': [{'name': 'c', 'image': 'nginx:1.25.3'}]}
-    if rng.random() < 0.5:
-        spec['dnsPolicy'] = 'Default'
-    return {'apiVersion': 'v1', 'kind': 'Pod', 'metadata': meta,
-            'spec': spec}
-
-
-def load_mutate_pack():
-    import yaml
-    from kyverno_tpu.api.policy import Policy
-    return [Policy(d) for d in yaml.safe_load_all(MUTATE_PACK) if d]
 
 
 def check_mutate_row(engine, policies, pod: dict, row, what: str) -> None:

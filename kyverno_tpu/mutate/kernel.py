@@ -3,7 +3,8 @@
 One jitted straight-line program per lowered policy set, batched over
 resources and edit sites.  Per (resource, site) it decides whether the
 edit applies — leaf missing → apply; add-only anchors skip present
-leaves; otherwise apply iff the encoded value differs from the patch
+leaves; an element site with no element to patch (``istate`` 3) never
+applies; otherwise apply iff the encoded value differs from the patch
 constant (Python equality semantics: bool/int/float compare through the
 exact milli lane, strings through length + byte window; cross-kind
 never equal except the numeric tower) — then reduces sites to per-rule
@@ -18,7 +19,9 @@ outputs:
                        the host fast path's check order: 1 = a
                        json6902 replace path is missing, 2 = a non-map
                        intermediate, 3 = equality undecidable in the
-                       encoded lanes
+                       encoded lanes, 4 = a list of an element site is
+                       longer than ``MAX_ELEMENTS`` or of a shape the
+                       lanes cannot stand for (``llen`` -1)
 
 The kernel is intentionally tiny (a few element-wise ops and one
 masked reduction per output) — it is not AOT-persisted; XLA
@@ -37,8 +40,8 @@ import numpy as np
 
 from ..compiler.ir import TAG_BOOL, TAG_FLOAT, TAG_INT, TAG_MISSING, \
     TAG_STRING
-from .encode import exact_milli, string_window
-from .plan import MutateSetProgram
+from .encode import ISTATE_NO_ELEMENT, exact_milli, string_window
+from .plan import MAX_ELEMENTS, MutateSetProgram, split_element_path
 
 #: per-(resource, rule) device statuses
 MUT_SKIP = 0
@@ -50,6 +53,7 @@ RC_NONE = 0
 RC_REPLACE_MISSING = 1
 RC_NON_DICT = 2
 RC_UNDECIDABLE = 3
+RC_LIST_SHAPE = 4
 
 
 class MutateKernel:
@@ -73,6 +77,10 @@ class MutateKernel:
         # edit mask; both feed the per-rule reductions
         self._onehot = np.zeros((s, self.n_rules), bool)
         self._bit_w = np.zeros(s, np.int64)
+        # list → rule selector: the rules with an element site on the
+        # list of each ``llen`` column
+        self._list_rules = np.zeros((max(1, len(program.lists)),
+                                     self.n_rules), bool)
         for idx, (ri, k, site) in enumerate(sites):
             v = site.value
             if isinstance(v, str) and not isinstance(v, bool):
@@ -89,9 +97,12 @@ class MutateKernel:
             self._replace[idx] = site.replace
             self._onehot[idx, ri] = True
             self._bit_w[idx] = np.int64(1) << np.int64(k)
+            split = split_element_path(site.path)
+            if split is not None:
+                self._list_rules[program.lists.index(split[0]), ri] = True
         self._jitted = None
 
-    def _eval(self, lanes):
+    def mutate_eval(self, lanes):
         import jax.numpy as jnp
         tag = lanes['tag']
         istate = lanes['istate']
@@ -99,9 +110,12 @@ class MutateKernel:
         milli_ok = lanes['milli_ok']
         slen = lanes['slen']
         sbytes = lanes['sbytes']
-        missing = tag == TAG_MISSING
+        # an element site with no element to patch is inert: neither
+        # missing (an edit) nor present (a comparison) nor bad
+        inert = istate == ISTATE_NO_ELEMENT
+        missing = (tag == TAG_MISSING) & ~inert
         bad = istate == 2
-        present = (~missing) & (~bad)
+        present = (~missing) & (~bad) & ~inert
         num_tag = (tag == TAG_BOOL) | (tag == TAG_INT) | \
             (tag == TAG_FLOAT)
         eq_num = self._t_is_num & present & num_tag & milli_ok & \
@@ -129,17 +143,23 @@ class MutateKernel:
         rep_any = per_rule(rep_bad)
         bad_any = per_rule(bad)
         undec_any = per_rule(undec)
-        fb = rep_any | bad_any | undec_any
+        llen = lanes['llen']
+        list_any = jnp.any(
+            ((llen < 0) | (llen > MAX_ELEMENTS))[:, :, None] &
+            self._list_rules, axis=1)
+        fb = rep_any | list_any | bad_any | undec_any
         status = jnp.where(
             fb, MUT_FALLBACK,
             jnp.where(edits != 0, MUT_PASS, MUT_SKIP)).astype(jnp.int8)
         # first-fault reason in the host fast path's check order:
-        # replace guard, then the non-dict walk, then equality
+        # replace guard, then the walk (a list's shape before the maps
+        # under it), then equality
         reason = jnp.where(
             rep_any, RC_REPLACE_MISSING,
-            jnp.where(bad_any, RC_NON_DICT,
-                      jnp.where(undec_any, RC_UNDECIDABLE,
-                                RC_NONE))).astype(jnp.int8)
+            jnp.where(list_any, RC_LIST_SHAPE,
+                      jnp.where(bad_any, RC_NON_DICT,
+                                jnp.where(undec_any, RC_UNDECIDABLE,
+                                          RC_NONE)))).astype(jnp.int8)
         # ragged batches: capacity-padding rows (all-MISSING leaves
         # would otherwise read as "every edit applies") are masked to
         # SKIP / empty-bitmask / no-reason inside the program
@@ -163,6 +183,8 @@ class MutateKernel:
             if self._jitted is None:
                 from ..aotcache import enable_persistent_compilation_cache
                 enable_persistent_compilation_cache()
-                self._jitted = jax.jit(self._eval)
+                # the method's name is the device program's in a
+                # profiler trace: ``jit_mutate_eval``
+                self._jitted = jax.jit(self.mutate_eval)
             out = self._jitted(lanes)
             return tuple(np.asarray(o) for o in out)

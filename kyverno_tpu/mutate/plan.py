@@ -4,11 +4,17 @@ A mutate rule lowers when its whole patch is expressible as a fixed set
 of **edit sites** — (slot path, static scalar value) pairs with an
 optional add-if-absent anchor or json6902 ``replace`` existence guard —
 over the same wildcard-free slot-path vocabulary the validate encoder
-resolves at encode time (``compiler/encode.py``).  The device program
+resolves at encode time (``compiler/encode.py``).  One list shape is in
+the vocabulary: a strategic-merge list holding a single map anchored
+``(name): "*"`` (or ``"?*"``) patches every named element of the live
+list, and lowers to **element sites**, one per leaf and element slot
+``i < MAX_ELEMENTS``, whose path carries the slot as an int
+(``('spec', 'containers', 2, 'imagePullPolicy')``).  The device program
 then decides, per (resource, site), whether the edit applies, and emits
 a compact per-rule edit bitmask the host decodes back into patched JSON
 (``scanner.py``).  Anything outside that vocabulary — foreach, contexts,
-preconditions, variables, anchors needing live lookups, list patches,
+preconditions, variables, anchors needing live lookups, any other list
+patch (several maps, other anchors, scalars, nested lists, appends),
 null values (RFC-7386 deletes), non-scalar values — does NOT lower and
 keeps the host engine, attributed on the coverage ledger.
 
@@ -34,6 +40,10 @@ from ..compiler.mutate_compile import _compile_overlay, parse_json6902_sets
 #: edit bitmask budget: one i32 lane per (resource, rule)
 MAX_SITES = 32
 
+#: element slots of a list of maps: a live list longer than this is a
+#: per-row FALLBACK (``RC_LIST_SHAPE``)
+MAX_ELEMENTS = 4
+
 #: resource-identity paths no lowered edit may write: match/exclude and
 #: namespace gating read them, so a rule that mutates them could change
 #: a later rule's match decision mid-chain
@@ -51,7 +61,8 @@ class LowerError(Exception):
 
 
 class EditSite(NamedTuple):
-    path: Tuple[str, ...]   # slot path of the written leaf
+    path: tuple             # slot path of the written leaf; an element
+    #                         site's carries its slot as an int
     add_only: bool          # ``+(key)`` anchor: write only when absent
     value: Any              # static scalar (str | bool | int | float)
     replace: bool           # json6902 replace: whole path must exist
@@ -82,8 +93,21 @@ class RuleMutateProgram:
         self.rule_index = -1
 
 
-def _identity_site(path: Tuple[str, ...]) -> bool:
+def _identity_site(path: tuple) -> bool:
     return any(path[:len(idp)] == idp for idp in _IDENTITY_PATHS)
+
+
+def split_element_path(path: tuple):
+    """``(list path, slot, leaf path)`` of an element site's path; None
+    for a plain site's."""
+    for k, part in enumerate(path):
+        if isinstance(part, int):
+            return path[:k], part, path[k + 1:]
+    return None
+
+
+def _path_text(path: tuple) -> str:
+    return '/'.join(map(str, path))
 
 
 def _check_sites(sites: List[EditSite]) -> Tuple[EditSite, ...]:
@@ -101,11 +125,11 @@ def _check_sites(sites: List[EditSite]) -> Tuple[EditSite, ...]:
         if not isinstance(site.value, (str, bool, int, float)):
             raise LowerError(
                 coverage.REASON_UNSUPPORTED_OPERATOR,
-                f'non-scalar patch value at {"/".join(site.path)}')
+                f'non-scalar patch value at {_path_text(site.path)}')
         if _identity_site(site.path):
             raise LowerError(
                 coverage.REASON_UNSUPPORTED_OPERATOR,
-                f'edit writes the identity field {"/".join(site.path)} '
+                f'edit writes the identity field {_path_text(site.path)} '
                 f'— later rules\' match decisions could change '
                 f'mid-chain')
     return tuple(sites)
@@ -139,12 +163,13 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
     overlay = mutation.get('patchStrategicMerge')
     json6902 = mutation.get('patchesJson6902')
     if overlay is not None and not json6902:
-        sets = _compile_overlay(overlay)
+        sets = _compile_overlay(overlay, MAX_ELEMENTS)
         if sets is None:
             raise LowerError(
                 coverage.REASON_UNSUPPORTED_OPERATOR,
                 'overlay outside the static scalar vocabulary '
-                '(anchors needing live lookups, lists, or variables)')
+                '(anchors needing live lookups, variables, or a list '
+                'other than one map anchored (name): "*" | "?*")')
         sites = _check_sites([EditSite(path, add_only, value, False)
                               for path, add_only, value in sets])
         return RuleMutateProgram(policy_name, str(raw.get('name', '')),
@@ -166,7 +191,10 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
                      'empty or mixed patch document')
 
 
-def _paths_conflict(a: Tuple[str, ...], b: Tuple[str, ...]) -> bool:
+def _paths_conflict(a: tuple, b: tuple) -> bool:
+    """Prefix-or-equal overlap.  An element slot is one more path part,
+    so two element paths are compared slot by slot, and a plain path
+    that writes the list (or above it) conflicts with every slot."""
     n = min(len(a), len(b))
     return a[:n] == b[:n]
 
@@ -256,6 +284,15 @@ class MutateSetProgram:
                 self.policies[pi].name, str(r.raw.get('name', '')),
                 'mutate', coverage.PLACEMENT_HOST, e.reason, e.detail,
                 pi))
+
+        #: the distinct list paths the device programs' element sites
+        #: walk, in first-seen order: one ``llen`` lane column each
+        self.lists: List[tuple] = []
+        for prog in self.programs:
+            for site in prog.sites:
+                split = split_element_path(site.path)
+                if split is not None and split[0] not in self.lists:
+                    self.lists.append(split[0])
 
     @property
     def n_sites(self) -> int:

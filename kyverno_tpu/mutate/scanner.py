@@ -8,8 +8,8 @@ evaluates admission batches as one device dispatch:
    the ORIGINAL document (sound because lowered rules are simple-match
    and edits cannot touch identity fields; see plan.py)
 2. encode the edit-site lanes, run the jitted kernel → per-(resource,
-   rule) status + edit bitmask + fallback reason (the *patch emit*
-   stage, read back like fail details)
+   rule) status + edit bitmask + fallback reason, read back like fail
+   details
 3. decode on the host: set bits → (slot, value) edit list →
    ``apply_edit_list`` copy-on-write patch → ``generate_patches`` diff
    → the exact ``EngineResponse`` the handler's engine loop would have
@@ -26,7 +26,10 @@ are attributed per rule on the coverage ledger (``path="mutate"``).
 ``scan`` accepts the same signature the admission batcher dispatches
 (``resources/contexts/admission/pctx_factory/operations/
 old_resources``), so mutate tickets ride the same queue and coalescing
-loop as validate tickets.
+loop as validate tickets.  Its four steps are leaf stages of
+``observability/device.py`` ``stage()``: ``mutate_match``,
+``mutate_encode``, ``mutate_eval`` (the jitted call to its results on
+the host: it is synchronous) and ``mutate_decode``.
 """
 
 from __future__ import annotations
@@ -45,21 +48,22 @@ from ..engine.match import matches_resource_description
 from ..engine.mutate.jsonpatch import generate_patches
 from ..engine.mutate.mutate import _success_message
 from ..compiler.mutate_compile import apply_edit_list
-from ..observability import coverage, tracing
+from ..observability import coverage
+from ..observability import device as devtel
 from ..observability.metrics import global_registry
 from .encode import encode_mutate_batch, string_window
-from .kernel import (MUT_FALLBACK, MUT_PASS, MUT_SKIP, RC_NON_DICT,
-                     RC_REPLACE_MISSING, RC_UNDECIDABLE, MutateKernel)
+from .kernel import (MUT_FALLBACK, MUT_PASS, MUT_SKIP, RC_LIST_SHAPE,
+                     RC_NON_DICT, RC_REPLACE_MISSING, RC_UNDECIDABLE,
+                     MutateKernel)
 from .plan import MutateSetProgram, compile_mutate_set
 
-MUTATE_PATCH_EMIT = 'kyverno_tpu_mutate_patch_emit_seconds'
-MUTATE_DECODE = 'kyverno_tpu_mutate_decode_seconds'
 MUTATE_EDITS = 'kyverno_tpu_mutate_device_edits_total'
 
 _RC_REASON = {
     RC_REPLACE_MISSING: coverage.REASON_REPLACE_PATH_MISSING,
     RC_NON_DICT: coverage.REASON_NON_DICT,
     RC_UNDECIDABLE: coverage.REASON_PATCH_UNDECIDABLE,
+    RC_LIST_SHAPE: coverage.REASON_LIST_SHAPE,
 }
 
 
@@ -70,6 +74,10 @@ class MutateScanner:
     keep the host engine loop and the placement records already name
     why, per rule.
     """
+
+    #: what the admission batcher tells a mutate dispatch from a
+    #: validate one by
+    kind = 'mutate'
 
     def __init__(self, policies: List[Policy],
                  engine: Optional[Engine] = None):
@@ -83,6 +91,9 @@ class MutateScanner:
         from ..compiler.scan import next_scanner_serial
         self.serial = next_scanner_serial()
         self.supports_row_admissions = True
+        #: rows of the last scan in which a policy fell back to the
+        #: host engine (scans are serialized on the batcher's thread)
+        self.last_fallback_rows = 0
         if coverage.enabled():
             coverage.record_placements(self.program.placements)
         from ..aotcache.keys import policy_set_fingerprint
@@ -139,13 +150,10 @@ class MutateScanner:
             return []
         adm_rows = admissions if admissions is not None \
             else [admission] * n
-        match = np.stack([self._match_row(doc, adm_rows[i])
-                          for i, doc in enumerate(resources)])
-        registry = global_registry()
-        t0 = time.monotonic()
-        with tracing.start_span('kyverno/mutate/patch_emit',
-                                {'rows': n,
-                                 'sites': self.program.n_sites}):
+        with devtel.stage('mutate_match', {'rows': n}):
+            match = np.stack([self._match_row(doc, adm_rows[i])
+                              for i, doc in enumerate(resources)])
+        with devtel.stage('mutate_encode', {'rows': n}):
             # canonical capacity (compiler/shapes.py): the kernel masks
             # padding rows via the `valid` lane, so one compiled shape
             # serves every admission occupancy
@@ -153,17 +161,13 @@ class MutateScanner:
             lanes = encode_mutate_batch(resources, self.program,
                                         padded_n=canonical_capacity(n),
                                         width=self._width)
+        with devtel.stage('mutate_eval', {'rows': n}):
             status, edits, reason = self._kernel(lanes)
-        if registry is not None:
-            registry.observe(MUTATE_PATCH_EMIT, time.monotonic() - t0)
-        t1 = time.monotonic()
-        with tracing.start_span('kyverno/mutate/decode', {'rows': n}):
-            rows = [self._decode_row(resources[i], match[i], status[i],
+        self.last_fallback_rows = 0
+        with devtel.stage('mutate_decode', {'rows': n}):
+            return [self._decode_row(resources[i], match[i], status[i],
                                      edits[i], reason[i], pctx_factory)
                     for i in range(n)]
-        if registry is not None:
-            registry.observe(MUTATE_DECODE, time.monotonic() - t1)
-        return rows
 
     # -- decode -----------------------------------------------------------
 
@@ -187,6 +191,8 @@ class MutateScanner:
             ctx = pctx.copy()
             ctx.policy = policy
             if host_rest or pol_fb:
+                if not host_rest:
+                    self.last_fallback_rows += 1
                 er = self.engine.mutate(ctx)
                 self._tally_host(tally, matched, reason,
                                  fallback=pol_fb and not host_rest)

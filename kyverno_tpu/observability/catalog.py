@@ -194,14 +194,6 @@ METRICS: Dict[str, Metric] = {
         'to a fresh persistent-XLA-cache-assisted compile, never a '
         'possibly-SIGILL load).'),
     # device-side mutate (kyverno_tpu/mutate/scanner.py)
-    'kyverno_tpu_mutate_patch_emit_seconds': Metric(
-        'histogram', 'Mutate patch-emit stage: encode the edit-site '
-        'lanes and run the device kernel that decides per-(resource, '
-        'rule) edit bitmasks.'),
-    'kyverno_tpu_mutate_decode_seconds': Metric(
-        'histogram', 'Mutate decode stage: edit bitmasks back to '
-        '(slot, value) edit lists, copy-on-write patch application, '
-        'and EngineResponse assembly on the host.'),
     'kyverno_tpu_mutate_device_edits_total': Metric(
         'counter', 'Individual edits applied from device-decided '
         'mutate edit lists.'),
@@ -313,12 +305,16 @@ SPANS: Dict[str, str] = {
                               'per-row admission lanes.',
     'kyverno/device/resolve': 'Admission batch: provenance filled and '
                               'every rider\'s ticket resolved.',
-    'kyverno/mutate/patch_emit': 'Device mutate patch-emit stage: '
-                                 'edit-site lane encode + kernel '
-                                 'dispatch for one batch.',
-    'kyverno/mutate/decode': 'Device mutate decode stage: edit '
-                             'bitmasks to patched JSON + engine '
-                             'responses.',
+    'kyverno/device/mutate_match': 'Device mutate scan: the host '
+                                   'match sieve of one batch.',
+    'kyverno/device/mutate_encode': 'Device mutate scan: the edit-site '
+                                    'lanes of one batch encoded.',
+    'kyverno/device/mutate_eval': 'Device mutate scan: the jitted '
+                                  'kernel, h2d to its outputs on the '
+                                  'host.',
+    'kyverno/device/mutate_decode': 'Device mutate scan: edit bitmasks '
+                                    'to patched JSON + engine '
+                                    'responses.',
     'kyverno/mesh/step': 'One sharded mesh dispatch '
                          '(distributed_scan_step): carries mesh '
                          'shape, per-shard row occupancy, skew ratio '
@@ -380,4 +376,18 @@ PIPELINE_STAGES: Dict[str, str] = {
                     '(histogram only).',
     'deny_message': 'The denial message of one denied request, on its '
                     'thread (inside handler_post; histogram only).',
+    'mutate_match': 'Device mutate scan: host match sieve per '
+                    '(resource, rule) against the original document.',
+    'mutate_encode': 'Device mutate scan: edit-site lane encode at the '
+                     'canonical capacity.',
+    'mutate_eval': 'Device mutate scan: the jitted kernel from its '
+                   'call to its three outputs on the host (h2d, '
+                   'kernel, d2h: the call is synchronous).',
+    'mutate_decode': 'Device mutate scan: edit bitmasks to edit lists, '
+                     'patched JSON and engine responses; FALLBACK rows '
+                     're-run on the host engine inside it.',
+    'mutate_pre': 'mutate(): entry to the batcher\'s submit '
+                  '(histogram only).',
+    'mutate_post': 'mutate(): resolved ticket to return: per-policy '
+                   'bookkeeping, patch collection (histogram only).',
 }

@@ -86,6 +86,10 @@ REASON_SITE_CONFLICT = 'edit_site_conflict'  # two lowered mutate rules
 REASON_PATCH_UNDECIDABLE = 'patch_undecidable'  # the encoded lanes
 #   cannot decide whether the live value equals the patch constant
 #   (numeric outside the exact milli window) — host applies instead
+REASON_LIST_SHAPE = 'list_shape'  # a list that an element site walks
+#   is longer than the element slots, is no list, or holds an element
+#   the lanes cannot stand for (no map, a name that is no string, two
+#   of one name) — host applies instead (runtime, per row)
 # Per-row admission lanes (compiler/admission.py):
 REASON_ADMISSION_UNENCODABLE = 'admission_unencodable'  # a request's
 #   admission tuple did not intern exactly into the per-row lanes
@@ -111,7 +115,7 @@ REASONS = frozenset({
     REASON_PSS_DIRECT, REASON_CONTEXT_LOAD, REASON_NON_DICT,
     REASON_DUP_ELEMENT_NAMES, REASON_REPLACE_PATH_MISSING,
     REASON_PRECONDITION_ESCAPE,
-    REASON_SITE_CONFLICT, REASON_PATCH_UNDECIDABLE,
+    REASON_SITE_CONFLICT, REASON_PATCH_UNDECIDABLE, REASON_LIST_SHAPE,
     REASON_ADMISSION_UNENCODABLE, REASON_POISON_ROW,
     REASON_BREAKER_OPEN, REASON_STAGE_RETRY_EXHAUSTED,
 })

@@ -59,11 +59,19 @@ STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 #: policies that apply to the request and the installed set's key),
 #: ``handler_pre``, ``handler_post``, and per denied request
 #: ``deny_message`` (inside ``handler_post`` where it rode a batch).
+#: The device mutate scan's (``mutate/scanner.py``, one batch on the
+#: batcher's thread): ``mutate_match``, ``mutate_encode``,
+#: ``mutate_eval`` (the jitted call to its results on the host: it is
+#: synchronous), ``mutate_decode``; and on the request's thread
+#: ``mutate_pre`` (``mutate()``'s entry to the batcher's submit) and
+#: ``mutate_post`` (resolved ticket to return).
 STAGES = ('match', 'encode', 'encode_wait', 'pack', 'h2d', 'compile',
           'device_eval', 'd2h', 'device_wait', 'expand', 'filter',
           'chunk_wait', 'report', 'store', 'flush', 'reconcile',
           'unnamed', 'prepare', 'resolve', 'candidates', 'handler_pre',
-          'handler_post', 'deny_message')
+          'handler_post', 'deny_message', 'mutate_match',
+          'mutate_encode', 'mutate_eval', 'mutate_decode', 'mutate_pre',
+          'mutate_post')
 
 _log = logging.getLogger('kyverno.device')
 

@@ -107,6 +107,10 @@ _BATCH_STAGES = (('prepare', 'prepare'), ('match', 'match'),
                  ('expand', 'expand'), ('report', 'report'),
                  ('resolve', 'resolve'))
 
+#: the program kinds that take turns on the batcher's one thread: a
+#: scanner says which it is (``kind``; a validate scanner says nothing)
+KINDS = ('validate', 'mutate')
+
 #: why the host loop answered a request that asked for the compiled
 #: path: the set's scanner was still compiling, its breaker was not
 #: closed (or the scan raised in the handler), the batcher shed it
@@ -226,6 +230,19 @@ class AdmissionBatcher:
         # reset_stats leaves it (a build belongs to set-up, and the
         # count is there to show that none came after it)
         self._scanner_builds = 0
+        # the same dispatches by the kind of scanner they served:
+        # dispatches, requests, timed dispatches and their wall; of the
+        # mutate ones also the riders' waits, the rows scanned and
+        # those in which a policy fell back to the host engine; and
+        # the /mutate requests that asked for the compiled path, by
+        # whether it answered (True) or the host loop did
+        self._by_kind = {kind: dict.fromkeys(
+            ('dispatches', 'requests', 'timed', 'batch_s'), 0)
+            for kind in KINDS}
+        self._mutate_waits_s: deque = deque(maxlen=8192)
+        self._mutate_rows = 0
+        self._mutate_fallback_rows = 0
+        self._mutate_paths = {True: 0, False: 0}
         # consecutive all-failed dispatch count per key; touched only
         # by the batcher thread (dispatches are serialized), reset the
         # moment any rider of the key resolves on device
@@ -294,6 +311,13 @@ class AdmissionBatcher:
             self._paths[host_reason] += 1
             self._candidate_policies += candidates
             self._installed_policies += installed
+
+    def record_mutate_path(self, on_device: bool) -> None:
+        """One /mutate request that asked for the compiled path:
+        answered by it, or by the host loop (the set does not lower or
+        is still building, a breaker, a shed, a failed scan)."""
+        with self._stats_lock:
+            self._mutate_paths[on_device] += 1
 
     def record_build(self) -> None:
         """One validate scanner built (and warmed) by the handler."""
@@ -432,11 +456,18 @@ class AdmissionBatcher:
                         }
                 for t, row in zip(batch, rows):
                     t.resolve(row)
+        kind = getattr(scanner, 'kind', KINDS[0])
+        if kind == 'mutate':
+            with self._stats_lock:
+                self._mutate_rows += len(batch)
+                self._mutate_fallback_rows += scanner.last_fallback_rows
         if cap is not None:
             wall = time.monotonic() - t_in
             with self._stats_lock:
                 self._timed += 1
                 self._batch_s += wall
+                self._by_kind[kind]['timed'] += 1
+                self._by_kind[kind]['batch_s'] += wall
                 for name, seconds in cap.stages.items():
                     self._stage_s[name] = \
                         self._stage_s.get(name, 0.0) + seconds
@@ -525,10 +556,15 @@ class AdmissionBatcher:
         # coalescing regime from same-tuple (homogeneous) batching
         hetero = len(batch) > 1 and \
             len({admission_key(t.admission) for t in batch}) > 1
+        kind = getattr(batch[0].scanner, 'kind', KINDS[0])
         with self._stats_lock:
             self._dispatches += 1
             self._requests += len(batch)
             self._occupancies.append(len(batch))
+            self._by_kind[kind]['dispatches'] += 1
+            self._by_kind[kind]['requests'] += len(batch)
+            if kind == 'mutate':
+                self._mutate_waits_s.extend(waits)
             if hetero:
                 self._hetero_dispatches += 1
                 self._hetero_occupancies.append(len(batch))
@@ -570,6 +606,22 @@ class AdmissionBatcher:
             candidates = self._candidate_policies
             installed = self._installed_policies
             builds = self._scanner_builds
+            by_kind = {}
+            for kind, n in self._by_kind.items():
+                by_kind[f'{kind}_dispatches'] = n['dispatches']
+                by_kind[f'{kind}_occupancy_mean'] = \
+                    n['requests'] / n['dispatches'] \
+                    if n['dispatches'] else 0.0
+                by_kind[f'{kind}_batch_ms'] = \
+                    1000.0 * n['batch_s'] / n['timed'] \
+                    if n['timed'] else 0.0
+            by_kind.update(
+                mutate_queue_wait_p50_ms=self._p50(
+                    self._mutate_waits_s) * 1000.0,
+                mutate_device_path_requests=self._mutate_paths[True],
+                mutate_host_loop_requests=self._mutate_paths[False],
+                mutate_rows=self._mutate_rows,
+                mutate_fallback_rows=self._mutate_fallback_rows)
         timing = {f'batch_{field}_ms': stage_ms.get(name, 0.0)
                   for name, field in _BATCH_STAGES}
         return {
@@ -597,6 +649,7 @@ class AdmissionBatcher:
             'candidate_policies': candidates,
             'installed_policies': installed,
             'scanner_builds': builds,
+            **by_kind,
         }
 
     def reset_stats(self) -> None:
@@ -613,6 +666,11 @@ class AdmissionBatcher:
             self._stage_s.clear()
             self._paths = dict.fromkeys(self._paths, 0)
             self._candidate_policies = self._installed_policies = 0
+            for n in self._by_kind.values():
+                n.update(dict.fromkeys(n, 0))
+            self._mutate_waits_s.clear()
+            self._mutate_rows = self._mutate_fallback_rows = 0
+            self._mutate_paths = dict.fromkeys(self._mutate_paths, 0)
         self.sheds.reset()
 
     # -- lifecycle ---------------------------------------------------------

@@ -739,10 +739,13 @@ class ResourceHandlers:
                         'policy sets failing (systemic backend failure)',
                         level=logging.ERROR)
 
-    def wait_device_ready(self, policies, timeout: float = 600.0) -> bool:
-        """Block until the compiled scanner for ``policies`` is serving
-        (benchmarks / tests measuring steady-state latency).  Returns
-        False immediately while the set's circuit breaker is open."""
+    def wait_device_ready(self, policies, timeout: float = 600.0,
+                          kind: str = 'validate') -> bool:
+        """Block until the compiled scanner of ``kind`` for ``policies``
+        is serving (benchmarks / tests measuring steady-state latency;
+        a mutate set that does not lower is "serving" too: ask its
+        scanner's ``ok``).  Returns False immediately while the set's
+        circuit breaker is open."""
         from ..serving import breaker as breaker_mod
         key = self._policy_key(policies)
         deadline = time.time() + timeout
@@ -751,7 +754,7 @@ class ResourceHandlers:
                 return False
             if self._breakers.state(key) == breaker_mod.OPEN:
                 return False
-            if self._device_scanner(policies) is not None:
+            if self._device_scanner(policies, kind) is not None:
                 # readiness polling never scans: release any half-open
                 # probe slot the allow() check granted on our behalf
                 self._breakers.probe_abort(key)
@@ -1241,40 +1244,52 @@ class ResourceHandlers:
             return self._denied(uid, responses)[0]
         return None
 
-    def _device_mutate_steps(self, request: dict, pctx,
-                             mutate_policies) -> Optional[list]:
+    def _device_mutate_steps(self, request: dict, pctx, mutate_policies,
+                             t_start: float = 0.0) -> Optional[tuple]:
         """The device mutate chain for one request, or None when the
         host engine loop must serve it (knob off, verb outside
         CREATE/UPDATE, exceptions/subresource in play, set not lowered,
         scanner still building, shed, or scan failure — never a 500).
-        Returns the ordered ``[(policy, EngineResponse), ...]`` steps,
-        bit-identical to the host loop by construction
-        (kyverno_tpu/mutate/scanner.py)."""
+        Returns ``(steps, patched, t_back)``: the ordered ``[(policy,
+        EngineResponse), ...]`` steps, bit-identical to the host loop by
+        construction (kyverno_tpu/mutate/scanner.py), the cumulative
+        document, and when the resolved ticket came back where the
+        request rode a batch (else None).  Such a request leaves a
+        sample of stage ``mutate_pre``, ``t_start`` (``mutate()``'s
+        entry) to its submit; and in batch mode every request that gets
+        past the first gate is counted by the batcher as answered by
+        the compiled path or by the host loop."""
+        from ..observability import device as devtel
         operation = request.get('operation') or ''
         if not (self.device and self.mutate_device and mutate_policies and
                 operation in ('CREATE', 'UPDATE') and
                 not pctx.exceptions and not request.get('subResource')):
             return None
+        row = None
         try:
             scanner = self._device_scanner(mutate_policies, kind='mutate')
-            if scanner is None or not scanner.ok:
-                # still lowering, or the set does not lower (the
-                # placement records on the coverage ledger name why)
-                return None
-            if self.serving_mode == 'batch':
-                row, _prov = self._batched_scan(
-                    scanner, mutate_policies, request, pctx,
-                    resource=pctx.new_resource)
-                return row  # None on shed -> host loop
-            [row] = scanner.scan(
-                [pctx.new_resource],
-                admission=(pctx.admission_info,
-                           pctx.exclude_group_roles,
-                           pctx.namespace_labels, operation),
-                pctx_factory=lambda doc: pctx)
-            self._breakers.record_success(
-                self._policy_key(mutate_policies))
-            return row
+            # None: still lowering; not ok: the set does not lower (the
+            # placement records on the coverage ledger name why)
+            if scanner is not None and scanner.ok:
+                if self.serving_mode == 'batch':
+                    t_submit = time.monotonic()
+                    batched, _prov = self._batched_scan(
+                        scanner, mutate_policies, request, pctx,
+                        resource=pctx.new_resource)
+                    if batched is not None:  # None on shed -> host loop
+                        row = batched + (time.monotonic(),)
+                        devtel.record_stage('mutate_pre',
+                                            t_submit - t_start)
+                else:
+                    [scanned] = scanner.scan(
+                        [pctx.new_resource],
+                        admission=(pctx.admission_info,
+                                   pctx.exclude_group_roles,
+                                   pctx.namespace_labels, operation),
+                        pctx_factory=lambda doc: pctx)
+                    self._breakers.record_success(
+                        self._policy_key(mutate_policies))
+                    row = scanned + (None,)
         except Exception as e:  # noqa: BLE001
             # identical never-500 recovery to the validate path: drop
             # the broken scanner, count one breaker failure, host loop
@@ -1285,11 +1300,15 @@ class ResourceHandlers:
             self._record_key_failure(
                 base, mutate_policies,
                 f'mutate scan failed, falling back to host engine: {e}')
-            return None
+        if self.serving_mode == 'batch':
+            self._get_batcher().record_mutate_path(row is not None)
+        return row
 
     def mutate(self, request: dict, failure_policy: str = 'Fail') -> dict:
         """reference: pkg/webhooks/resource/handlers.go:157 Mutate +
         mutation.go:80 applyMutations (sequential, cumulative)."""
+        from ..observability import device as devtel
+        t_start = time.monotonic()
         uid = request.get('uid', '')
         kind = (request.get('kind') or {}).get('kind', '')
         ns = request.get('namespace', '')
@@ -1316,14 +1335,24 @@ class ResourceHandlers:
         # (kyverno_tpu/mutate/) whose rows coalesce with concurrent
         # mutate requests in batch serving mode
         device_row = self._device_mutate_steps(request, pctx,
-                                               mutate_policies)
+                                               mutate_policies, t_start)
+        # when the resolved ticket came back, where the request rode a
+        # batch: from there to the return is stage ``mutate_post``
+        t_back = None
+
+        def answered(response: dict) -> dict:
+            if t_back is not None:
+                devtel.record_stage('mutate_post',
+                                    time.monotonic() - t_back)
+            return response
+
         if device_row is not None:
-            steps, patched = device_row
+            steps, patched, t_back = device_row
             for policy, er in steps:
                 deny = self._post_mutate_policy(uid, policy, er, patches,
                                                 responses, failure_policy)
                 if deny is not None:
-                    return deny
+                    return answered(deny)
             if steps:
                 # verify-images policies see the chain's cumulative
                 # output, exactly as the host loop threads it
@@ -1358,6 +1387,7 @@ class ResourceHandlers:
             patches.extend(iv_patches)
             responses.append(er)
             if er.is_failed():
-                return self._denied(uid, responses)[0]
+                return answered(self._denied(uid, responses)[0])
         warnings = get_warning_messages(responses)
-        return admission.mutation_response(uid, patches, warnings)
+        return answered(admission.mutation_response(uid, patches,
+                                                    warnings))

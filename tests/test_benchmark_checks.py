@@ -3,7 +3,7 @@
 ``benchmarks/tests/`` is outside ``pytest tests/``, so nothing held
 ``BENCHMARK.json``, the layer files, the open-loop schedule, the bytes table
 or the trace reduction until a chip run failed.  This file takes the tests
-and fixtures of the five files that need no subprocess run of a cell (about a
+and fixtures of the six files that need no subprocess run of a cell (about a
 second together) into its own namespace, as they are; ``tests/conftest.py``
 has put ``benchmarks/`` on ``sys.path``, which is all their own
 ``conftest.py`` does.  ``test_rehearse.py`` (two minutes of CPU rehearsals)
@@ -16,8 +16,8 @@ import importlib.util
 
 import benchlib
 
-FILES = ['test_bytes', 'test_files', 'test_layer_sources', 'test_schedule',
-         'test_trace_reduce']
+FILES = ['test_bytes', 'test_bytes_mutate', 'test_files',
+         'test_layer_sources', 'test_schedule', 'test_trace_reduce']
 
 
 def _collect(name: str) -> dict:

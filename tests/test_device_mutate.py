@@ -497,3 +497,318 @@ class TestWebhookIntegration:
         _server, handlers = chain
         assert handlers._device_mutate_steps(
             {'operation': 'DELETE'}, None, ['x']) is None
+
+
+# ---------------------------------------------------------------------------
+# element sites: one map anchored (name): "*" | "?*" over a list of maps
+
+
+def pull_policy(pattern='?*', name='pull'):
+    return sm_policy(name, {'spec': {'containers': [
+        {'(name)': pattern, 'imagePullPolicy': 'Always'}]}})
+
+
+def requests_policy(pattern='*', name='res'):
+    return sm_policy(name, {'spec': {'containers': [
+        {'(name)': pattern, 'resources': {'requests': {
+            '+(memory)': '100Mi', '+(cpu)': '100m'}}}]}})
+
+
+def containers_pod(i, containers, **spec):
+    return pod(i, spec=dict(spec, containers=containers))
+
+
+def named(n, **fields):
+    return [dict({'name': f'c{j}', 'image': 'nginx'}, **fields)
+            for j in range(n)]
+
+
+ELEMENT_DOCS = {
+    **{f'{n}-containers': containers_pod(n, named(n)) for n in range(7)},
+    'leaf-present-equal': containers_pod(
+        10, named(2, imagePullPolicy='Always')),
+    'leaf-present-other': containers_pod(
+        11, named(3, imagePullPolicy='IfNotPresent')),
+    'leaf-null': containers_pod(12, named(1, imagePullPolicy=None)),
+    'requests-partly-there': containers_pod(13, [
+        {'name': 'a', 'resources': {'requests': {'cpu': '1'}}},
+        {'name': 'b', 'resources': {'limits': {'cpu': '2'}}},
+        {'name': 'c', 'resources': {'requests': {
+            'memory': '1Gi', 'cpu': '1'}}},
+        {'name': 'd', 'resources': None}]),
+    'requests-not-a-map': containers_pod(14, [
+        {'name': 'a', 'resources': {'requests': 'lots'}}]),
+    'resources-not-a-map': containers_pod(15, [
+        {'name': 'a', 'resources': 'lots'}, {'name': 'b'}]),
+    'missing-name': containers_pod(16, [
+        {'image': 'x'}, {'name': 'b', 'image': 'y'}]),
+    'empty-name': containers_pod(17, [
+        {'name': '', 'image': 'x'}, {'name': 'b', 'image': 'y'}]),
+    'null-name': containers_pod(18, [
+        {'name': None, 'image': 'x'}, {'name': 'b'}]),
+    'number-for-a-name': containers_pod(19, [{'name': 7}, {'name': 'b'}]),
+    'two-of-one-name': containers_pod(20, [
+        {'name': 'a'}, {'name': 'a', 'imagePullPolicy': 'Never'},
+        {'name': 'b'}]),
+    'element-not-a-map': containers_pod(21, [{'name': 'a'}, 'stray']),
+    'element-null': containers_pod(22, [None, {'name': 'a'}]),
+    'containers-not-a-list': pod(23, spec={'containers': {'name': 'a'}}),
+    'containers-a-string': pod(24, spec={'containers': 'a'}),
+    'containers-null': pod(25, spec={'containers': None}),
+    'no-containers': pod(26, spec={'dnsPolicy': 'Default'}),
+    'spec-not-a-map': pod(27, spec='nothing'),
+    'no-spec': {'apiVersion': 'v1', 'kind': 'Pod',
+                'metadata': {'name': 'p28', 'namespace': 'default'}},
+}
+
+
+class TestElementSites:
+    def test_lowers_to_a_site_per_leaf_and_slot(self):
+        from kyverno_tpu.mutate.plan import MAX_ELEMENTS, split_element_path
+        prog = lower_mutate_rule(requests_policy().rules[0], 'res')
+        assert [s.path for s in prog.sites] == [
+            ('spec', 'containers', i, 'resources', 'requests', leaf)
+            for i in range(MAX_ELEMENTS) for leaf in ('memory', 'cpu')]
+        assert all(s.add_only and not s.replace for s in prog.sites)
+        assert split_element_path(prog.sites[3].path) == (
+            ('spec', 'containers'), 1, ('resources', 'requests', 'cpu'))
+        assert split_element_path(('spec', 'dnsPolicy')) is None
+        assert compile_mutate_set([requests_policy()]).lists == \
+            [('spec', 'containers')]
+
+    @pytest.mark.parametrize('pattern', ['*', '?*'])
+    @pytest.mark.parametrize('case', sorted(ELEMENT_DOCS))
+    def test_byte_identical_to_the_host_engine(self, pattern, case):
+        """Both container policies, then a plain one: the chain the
+        defaults pack runs, over one shape of container list."""
+        policies = [pull_policy(pattern), requests_policy(pattern),
+                    sm_policy('dns', {'spec': {
+                        'dnsPolicy': 'ClusterFirst'}})]
+        assert_identical(policies, [ELEMENT_DOCS[case]])
+
+    @pytest.mark.parametrize('case, status, reason', [
+        ('0-containers', MUT_SKIP, 0),
+        ('4-containers', MUT_PASS, 0),
+        ('5-containers', MUT_FALLBACK, 4),
+        ('6-containers', MUT_FALLBACK, 4),
+        ('leaf-present-equal', MUT_SKIP, 0),
+        ('missing-name', MUT_PASS, 0),
+        ('empty-name', MUT_PASS, 0),
+        ('number-for-a-name', MUT_FALLBACK, 4),
+        ('two-of-one-name', MUT_FALLBACK, 4),
+        ('element-not-a-map', MUT_FALLBACK, 4),
+        ('containers-not-a-list', MUT_FALLBACK, 4),
+        ('containers-null', MUT_SKIP, 0),
+        ('no-containers', MUT_SKIP, 0),
+        ('spec-not-a-map', MUT_FALLBACK, 2),
+    ])
+    def test_kernel_decisions(self, case, status, reason):
+        prog = compile_mutate_set([pull_policy()])
+        lanes = encode_mutate_batch([ELEMENT_DOCS[case]], prog)
+        st, _edits, rc = MutateKernel(prog)(lanes)
+        assert (int(st[0, 0]), int(rc[0, 0])) == (status, reason)
+
+    def test_the_edit_mask_has_a_bit_per_patched_slot(self):
+        prog = compile_mutate_set([pull_policy()])
+        doc = containers_pod(0, [
+            {'name': 'a'}, {'name': 'b', 'imagePullPolicy': 'Always'},
+            {'image': 'nameless'}, {'name': 'd', 'imagePullPolicy': 'Never'}])
+        lanes = encode_mutate_batch([doc], prog)
+        assert lanes['llen'].tolist() == [[4]]
+        assert lanes['istate'][0].tolist() == [0, 0, 3, 0]
+        _st, edits, _rc = MutateKernel(prog)(lanes)
+        assert int(edits[0, 0]) == 0b1001
+
+    def test_list_shape_fallback_is_on_the_ledger_with_its_reason(self):
+        from kyverno_tpu.observability.metrics import MetricsRegistry
+        ledger = coverage.configure(MetricsRegistry())
+        try:
+            scanner = MutateScanner([pull_policy()])
+            scanner.scan([ELEMENT_DOCS['5-containers'],
+                          ELEMENT_DOCS['2-containers']])
+            assert scanner.last_fallback_rows == 1
+            assert ledger.report()['fallbacks']['mutate'] == {
+                coverage.REASON_LIST_SHAPE: 1}
+        finally:
+            coverage.disable()
+
+    def test_the_conflict_rule_compares_element_paths_slot_by_slot(self):
+        from kyverno_tpu.mutate.plan import _paths_conflict
+        assert compile_mutate_set([pull_policy(),
+                                   requests_policy()]).device_ok
+        third = sm_policy('limits', {'spec': {'containers': [
+            {'(name)': '*', 'resources': 'none'}]}})
+        prog = compile_mutate_set([pull_policy(), requests_policy(), third])
+        assert not prog.device_ok
+        by_policy = {p.policy: p.reason for p in prog.placements}
+        assert by_policy == {
+            'pull': coverage.REASON_POLICY_COUPLING,
+            'res': coverage.REASON_SITE_CONFLICT,
+            'limits': coverage.REASON_SITE_CONFLICT}
+        # a plain rule that writes the list itself conflicts with every slot
+        whole = j6_policy('whole', [{'op': 'add', 'path': '/spec/containers',
+                                     'value': 'none'}])
+        assert not compile_mutate_set([pull_policy(), whole]).device_ok
+        at = ('spec', 'containers')
+        assert _paths_conflict(at + (0, 'resources'),
+                               at + (0, 'resources', 'requests', 'cpu'))
+        assert not _paths_conflict(at + (0, 'resources'),
+                                   at + (1, 'resources'))
+        assert not _paths_conflict(at + (0, 'imagePullPolicy'),
+                                   at + (0, 'resources', 'requests', 'cpu'))
+
+    @pytest.mark.parametrize('containers', [
+        [{'(name)': '*', 'tty': True}, {'(name)': '?*', 'stdin': True}],
+        [{'name': 'sidecar', 'image': 'proxy'}],        # merge by key
+        [{'(name)': 'c*', 'tty': True}],                # another pattern
+        [{'(image)': '*', 'tty': True}],                # another anchor
+        [{'(name)': '*', '<(image)': '*:latest', 'tty': True}],
+        [{'(name)': '*', '=(tty)': True}],
+        [{'(name)': '*', 'name': 'renamed'}],
+        [{'(name)': '*'}],                              # nothing to write
+        [{'(name)': '*', 'args': ['--flag']}],          # a scalar list
+        [{'(name)': '*', 'ports': [{'(name)': '*', 'protocol': 'TCP'}]}],
+        [{'(name)': '*', 'env': None}],                 # RFC 7386 delete
+        [{'(name)': '*', 'image': '{{ request.object.metadata.name }}'}],
+        ['nginx'],
+        [],
+    ])
+    def test_every_other_list_shape_still_raises_with_a_reason(
+            self, containers):
+        p = sm_policy('p', {'spec': {'containers': containers}})
+        with pytest.raises(LowerError) as ei:
+            lower_mutate_rule(p.rules[0], 'p')
+        assert ei.value.reason == coverage.REASON_UNSUPPORTED_OPERATOR
+        assert ei.value.detail
+
+    def test_the_host_appliers_keep_lists_on_the_engine_loop(self):
+        """``_compile_overlay`` lowers the list shape for the device
+        alone: the bulk appliers have no element walk."""
+        from kyverno_tpu.compiler.mutate_compile import (
+            _compile_overlay, compile_strategic_merge)
+        overlay = pull_policy().rules[0].raw['mutate'][
+            'patchStrategicMerge']
+        assert _compile_overlay(overlay) is None
+        assert compile_strategic_merge(overlay) is None
+        assert len(_compile_overlay(overlay, 4)) == 4
+
+
+# ---------------------------------------------------------------------------
+# the defaults pack of the cell admission_mutate_open, on the CPU
+
+
+class TestDefaultsPack:
+    """``benchmarks/packs/mutate-defaults.yaml`` over the Pods of
+    ``benchmarks/generators/mutate_reviews.py``: the program is held to
+    the host engine, and the benchmark's plain reference to it too."""
+
+    @pytest.fixture(scope='class')
+    def policies(self):
+        import benchlib
+        return benchlib.load_policies(['mutate-defaults'])
+
+    @pytest.fixture(scope='class')
+    def bodies(self):
+        import benchlib
+        cluster = benchlib.load_module(
+            'generators', 'mixed_cluster').generate(32, n=512)
+        return benchlib.load_module(
+            'generators', 'mutate_reviews').generate(32, cluster, 300)
+
+    def test_the_whole_set_lowers(self, policies):
+        prog = compile_mutate_set(policies)
+        assert prog.device_ok and len(prog.programs) == 7
+        assert prog.n_sites == 22 and prog.lists == [('spec', 'containers')]
+        assert all(p.placement == coverage.PLACEMENT_DEVICE
+                   for p in prog.placements)
+
+    def test_300_generated_pods_against_the_host_chain(self, policies,
+                                                       bodies):
+        from kyverno_tpu.conformance.corpus import check_mutate_row
+        pods = [json.loads(b)['request']['object'] for b in bodies]
+        scanner = MutateScanner(policies)
+        rows = scanner.scan([json.loads(json.dumps(p)) for p in pods])
+        engine = Engine()
+        for i, (doc, row) in enumerate(zip(pods, rows)):
+            check_mutate_row(engine, policies, doc, row, f'pod {i}')
+        # the generator's share of five-container Pods, and no other row
+        five = sum(len(p['spec']['containers']) > 4 for p in pods)
+        assert scanner.last_fallback_rows == five == 12
+        assert sum(patched != doc for doc, (_s, patched)
+                   in zip(pods, rows)) == len(pods)
+
+    def test_the_plain_reference_gives_the_host_chains_document(
+            self, policies, bodies):
+        import benchlib
+        reference = benchlib.load_module('reference', 'mutate_defaults')
+        assert list(reference.CHAIN) == sorted(p.name for p in policies)
+        for body in bodies:
+            doc = json.loads(body)['request']['object']
+            _steps, patched = host_chain(policies, doc)
+            assert reference.canonical(reference.mutate(doc)) == \
+                reference.canonical(patched)
+
+    def test_mutate_then_validate_in_batch_mode_byte_for_byte(
+            self, bodies, monkeypatch):
+        """Every write through ``/mutate/fail`` and then, patched,
+        ``/validate/fail`` of a batch-mode server, against the same
+        server built with ``device=False``."""
+        # the first write compiles the evaluator's admission shape, for
+        # seconds on this CPU: it must not shed the ones behind it
+        monkeypatch.setenv('KTPU_SHED_DEADLINE_MS', '120000')
+        import copy
+        from concurrent.futures import ThreadPoolExecutor
+        import benchlib
+        from kyverno_tpu.policycache import cache as pcache
+        from kyverno_tpu.webhooks.handlers import ResourceHandlers
+        from kyverno_tpu.webhooks.server import WebhookServer
+        driver = benchlib.load_module('drivers', 'webhook_mutate')
+        enforce = []
+        for p in benchlib.load_policies(['pss', 'pack', 'config4']):
+            doc = copy.deepcopy(p.raw)
+            doc['spec']['validationFailureAction'] = 'Enforce'
+            enforce.append(Policy(doc))
+        cache = pcache.Cache()
+        cache.warm_up(enforce + benchlib.load_policies(['mutate-defaults']))
+        handlers = ResourceHandlers(cache, serving_mode='batch')
+        server = WebhookServer(handlers)
+        host = WebhookServer(ResourceHandlers(cache, device=False))
+        try:
+            assert handlers.wait_device_ready(
+                cache.get_installed(pcache.VALIDATE_ENFORCE, 'Pod'),
+                timeout=300)
+            mutate_set = cache.get_policies(pcache.MUTATE, 'Pod', 'ns-0')
+            assert handlers.wait_device_ready(mutate_set, timeout=120,
+                                              kind='mutate')
+            assert handlers._device_scanner(mutate_set, kind='mutate').ok
+
+            def write(body):
+                mutated = server.handle('/mutate/fail', body)
+                allowed, review = driver.patched_review(body, mutated)
+                assert allowed
+                return mutated, review, server.handle('/validate/fail',
+                                                      review)
+
+            write(bodies[48])
+            handlers._get_batcher().reset_stats()
+            with ThreadPoolExecutor(8) as pool:
+                answers = list(pool.map(write, bodies[:48]))
+            verdicts = set()
+            for body, (mutated, review, validated) in zip(bodies, answers):
+                assert mutated == host.handle('/mutate/fail', body)
+                assert validated == host.handle('/validate/fail', review)
+                verdicts.add(json.loads(validated)['response']['allowed'])
+            assert verdicts == {True, False}
+            stats = handlers._get_batcher().stats()
+            assert stats['mutate_device_path_requests'] == 48
+            assert stats['mutate_host_loop_requests'] == 0
+            assert stats['mutate_rows'] == 48
+            assert stats['mutate_fallback_rows'] == sum(
+                len(json.loads(b)['request']['object']['spec'][
+                    'containers']) > 4 for b in bodies[:48])
+            assert stats['device_path_requests'] == 48
+            assert stats['mutate_dispatches'] + \
+                stats['validate_dispatches'] == stats['dispatches']
+        finally:
+            handlers.shutdown()
+            host.stop()

@@ -520,3 +520,110 @@ class TestStagesOnTheProfilersClock:
         assert stats['batch_device_wait_ms'] <= stats['batch_d2h_ms']
         # no share is asked of batch_unnamed_ms here: with one rule a
         # dispatch takes a millisecond or two, most of it fixed cost
+
+
+# -- the device mutate scan's stages ------------------------------------------
+
+MUTATE_STAGES = ('mutate_match', 'mutate_encode', 'mutate_eval',
+                 'mutate_decode', 'mutate_pre', 'mutate_post')
+
+
+@pytest.fixture(scope='module')
+def mutated(tmp_path_factory):
+    """A few ``/mutate`` requests through a batch-mode server with the
+    stage histogram on, the last of them under ``jax.profiler``."""
+    import glob
+    import os
+
+    import jax
+
+    from kyverno_tpu.policycache import cache as pcache
+    from kyverno_tpu.webhooks.handlers import ResourceHandlers
+    from kyverno_tpu.webhooks.server import WebhookServer
+    policy = Policy({
+        'apiVersion': 'kyverno.io/v1', 'kind': 'ClusterPolicy',
+        'metadata': {'name': 'always-pull', 'annotations': {
+            'pod-policies.kyverno.io/autogen-controllers': 'none'}},
+        'spec': {'rules': [{
+            'name': 'always-pull',
+            'match': {'any': [{'resources': {'kinds': ['Pod']}}]},
+            'mutate': {'patchStrategicMerge': {'spec': {'containers': [
+                {'(name)': '*', 'imagePullPolicy': 'Always'}]}}}}]}})
+    tracing.disable()
+    reg = devtel.configure(MetricsRegistry())
+    try:
+        cache = pcache.Cache()
+        cache.warm_up([policy])
+        handlers = ResourceHandlers(cache, serving_mode='batch')
+        server = WebhookServer(handlers)
+        mutate_set = cache.get_policies(pcache.MUTATE, 'Pod', 'default')
+        assert handlers.wait_device_ready(mutate_set, timeout=300,
+                                          kind='mutate')
+        scanner = handlers._device_scanner(mutate_set, kind='mutate')
+        assert scanner.ok
+        for i in range(4):
+            server.handle('/mutate/fail', review_bytes(pod(i), f'm{i}'))
+        out_dir = str(tmp_path_factory.mktemp('mutate-trace'))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(out_dir, profiler_options=options)
+        try:
+            for i in range(3):
+                server.handle('/mutate/fail', review_bytes(pod(i), f't{i}'))
+        finally:
+            jax.profiler.stop_trace()
+        stats = handlers._get_batcher().stats()
+        handlers.shutdown()
+        [path] = glob.glob(os.path.join(out_dir, '**', '*.xplane.pb'),
+                           recursive=True)
+        return {'marks': marks(path), 'stats': stats, 'scanner': scanner,
+                'counts': {dict(key)['stage']: count
+                           for key, count, _total in reg.histogram_series(
+                               devtel.SCAN_STAGE_DURATION)}}
+    finally:
+        devtel.disable()
+
+
+class TestMutateStages:
+    @pytest.mark.parametrize('stage', MUTATE_STAGES)
+    def test_the_stage_is_registered_and_sampled(self, mutated, stage):
+        """The four of a scan once a dispatch (the scanner's warm-up is
+        one more), the handler's two once a request that rode a batch."""
+        from kyverno_tpu.observability.catalog import PIPELINE_STAGES
+        assert stage in devtel.STAGES and stage in PIPELINE_STAGES
+        dispatches = mutated['stats']['mutate_dispatches']
+        assert dispatches == 7    # one request at a time
+        want = 7 if stage in ('mutate_pre', 'mutate_post') \
+            else dispatches + 1
+        assert mutated['counts'][stage] == want
+
+    @pytest.mark.parametrize('stage', MUTATE_STAGES[:4])
+    def test_a_scans_stage_is_in_the_trace_with_its_batch(self, mutated,
+                                                          stage):
+        found = [m for m in mutated['marks'] if m[1] == stage]
+        assert len(found) == 3
+        assert all(m[4]['rows'] == 1 for m in found)
+        assert len({m[4]['batch'] for m in found}) == 3
+        assert len({m[0] for m in found}) == 1  # one thread: the batcher
+
+    def test_the_handlers_two_are_in_the_histogram_only(self, mutated):
+        assert not [m for m in mutated['marks']
+                    if m[1] in ('mutate_pre', 'mutate_post')]
+
+    def test_the_older_histograms_are_retired(self):
+        from kyverno_tpu.observability import catalog
+        assert not [name for name in catalog.METRICS
+                    if 'patch_emit' in name or 'mutate_decode' in name]
+
+    def test_the_device_program_is_named_for_a_trace_reader(self, mutated):
+        """``jit_mutate_eval``: what ``trace_module_ms`` looks for."""
+        import jax
+        from kyverno_tpu.compiler.scan import WARM_POD
+        from kyverno_tpu.mutate.encode import encode_mutate_batch
+        scanner = mutated['scanner']
+        lanes = encode_mutate_batch([WARM_POD], scanner.program,
+                                    padded_n=64)
+        with jax.enable_x64(True):
+            text = scanner._kernel._jitted.lower(lanes).as_text()
+        assert 'jit_mutate_eval' in text.split('\n', 1)[0]

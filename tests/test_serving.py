@@ -389,6 +389,69 @@ class TestBatcherUnit:
         finally:
             batcher.stop(drain=False)
 
+    MUTATE_FIELDS = ('mutate_dispatches', 'mutate_occupancy_mean',
+                     'mutate_batch_ms', 'mutate_queue_wait_p50_ms',
+                     'validate_dispatches', 'validate_occupancy_mean',
+                     'validate_batch_ms', 'mutate_device_path_requests',
+                     'mutate_host_loop_requests', 'mutate_rows',
+                     'mutate_fallback_rows')
+
+    def test_a_fresh_batcher_has_the_fields_of_both_kinds_at_zero(self):
+        batcher = AdmissionBatcher(window_ms=5)
+        try:
+            stats = batcher.stats()
+            for field in self.MUTATE_FIELDS:
+                assert stats[field] == 0 and \
+                    isinstance(stats[field], (int, float)), field
+        finally:
+            batcher.stop(drain=False)
+
+    def test_dispatches_are_split_by_the_kind_of_scanner_they_served(self):
+        """Mutate and validate tickets take turns on the one thread: the
+        fields there were keep counting every dispatch, the new ones
+        tell the two kinds apart, and ``reset_stats`` clears both."""
+        from kyverno_tpu.observability import device as devtel
+        from kyverno_tpu.observability.metrics import MetricsRegistry
+        devtel.configure(MetricsRegistry())    # dispatches are timed
+        batcher = AdmissionBatcher(window_ms=60_000, max_batch=3,
+                                   queue_cap=64)
+        try:
+            validate = _RowAdmScanner()
+            mutate = _RowAdmScanner()
+            mutate.kind = 'mutate'
+            mutate.last_fallback_rows = 2
+            tickets = [_submit(batcher, mutate, f'm{i}') for i in range(3)]
+            tickets += [_submit(batcher, validate, f'v{i}')
+                        for i in range(3)]
+            tickets += [_submit(batcher, mutate, f'n{i}') for i in range(3)]
+            assert all(t.wait(10.0) is not None for t in tickets)
+            batcher.record_mutate_path(True)
+            batcher.record_mutate_path(True)
+            batcher.record_mutate_path(False)
+            stats = batcher.stats()
+            assert mutate.calls == [3, 3] and validate.calls == [3]
+            assert (stats['dispatches'], stats['requests']) == (3, 9)
+            assert stats['occupancy_mean'] == 3.0
+            assert stats['mutate_dispatches'] == 2
+            assert stats['validate_dispatches'] == 1
+            assert stats['mutate_occupancy_mean'] == 3.0
+            assert stats['validate_occupancy_mean'] == 3.0
+            assert stats['mutate_rows'] == 6
+            assert stats['mutate_fallback_rows'] == 4
+            assert stats['mutate_device_path_requests'] == 2
+            assert stats['mutate_host_loop_requests'] == 1
+            assert stats['mutate_batch_ms'] > 0.0
+            assert stats['validate_batch_ms'] > 0.0
+            assert stats['batch_ms'] * 3 == pytest.approx(
+                stats['mutate_batch_ms'] * 2 + stats['validate_batch_ms'])
+            assert stats['mutate_queue_wait_p50_ms'] >= 0.0
+            batcher.reset_stats()
+            stats = batcher.stats()
+            assert all(stats[f] == 0 for f in self.MUTATE_FIELDS)
+        finally:
+            batcher.stop(drain=False)
+            devtel.disable()
+
     def test_canonical_admission_key_coalesces_reordered_lists(self):
         """Equivalent tuples differing only in list order produce one
         residual key (deterministic canonicalization)."""

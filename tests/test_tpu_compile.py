@@ -125,21 +125,23 @@ def test_evaluator_compiles_for_one_chip(pack, one_chip,
 def test_mutate_kernel_compiles_for_one_chip(one_chip,
                                              no_persistent_cache):
     import jax
+    import benchlib
     from kyverno_tpu.compiler.scan import WARM_POD
-    from kyverno_tpu.conformance import corpus
     from kyverno_tpu.mutate.encode import (encode_mutate_batch,
                                            string_window)
     from kyverno_tpu.mutate.kernel import MutateKernel
     from kyverno_tpu.mutate.plan import compile_mutate_set
-    program = compile_mutate_set(corpus.load_mutate_pack())
-    assert program.device_ok and program.programs
+    program = compile_mutate_set(
+        benchlib.load_policies(['mutate-defaults']))
+    assert program.device_ok and len(program.programs) == 7
+    assert program.lists == [('spec', 'containers')]
     kernel = MutateKernel(program)
     lanes = encode_mutate_batch([WARM_POD], program, padded_n=64,
                                 width=string_window(program))
     assert lanes['milli'].dtype == np.int64
     for capacity in (64, 16384):
         with jax.enable_x64(True):
-            compiled = jax.jit(kernel._eval).lower(
+            compiled = jax.jit(kernel.mutate_eval).lower(
                 _shapes(lanes, capacity, one_chip)).compile()
         _report(f'mutate@{capacity}', compiled)
 
