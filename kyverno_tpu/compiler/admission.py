@@ -414,3 +414,13 @@ def zero_lanes(table: AdmissionTable, padded: int) -> Dict[str, np.ndarray]:
         '__adm_hasinfo__': np.zeros(padded, np.int8),
         '__adm_excluded__': np.zeros(padded, np.int8),
     }
+
+
+def lane_signature(table: AdmissionTable
+                   ) -> Dict[str, Tuple[np.dtype, Tuple[int, ...]]]:
+    """``{name: (dtype, shape past the row axis)}`` of the admission
+    lanes: known from the table before any batch exists, so that the
+    encoder can keep their columns free in the packed buffers
+    (``compiler/packing.py``)."""
+    return {name: (lane.dtype, lane.shape[1:])
+            for name, lane in zero_lanes(table, 0).items()}

@@ -33,7 +33,7 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from fractions import Fraction
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -44,6 +44,7 @@ from .ir import (MAX_ELEMS, MAX_GATHER, STR_LEN, TAG_ARRAY, TAG_BOOL,
                  TAG_FLOAT, TAG_INT, TAG_MAP, TAG_MISSING, TAG_NULL,
                  TAG_STRING, TAIL_LEN, CompiledPolicySet, GatherSlot, Slot,
                  StatusExpr)
+from .packing import PackedLanes, PackedSet
 
 _INT64_MAX = (1 << 63) - 1
 
@@ -419,16 +420,24 @@ class LaneArena:
     The streaming scan pipeline holds a small fixed number of chunks in
     flight; the arena recycles their lane tensors (zeroed in place)
     instead of allocating ~100MB of numpy arrays per chunk, which is
-    what kept the 1M-resource path allocating monotonically.  A batch
-    is released back only after its device inputs are freed (d2h
-    complete), so a zero-copy host-to-device path can never observe a
-    recycled buffer."""
+    what kept the 1M-resource path allocating monotonically.
 
-    def __init__(self, max_pool: int = 4):
+    A batch's lanes are views of its packed buffers, one a dtype
+    (``compiler/packing.py``), and ``pack_batch`` hands those buffers to
+    the transfer as they are: the host-to-device path is zero-copy on
+    the host side.  So a batch is released back only after its device
+    inputs are freed (d2h complete); a batch recycled earlier would be
+    zeroed and re-encoded under a transfer that still reads it."""
+
+    def __init__(self, max_pool: int = 4, joining=None):
         #: buffers kept per shape key; 0 = palettes only (an encoder
         #: worker lays each chunk's lanes over a block of the parent's:
         #: :class:`BlockArena`)
         self.max_pool = max_pool
+        #: the lanes that join a batch after the encode, name -> (dtype,
+        #: shape past the row axis): their columns are kept free in the
+        #: packed buffers (``PackedSet``)
+        self.joining = dict(joining or {})
         self._lock = __import__('threading').Lock()
         self._free: Dict[tuple, List['Batch']] = {}
         self._palettes: Dict[tuple, _Palette] = {}
@@ -449,9 +458,26 @@ class LaneArena:
         return None
 
     def build(self, make) -> 'Batch':
-        """A fresh batch: ``make(zeros)`` allocates every lane through
-        ``zeros(shape, dtype)``; here that is plain numpy."""
-        return make(np.zeros)
+        """A fresh batch whose lanes are views of its packed buffers.
+        ``make(zeros)`` allocates every lane through ``zeros(shape,
+        dtype)`` and is run twice: once to learn the lanes, once over
+        the views."""
+        signature, order = _learn_lanes(make)
+        packed = PackedSet(signature, self.joining, self._allocate)
+        names = iter(order)
+
+        def view(shape, dtype):
+            name = next(names)
+            if name is None:  # a lane the batch does not ship
+                return np.zeros(shape, dtype)
+            return packed.views[name]
+        batch = make(view)
+        batch.packed = packed
+        return batch
+
+    def _allocate(self, specs) -> list:
+        """The packed buffers' memory; here that is plain numpy."""
+        return [np.zeros(shape, dtype) for _buf, shape, dtype in specs]
 
     def release(self, batch: 'Batch') -> None:
         key = getattr(batch, 'arena_key', None)
@@ -716,6 +742,9 @@ class Batch:
         #: the ``__rowvalid__`` lane (``_build_batch`` allocates it with
         #: the rest, ``encode_batch`` writes it)
         self.rowvalid: Optional[np.ndarray] = None
+        #: the packed buffers the lanes are views of (an arena's batch;
+        #: None: loose arrays)
+        self.packed: Optional[PackedSet] = None
         self.slot_lanes: Dict[Slot, Lanes] = {}
         self.array_meta: Dict[Tuple[str, ...], Dict[str, np.ndarray]] = {}
         self.gather_lanes: Dict[GatherSlot, Lanes] = {}
@@ -724,7 +753,11 @@ class Batch:
         self.elem_meta: Dict[Any, Dict[str, np.ndarray]] = {}
 
     def clear(self) -> None:
-        """Zero every tensor in place for arena reuse."""
+        """Zero every tensor in place for arena reuse: by buffer where
+        the lanes are views of packed buffers, else lane by lane."""
+        if self.packed is not None:
+            self.packed.clear()
+            return
         for lanes in self.slot_lanes.values():
             lanes.clear()
         for lanes in self.gather_lanes.values():
@@ -741,13 +774,17 @@ class Batch:
             for arr in meta.values():
                 arr.fill(0)
 
-    def tensors(self) -> Dict[str, np.ndarray]:
+    def tensors(self) -> PackedLanes:
+        if self.packed is not None:
+            # an arena's batch: the names below were walked once, when
+            # its packed buffers were planned
+            return self.packed.lanes()
         # the row-validity lane rides with every batch: the ragged
         # evaluator masks the capacity-padding tail rows inside the
         # jitted program (cross-row reductions — the mesh verdict
         # summary, the compact fail-detail selection — must never read
         # them), so one compiled capacity serves every occupancy
-        out: Dict[str, np.ndarray] = {'__rowvalid__': self.rowvalid}
+        out = PackedLanes({'__rowvalid__': self.rowvalid})
         for i, (slot, lanes) in enumerate(self.slot_lanes.items()):
             out.update(lanes.tensors(f's{i}'))
         for j, (path, meta) in enumerate(self.array_meta.items()):
@@ -956,9 +993,11 @@ def encode_batch(resources: List[dict], cps: CompiledPolicySet,
     key = (n, elems, gwidth, egwidth)
     batch = arena.acquire(key) if pooled else None
     if batch is None:
-        batch = arena.build(lambda zeros: _build_batch(
-            cps, n, elems, gwidth, egwidth, slot_needs, gather_needs,
-            elem_needs, array_paths, zeros))
+        # the caller's arena lays the lanes out packed; without one
+        # they are loose arrays, which ``pack_batch`` copies
+        def make(zeros):
+            return _build_batch(cps, *key, zeros)
+        batch = arena.build(make) if pooled else make(np.zeros)
         if pooled:
             batch.arena_key = key
     else:
@@ -1074,13 +1113,12 @@ def encode_batch(resources: List[dict], cps: CompiledPolicySet,
 
 
 def _build_batch(cps: CompiledPolicySet, n: int, elems: int, gwidth: int,
-                 egwidth: int, slot_needs, gather_needs, elem_needs,
-                 array_paths, zeros=np.zeros) -> Batch:
+                 egwidth: int, zeros=np.zeros) -> Batch:
     """Allocate the full lane tensor set for one batch shape (reused
     across chunks via the LaneArena).  Every lane comes from
-    ``zeros(shape, dtype)``: numpy's in this process, a
-    :class:`_BlockZeros` over a shared-memory block in an encoder
-    worker."""
+    ``zeros(shape, dtype)``: numpy's for loose lanes, an arena's views
+    of its packed buffers (:meth:`LaneArena.build`)."""
+    slot_needs, gather_needs, elem_needs, array_paths = _needs_cached(cps)
     batch = Batch(n)
     batch.rowvalid = zeros(n, np.int8)
     for path in array_paths:
@@ -1118,6 +1156,44 @@ def _build_batch(cps: CompiledPolicySet, n: int, elems: int, gwidth: int,
             'notfound': zeros((n, gwidth), bool),
         }
     return batch
+
+
+class _LaneSpec(NamedTuple):
+    """What ``zeros(shape, dtype)`` was asked for, in a lane's place."""
+    dtype: np.dtype
+    shape: Tuple[int, ...]
+
+
+def _learn_lanes(make):
+    """Run ``make`` over no memory.  Returns the batch's lanes as
+    ``{name: (dtype, shape)}``, in the order ``Batch.tensors`` names
+    them, and the lane each call of ``zeros`` was for, in the order of
+    the calls (None: for none that the batch ships)."""
+    calls: List[_LaneSpec] = []
+
+    def spec(shape, dtype):
+        shape = (shape,) if isinstance(shape, int) else tuple(shape)
+        calls.append(_LaneSpec(np.dtype(dtype), shape))
+        return calls[-1]
+    named = make(spec).tensors()
+    name_of = {id(lane): name for name, lane in named.items()}
+    return ({name: tuple(lane) for name, lane in named.items()},
+            [name_of.get(id(lane)) for lane in calls])
+
+
+def lane_signature(cps: CompiledPolicySet, key: tuple):
+    """``{name: (dtype, shape)}`` of the batch of shape ``key`` (rows,
+    element, gather and element-gather widths), cached on the cps: what
+    this process needs to find the lanes in the buffers an encoder
+    worker filled."""
+    cache = getattr(cps, '_lane_signature_cache', None)
+    if cache is None:
+        cache = cps._lane_signature_cache = {}
+    signature = cache.get(key)
+    if signature is None:
+        signature = cache[key] = _learn_lanes(
+            lambda zeros: _build_batch(cps, *key, zeros))[0]
+    return signature
 
 
 def _needs_cached(cps: CompiledPolicySet):
@@ -1351,11 +1427,12 @@ def _fill_elem_gather_column(rows: list, lanes: Lanes, meta, egwidth: int,
 # this module because this module imports no jax: a worker, and the fork
 # server it comes from, import nothing else.
 #
-# A chunk's lanes do not travel home through the pool's result pipe (some
-# hundreds of arrays, 272 MB at capacity 16,384: the parent's result
-# thread reads a pipe 64 kB at a time and retakes the interpreter lock
-# after every read).  The worker encodes them in place into a
-# shared-memory block and returns what names them: lane name, dtype,
+# A chunk's lanes do not travel home through the pool's result pipe (272
+# MB at capacity 16,384: the parent's result thread reads a pipe 64 kB
+# at a time and retakes the interpreter lock after every read).  The
+# worker encodes them in place into a shared-memory block, as views of
+# the packed buffers it lays there, and returns what places them: the
+# batch's shape key, which names the lanes, and each buffer's dtype,
 # shape and offset.  The parent owns every block and chooses every name
 # (scan.py _Block): with a task it offers the block it has and a spare
 # name, and a worker whose batch does not fit creates a block of the
@@ -1363,12 +1440,12 @@ def _fill_elem_gather_column(rows: list, lanes: Lanes, meta, egwidth: int,
 # fresh block a chunk needs none of this and was tried: it cost 1.3 s a
 # reconcile on the chip, PERF.md section 6, PR 29.)
 
-#: lanes in a block start on a multiple of this many bytes
+#: buffers in a block start on a multiple of this many bytes
 _BLOCK_ALIGN = 64
 
 
 class _BlockZeros:
-    """``np.zeros`` over one buffer: lanes laid one after the other,
+    """``np.zeros`` over one buffer: arrays laid one after the other,
     each start aligned.  Without a buffer it lays nothing and only adds
     the sizes up: ``offset`` is then the bytes a block needs."""
 
@@ -1378,15 +1455,14 @@ class _BlockZeros:
 
     def __call__(self, shape, dtype):
         dtype = np.dtype(dtype)
-        shape = (shape,) if isinstance(shape, int) else tuple(shape)
         at = self.offset
         end = at + dtype.itemsize * math.prod(shape)
         self.offset = -(-end // _BLOCK_ALIGN) * _BLOCK_ALIGN
         if self.buf is None:
             return None
-        lane = np.ndarray(shape, dtype, buffer=self.buf, offset=at)
-        lane.fill(0)  # a block comes back with its last chunk's lanes
-        return lane
+        arr = np.ndarray(shape, dtype, buffer=self.buf, offset=at)
+        arr.fill(0)  # a block comes back with its last chunk's lanes
+        return arr
 
 
 def open_block(offer, need: int):
@@ -1413,62 +1489,83 @@ def open_block(offer, need: int):
     return shm
 
 
-def block_layout(tensors: Dict[str, np.ndarray], buf) -> list:
-    """``(name, dtype, shape, offset)`` of each lane within ``buf``."""
+def block_layout(buffers: Dict[str, np.ndarray], buf) -> list:
+    """``(name, dtype, shape, offset)`` of each packed buffer within
+    ``buf``."""
     whole = np.frombuffer(buf, np.uint8)
     layout = []
-    for name, lane in tensors.items():
-        at = lane.ctypes.data - whole.ctypes.data
-        if not lane.flags.c_contiguous or at < 0 \
-                or at + lane.nbytes > whole.nbytes:
-            raise ValueError(f'lane {name} does not lie in the block')
-        layout.append((name, lane.dtype.str, lane.shape, at))
+    for name, arr in buffers.items():
+        at = arr.ctypes.data - whole.ctypes.data
+        if not arr.flags.c_contiguous or at < 0 \
+                or at + arr.nbytes > whole.nbytes:
+            raise ValueError(f'buffer {name} does not lie in the block')
+        layout.append((name, arr.dtype.str, arr.shape, at))
     return layout
 
 
-def block_lanes(buf, layout) -> Dict[str, np.ndarray]:
-    """The lanes ``layout`` names, as views over ``buf``."""
+def block_buffers(buf, layout) -> Dict[str, np.ndarray]:
+    """The packed buffers ``layout`` places, as views over ``buf``."""
     return {name: np.ndarray(shape, dtype, buffer=buf, offset=at)
             for name, dtype, shape, at in layout}
+
+
+def block_lanes(cps: CompiledPolicySet, joining, key: tuple,
+                buffers: Dict[str, np.ndarray]) -> PackedLanes:
+    """The lanes of the batch of shape ``key`` that a worker encoded
+    into ``buffers``: views of them, as the worker's own were.  Raises
+    ``ValueError`` where the buffers are not the ones that batch
+    packs into."""
+    def adopt(specs):
+        for name, shape, dtype in specs:
+            have = buffers.get(name)
+            if have is None or have.shape != shape or have.dtype != dtype:
+                raise ValueError(f'a worker answered with no {name} of '
+                                 f'{shape} {dtype}')
+        return [buffers[name] for name, _shape, _dtype in specs]
+    return PackedSet(lane_signature(cps, key), joining, adopt).lanes()
 
 
 class BlockArena(LaneArena):
     """An encoder worker's arena.  The columnar value palettes stay
     warm across the chunks one worker serves; no buffer is pooled,
-    because a chunk's lanes are laid over the block offered with its
-    task (``offer``).  ``shm`` is the block in use until the worker has
-    handed it back."""
+    because a chunk's packed buffers are laid over the block offered
+    with its task (``offer``).  ``shm`` is the block in use until the
+    worker has handed it back."""
 
-    def __init__(self):
-        super().__init__(max_pool=0)
+    def __init__(self, joining=None):
+        super().__init__(max_pool=0, joining=joining)
         self.offer = None
         self.shm = None
 
-    def build(self, make) -> 'Batch':
+    def _allocate(self, specs) -> list:
         sizes = _BlockZeros()
-        make(sizes)
+        for _buf, shape, dtype in specs:
+            sizes(shape, dtype)
         self.shm = open_block(self.offer, sizes.offset)
-        return make(_BlockZeros(self.shm.buf))
+        zeros = _BlockZeros(self.shm.buf)
+        # zeroed by buffer, the joining lanes' columns with the rest
+        return [zeros(shape, dtype) for _buf, shape, dtype in specs]
 
 
 _WORKER_CPS = None
 _WORKER_ARENA: Optional[BlockArena] = None
 
 
-def encode_worker_init(cps) -> None:
-    global _WORKER_CPS
+def encode_worker_init(cps, joining=None) -> None:
+    """``joining``: the lanes this process's scanner adds to a batch
+    after the encode (:class:`LaneArena`)."""
+    global _WORKER_CPS, _WORKER_ARENA
     _WORKER_CPS = cps
+    _WORKER_ARENA = BlockArena(joining)
 
 
 def encode_worker(args):
-    """Encode one chunk into a block; returns ``(block name, layout,
-    stage seconds, (t0, t1, pid))``."""
-    global _WORKER_ARENA
+    """Encode one chunk into a block; returns ``(block name, (the
+    batch's shape key, the buffers' layout), stage seconds, (t0, t1,
+    pid))``."""
     import os
     import time
     docs, contexts, padded_n, offer = args
-    if _WORKER_ARENA is None:
-        _WORKER_ARENA = BlockArena()
     arena = _WORKER_ARENA
     arena.offer = offer
     # the worker's metric increments and contextvars die with the
@@ -1486,13 +1583,14 @@ def encode_worker(args):
                                  contexts=contexts, arena=arena)
         t1 = time.monotonic()
         cap.add('encode', t1 - t0)
-        layout = block_layout(batch.tensors(), arena.shm.buf)
+        layout = block_layout(batch.packed.buffers, arena.shm.buf)
     except BaseException:
         # the error's traceback may still hold lanes, and a mapping
         # cannot close under them: it goes when they do
         arena.shm = None
         raise
     shm, arena.shm = arena.shm, None
+    key = batch.arena_key
     del batch  # its lanes are views of the mapping about to close
     shm.close()
-    return shm.name, layout, dict(cap.stages), (t0, t1, os.getpid())
+    return shm.name, (key, layout), dict(cap.stages), (t0, t1, os.getpid())

@@ -178,12 +178,13 @@ class _Blocks:
             self._all.append(block)
             return block
 
-    def lanes(self, block: _Block, name: str,
-              layout) -> Dict[str, np.ndarray]:
-        """The lanes a worker left in segment ``name``: the one offered,
-        or the spare it had to create, which replaces it."""
+    def buffers(self, block: _Block, name: str,
+                layout) -> Dict[str, np.ndarray]:
+        """The packed buffers a worker left in segment ``name``: the
+        one offered, or the spare it had to create, which replaces
+        it."""
         from multiprocessing import shared_memory
-        from .encode import block_lanes
+        from .encode import block_buffers
         if name == block.spare:
             old, block.shm = block.shm, shared_memory.SharedMemory(name=name)
             if old is not None:
@@ -193,7 +194,7 @@ class _Blocks:
             raise ValueError(f'a worker answered from block {name!r}, '
                              f'which it was not offered')
         block.spare = block.task = None
-        return block_lanes(block.shm.buf, layout)
+        return block_buffers(block.shm.buf, layout)
 
     def release(self, block: _Block) -> None:
         """Free for the next chunk, if its lanes had come home.  A block
@@ -269,9 +270,12 @@ class _EncoderPool:
     """Lazy fork-server pool; falls back to in-process encoding on
     failure, counted on ``kyverno_tpu_encode_worker_chunks_total``."""
 
-    def __init__(self, cps, procs: int):
+    def __init__(self, cps, procs: int, joining=None):
         self.cps = cps
         self.procs = procs
+        #: the lanes the scanner adds to a batch after the encode: a
+        #: worker keeps their columns free (encode.py ``LaneArena``)
+        self.joining = dict(joining or {})
         self._pool = None
         self._broken = False
         self.blocks = _Blocks()
@@ -285,7 +289,7 @@ class _EncoderPool:
                 ctx = multiprocessing.get_context('forkserver')
                 ctx.set_forkserver_preload([encode_worker.__module__])
                 pool = ctx.Pool(self.procs, initializer=encode_worker_init,
-                                initargs=(self.cps,))
+                                initargs=(self.cps, self.joining))
                 # weakref.finalize runs at collection OR interpreter exit
                 # (atexit=True default), so workers are reaped and blocks
                 # unlinked when the scanner is dropped and
@@ -314,6 +318,14 @@ class _EncoderPool:
         block.task = self._pool.apply_async(
             encode_worker, ((docs, contexts, padded_n, block.offer()),))
         return block.task
+
+    def lanes(self, block: _Block, home):
+        """The lanes of a worker's answer ``home``, as views of the
+        packed buffers it left in ``block``."""
+        from .encode import block_lanes
+        name, (key, layout) = home[:2]
+        return block_lanes(self.cps, self.joining, key,
+                           self.blocks.buffers(block, name, layout))
 
     def close(self) -> None:
         if self._pool is not None:
@@ -509,9 +521,19 @@ class BatchScanner:
         # assembly take turns on the same core
         _os = __import__('os')
         _default_procs = '2' if (_os.cpu_count() or 1) > 2 else '0'
+        # the lanes stage_h2d adds to every batch of this set after the
+        # encode (the mesh step adds none): known before any batch
+        # exists, so the encoder keeps their columns free in the packed
+        # buffers it fills and packing is a hand-over
+        joining: Dict[str, Tuple[Any, Tuple[int, ...]]] = {}
+        if mesh is None and self.cps.programs:
+            joining['__match__'] = (np.uint8, (self._evaluator.n_uniq,))
+            if self._adm is not None:
+                joining.update(admission_lanes.lane_signature(self._adm))
         self._encoder_pool = _EncoderPool(
             self.cps,
-            int(_os.environ.get('KTPU_ENCODE_PROCS', _default_procs)))
+            int(_os.environ.get('KTPU_ENCODE_PROCS', _default_procs)),
+            joining)
         # static per-policy response header fields (avoids re-deriving
         # them from the raw policy dict per (resource, policy) pair)
         self._policy_header = [
@@ -521,7 +543,7 @@ class BatchScanner:
         # streaming pipeline (compiler/encode.py LaneArena): chunk lane
         # tensors recycle instead of reallocating ~100MB per chunk
         from .encode import LaneArena
-        self._arena = LaneArena()
+        self._arena = LaneArena(joining=joining)
 
     def warmup(self, resources: Optional[List[dict]] = None) -> float:
         """Bring the admission-shape executable to serving readiness.
@@ -922,9 +944,14 @@ class BatchScanner:
             the encoder pool's block — exactly once: after d2h frees its
             device inputs on the success path, or via the pipeline's
             cleanup hook when the chunk dies mid-flight (stage crash,
-            aborted stream).  Device references are dropped first so a
-            zero-copy h2d path never sees its backing buffer recycled
-            while still reachable."""
+            aborted stream).  The order carries weight: ``pack_batch``
+            hands these very buffers to the transfer (the lanes are
+            views of them, ops/eval.py), and the transfer may read them
+            until the device has its copy — on XLA:CPU the device array
+            IS this memory.  So the device references are dropped
+            first, and a buffer is never zeroed and re-encoded under a
+            dispatch that still reads it
+            (tests/test_pack_views.py TestLifetime)."""
             if not isinstance(p, dict):
                 return
             p['t'] = p['out'] = p['enc'] = None
@@ -992,9 +1019,9 @@ class BatchScanner:
                         with devtel.stage('encode_wait'):
                             home = tensors.get(
                                 timeout=self.ENCODE_TIMEOUT_S)
-                        name, lanes_at, wstages, wspan = home
-                        tensors = self._encoder_pool.blocks.lanes(
-                            p['block'], name, lanes_at)
+                        wstages, wspan = home[2:]
+                        tensors = self._encoder_pool.lanes(p['block'],
+                                                           home)
                     except Exception as e:  # noqa: BLE001 - no answer
                         # inside the timeout: the worker is presumed
                         # dead; an answer that is an error, or lanes
@@ -1036,7 +1063,9 @@ class BatchScanner:
                 mm_u = fold_match_unique(mm_p, self._evaluator)
                 mm = np.zeros((padded, mm_u.shape[1]), np.uint8)
                 mm[:ln] = mm_u
-                tensors = dict(tensors)
+                # a copy that still knows whose views the lanes are
+                # (packing.py PackedLanes): p['enc'] stays as encoded
+                tensors = tensors.copy()
                 tensors['__match__'] = mm
             if self._adm is not None and self.mesh is None and tensors:
                 # admission lanes ride EVERY non-mesh dispatch of this
@@ -1044,7 +1073,7 @@ class BatchScanner:
                 # admission data) so the executable signature — and the
                 # fresh-process census — never depends on traffic mix
                 padded = next(iter(tensors.values())).shape[0]
-                tensors = dict(tensors)
+                tensors = tensors.copy()
                 if adm_plan is not None:
                     tensors.update(admission_lanes.slice_lanes(
                         adm_plan.lanes, start, ln, padded))

@@ -86,8 +86,14 @@ METRICS: Dict[str, Metric] = {
     'kyverno_tpu_encode_result_bytes_total': Metric(
         'counter', 'Bytes a chunk\'s encode brought home from its '
         'worker; via=block (the lanes, in a shared-memory block this '
-        'process maps)|pipe (the pickled answer that names them: a few '
-        'kB a chunk).'),
+        'process maps)|pipe (the pickled answer that places them: the '
+        'batch\'s shape key and the packed buffers\' offsets).'),
+    'kyverno_tpu_pack_batches_total': Metric(
+        'counter', 'Batches through ops/eval.py pack_batch; via=view '
+        '(the lanes were views of the packed buffers their encode '
+        'wrote, and those were handed to the transfer)|copy (loose '
+        'lanes, concatenated into new buffers: warm-up dispatches, '
+        'partitioned scanners, a scan with no match plane).'),
     # device-coverage ledger (observability/coverage.py)
     'kyverno_tpu_rule_placement_info': Metric(
         'gauge', '1 per compiled (policy, rule, path); placement=device|'
@@ -278,7 +284,9 @@ SPANS: Dict[str, str] = {
                            'assembly.',
     'kyverno/device/chunk': 'Dispatch-thread wrapper seeding the '
                             'per-chunk stage spans.',
-    'kyverno/device/pack': 'Pack-plan build stage.',
+    'kyverno/device/pack': 'Pack stage: the packed buffers handed over '
+                           '(via=view) or built by copying every lane '
+                           '(via=copy).',
     'kyverno/device/encode': 'Host feature-extraction (encode) stage.',
     'kyverno/device/h2d': 'Host-to-device transfer stage.',
     'kyverno/device/compile': 'Executable lookup / XLA compile stage.',
@@ -340,7 +348,9 @@ SPANS: Dict[str, str] = {
 PIPELINE_STAGES: Dict[str, str] = {
     'intake': 'Feeder admission into the streaming pipeline (chunk '
               'slot acquire + first-queue handoff).',
-    'pack': 'Pack-plan build.',
+    'pack': 'The batch as one buffer a dtype: handed over where the '
+            'encoder wrote its lanes as views of them (the joining '
+            'lanes copied into place), else every lane copied.',
     'encode': 'Host feature extraction (columnar lane encode, inline '
               'or forked worker).',
     'h2d': 'Host-to-device transfer (and forked-encode resolution).',

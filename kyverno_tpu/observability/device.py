@@ -1,7 +1,7 @@
 """Device-pipeline telemetry: stage spans, TPU metrics, d2h watchdog.
 
 The batched scan path (``compiler/scan.py`` + ``ops/eval.py``) runs as
-a pipeline — pack-plan build, host feature extraction (encode), h2d
+a pipeline — host feature extraction (encode), pack, h2d
 transfer, XLA trace/compile, device eval dispatch, d2h readback, report
 assembly.  This module gives each stage an OTel-shaped child span (via
 ``observability.tracing``) and a matching Prometheus series
@@ -44,6 +44,7 @@ BACKPRESSURE = 'kyverno_tpu_scan_backpressure_seconds_total'
 ENCODE_WORKER_CHUNKS = 'kyverno_tpu_encode_worker_chunks_total'
 ENCODE_RESULT_BYTES = 'kyverno_tpu_encode_result_bytes_total'
 STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
+PACK_BATCHES = 'kyverno_tpu_pack_batches_total'
 
 #: canonical stage labels.  The pipeline's, in order: ``match`` (host
 #: match sieve), ``encode`` (in a worker process or inline),
@@ -169,11 +170,14 @@ class ScanCapture:
     a registry-sum delta would."""
 
     __slots__ = ('stages', 'aot', 'coverage_ratio', 'critical_path',
-                 '_lock')
+                 'pack_views', '_lock')
 
     def __init__(self):
         self.stages: Dict[str, float] = {}
         self.aot = ''
+        #: batches of this scan that ``pack_batch`` handed over as the
+        #: buffers their lanes were encoded into (:func:`record_pack`)
+        self.pack_views = 0
         self.coverage_ratio: Optional[float] = None
         #: critical-path blame summary for this scan, filled by the
         #: timeline recorder (observability/timeline.py) when armed
@@ -414,10 +418,23 @@ def record_encode_worker(result: str) -> None:
         _registry.inc(ENCODE_WORKER_CHUNKS, result=result)
 
 
+def record_pack(via: str) -> None:
+    """One batch through ``ops/eval.py`` ``pack_batch``: ``view`` where
+    its lanes were views of the packed buffers already and those were
+    handed over, ``copy`` where every lane was copied into new ones."""
+    if _registry is not None:
+        _registry.inc(PACK_BATCHES, via=via)
+    capture = _capture_var.get()
+    if capture is not None and via == 'view':
+        with capture._lock:
+            capture.pack_views += 1
+
+
 def record_encode_result_bytes(lanes, answer) -> None:
     """What one chunk's encode brought home from its worker, by the way
     it came: the bytes of ``lanes`` in the shared-memory block, and
-    through the pipe ``answer``, which names them (a few kB pickled).
+    through the pipe ``answer``, which places them (pickled: the batch's
+    shape key and the packed buffers' offsets).
     Both are sized here, so only where metrics are on."""
     if _registry is not None:
         _registry.inc(ENCODE_RESULT_BYTES,

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from ..compiler.encode import _needs_cached
+from ..compiler.packing import plan_layout, unpack_batch  # noqa: F401
 from ..compiler.ir import (STR_LEN, TAG_ARRAY, TAG_BOOL, TAG_FLOAT, TAG_INT,
                            TAG_MAP, TAG_MISSING, TAG_NULL, TAG_STRING,
                            TAIL_LEN, BoolExpr, CompiledPolicySet, CondCheck,
@@ -2080,67 +2081,58 @@ def expand_compact(out8: np.ndarray, out32: np.ndarray, evaluator):
             adm)
 
 
-#: pack plans memoized by lane signature — admission serves thousands of
-#: identical-signature single-request packs, and rebuilding the grouping
-#: (dtype stringification, offset bookkeeping over ~900 lanes) per call
-#: costs more than the actual concatenation
+#: the layouts of loose lane sets, memoized by their signature: the
+#: paths that still copy (warm-up dispatches, the partitions' scanners,
+#: a scan that ships no match plane) repeat a few signatures, and the
+#: grouping costs more than a small batch's concatenation
 _PACK_PLANS: Dict[Tuple, Tuple] = {}
 
 
 def pack_batch(tensors: Dict[str, np.ndarray]):
-    """Coalesce all lanes into ONE flat [R, W] buffer per dtype.
+    """All lanes as ONE flat [R, W] buffer per dtype, and their layout.
 
-    The encoder produces hundreds of small per-lane arrays; transferring
-    each individually costs one host→device transfer apiece, and
-    per-transfer latency — not bandwidth — then bounds the pipeline.
-    Every lane has the resource axis
-    leading, so each is viewed as [R, prod(rest)] and concatenated per
-    dtype; the evaluator unpacks with static slices + reshapes that XLA
-    folds away.  Five dtypes → five host→device transfers per chunk.
-    """
+    Transferring hundreds of lanes one by one costs a host→device
+    transfer apiece, and per-transfer latency — not bandwidth — then
+    bounds the pipeline.  Every lane has the resource axis leading, so
+    each is a run of columns of its dtype's buffer
+    (``compiler/packing.py`` ``plan_layout``); the evaluator unpacks
+    with static slices + reshapes that XLA folds away.  Five dtypes →
+    five host→device transfers per chunk.
+
+    Lanes that an arena's encode wrote (``compiler/encode.py``
+    ``LaneArena``) are views of such buffers already: those are handed
+    over as they are, after the few lanes that joined the batch since
+    (``__match__``, the admission lanes) were copied into the columns
+    kept for them.  Whether they are is decided lane by lane, by
+    identity (``PackedSet.takes``).  Anything else — loose arrays, or a
+    set that lacks a joining lane — is concatenated into new buffers,
+    in the same layout.  Returns ``(packed, layout)``."""
+    return _pack_batch(tensors)[:2]
+
+
+def _pack_batch(tensors: Dict[str, np.ndarray]):
+    """:func:`pack_batch`, and which way it went: ``'view'`` or
+    ``'copy'``."""
+    owner = getattr(tensors, 'owner', None)
+    if owner is not None and owner.takes(tensors):
+        return dict(owner.buffers), owner.layout, 'view'
     sig = tuple((name, arr.dtype.num, arr.shape)
                 for name, arr in sorted(tensors.items()))
     plan = _PACK_PLANS.get(sig)
     if plan is None:
-        groups: Dict[str, List[Tuple[str, np.ndarray]]] = {}
-        for name, arr in sorted(tensors.items()):
-            groups.setdefault(str(arr.dtype), []).append((name, arr))
-        layout: Dict[str, Tuple[str, int, int, Tuple[int, ...]]] = {}
-        group_names: List[Tuple[str, List[str]]] = []
-        for dt, members in sorted(groups.items()):
-            r = members[0][1].shape[0]
-            off = 0
-            names: List[str] = []
-            for name, arr in members:
-                w = int(np.prod(arr.shape[1:], dtype=np.int64)) \
-                    if arr.ndim > 1 else 1
-                layout[name] = (f'pk_{dt}', off, w, arr.shape[1:])
-                names.append(name)
-                off += w
-            group_names.append((f'pk_{dt}', names))
-        plan = (layout, group_names)
+        plan = plan_layout({name: (arr.dtype, arr.shape)
+                            for name, arr in tensors.items()})
         if len(_PACK_PLANS) > 256:
             _PACK_PLANS.clear()
         _PACK_PLANS[sig] = plan
-    layout, group_names = plan
+    layout, groups = plan
     packed: Dict[str, np.ndarray] = {}
-    for buf_name, names in group_names:
+    for buf_name, _dtype, _width, names in groups:
         r = tensors[names[0]].shape[0]
         parts = [tensors[n].reshape(r, -1) for n in names]
         packed[buf_name] = parts[0] if len(parts) == 1 \
             else np.concatenate(parts, axis=1)
-    return packed, layout
-
-
-def unpack_batch(packed: Dict[str, Any],
-                 layout: Dict[str, Tuple[str, int, int, Tuple[int, ...]]]
-                 ) -> Dict[str, Any]:
-    out: Dict[str, Any] = {}
-    for name, (g, off, width, tail) in layout.items():
-        buf = packed[g]
-        sl = buf[:, off:off + width]
-        out[name] = sl.reshape((buf.shape[0],) + tuple(tail))
-    return out
+    return packed, layout, 'copy'
 
 
 def shard_batch(tensors: Dict[str, np.ndarray], mesh=None,
@@ -2151,8 +2143,10 @@ def shard_batch(tensors: Dict[str, np.ndarray], mesh=None,
     downcast.  Returns (packed_device_dict, layout)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ..observability import device as devtel
-    with devtel.stage('pack'):
-        packed, layout = pack_batch(tensors)
+    with devtel.stage('pack') as st:
+        packed, layout, via = _pack_batch(tensors)
+        st.set_attribute('via', via)
+        devtel.record_pack(via)
     with jax.enable_x64(True), devtel.stage('h2d') as st:
         st.set_attribute('bytes', sum(v.nbytes for v in packed.values()))
         if mesh is None:

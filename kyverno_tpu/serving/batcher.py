@@ -214,6 +214,9 @@ class AdmissionBatcher:
         self._timed = 0
         self._batch_s = 0.0
         self._stage_s: Dict[str, float] = {}
+        # of the timed dispatches, those whose lanes ``pack_batch``
+        # handed over as the buffers they were encoded into
+        self._pack_views = 0
         self._handled = 0
         self._handler_s = 0.0
         self._message_s = 0.0
@@ -465,6 +468,7 @@ class AdmissionBatcher:
             wall = time.monotonic() - t_in
             with self._stats_lock:
                 self._timed += 1
+                self._pack_views += bool(cap.pack_views)
                 self._batch_s += wall
                 self._by_kind[kind]['timed'] += 1
                 self._by_kind[kind]['batch_s'] += wall
@@ -592,6 +596,7 @@ class AdmissionBatcher:
             hetero = self._hetero_dispatches
             requests = self._requests
             quarantine = self._quarantine_dispatches
+            pack_views = self._pack_views
             # mean milliseconds per timed dispatch, per handled request
             per = 1000.0 / self._timed if self._timed else 0.0
             batch_ms = self._batch_s * per
@@ -633,6 +638,7 @@ class AdmissionBatcher:
             'handler_message_ms': message_ms,
             'dispatches': dispatches,
             'quarantine_dispatches': quarantine,
+            'pack_view_dispatches': pack_views,
             'requests': requests,
             'occupancy_mean': (sum(occ) / len(occ)) if occ else 0.0,
             'occupancy_p50': self._p50(occ),
@@ -661,7 +667,7 @@ class AdmissionBatcher:
             self._hetero_dispatches = 0
             self._requests = 0
             self._quarantine_dispatches = 0
-            self._timed = self._handled = 0
+            self._timed = self._handled = self._pack_views = 0
             self._batch_s = self._handler_s = self._message_s = 0.0
             self._stage_s.clear()
             self._paths = dict.fromkeys(self._paths, 0)

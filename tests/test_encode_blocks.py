@@ -1,12 +1,20 @@
-"""An encoder worker's lanes come home in a shared-memory block (ISSUE 29).
+"""An encoder worker's lanes come home in a shared-memory block (ISSUE 29),
+as views of the packed buffers the evaluator takes (ISSUE 33).
 
-A worker encodes a chunk in place into a block this process owns and
-returns what names the lanes; the h2d thread maps the block.  Pinned
-here:
+A worker encodes a chunk in place into a block this process owns, its
+lanes views of one ``[R, W]`` buffer a dtype laid over the block, and
+returns what places the buffers; the h2d thread maps the block and finds
+the lanes in them.  Pinned here:
 
 * the lanes through a block are ``encode_batch``'s in-process lanes, name
   for name and byte for byte, in every lane family, also when the block
   is reused after a larger batch and when the batch outgrows the block;
+* the block's buffers, once the joining lanes are in their columns, are
+  ``pack_batch``'s over loose copies of the same lanes, byte for byte and
+  offset for offset, and are handed over, not copied; a reused block
+  holds nothing of its last chunk in those columns either;
+* a block goes back for the next chunk only after its chunk's device
+  inputs were freed;
 * a multi-chunk scan through two blocks gives the in-process scan's
   reports (on XLA:CPU ``jnp.asarray`` of a numpy view is zero-copy: a
   block recycled too early would show);
@@ -125,9 +133,10 @@ def registry():
 def through_a_block(pool, block, docs, padded_n):
     """One chunk the way ``stage_encode`` and ``stage_h2d`` move it."""
     home = pool.submit(docs, None, padded_n, block).get(timeout=120)
-    name, layout, stages, span = home
+    name, (key, layout), stages, span = home
     assert stages['encode'] > 0 and span[2] != os.getpid()
-    return pool.blocks.lanes(block, name, layout), home
+    assert key[0] == padded_n
+    return pool.lanes(block, home), home
 
 
 def assert_same_lanes(got, want):
@@ -163,14 +172,24 @@ class TestLanes:
         block = pool.blocks.acquire()
         got, home = through_a_block(pool, block, docs, CAP)
         assert_same_lanes(got, want)
-        # laid one after the other inside the one segment, each start
-        # aligned
+        # what came through the pipe places one buffer a dtype, laid
+        # one after the other inside the one segment, each start
+        # aligned; the lanes are views of them
+        placed = home[1][1]
+        assert [name for name, *_ in placed] == [
+            'pk_bool', 'pk_int32', 'pk_int64', 'pk_int8', 'pk_uint8']
         ends = 0
-        for at, nbytes in sorted((at, got[name].nbytes)
-                                 for name, _dtype, _shape, at in home[1]):
-            assert at % 64 == 0 and at >= ends
-            ends = at + nbytes
+        for _name, dtype, shape, at in placed:
+            assert at % 64 == 0 and at >= ends and shape[0] == CAP
+            ends = at + np.dtype(dtype).itemsize * shape[0] * shape[1]
         assert ends <= block.shm.size < ends + 64
+        assert sum(lane.nbytes for lane in got.values()) == sum(
+            np.dtype(dtype).itemsize * shape[0] * shape[1]
+            for _name, dtype, shape, _at in placed)
+        whole = np.frombuffer(block.shm.buf, np.uint8)
+        for lane in got.values():
+            assert np.shares_memory(lane, whole)
+        del whole
         assert segments() == [home[0]]
         del got
         pool.blocks.release(block)
@@ -469,7 +488,9 @@ def test_result_bytes_are_not_sized_with_metrics_off():
     try:
         lanes = {'a': np.zeros((4, 8), np.int64), 'b': np.zeros(3, bool)}
         devtel.record_encode_result_bytes(
-            lanes, ('ktpu-enc-1-1', [('a', '<i8', (4, 8), 0)], {}, None))
+            lanes, ('ktpu-enc-1-1', ((4, 4, 4, 4),
+                                     [('pk_int64', '<i8', (4, 8), 0)]),
+                    {}, None))
         assert reg.counter_value(devtel.ENCODE_RESULT_BYTES,
                                  via='block') == 4 * 8 * 8 + 3
         assert 0 < reg.counter_value(devtel.ENCODE_RESULT_BYTES,
@@ -494,9 +515,9 @@ if __name__ == '__main__':
     assert pool.start()
     block = pool.blocks.acquire()
     docs = t.pods(16)
-    name, layout, _stages, span = pool.submit(docs, None, 16,
-                                              block).get(timeout=120)
-    lanes = pool.blocks.lanes(block, name, layout)
+    home = pool.submit(docs, None, 16, block).get(timeout=120)
+    name, span = home[0], home[3]
+    lanes = pool.lanes(block, home)
     before = {{k: v.tobytes() for k, v in lanes.items()}}
     # the worker that created the block exits; the block is this
     # process's and stays
