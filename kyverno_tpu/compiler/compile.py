@@ -31,7 +31,8 @@ from ..utils.duration import parse_duration
 from ..utils.quantity import Quantity
 from .ir import (CMP_EQ, CMP_GE, CMP_GT, CMP_LE, CMP_LT, CMP_NE, STR_LEN,
                  TAIL_LEN, BoolExpr, CompileError, CompiledPolicySet,
-                 CondCheck, GatherSlot, Leaf, RuleProgram, Slot, StatusExpr)
+                 CondCheck, CtxValue, GatherSlot, Leaf, RuleProgram, Slot,
+                 StatusExpr)
 
 _CMP_OF_OP = {
     leaf_pattern.OP_MORE: CMP_GT,
@@ -90,11 +91,15 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
     validate = rule['validate']
     context_spec = None
     context_inputs = None
+    scope: Optional[_ContextScope] = None
     if rule.get('context'):
-        # compilable when every entry is a cluster-data lookup whose
-        # value feeds NO compiled lane — the load's success/failure
-        # semantics are enforced per resource by the scanner (imageData
-        # entries stay host-side: network-bound)
+        # compilable when every entry is a cluster-data lookup.  Where
+        # no entry's value feeds a compiled lane the device decision is
+        # context-independent; where a deny condition or precondition
+        # reads one as its ``value``, the value reaches the device as
+        # per-row lanes (CondCheck mode C).  Either way the load's
+        # success/failure semantics are enforced per resource by the
+        # scanner (imageData entries stay host-side: network-bound)
         entries = rule['context']
         if not isinstance(entries, list):
             raise CompileError('malformed context block')
@@ -105,13 +110,8 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
                 raise CompileError(
                     'imageRegistry context entries require the host '
                     'engine', reason='api_call')
-        body = json.dumps({'v': validate,
-                           'p': rule.get('preconditions')})
-        for entry in entries:
-            nm = str((entry or {}).get('name', ''))
-            if nm and re.search(r'\b' + re.escape(nm) + r'\b', body):
-                raise CompileError(
-                    'context entry value feeds compiled lanes')
+        scope = _ContextScope(entries)
+        scope.check_body(rule, validate)
         context_spec = tuple(entries)
         # cacheable when every consumed variable is request.object-rooted
         # AND no entry evaluates bare (un-braced) expressions per
@@ -119,17 +119,16 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
         # context, so their outcome can depend on more than the captured
         # inputs (the load then re-runs per resource)
         from ..engine.variables import RE_VARIABLES as _RV
-        exprs = []
         cacheable = all((e or {}).get('configMap') or (e or {}).get('apiCall')
                         for e in entries)
         if cacheable:
-            for m in _RV.finditer(json.dumps(entries)):
-                expr = m.group(2)[2:-2].strip()
-                if not expr.startswith('request.object'):
-                    cacheable = False
-                    break
-                exprs.append(expr)
-        context_inputs = tuple(sorted(set(exprs))) if cacheable else None
+            for leaf in _string_leaves(entries):
+                for m in _RV.finditer(leaf):
+                    expr = m.group(2)[2:-2].strip()
+                    if not expr.startswith('request.object'):
+                        cacheable = False
+                    scope.inputs.add(expr)
+        scope.cacheable = cacheable
     if validate.get('manifests') is not None:
         raise CompileError('manifests rules require the host engine',
                            reason='host_closure')
@@ -153,18 +152,19 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
 
     # preconditions gate everything (engine.py Validator.validate order)
     if rule.get('preconditions') is not None:
-        pre = _compile_conditions(cps, rule['preconditions'])
+        pre = _compile_conditions(cps, rule['preconditions'], scope=scope)
         plan = _error_plan(cps, rule['preconditions'],
-                           'failed to evaluate preconditions', error_messages)
+                           'failed to evaluate preconditions', error_messages,
+                           scope)
         units.append(StatusExpr('precond', expr=pre, operand=plan))
 
     if validate.get('deny') is not None:
         conditions = (validate['deny'] or {}).get('conditions')
-        deny = _compile_conditions(cps, conditions)
+        deny = _compile_conditions(cps, conditions, scope=scope)
         plan = _error_plan(
             cps, conditions,
             'failed to substitute variables in deny conditions',
-            error_messages)
+            error_messages, scope)
         units.append(StatusExpr('deny', expr=deny, operand=plan))
         if static_msg:
             # deny FAIL message is the (static) message verbatim, or the
@@ -235,6 +235,15 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
     else:
         raise CompileError('no compilable validate sub-key')
 
+    ctx_values: Tuple[CtxValue, ...] = ()
+    if scope is not None:
+        # registered only now: a rule that failed to compile above
+        # leaves no value lanes behind
+        ctx_values = tuple(dict.fromkeys(scope.used))
+        for cv in ctx_values:
+            cps.ctx_value_id(cv)
+        context_inputs = tuple(sorted(scope.inputs)) \
+            if scope.cacheable else None
     return RuleProgram(
         policy_name=policy.name, rule_name=name,
         policy_index=p_idx, rule_index=r_idx,
@@ -244,13 +253,125 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
         skip_message=skip_message,
         background=policy.background, rule_raw=rule,
         context_spec=context_spec, context_inputs=context_inputs,
+        ctx_values=ctx_values,
         fail_sites=tuple(fail_sites) if fail_sites is not None else None,
         fail_prefix=fail_prefix, deny_fail_message=deny_fail_message,
         any_fail_sites=any_fail_sites, any_fail_prefix=any_fail_prefix)
 
 
+_CTX_NUMERIC_OPS = ('greaterthan', 'greaterthanorequals', 'lessthan',
+                    'lessthanorequals')
+_CTX_EQUALITY_OPS = ('equal', 'equals', 'notequal', 'notequals')
+
+
+def _string_leaves(node: Any):
+    """Every string of a JSON document, keys included."""
+    if isinstance(node, str):
+        yield node
+    elif isinstance(node, dict):
+        for k, v in node.items():
+            yield from _string_leaves(k)
+            yield from _string_leaves(v)
+    elif isinstance(node, list):
+        for v in node:
+            yield from _string_leaves(v)
+
+
+class _ContextScope:
+    """What one rule's ``context`` block means to its conditions: the
+    entries' names, which of them the body reads, and what the
+    conditions that read one consumed (the value lanes, the
+    ``{{request.object…}}`` inputs nested in their expressions)."""
+
+    #: roots a value expression may not read bare: the value is resolved
+    #: once per distinct tuple of the rule's inputs, so it may depend on
+    #: the row only through a nested ``{{request.object…}}``
+    _ROW_ROOTS = re.compile(
+        r'\b(request|element\w*|images|serviceAccount\w*|target)\b|@|\$\(')
+
+    def __init__(self, entries: List[dict]):
+        self.key = json.dumps(entries, sort_keys=True)
+        self.kinds = {}
+        for entry in entries:
+            e = entry or {}
+            nm = str(e.get('name', ''))
+            if nm:
+                self.kinds[nm] = 'configMap' if e.get('configMap') else \
+                    'apiCall' if e.get('apiCall') else 'variable'
+        self.used: List[CtxValue] = []
+        self.inputs: set = set()
+        self.cacheable = False
+
+    def names_in(self, node: Any) -> List[str]:
+        text = node if isinstance(node, str) else json.dumps(node)
+        return [nm for nm in self.kinds
+                if re.search(r'\b' + re.escape(nm) + r'\b', text)]
+
+    def check_body(self, rule: dict, validate: dict) -> None:
+        """Where a context value may be read: a condition's ``value``.
+        The message keeps its upstream ``{{…}}`` (the host words such a
+        FAIL); everything else that reads one keeps the rule on the
+        host, each shape under its own reason."""
+        if self.names_in({'p': validate.get('pattern'),
+                          'a': validate.get('anyPattern')}):
+            raise CompileError('context entry value read in a pattern leaf',
+                               reason='context_in_pattern')
+        if self.names_in(validate.get('foreach')):
+            raise CompileError('context entry value read in a foreach',
+                               reason='context_in_foreach')
+        rest = {k: v for k, v in validate.items()
+                if k not in ('message', 'deny', 'pattern', 'anyPattern',
+                             'foreach')}
+        if self.names_in(rest):
+            raise CompileError('context entry value feeds compiled lanes',
+                               reason='context_value_expr')
+        fed = self.names_in({'d': validate.get('deny'),
+                             'p': rule.get('preconditions')})
+        if fed and 'variable' in self.kinds.values():
+            # a variable entry may shadow or feed any other entry, and
+            # its own jmesPath reads the whole context
+            raise CompileError(
+                'a condition reads the context beside a variable entry',
+                reason='context_entry_kind')
+
+    def value_of(self, value: str, op: str) -> CtxValue:
+        """The lanes' source for a condition value that reads an entry:
+        one ``{{ expr }}`` whose nested variables are
+        ``request.object``-rooted and whose own text reads the row
+        nowhere else."""
+        from ..engine.variables import RE_VARIABLES as _RV
+        m = _SINGLE_VAR_RE.match(value.strip())
+        if not m:
+            raise CompileError(
+                f'context value is not a single variable: {value!r}',
+                reason='context_value_expr')
+        outer = m.group(1)
+        nested = [mm.group(2)[2:-2].strip() for mm in _RV.finditer(outer)]
+        bare = _RV.sub(lambda mm: mm.group(1) + '""', outer)
+        if '{{' in bare or '}}' in bare:
+            raise CompileError(
+                f'context value is not a single variable: {value!r}',
+                reason='context_value_expr')
+        for expr in nested:
+            if not re.match(r'request\.object\b', expr) or '{{' in expr:
+                raise CompileError(
+                    f'context value input {expr!r} is not '
+                    f'request.object-rooted', reason='context_value_inputs')
+        if self._ROW_ROOTS.search(bare) or _STATEFUL_FN_RE.search(bare):
+            raise CompileError(
+                f'context value reads the row outside a nested variable: '
+                f'{value!r}', reason='context_value_inputs')
+        self.inputs.update(nested)
+        family = 'num' if op in _CTX_NUMERIC_OPS else \
+            'eq' if op in _CTX_EQUALITY_OPS else 'in'
+        cv = CtxValue(self.key, value.strip(), family)
+        self.used.append(cv)
+        return cv
+
+
 def _error_plan(cps: CompiledPolicySet, conditions: Any, prefix: str,
-                messages: List[str]) -> Tuple[Tuple[GatherSlot, int], ...]:
+                messages: List[str], scope: Optional[_ContextScope] = None
+                ) -> Tuple[Tuple[GatherSlot, int], ...]:
     """Ordered (gather, message-index) plan for unresolvable condition
     variables.  Mirrors the substitution traversal order
     (variables.py _traverse, reference: pkg/engine/jsonutils/traverse.go)
@@ -266,6 +387,10 @@ def _error_plan(cps: CompiledPolicySet, conditions: Any, prefix: str,
             for i, v in enumerate(node):
                 walk(v, f'{path}/{i}')
         elif isinstance(node, str):
+            if scope is not None and scope.names_in(node):
+                # a context value: where it does not resolve the parent
+                # marks the cell and the host words the error
+                return
             m = _SINGLE_VAR_RE.match(node.strip())
             if m:
                 leaves.append((m.group(1).strip(), path))
@@ -719,7 +844,8 @@ _SUPPORTED_COND_OPS = {
 
 def _compile_conditions(cps: CompiledPolicySet, conditions: Any,
                         elem_list_expr: Optional[str] = None,
-                        err_gathers: Optional[List] = None) -> BoolExpr:
+                        err_gathers: Optional[List] = None,
+                        scope: Optional['_ContextScope'] = None) -> BoolExpr:
     """Compile any/all condition blocks to a BoolExpr
     (semantics: kyverno_tpu/engine/operators.py evaluate_conditions).
     With ``elem_list_expr`` set, conditions compile at foreach-element
@@ -730,7 +856,7 @@ def _compile_conditions(cps: CompiledPolicySet, conditions: Any,
                 raise CompileError('bad condition')
             return _compile_condition_elem(cps, elem_list_expr, c,
                                            err_gathers)
-        return _compile_condition(cps, c)
+        return _compile_condition(cps, c, scope)
 
     if conditions is None:
         return BoolExpr.of(Leaf(Slot(()), 'true'))
@@ -770,7 +896,8 @@ def _compile_any_all(cps: CompiledPolicySet, block: dict, one) -> BoolExpr:
     return BoolExpr.all(parts)
 
 
-def _compile_condition(cps: CompiledPolicySet, cond: Any) -> BoolExpr:
+def _compile_condition(cps: CompiledPolicySet, cond: Any,
+                       scope: Optional['_ContextScope'] = None) -> BoolExpr:
     if not isinstance(cond, dict):
         raise CompileError('bad condition')
     op = str(cond.get('operator', '')).lower()
@@ -778,6 +905,20 @@ def _compile_condition(cps: CompiledPolicySet, cond: Any) -> BoolExpr:
         raise CompileError(f'operator {op!r} not vectorized')
     key = cond.get('key')
     value = cond.get('value')
+    if scope is not None:
+        if scope.names_in(key):
+            raise CompileError('context entry value read in a condition key',
+                               reason='context_in_key')
+        if scope.names_in(value):
+            if not isinstance(value, str):
+                raise CompileError(
+                    'context entry value inside a list or map value',
+                    reason='context_value_expr')
+            gather, _ = _compile_condition_key(key)
+            cv = scope.value_of(value, op)
+            cps.gather_id(gather)
+            return BoolExpr.of_cond(CondCheck(gather=gather, op=op,
+                                              ctx_value=cv))
     _check_constant(value)
     gather, _ = _compile_condition_key(key)
     cps.gather_id(gather)

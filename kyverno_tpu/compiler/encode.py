@@ -40,7 +40,7 @@ import numpy as np
 from ..utils.duration import parse_duration
 from ..utils.quantity import Quantity
 from ..utils.wildcard import match as _wild_match
-from .ir import (MAX_ELEMS, MAX_GATHER, STR_LEN, TAG_ARRAY, TAG_BOOL,
+from .ir import (CTX_HEAD, MAX_ELEMS, MAX_GATHER, STR_LEN, TAG_ARRAY, TAG_BOOL,
                  TAG_FLOAT, TAG_INT, TAG_MAP, TAG_MISSING, TAG_NULL,
                  TAG_STRING, TAIL_LEN, CompiledPolicySet, GatherSlot, Slot,
                  StatusExpr)
@@ -535,6 +535,18 @@ def _cond_needs(check) -> LaneNeeds:
     from ..engine import pattern as leaf_pattern
     n = LaneNeeds()
     op = check.op
+    if check.ctx_value is not None:
+        # mode C (ops/eval.py _cond_ctx_tf): the key's string form
+        # against the value lanes' byte windows, or its number against
+        # the value's
+        family = check.ctx_value.family
+        if family == 'num':
+            n.milli = True
+        else:
+            n.head = CTX_HEAD
+            n.length = True
+            n.wild = family == 'in'
+        return n
     if op in ('equal', 'equals', 'notequal', 'notequals'):
         if check.list_value:
             for cv in check.values:

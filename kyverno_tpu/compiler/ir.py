@@ -59,6 +59,21 @@ STATUS_PASS, STATUS_FAIL, STATUS_SKIP, STATUS_HOST = 0, 1, 2, 3
 STATUS_SKIP_PRECOND = 4
 STATUS_VAR_ERR = 5
 N_STATUS_CODES = 6
+# host-only codes, never a device output: the scanner writes them over
+# the device's status of a (row, program) cell whose context the parent
+# could not hand to the device (compiler/context_lanes.py), and assembly
+# sends exactly those cells to host materialization under the reason
+# each names (scan.py _CTX_STATUS_REASON)
+STATUS_CTX_LOAD = 6        # the rule's context load failed
+STATUS_CTX_UNRESOLVED = 7  # a context value's variable did not resolve
+STATUS_CTX_WIDE = 8        # more list elements than the value lane holds
+STATUS_CTX_SHAPE = 9       # a value outside the device's exact zone
+
+# a context value's lanes (CondCheck mode C): the value itself and up to
+# CTX_WIDTH list elements, each the first CTX_HEAD bytes of its string
+# form and its length
+CTX_WIDTH = 16
+CTX_HEAD = 32
 
 
 @dataclass(frozen=True)
@@ -100,6 +115,29 @@ class GatherSlot:
 
     def __str__(self):
         return self.expr
+
+
+@dataclass(frozen=True)
+class CtxValue:
+    """A condition value the rule's own ``context`` supplies: ``value``
+    is the condition's raw value string (one ``{{ expr }}`` over
+    configMap / apiCall entries), ``context_key`` the canonical JSON of
+    the rule's context entries, so that two rules with the same entries
+    and the same expression (autogen's copies) share lanes.  The parent
+    process resolves it per distinct tuple of the rule's
+    ``context_inputs`` with the engine's own loader and substitution
+    (``compiler/context_lanes.py``) and ships it as per-row lanes
+    ``cv<i>_len`` / ``cv<i>_head`` (in-family and equality operators) or
+    ``cv<i>_milli`` (numeric comparisons).  ``family`` is the operator
+    family that reads it — ``in``, ``eq`` or ``num`` — which decides the
+    lanes and the zone of values they are exact for; one expression read
+    by two families has lanes for each."""
+    context_key: str
+    value: str
+    family: str = 'in'
+
+    def __str__(self):
+        return self.value
 
 
 @dataclass(frozen=True)
@@ -194,11 +232,14 @@ class Leaf:
 class CondCheck:
     """One compiled deny/precondition condition.
 
-    Two modes (semantics: kyverno_tpu/engine/operators.py, reference:
+    Three modes (semantics: kyverno_tpu/engine/operators.py, reference:
     pkg/engine/variables/operator/*.go):
       A — ``gather`` key vs constant ``values`` (the common shape);
       B — constant ``key_const`` vs a ``value_gather`` projection
-          (foreach conditions like ``key: ALL, value: {{element...}}``).
+          (foreach conditions like ``key: ALL, value: {{element...}}``);
+      C — ``gather`` key vs ``ctx_value``, a value that varies by row:
+          what the rule's context loaded (an allowlist in a ConfigMap),
+          compared lane with lane.
     ``op`` is the lower-cased reference operator name.  ``list_value``
     records whether the constant side was a YAML list — the reference
     dispatches on the operand's type, not just its contents.
@@ -210,6 +251,7 @@ class CondCheck:
     list_value: bool = False
     key_const: Any = None        # mode B constant key
     value_gather: Optional[Any] = None  # mode B value projection
+    ctx_value: Optional[CtxValue] = None  # mode C value lanes
 
 
 @dataclass(frozen=True)
@@ -372,6 +414,9 @@ class RuleProgram:
     # values, so the scanner memoizes per (rule, inputs) instead of
     # re-loading per cell; None -> not cacheable (re-load per resource)
     context_inputs: Optional[Tuple[str, ...]] = None
+    # the context values this rule's conditions read (mode-C checks), in
+    # compile order; empty: the context's values feed nothing
+    ctx_values: Tuple[CtxValue, ...] = ()
 
 
 @dataclass(frozen=True)
@@ -396,6 +441,8 @@ class CompiledPolicySet:
     gather_index: Dict[GatherSlot, int] = field(default_factory=dict)
     elem_gathers: List[ElemGather] = field(default_factory=list)
     elem_gather_index: Dict[ElemGather, int] = field(default_factory=dict)
+    ctx_values: List[CtxValue] = field(default_factory=list)
+    ctx_value_index: Dict[CtxValue, int] = field(default_factory=dict)
     programs: List[RuleProgram] = field(default_factory=list)
     # (policy_index, rule dict, policy) for rules the device cannot evaluate
     host_rules: List[Tuple[int, dict, Any]] = field(default_factory=list)
@@ -416,6 +463,12 @@ class CompiledPolicySet:
             self.gather_index[g] = len(self.gathers)
             self.gathers.append(g)
         return self.gather_index[g]
+
+    def ctx_value_id(self, v: CtxValue) -> int:
+        if v not in self.ctx_value_index:
+            self.ctx_value_index[v] = len(self.ctx_values)
+            self.ctx_values.append(v)
+        return self.ctx_value_index[v]
 
     def elem_gather_id(self, g: ElemGather) -> int:
         if g not in self.elem_gather_index:

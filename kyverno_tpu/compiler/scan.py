@@ -38,9 +38,11 @@ from . import admission as admission_lanes
 from .compile import compile_policies
 from .encode import encode_batch, encode_worker, encode_worker_init
 from .shapes import canonical_capacity, canonical_caps
-from .ir import (STATUS_FAIL, STATUS_HOST, STATUS_PASS, STATUS_SKIP,
-                 STATUS_SKIP_PRECOND, STATUS_VAR_ERR, CompiledPolicySet,
-                 RuleProgram)
+from .context_lanes import ContextLanes
+from .ir import (STATUS_CTX_LOAD, STATUS_CTX_SHAPE, STATUS_CTX_UNRESOLVED,
+                 STATUS_CTX_WIDE, STATUS_FAIL, STATUS_HOST, STATUS_PASS,
+                 STATUS_SKIP, STATUS_SKIP_PRECOND, STATUS_VAR_ERR,
+                 CompiledPolicySet, RuleProgram)
 
 _SIMPLE_MATCH_KEYS = {'kinds', 'namespaces', 'operations'}
 
@@ -60,6 +62,15 @@ PRECONDITIONS_SKIP_MESSAGE = 'preconditions not met'
 
 # sentinel: a device cell that must be re-run on the host engine
 _HOST_MARKER = object()
+
+#: the ledger's reason for each status the context fill writes over the
+#: device's (compiler/context_lanes.py): the cell is the host's
+_CTX_STATUS_REASON = {
+    STATUS_CTX_LOAD: coverage.REASON_CONTEXT_LOAD,
+    STATUS_CTX_UNRESOLVED: coverage.REASON_CONTEXT_VALUE_UNRESOLVED,
+    STATUS_CTX_WIDE: coverage.REASON_CONTEXT_VALUE_WIDE,
+    STATUS_CTX_SHAPE: coverage.REASON_CONTEXT_VALUE_SHAPE,
+}
 
 #: process-unique monotonic scanner ids for batch coalescing keys —
 #: ``id()`` can be reused after GC/eviction, which would let a fresh
@@ -444,7 +455,10 @@ class BatchScanner:
         self._composer = None
         from ..partition.plan import PartitionError, env_partitions
         _n_parts = env_partitions()
-        if _n_parts > 0 and mesh is None and self.cps.programs:
+        # (a set whose conditions read context values stays monolithic:
+        # the value lanes join a batch here, in whole-set space)
+        if _n_parts > 0 and mesh is None and self.cps.programs and \
+                not self.cps.ctx_values:
             try:
                 from ..partition import census as _census
                 from ..partition.compose import Composer
@@ -526,10 +540,18 @@ class BatchScanner:
         # exists, so the encoder keeps their columns free in the packed
         # buffers it fills and packing is a hand-over
         joining: Dict[str, Tuple[Any, Tuple[int, ...]]] = {}
+        # the context side of the set: which programs share a context,
+        # and the value lanes their conditions read; nothing of it
+        # exists for a set without a context
+        self._ctx = ContextLanes(self.cps)
+        self._host_reads_context = any(
+            (rule or {}).get('context')
+            for _i, rule, _p in self.cps.host_rules)
         if mesh is None and self.cps.programs:
             joining['__match__'] = (np.uint8, (self._evaluator.n_uniq,))
             if self._adm is not None:
                 joining.update(admission_lanes.lane_signature(self._adm))
+            joining.update(self._ctx.signature)
         self._encoder_pool = _EncoderPool(
             self.cps,
             int(_os.environ.get('KTPU_ENCODE_PROCS', _default_procs)),
@@ -623,6 +645,7 @@ class BatchScanner:
                     # admission lanes are part of the signature too
                     tensors.update(admission_lanes.zero_lanes(
                         self._adm, cap))
+            tensors.update(self._ctx.zero_lanes(cap))
             t, layout = shard_batch(tensors, self.mesh)
             out = self._evaluator(t, layout)
             for arr in out:
@@ -995,9 +1018,21 @@ class BatchScanner:
                     enc = block = None
             if enc is None:
                 enc, batch = inline_encode(part, part_ctx, bucket)
+            ctx_lanes = ctx_marks = None
+            if self._ctx:
+                # beside the worker's encode: the chunk's distinct
+                # context inputs resolved once each, the value lanes and
+                # the load-outcome mask written.  Admission rows carry
+                # their own request, so each is resolved on its own
+                with devtel.stage('context', {'chunk': start // chunk,
+                                              'rows': len(part)}):
+                    ctx_lanes, ctx_marks = self._ctx.fill(
+                        part, cm, bucket, self,
+                        memo=getattr(self, '_pctx_factory', None) is None)
             return {'start': start, 'ln': len(part), 'part': part,
                     'part_ctx': part_ctx, 'bucket': bucket, 'enc': enc,
-                    'batch': batch, 'block': block, 'cm': cm}
+                    'batch': batch, 'block': block, 'cm': cm,
+                    'ctx_lanes': ctx_lanes, 'ctx_marks': ctx_marks}
 
         def stage_h2d(p):
             faults.check(faults.SITE_H2D)
@@ -1080,8 +1115,11 @@ class BatchScanner:
                 else:
                     tensors.update(admission_lanes.zero_lanes(
                         self._adm, padded))
+            if p['ctx_lanes']:
+                tensors = tensors.copy()
+                tensors.update(p['ctx_lanes'])
             t, layout = shard_batch(tensors, self.mesh)
-            p['enc'] = p['part'] = p['part_ctx'] = None
+            p['enc'] = p['part'] = p['part_ctx'] = p['ctx_lanes'] = None
             p['t'], p['layout'] = t, layout
             return p
 
@@ -1111,6 +1149,7 @@ class BatchScanner:
                                                    self._evaluator)
                     self._free_inputs(t, out)
                     cm = p['cm']
+                    self._mark_context(s, p['ctx_marks'])
                     release_chunk(p)
                 return (start, s[:ln], d[:ln], fd[:ln],
                         adm[:ln] if adm is not None else None, cm)
@@ -1153,6 +1192,7 @@ class BatchScanner:
             if self.mesh is None:
                 self._free_inputs(t, out)
             cm = p['cm']
+            self._mark_context(s, p['ctx_marks'])
             release_chunk(p)
             return start, s, d, fd, None, cm
 
@@ -1199,6 +1239,30 @@ class BatchScanner:
             depth=depth, capture=tel_capture, parent_span=tel_parent,
             cleanup=release_chunk, timeline=timeline)
         yield from pipe.run(range(0, n, chunk))
+
+    def context_digest(self, resource: dict) -> Optional[tuple]:
+        """What the rules of this set would read of their contexts for
+        ``resource`` now (``ContextLanes.row_digest``); None where that
+        cannot be told from the resource: a rule that loads context on
+        the host."""
+        if self._host_reads_context:
+            return None
+        return self._ctx.row_digest(resource, self)
+
+    @property
+    def reads_context(self) -> bool:
+        """Whether any rule of this set loads context, compiled or on
+        the host: its verdicts are no function of the resource alone."""
+        return bool(self._ctx) or self._host_reads_context
+
+    @staticmethod
+    def _mark_context(status: np.ndarray, marks) -> None:
+        """The chunk's load-outcome mask, laid over the device's
+        statuses: a cell whose context load failed, or whose value the
+        lanes could not carry, reads ``STATUS_CTX_*`` from here on and
+        assembly hands exactly those cells to the host."""
+        for j, rows, st in marks or ():
+            status[rows, j] = st
 
     def _partitioned_status_chunks(self, resources: List[dict],
                                    contexts: Optional[List[dict]] = None,
@@ -1439,7 +1503,7 @@ class BatchScanner:
         # context-load outcomes are memoized within one scan pass only —
         # the host engine reloads per evaluation, so staleness must not
         # outlive a pass
-        self._ctx_ok_cache = {}
+        self._ctx.begin_pass()
         # admission scans evaluate every policy; the background gate
         # (engine.py:174 apply_background_checks) only applies to scans
         background_mode = admission is None and admissions is None and \
@@ -1603,7 +1667,7 @@ class BatchScanner:
                         continue
                     rr = self._cell(prog, j, int(st_row[j]),
                                     int(det_row[j]), fdet[k], ts, fly,
-                                    resources[start + k], tally)
+                                    tally)
                     if rr is _HOST:
                         rr = self._materialize(prog,
                                                resources[start + k])
@@ -1630,7 +1694,7 @@ class BatchScanner:
                 det_col = detail[rows, j].tolist()
                 for k, st, det in zip(rows.tolist(), st_col, det_col):
                     rr = self._cell(prog, j, st, det, fdet[k], ts, fly,
-                                    resources[start + k], tally)
+                                    tally)
                     if rr is _HOST:
                         # anchor-SKIP / HOST / unsynthesizable FAIL:
                         # re-run on the host for exact status+message
@@ -1751,26 +1815,15 @@ class BatchScanner:
                 tally.total_rows += int(rows_j.size)
             st_col = status[rows_j, j].astype(np.int32)
             det_col = detail[rows_j, j].astype(np.int32)
-            # context-loading programs keep the per-cell path: the load
-            # outcome depends on each resource's own context inputs
-            per_cell = prog.context_spec is not None
-            if per_cell:
-                groups = [(None, None, rows_j)]
-            else:
-                combined = st_col * 1024 + (det_col + 512)
-                uniq, inv = np.unique(combined, return_inverse=True)
-                groups = [(int(u) // 1024 , int(u) % 1024 - 512,
-                           rows_j[inv == gi])
-                          for gi, u in enumerate(uniq)]
+            # a context program's load outcomes came with the chunk, as
+            # statuses of their own (_mark_context), so its cells group
+            # like any other's
+            combined = st_col * 1024 + (det_col + 512)
+            uniq, inv = np.unique(combined, return_inverse=True)
+            groups = [(int(u) // 1024 , int(u) % 1024 - 512,
+                       rows_j[inv == gi])
+                      for gi, u in enumerate(uniq)]
             for st, det, sub in groups:
-                if per_cell:
-                    # context programs check per resource: stay
-                    # row-at-a-time (memoized on context inputs)
-                    self._assemble_cells(
-                        prog, j, p_idx, key, scored, category, severity,
-                        sub, status, detail, fdet, resources, base, ts,
-                        stamp, fly, rows, row_pols, counts, tally)
-                    continue
                 if st == STATUS_FAIL:
                     # FAIL messages hang off the per-row fail-detail
                     # buffer — but the relevant fdet columns take few
@@ -1795,11 +1848,8 @@ class BatchScanner:
                 result, bucket = cell
                 if result is _HOST_MARKER:
                     if tally is not None:
-                        tally.fallback_n(
-                            prog, coverage.REASON_STATUS_HOST
-                            if st == STATUS_HOST
-                            else self._message_reason(prog),
-                            int(sub.size))
+                        tally.fallback_n(prog, self._host_reason(prog, st),
+                                         int(sub.size))
                     for k in sub.tolist():
                         rr = self._materialize(prog, resources[base + k])
                         if rr is None:
@@ -1874,35 +1924,6 @@ class BatchScanner:
                 row_pols[k].append(p_idx)
             counts[sg, bucket] += 1
 
-    def _assemble_cells(self, prog, j, p_idx, key, scored, category,
-                        severity, sub, status, detail, fdet, resources,
-                        base, ts, stamp, fly, rows, row_pols, counts,
-                        tally):
-        """Row-at-a-time assembly for the cells the columnar sweep
-        cannot group: FAIL messages (per-row fail details) and
-        context-loading programs (per-resource load outcomes)."""
-        from ..reports.results import _rule_result
-        bucket_idx = self._BUCKET_IDX
-        _HOST = _HOST_MARKER
-        for k in sub.tolist():
-            rr = self._cell(prog, j, int(status[k, j]), int(detail[k, j]),
-                            fdet[k], ts, fly, resources[base + k], tally)
-            if rr is _HOST:
-                rr = self._materialize(prog, resources[base + k])
-                if rr is not None:
-                    rr.timestamp = ts
-            if rr is None or rr is _HOST:
-                continue
-            result = _rule_result(rr, key, scored, category, severity,
-                                  stamp, ts)
-            rows[k].append(result)
-            row_pols[k].append(p_idx)
-            counts[k, bucket_idx[result['result']]] += 1
-        # _cell already incremented total_rows per cell — undo the
-        # double count from the column-level bulk add
-        if tally is not None:
-            tally.total_rows -= int(sub.size)
-
     def scan_report_results(self, resources: List[dict],
                             now: Optional[float] = None):
         """Yield ``(results, summary, policies)`` per resource — the
@@ -1930,7 +1951,7 @@ class BatchScanner:
         ts = int(now)
         ts_key = str(ts)
         stamp = {'seconds': ts}
-        self._ctx_ok_cache = {}
+        self._ctx.begin_pass()
         progs = self.cps.programs
         background_ok = getattr(self, '_background_ok', None)
         if background_ok is None:
@@ -2061,8 +2082,7 @@ class BatchScanner:
             tlmod.finish_scan(tl)
 
     def _cell(self, prog, j: int, st: int, det: int, fdet_row, ts: int,
-              fly: Dict[Tuple, Any], resource: Optional[dict] = None,
-              tally=None):
+              fly: Dict[Tuple, Any], tally=None):
         """Flyweight RuleResponse for one device cell (or _HOST_MARKER).
 
         FAIL cells key on the synthesized message — the fail-site detail
@@ -2073,12 +2093,6 @@ class BatchScanner:
         name its reason, so no fallback is ever silent."""
         if tally is not None:
             tally.total_rows += 1
-        if prog.context_spec is not None and resource is not None and \
-                not self._context_ok(prog, resource):
-            # load failure must surface the host's exact error response
-            if tally is not None:
-                tally.fallback(prog, coverage.REASON_CONTEXT_LOAD)
-            return _HOST_MARKER
         if st == STATUS_FAIL:
             msg = self._fail_message_cached(prog, j, fdet_row)
             if msg is None:
@@ -2102,10 +2116,7 @@ class BatchScanner:
             fly[key] = rr
         if tally is not None:
             if rr is _HOST_MARKER:
-                tally.fallback(
-                    prog, coverage.REASON_STATUS_HOST
-                    if st == STATUS_HOST
-                    else self._message_reason(prog))
+                tally.fallback(prog, self._host_reason(prog, st))
             else:
                 tally.device(prog)
         return rr
@@ -2276,48 +2287,6 @@ class BatchScanner:
             return pctx
         return PolicyContext(policy, new_resource=resource)
 
-    def _context_ok(self, prog: RuleProgram, resource: dict) -> bool:
-        """Attempt the rule's context loads the way the host engine
-        would (reference: pkg/engine/jsonContext.go:126 LoadContext);
-        False → the cell falls back to host materialization so the
-        load-failure response is exact.  When the spec's variables are
-        all request.object-rooted, outcomes memoize on their values —
-        bulk scans then pay one load per distinct input combination."""
-        cache_key = None
-        if prog.context_inputs is not None:
-            from ..engine.jmespath import search as jp_search
-            doc_ctx = {'request': {'object': resource}}
-            try:
-                cache_key = (id(prog),) + tuple(
-                    repr(jp_search(expr, doc_ctx))
-                    for expr in prog.context_inputs)
-            except Exception:  # noqa: BLE001 - unkeyable: just load
-                cache_key = None
-            if cache_key is not None:
-                cache = getattr(self, '_ctx_ok_cache', None)
-                if cache is None:
-                    cache = self._ctx_ok_cache = {}
-                hit = cache.get(cache_key)
-                if hit is not None:
-                    return hit
-        pctx = self._pctx(self.policies[prog.policy_index], resource)
-        ctx = pctx.json_context
-        ctx.checkpoint()
-        try:
-            self.engine.context_loader.load(
-                list(prog.context_spec), ctx,
-                policy_name=prog.policy_name, rule_name=prog.rule_name)
-            ok = True
-        except Exception:  # noqa: BLE001 - exact failure via host path
-            ok = False
-        finally:
-            ctx.restore()
-        if cache_key is not None:
-            if len(self._ctx_ok_cache) > 4096:
-                self._ctx_ok_cache.clear()
-            self._ctx_ok_cache[cache_key] = ok
-        return ok
-
     def _materialize(self, prog: RuleProgram,
                      resource: dict) -> Optional[RuleResponse]:
         """Produce the exact host-engine rule response for one rule.
@@ -2343,6 +2312,15 @@ class BatchScanner:
                     self.engine.pss_evaluator)
         pctx = self._pctx(self.policies[prog.policy_index], resource)
         return Validator(self.engine, pctx, rule).validate()
+
+    def _host_reason(self, prog: RuleProgram, st: int) -> str:
+        """The ledger's reason for a cell of status ``st`` that the host
+        phrases: undecided on the device, marked by the context fill
+        (a failed load must surface the host's exact error response), or
+        decided there and worded here."""
+        if st == STATUS_HOST:
+            return coverage.REASON_STATUS_HOST
+        return _CTX_STATUS_REASON.get(st) or self._message_reason(prog)
 
     def _message_reason(self, prog: RuleProgram) -> str:
         """The ledger's reason for a cell whose verdict the device

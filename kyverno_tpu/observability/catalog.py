@@ -94,6 +94,16 @@ METRICS: Dict[str, Metric] = {
         'wrote, and those were handed to the transfer)|copy (loose '
         'lanes, concatenated into new buffers: warm-up dispatches, '
         'partitioned scanners, a scan with no match plane).'),
+    'kyverno_tpu_context_lookups_total': Metric(
+        'counter', '(row, context) pairs of scanned chunks that asked '
+        'for the outcome of their rule\'s context (compiler/'
+        'context_lanes.py): one per matched row and group of programs '
+        'that share a context.'),
+    'kyverno_tpu_context_loads_total': Metric(
+        'counter', 'Calls of the engine\'s context loader by the '
+        'scanner, one per distinct tuple of a context\'s inputs in a '
+        'scan pass; result=ok|failed. Lookups minus loads are the '
+        'memo\'s hits.'),
     # device-coverage ledger (observability/coverage.py)
     'kyverno_tpu_rule_placement_info': Metric(
         'gauge', '1 per compiled (policy, rule, path); placement=device|'
@@ -297,6 +307,11 @@ SPANS: Dict[str, str] = {
     'kyverno/device/report': 'Response/report assembly stage.',
     'kyverno/device/match': 'Host match sieve over the policy axis '
                             '(once a chunk or batch).',
+    'kyverno/device/context': 'Context fill of a chunk: its distinct '
+                              'context inputs resolved by the engine\'s '
+                              'loader, the value lanes and the '
+                              'load-outcome mask written '
+                              '(compiler/context_lanes.py).',
     'kyverno/device/encode_wait': 'The h2d thread waiting out a '
                                   'worker\'s encode of the chunk (its '
                                   'lanes come home in a block, not '
@@ -348,6 +363,10 @@ SPANS: Dict[str, str] = {
 PIPELINE_STAGES: Dict[str, str] = {
     'intake': 'Feeder admission into the streaming pipeline (chunk '
               'slot acquire + first-queue handoff).',
+    'context': 'The chunk\'s distinct context inputs resolved once '
+               'each (loader calls included), the value lanes and the '
+               'load-outcome mask written; on the encode thread, beside '
+               'a worker\'s encode.',
     'pack': 'The batch as one buffer a dtype: handed over where the '
             'encoder wrote its lanes as views of them (the joining '
             'lanes copied into place), else every lane copied.',

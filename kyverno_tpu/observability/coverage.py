@@ -71,6 +71,31 @@ REASON_PSS_DIRECT = 'pss_direct_message'  # verdict the device's; the
 #   unsynthesizable_message
 REASON_CONTEXT_LOAD = 'context_load_failed'  # rule context load failed;
 #   host materialization produces the exact error response
+# Context values in conditions (compiler/compile.py _ContextScope): a
+# condition whose ``value`` is one {{ expr }} over the rule's own
+# configMap / apiCall entries compiles (CondCheck mode C); these are the
+# shapes that still keep the rule on the host (compile time) ...
+REASON_CONTEXT_IN_PATTERN = 'context_in_pattern'  # a context value in
+#   a pattern / anyPattern leaf
+REASON_CONTEXT_IN_FOREACH = 'context_in_foreach'  # ... in a foreach
+REASON_CONTEXT_IN_KEY = 'context_in_key'  # ... in a condition key
+REASON_CONTEXT_ENTRY_KIND = 'context_entry_kind'  # a condition reads
+#   the context of a rule that has a ``variable`` entry
+REASON_CONTEXT_VALUE_EXPR = 'context_value_expr'  # the value is not one
+#   {{ expr }} (spliced into a string, inside a list or a map)
+REASON_CONTEXT_VALUE_INPUTS = 'context_value_inputs'  # the expression
+#   reads the row other than through a nested {{request.object…}}
+# ... and the cells of a compiled rule that the parent process hands to
+# host materialization because the value it resolved cannot ride the
+# lanes (runtime, per (row, program); compiler/context_lanes.py):
+REASON_CONTEXT_VALUE_UNRESOLVED = 'context_value_unresolved'  # the
+#   value's variable did not resolve: the host words the error
+REASON_CONTEXT_VALUE_WIDE = 'context_value_wide'  # more list elements
+#   than the value lane holds (ir.CTX_WIDTH)
+REASON_CONTEXT_VALUE_SHAPE = 'context_value_shape'  # a value outside
+#   the device's exact zone: a wildcard or a range in an allowlist, a
+#   number, duration or quantity where Equals wants a plain string, a
+#   map, a list with an element that is no scalar
 # Runtime (mutate fast-path escapes):
 REASON_NON_DICT = 'non_dict_intermediate'  # overlay path hit a non-map
 REASON_DUP_ELEMENT_NAMES = 'duplicate_element_names'  # merge-by-name
@@ -112,7 +137,12 @@ REASON_STAGE_RETRY_EXHAUSTED = 'stage_retry_exhausted'  # a scan
 REASONS = frozenset({
     REASON_UNSUPPORTED_OPERATOR, REASON_HOST_CLOSURE, REASON_API_CALL,
     REASON_POLICY_COUPLING, REASON_STATUS_HOST, REASON_UNSYNTHESIZABLE,
-    REASON_PSS_DIRECT, REASON_CONTEXT_LOAD, REASON_NON_DICT,
+    REASON_PSS_DIRECT, REASON_CONTEXT_LOAD,
+    REASON_CONTEXT_IN_PATTERN, REASON_CONTEXT_IN_FOREACH,
+    REASON_CONTEXT_IN_KEY, REASON_CONTEXT_ENTRY_KIND,
+    REASON_CONTEXT_VALUE_EXPR, REASON_CONTEXT_VALUE_INPUTS,
+    REASON_CONTEXT_VALUE_UNRESOLVED, REASON_CONTEXT_VALUE_WIDE,
+    REASON_CONTEXT_VALUE_SHAPE, REASON_NON_DICT,
     REASON_DUP_ELEMENT_NAMES, REASON_REPLACE_PATH_MISSING,
     REASON_PRECONDITION_ESCAPE,
     REASON_SITE_CONFLICT, REASON_PATCH_UNDECIDABLE, REASON_LIST_SHAPE,

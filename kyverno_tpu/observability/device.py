@@ -45,10 +45,15 @@ ENCODE_WORKER_CHUNKS = 'kyverno_tpu_encode_worker_chunks_total'
 ENCODE_RESULT_BYTES = 'kyverno_tpu_encode_result_bytes_total'
 STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 PACK_BATCHES = 'kyverno_tpu_pack_batches_total'
+CONTEXT_LOOKUPS = 'kyverno_tpu_context_lookups_total'
+CONTEXT_LOADS = 'kyverno_tpu_context_loads_total'
 
 #: canonical stage labels.  The pipeline's, in order: ``match`` (host
 #: match sieve), ``encode`` (in a worker process or inline),
 #: ``encode_wait`` (the h2d thread waiting out a worker's encode),
+#: ``context`` (the encode thread, while a worker encodes: the chunk's
+#: distinct context inputs resolved, loader calls included, and the
+#: value lanes and the load-outcome mask written),
 #: ``pack``, ``h2d``, ``compile``, ``device_eval`` (the dispatch: it
 #: times the ENQUEUE), ``d2h`` (wait + copy), ``device_wait`` (nested in
 #: ``d2h``: blocked until the evaluator's outputs are ready),
@@ -72,7 +77,7 @@ STAGES = ('match', 'encode', 'encode_wait', 'pack', 'h2d', 'compile',
           'unnamed', 'prepare', 'resolve', 'candidates', 'handler_pre',
           'handler_post', 'deny_message', 'mutate_match',
           'mutate_encode', 'mutate_eval', 'mutate_decode', 'mutate_pre',
-          'mutate_post')
+          'mutate_post', 'context')
 
 _log = logging.getLogger('kyverno.device')
 
@@ -428,6 +433,21 @@ def record_pack(via: str) -> None:
     if capture is not None and via == 'view':
         with capture._lock:
             capture.pack_views += 1
+
+
+def record_context(lookups: int, loads_ok: int, loads_failed: int) -> None:
+    """One chunk's context fill (``compiler/context_lanes.py``): a
+    lookup is a (row, context) pair that asked for its context's
+    outcome, a load is a call of the engine's loader; lookups − loads
+    are the memo's hits."""
+    if _registry is not None:
+        if lookups:
+            _registry.inc(CONTEXT_LOOKUPS, float(lookups))
+        if loads_ok:
+            _registry.inc(CONTEXT_LOADS, float(loads_ok), result='ok')
+        if loads_failed:
+            _registry.inc(CONTEXT_LOADS, float(loads_failed),
+                          result='failed')
 
 
 def record_encode_result_bytes(lanes, answer) -> None:

@@ -1095,6 +1095,112 @@ def _b_equals(t, prefix: str, key, sv: _View, kind, count, overflow) -> _K:
     return _K(zeros, jnp.ones(shape, bool))
 
 
+def _ctx_member(view: _View, vlen, vhead) -> _K:
+    """Whether the string form of each key element is one of a value
+    lane's elements: ``vlen`` [R, S] (−1: no such element) and ``vhead``
+    [R, S, CTX_HEAD] against the key's own length and head window.  Two
+    strings of one length that agree on the window and do not fit it
+    are undecidable.  The parent process hands the device no value with
+    a wildcard or a range in it (compiler/context_lanes.py), so what is
+    left of ``wildcard.match`` in either direction is equality, except
+    for a key that has a wildcard itself."""
+    head = view.lane('str_head')
+    w = min(head.shape[-1], vhead.shape[-1])
+    klen = view.str_len
+    # the value's axis comes last: [R, S] beside a scalar key's [R],
+    # [R, 1, S] beside a list key's [R, G]
+    mid = (1,) * (klen.ndim - 1)
+    vlen = vlen.reshape(vlen.shape[:1] + mid + vlen.shape[1:])
+    vhead = vhead.reshape(vhead.shape[:1] + mid + vhead.shape[1:])
+    same = (vlen >= 0) & (klen[..., None] == vlen) & \
+        jnp.all(head[..., None, :w] == vhead[..., :w], axis=-1)
+    fits = (klen <= w)[..., None]
+    conv = view.convertible
+    hit = conv & jnp.any(same & fits, axis=-1)
+    maybe = conv & jnp.any(same & ~fits, axis=-1)
+    unknown = maybe | view.arrayish
+    if view.has('has_wild'):
+        unknown = unknown | view.lane('has_wild')
+    return _K(hit, ~hit & ~unknown)
+
+
+def _cond_ctx_tf(t: Dict[str, Any], prefix: str, vprefix: str,
+                 check: CondCheck) -> _K:
+    """Mode-C checks: the gathered key against a value that varies by
+    row, lane with lane (operators.py with the value side at run time).
+    Exact inside the zone the parent process admits a value to — a
+    string or a list of scalars without wildcard or range for the In
+    family, a plain string for Equals, a number for the comparisons —
+    and for every other value the parent marks the cell for the host
+    before the device's answer is read."""
+    op = check.op
+    kind = t[f'{prefix}_kind']
+    count = t[f'{prefix}_count']
+    overflow = t[f'{prefix}_overflow']
+    sv = _View(t, prefix, 0)
+    scalar = kind == 1
+    raised = ((kind == 0) & overflow) | t[f'{prefix}_notfound']
+    family = check.ctx_value.family
+    if family == 'num':
+        # numeric key against a number (operators._numeric_num_key):
+        # both sides through float64 as the host has them; a string key
+        # (duration, quantity, semver) is the host's
+        cmp = {'greaterthan': '>', 'greaterthanorequals': '>=',
+               'lessthan': '<', 'lessthanorequals': '<='}[op]
+        mok = sv.lane('milli_ok') & (jnp.abs(sv.milli) <= (1 << 53))
+        key_f = sv.milli.astype(jnp.float64) / 1000.0
+        val_f = t[f'{vprefix}_milli'].astype(jnp.float64) / 1000.0
+        tt = scalar & sv.numish & mok & _cmp_arr(key_f, val_f, cmp)
+        uu = scalar & ((sv.numish & ~mok) | (sv.tag == TAG_STRING))
+        return _K(tt & ~raised, ~tt & ~uu & ~raised)
+    vlen = t[f'{vprefix}_len']
+    vhead = t[f'{vprefix}_head']
+    if family == 'eq':
+        # a plain string value: equal to a string key of the same bytes
+        # and to nothing else (operators._equal_string falls through to
+        # wildcard.match(value, key), the value without wildcards)
+        eq = _ctx_member(sv, vlen[:, :1], vhead[:, :1])
+        is_str = scalar & (sv.tag == TAG_STRING)
+        tt = is_str & eq.t
+        uu = is_str & eq.unknown() & ~sv.arrayish
+        res = _K(tt, ~tt & ~uu)
+        if op in ('notequal', 'notequals'):
+            res = res.negate()
+        return _K(res.t & ~raised, res.f & ~raised)
+    # AnyIn / AllIn and their negations (operators.py:299-395): slot 0
+    # holds a string value itself, slots 1.. its elements (the JSON
+    # array it spells, or the one string it is; a list value's elements)
+    negate = op in ('anynotin', 'allnotin')
+    member = _ctx_member(sv, vlen, vhead)
+    if negate:
+        member = member.negate()
+    scalar_ok = sv.is_tag(TAG_STRING, TAG_INT, TAG_FLOAT)
+    scal_t = scalar & scalar_ok & member.t
+    scal_f = scalar & (~scalar_ok | member.f)
+    ev = _View(t, prefix)
+    gwidth = t[f'{prefix}_tag'].shape[-1]
+    elem_valid = jnp.arange(gwidth) < count[..., None]
+    em = _ctx_member(ev, vlen[:, 1:], vhead[:, 1:])
+    quant = {'anyin': 'any', 'allin': 'all',
+             'anynotin': 'any_not', 'allnotin': 'all_not'}[op]
+    lt, lf = _quantify(quant, em, elem_valid, overflow)
+    # a one-element list key that is the string value itself decides
+    # before the value is parsed (operators.py:332,383)
+    one = _ctx_member(sv, vlen[:, :1], vhead[:, :1])
+    short = (count == 1) & one.t
+    short_u = (count == 1) & one.unknown()
+    if negate:
+        lt, lf = lt & ~short, lf | short
+    else:
+        lt, lf = lt | short, lf & ~short
+    lst = kind == 2
+    list_t = lst & lt & ~short_u
+    list_f = lst & lf & ~short_u
+    t_out = scal_t | list_t
+    f_out = (scal_f | list_f | (kind == 0)) & ~t_out
+    return _K(t_out & ~raised, f_out & ~raised)
+
+
 def cond_tf(t: Dict[str, Any], prefix: str, check: CondCheck) -> _K:
     op = check.op
     kind = t[f'{prefix}_kind']
@@ -1252,6 +1358,7 @@ def build_evaluator(cps: CompiledPolicySet):
     slot_prefix = {slot: f's{i}' for i, slot in enumerate(cps.slots)}
     gather_prefix = {g: f'g{k}' for k, g in enumerate(cps.gathers)}
     elem_prefix = {g: f'e{k}' for k, g in enumerate(cps.elem_gathers)}
+    ctx_prefix = {v: f'cv{k}' for k, v in enumerate(cps.ctx_values)}
     _, _, _, array_paths = _needs_cached(cps)
     array_prefix = {path: f'a{j}' for j, path in enumerate(array_paths)}
 
@@ -1344,6 +1451,9 @@ def build_evaluator(cps: CompiledPolicySet):
             else:
                 if check.value_gather is not None:
                     out = _cond_b_tf(t, check_prefix(check), check)
+                elif check.ctx_value is not None:
+                    out = _cond_ctx_tf(t, check_prefix(check),
+                                       ctx_prefix[check.ctx_value], check)
                 else:
                     out = cond_tf(t, check_prefix(check), check)
                 cond_cache[check] = out

@@ -243,6 +243,12 @@ def distributed_scan_step(cps: CompiledPolicySet, mesh: Mesh,
         span_cm = tracing.start_span('kyverno/mesh/step')
     with span_cm as span:
         t_start = time.perf_counter() if fl else 0.0
+        if cps.ctx_values:
+            # the value lanes join a batch in a scanner, which has the
+            # engine's loader (compiler/context_lanes.py); this step has
+            # only the documents
+            raise ValueError('conditions of this policy set read context '
+                             'values: scan it through a BatchScanner')
         batch = encode_batch(resources, cps, padded_n=padded)
         raw = batch.tensors()
         tensors, layout = shard_tensors(raw, mesh, axis)

@@ -190,6 +190,13 @@ class BackgroundScanController:
         self._verdicts_cacheable = (
             not self.scanner._host_policy_idx and
             all(p.context_spec is None for p in self.scanner.cps.programs))
+        # a verdict must not outlive the ConfigMap it read: what a rule
+        # loaded is not part of the resource's hash, so a row of a set
+        # in which any rule loads context is kept with a digest of what
+        # its rules read (scanner.context_digest), and "this version was
+        # scanned already" holds only while they would read the same
+        self._reads_context = self.scanner.reads_context
+        self._scanned_ctx: Dict[str, tuple] = {}
         old_cache = self.verdict_cache
         if old_cache is not None:
             old_cache.flush()
@@ -290,10 +297,26 @@ class BackgroundScanController:
                 continue
             prior = self._scanned.get(uid)
             if prior is not None and prior[0] == entry['hash'] and \
-                    prior[1] >= epoch:
+                    prior[1] >= epoch and self._context_unchanged(
+                        uid, entry['resource']):
                 continue  # resumability: already scanned this version
             yield (uid, entry['resource'], entry['hash'],
                    entry.get('digest') or spec_digest(entry['resource']))
+
+    def _context_unchanged(self, uid: str, resource: dict) -> bool:
+        """Whether the rules of this set would read of their contexts
+        what they read when ``uid`` was last scanned."""
+        if not self._reads_context:
+            return True
+        seen = self._scanned_ctx.get(uid)
+        return seen is not None and \
+            seen == self.scanner.context_digest(resource)
+
+    def _mark_scanned(self, uid: str, resource: dict, rhash: str,
+                      now: float) -> None:
+        self._scanned[uid] = (rhash, now)
+        if self._reads_context:
+            self._scanned_ctx[uid] = self.scanner.context_digest(resource)
 
     def reconcile(self, now: Optional[float] = None) -> List[dict]:
         """Drain the pending set through the verdict-cache filter and
@@ -321,6 +344,9 @@ class BackgroundScanController:
         # compiled path has no exception lanes) and rows are
         # exception-dependent, so the verdict cache stands aside
         exceptions = self._list_exceptions()
+        if self._reads_context:
+            # what _pending_rows compares is read now, not remembered
+            self.scanner._ctx.begin_pass()
         vc = self.verdict_cache \
             if self._verdicts_cacheable and not exceptions else None
         reports: List[dict] = []
@@ -342,7 +368,7 @@ class BackgroundScanController:
                         uid, resource,
                         self._host_scan_row(resource, exceptions),
                         now, rhash)
-                    self._scanned[uid] = (rhash, now)
+                    self._mark_scanned(uid, resource, rhash, now)
                     if report is not None:
                         reports.append(report)
                     if prov_on:
@@ -399,7 +425,7 @@ class BackgroundScanController:
                         report = self._store_fused_report(
                             uid, resource,
                             vc.replay(row, self.policies, ts), now, rhash)
-                        self._scanned[uid] = (rhash, now)
+                        self._mark_scanned(uid, resource, rhash, now)
                         if report is not None:
                             reports.append(report)
                         replayed += 1
@@ -438,7 +464,7 @@ class BackgroundScanController:
                             (m_res, m_sum,
                              [self.policies[g] for g in m_idx]),
                             now, rhash)
-                        self._scanned[uid] = (rhash, now)
+                        self._mark_scanned(uid, resource, rhash, now)
                         if report is not None:
                             reports.append(report)
                 own_s += sum(cap_s.stage_s(k) for k in _OWN_STAGES)
@@ -471,7 +497,7 @@ class BackgroundScanController:
                                                              now)):
                         report = self._store_fused_report(
                             uid, resource, row, now, rhash)
-                        self._scanned[uid] = (rhash, now)
+                        self._mark_scanned(uid, resource, rhash, now)
                         if report is not None:
                             reports.append(report)
                         if vc is not None:

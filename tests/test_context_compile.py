@@ -57,13 +57,33 @@ def test_pack_fully_compiles():
     assert len(cps.programs) == 15
 
 
-def test_value_feeding_context_stays_host():
-    # a rule whose validate references the entry name must stay host
-    pack = CTX_PACK.replace('image tag required',
-                            'team is {{teamcfg.data.team}}')
+def test_value_feeding_a_pattern_leaf_stays_host():
+    # a context value in a pattern leaf keeps the rule on the host, under
+    # its own reason (a condition's value is a lane: test_context_lanes.py)
+    pack = CTX_PACK.replace('"*:*"', '"{{teamcfg.data.team}}:*"')
     cps = compile_policies(load_policies_from_yaml(pack))
     assert len(cps.host_rules) == 1
     assert len(cps.programs) == 0
+    assert cps.placements[0].reason == 'context_in_pattern'
+
+
+def test_value_in_the_message_compiles_and_the_host_words_the_fail():
+    # the message keeps its {{...}}: the verdict is the device's, a FAIL
+    # cell is phrased by the host with the context loaded
+    pack = CTX_PACK.replace('image tag required',
+                            'team is {{teamcfg.data.team}}')
+    client = FakeClient()
+    client.create_resource('v1', 'ConfigMap', 'has-cm', {
+        'apiVersion': 'v1', 'kind': 'ConfigMap',
+        'metadata': {'name': 'team-config', 'namespace': 'has-cm'},
+        'data': {'team': 'a'}})
+    policies = load_policies_from_yaml(pack)
+    engine = Engine(context_loader=make_context_loader(dclient=client))
+    scanner = BatchScanner(policies, engine=engine)
+    assert not scanner.cps.host_rules and len(scanner.cps.programs) == 1
+    (responses,) = scanner.scan([pod('bad', 'has-cm', 'nginx')])
+    (rule,) = responses[0].policy_response.rules
+    assert rule.status == 'fail' and 'team is a' in rule.message
 
 
 def test_device_matches_host_across_load_outcomes():

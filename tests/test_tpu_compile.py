@@ -122,6 +122,36 @@ def test_evaluator_compiles_for_one_chip(pack, one_chip,
     assert mem.argument_size_in_bytes >= capacity * rows_bytes
 
 
+def test_context_evaluator_compiles_for_one_chip(one_chip,
+                                                 no_persistent_cache):
+    """The cell ``bgscan_context_100k``'s set: the committed packs and
+    ``packs/context.yaml``, whose eight context programs compare gather
+    lanes with the value lanes that join in the parent
+    (``compiler/context_lanes.py``), at the bulk capacity."""
+    import jax
+    import benchlib
+    from kyverno_tpu.compiler.compile import compile_policies
+    from kyverno_tpu.compiler.context_lanes import ContextLanes
+    from kyverno_tpu.compiler.encode import encode_batch
+    from kyverno_tpu.compiler.scan import WARM_POD
+    from kyverno_tpu.ops.eval import build_evaluator, pack_batch
+    cps = compile_policies(
+        benchlib.load_policies(['pss', 'pack', 'config4', 'context']))
+    assert len(cps.programs) == 23 and not cps.host_rules
+    evaluator = build_evaluator(cps)
+    tensors = encode_batch([WARM_POD], cps, padded_n=64).tensors()
+    tensors['__match__'] = np.zeros((64, evaluator.n_uniq), np.uint8)
+    lanes = ContextLanes(cps).zero_lanes(64)
+    assert len(lanes) == 8
+    tensors.update(lanes)
+    packed, layout = pack_batch(tensors)
+    with evaluator.compile_lock, jax.enable_x64(True):
+        evaluator.layout_holder['layout'] = layout
+        compiled = evaluator.jitted.lower(
+            _shapes(packed, 16384, one_chip)).compile()
+    _report('context evaluator@16384', compiled)
+
+
 def test_mutate_kernel_compiles_for_one_chip(one_chip,
                                              no_persistent_cache):
     import jax
