@@ -244,6 +244,11 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
             cps.ctx_value_id(cv)
         context_inputs = tuple(sorted(scope.inputs)) \
             if scope.cacheable else None
+    message_inputs = None
+    if not static_msg and pss is None and validate.get('foreach') is None:
+        # a deny, pattern or anyPattern rule whose message has variables:
+        # the host words its FAIL once per distinct tuple of these
+        message_inputs = _message_inputs(msg, scope, context_inputs)
     return RuleProgram(
         policy_name=policy.name, rule_name=name,
         policy_index=p_idx, rule_index=r_idx,
@@ -253,7 +258,7 @@ def _compile_rule(cps: CompiledPolicySet, policy: Policy, p_idx: int,
         skip_message=skip_message,
         background=policy.background, rule_raw=rule,
         context_spec=context_spec, context_inputs=context_inputs,
-        ctx_values=ctx_values,
+        ctx_values=ctx_values, message_inputs=message_inputs,
         fail_sites=tuple(fail_sites) if fail_sites is not None else None,
         fail_prefix=fail_prefix, deny_fail_message=deny_fail_message,
         any_fail_sites=any_fail_sites, any_fail_prefix=any_fail_prefix)
@@ -367,6 +372,67 @@ class _ContextScope:
         cv = CtxValue(self.key, value.strip(), family)
         self.used.append(cv)
         return cv
+
+
+# one step of a JMESPath field chain: ``.name`` or ``."quoted name"``
+_SUBFIELD_RE = re.compile(r'\.\s*(?:[A-Za-z_]\w*|"[^"\\]*")')
+# where a nested variable stood, once it has been classified
+_HOLE = '\x00'
+
+
+def _message_inputs(msg: Any, scope: Optional[_ContextScope],
+                    context_inputs: Optional[Tuple[str, ...]]
+                    ) -> Optional[Tuple[str, ...]]:
+    """What a message with variables is a function of, or None where
+    that is more than the scanner can key a row on.
+
+    Every ``{{…}}`` of the message, the nested ones first as the
+    engine substitutes them (engine/variables.py _substitute_vars_leaf),
+    has to be either an expression over ``request.object`` alone, with
+    no variable nested in it, or an expression over the rule's own
+    configMap / apiCall entries whose load is a function of
+    ``context_inputs``.  The plan is then the first kind's expressions
+    and the context's inputs.  Anything else of the row (the roots
+    ``_ContextScope._ROW_ROOTS`` names: ``request.operation``,
+    ``images``, ``element``, ``@``, …), a ``$(…)`` reference, a
+    ``variable`` entry, a function of ``_STATEFUL_FN_RE`` or an
+    expression the parser refuses leaves the message to the Validator,
+    cell by cell."""
+    from ..engine.jmespath import compile as jp_compile
+    from ..engine.variables import RE_VARIABLES as _RV
+    if not isinstance(msg, str) or '$(' in msg:
+        return None
+    if scope is not None and context_inputs is None:
+        return None
+    inputs = set(context_inputs or ())
+    text = msg
+    while True:
+        found = [m.group(2)[2:-2].strip() for m in _RV.finditer(text)]
+        if not found:
+            break
+        for expr in found:
+            if _STATEFUL_FN_RE.search(expr):
+                return None
+            own = re.match(r'request\.object\b', expr) is not None
+            roots = _SUBFIELD_RE.sub('', re.sub(
+                r'\brequest\.object\b', '', expr) if own else expr)
+            if _ContextScope._ROW_ROOTS.search(roots):
+                return None
+            named = scope.names_in(expr) if scope is not None else []
+            if own:
+                if named or _HOLE in expr:
+                    return None
+                try:
+                    jp_compile(expr)
+                except Exception:  # noqa: BLE001 - the engine words it
+                    return None
+                inputs.add(expr)
+            elif not named:
+                return None
+        text = _RV.sub(lambda m: m.group(1) + _HOLE, text)
+    if '{{' in text or '}}' in text:
+        return None
+    return tuple(sorted(inputs))
 
 
 def _error_plan(cps: CompiledPolicySet, conditions: Any, prefix: str,
@@ -949,7 +1015,7 @@ def _normalize_values(value: Any) -> Tuple[Any, ...]:
 # JMESPath custom functions whose results vary between evaluations —
 # encode-time projection would diverge from a host re-run
 _STATEFUL_FN_RE = re.compile(
-    r'\b(random|time_now|time_now_utc)\s*\(')
+    r'\b(random|time_now|time_now_utc|time_since)\s*\(')
 
 
 def _compile_condition_key(key: Any) -> Tuple[GatherSlot, bool]:

@@ -171,6 +171,14 @@ class _Group:
         self.rule_name = prog.rule_name
 
 
+def _hashable(v: Any):
+    """A string as it is, anything else with its type: two values that
+    the engine would print differently (1, true, "1", the text of a map
+    and the map) never make the same key."""
+    return v if type(v) is str or v is _MISSING \
+        else (type(v).__name__, repr(v))
+
+
 def _input_walker(expr: str):
     """``doc -> hashable`` for one ``request.object``-rooted input; raises
     where the expression does (that row is then resolved on its own)."""
@@ -186,15 +194,13 @@ def _input_walker(expr: str):
                 if not isinstance(cur, dict):
                     return _MISSING
                 cur = cur.get(k, _MISSING)
-            return cur if isinstance(cur, (str, int, float, bool)) \
-                or cur is _MISSING else repr(cur)
+            return _hashable(cur)
         return walk
     from ..engine.jmespath import compile as jp_compile
     compiled = jp_compile(expr)
 
     def search(doc):
-        v = compiled.search({'request': {'object': doc}})
-        return v if isinstance(v, (str, int, float, bool)) else repr(v)
+        return _hashable(compiled.search({'request': {'object': doc}}))
     return search
 
 

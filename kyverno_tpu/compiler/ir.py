@@ -417,6 +417,13 @@ class RuleProgram:
     # the context values this rule's conditions read (mode-C checks), in
     # compile order; empty: the context's values feed nothing
     ctx_values: Tuple[CtxValue, ...] = ()
+    # a deny / pattern / anyPattern rule whose message has variables:
+    # the request.object expressions and the context inputs the message
+    # is a function of (compile.py _message_inputs), so the scanner lets
+    # the host engine word a FAIL once per distinct tuple of them in a
+    # scan pass; None -> a static message, or one that reads more of the
+    # row than that (the Validator words every such cell)
+    message_inputs: Optional[Tuple[str, ...]] = None
 
 
 @dataclass(frozen=True)

@@ -32,13 +32,14 @@ from ..engine.api import (EngineResponse, PolicyContext, RuleResponse,
                           RuleStatus, RuleType)
 from ..engine.engine import Engine, Validator, pod_security_response
 from ..engine.match import matches_resource_description
+from ..engine.validate_pattern import PatternError, match_pattern
 from ..observability import coverage
 from .. import faults
 from . import admission as admission_lanes
 from .compile import compile_policies
 from .encode import encode_batch, encode_worker, encode_worker_init
 from .shapes import canonical_capacity, canonical_caps
-from .context_lanes import ContextLanes
+from .context_lanes import ContextLanes, _input_walker, _row_key
 from .ir import (STATUS_CTX_LOAD, STATUS_CTX_SHAPE, STATUS_CTX_UNRESOLVED,
                  STATUS_CTX_WIDE, STATUS_FAIL, STATUS_HOST, STATUS_PASS,
                  STATUS_SKIP, STATUS_SKIP_PRECOND, STATUS_VAR_ERR,
@@ -62,6 +63,9 @@ PRECONDITIONS_SKIP_MESSAGE = 'preconditions not met'
 
 # sentinel: a device cell that must be re-run on the host engine
 _HOST_MARKER = object()
+
+#: entries a scanner's message caches hold before they start over
+_MESSAGE_CACHE_MAX = 65536
 
 #: the ledger's reason for each status the context fill writes over the
 #: device's (compiler/context_lanes.py): the cell is the host's
@@ -530,6 +534,19 @@ class BatchScanner:
                 cluster[js] = False
             self._candidate_masks[''] = cluster
         self._fail_msg_cache: Dict[Tuple, Optional[str]] = {}
+        # the programs whose message has variables and a plan
+        # (ir.py message_inputs): the walkers of its inputs and, for a
+        # pattern or an anyPattern, the patterns the engine walks; and
+        # the FAIL responses the engine worded for them in this scan
+        # pass, by (program, fail site, the inputs' values): see
+        # _fail_memoized
+        self._msg_plans = {
+            j: (tuple(_input_walker(e) for e in prog.message_inputs),
+                self._walked_patterns(self._host_rule[prog][0].validation))
+            for j, prog in enumerate(self.cps.programs)
+            if prog.message_inputs is not None}
+        self._msg_memo: Dict[Tuple, RuleResponse] = {}
+        self._msg_memo_hits = self._msg_memo_misses = 0
         # encode workers only pay off with spare cores: on a host of
         # one or two the worker's encode and this process's report
         # assembly take turns on the same core
@@ -1500,10 +1517,7 @@ class BatchScanner:
                     old_resources=None, admissions=None):
         n = len(resources)
         self._pctx_factory = pctx_factory
-        # context-load outcomes are memoized within one scan pass only —
-        # the host engine reloads per evaluation, so staleness must not
-        # outlive a pass
-        self._ctx.begin_pass()
+        self._begin_pass()
         # admission scans evaluate every policy; the background gate
         # (engine.py:174 apply_background_checks) only applies to scans
         background_mode = admission is None and admissions is None and \
@@ -1604,6 +1618,7 @@ class BatchScanner:
                             resources, wrapped, match, start, status,
                             detail, fdet, now, ts, background_mode,
                             background_ok, host_maybe, tally)
+                        self._record_fail_memo()
                         if tally is not None:
                             ratio = tally.ratio()
                             if ratio is not None:
@@ -1667,7 +1682,7 @@ class BatchScanner:
                         continue
                     rr = self._cell(prog, j, int(st_row[j]),
                                     int(det_row[j]), fdet[k], ts, fly,
-                                    tally)
+                                    tally, resources[start + k])
                     if rr is _HOST:
                         rr = self._materialize(prog,
                                                resources[start + k])
@@ -1694,7 +1709,7 @@ class BatchScanner:
                 det_col = detail[rows, j].tolist()
                 for k, st, det in zip(rows.tolist(), st_col, det_col):
                     rr = self._cell(prog, j, st, det, fdet[k], ts, fly,
-                                    tally)
+                                    tally, resources[start + k])
                     if rr is _HOST:
                         # anchor-SKIP / HOST / unsynthesizable FAIL:
                         # re-run on the host for exact status+message
@@ -1892,19 +1907,38 @@ class BatchScanner:
         for sg in subgroups:
             msg = self._fail_message_cached(prog, j, fdet[sg[0]])
             if msg is None:
-                if tally is not None:
-                    tally.fallback_n(prog, self._message_reason(prog),
-                                     int(sg.size))
+                # the host words these: the check library, the
+                # Validator, or for a message with a plan the response
+                # the Validator gave the first row of the same key
+                hits = 0
                 for k in sg.tolist():
-                    rr = self._materialize(prog, resources[base + k])
+                    rr, hit = self._fail_memoized(
+                        prog, j, fdet[k], resources[base + k], ts)
                     if rr is None:
                         continue
-                    rr.timestamp = ts
-                    res = _rule_result(rr, key, scored, category,
-                                       severity, stamp, ts)
+                    if hit:
+                        hits += 1
+                        cell_key = (j, STATUS_FAIL, rr.message)
+                        cell = fly.get(cell_key)
+                        if cell is None:
+                            res = _rule_result(rr, key, scored, category,
+                                               severity, stamp, ts)
+                            cell = fly[cell_key] = (
+                                res, bucket_idx[res['result']])
+                        res, bucket = cell
+                    else:
+                        res = _rule_result(rr, key, scored, category,
+                                           severity, stamp, ts)
+                        bucket = bucket_idx[res['result']]
                     rows[k].append(res)
                     row_pols[k].append(p_idx)
-                    counts[k, bucket_idx[res['result']]] += 1
+                    counts[k, bucket] += 1
+                if tally is not None:
+                    if hits:
+                        tally.device_n(prog, hits)
+                    if hits < sg.size:
+                        tally.fallback_n(prog, self._message_reason(prog),
+                                         int(sg.size) - hits)
                 continue
             cell_key = (j, STATUS_FAIL, msg)
             cell = fly.get(cell_key)
@@ -1951,7 +1985,7 @@ class BatchScanner:
         ts = int(now)
         ts_key = str(ts)
         stamp = {'seconds': ts}
-        self._ctx.begin_pass()
+        self._begin_pass()
         progs = self.cps.programs
         background_ok = getattr(self, '_background_ok', None)
         if background_ok is None:
@@ -2003,6 +2037,7 @@ class BatchScanner:
                                 status[w0:w1], detail[w0:w1],
                                 fdet[w0:w1], cm[w0:w1], background_ok,
                                 ts, stamp, tally)
+                        self._record_fail_memo()
                         if tally is not None:
                             ratio = tally.ratio()
                             if ratio is not None:
@@ -2082,7 +2117,7 @@ class BatchScanner:
             tlmod.finish_scan(tl)
 
     def _cell(self, prog, j: int, st: int, det: int, fdet_row, ts: int,
-              fly: Dict[Tuple, Any], tally=None):
+              fly: Dict[Tuple, Any], tally=None, doc=None):
         """Flyweight RuleResponse for one device cell (or _HOST_MARKER).
 
         FAIL cells key on the synthesized message — the fail-site detail
@@ -2096,6 +2131,16 @@ class BatchScanner:
         if st == STATUS_FAIL:
             msg = self._fail_message_cached(prog, j, fdet_row)
             if msg is None:
+                if doc is not None and j in self._msg_plans:
+                    rr, hit = self._fail_memoized(prog, j, fdet_row, doc,
+                                                  ts)
+                    if tally is not None:
+                        if hit:
+                            tally.device(prog)
+                        else:
+                            tally.fallback(prog,
+                                           self._message_reason(prog))
+                    return rr
                 if tally is not None:
                     tally.fallback(prog, self._message_reason(prog))
                 return _HOST_MARKER
@@ -2233,7 +2278,7 @@ class BatchScanner:
         if key in cache:
             return cache[key]
         v = self._fail_message(prog, j, fdet_row)
-        if len(cache) > 65536:
+        if len(cache) > _MESSAGE_CACHE_MAX:
             cache.clear()
         cache[key] = v
         return v
@@ -2277,6 +2322,95 @@ class BatchScanner:
         if site is None:
             return None
         return prog.fail_prefix + site
+
+    def _begin_pass(self) -> None:
+        """What was read from the cluster is kept within one scan pass
+        only — context-load outcomes, and the FAIL responses worded
+        from them: the host engine loads for every evaluation, so
+        nothing of a ConfigMap may outlive a pass."""
+        self._ctx.begin_pass()
+        self._msg_memo = {}
+
+    def _record_fail_memo(self) -> None:
+        """The memo's hits and misses since the last call, to the
+        counter; once an assembled window."""
+        if self._msg_memo_hits or self._msg_memo_misses:
+            from ..observability import device as devtel
+            devtel.record_fail_message_memo(self._msg_memo_hits,
+                                            self._msg_memo_misses)
+            self._msg_memo_hits = self._msg_memo_misses = 0
+
+    def _fail_memoized(self, prog: RuleProgram, j: int, fdet_row,
+                       doc: dict, ts: int
+                       ) -> Tuple[Optional[RuleResponse], bool]:
+        """``(response, hit)`` for a FAIL the device decided and the
+        host words (``_fail_message_cached`` gave None).
+
+        Where the program's message has a plan, the response is a
+        function of the fail site and of the values of
+        ``message_inputs``, so the Validator words it for the first row
+        of each such key in a scan pass and every later row takes that
+        response, shared (a flyweight: never mutate).  The site is the
+        evaluator's fail detail where it recorded one (a deny), else
+        what the engine's own pattern walk raises for the row.  A row
+        with no key (a walk that raises, a site that cannot be told),
+        a program with no plan, a response that is not a FAIL, and
+        every cell of an admission scan — its variables resolve in the
+        request's own context, not in the document's — go through
+        ``_materialize`` alone and nothing of them is kept."""
+        key = None
+        plan = self._msg_plans.get(j)
+        if plan is not None and \
+                getattr(self, '_pctx_factory', None) is None:
+            walkers, patterns = plan
+            site = int(fdet_row[j])
+            if site < 0:
+                site = self._walk_site(patterns, doc)
+            values = _row_key(walkers, doc) if site is not None else None
+            if values is not None:
+                key = (j, site, values)
+                rr = self._msg_memo.get(key)
+                if rr is not None:
+                    self._msg_memo_hits += 1
+                    return rr, True
+            self._msg_memo_misses += 1
+        rr = self._materialize(prog, doc)
+        if rr is not None:
+            rr.timestamp = ts
+            if key is not None and rr.status == RuleStatus.FAIL:
+                if len(self._msg_memo) > _MESSAGE_CACHE_MAX:
+                    self._msg_memo.clear()
+                self._msg_memo[key] = rr
+        return rr, False
+
+    @staticmethod
+    def _walked_patterns(validation: dict) -> Optional[list]:
+        """The patterns the Validator walks for a rule: one, an
+        anyPattern's, or None for a rule that is neither."""
+        if validation.get('deny') is not None:
+            return None
+        if validation.get('pattern') is not None:
+            return [validation['pattern']]
+        patterns = validation.get('anyPattern')
+        return patterns if isinstance(patterns, list) else None
+
+    @staticmethod
+    def _walk_site(patterns: Optional[list], doc: dict):
+        """Where and how the engine's pattern walk fails on ``doc``, one
+        entry a pattern, as a key: a compiled pattern has no variables,
+        so the walk the Validator would make is this one.  None for a
+        rule without patterns, and for a document that one accepts."""
+        if patterns is None:
+            return None
+        site = []
+        for pattern in patterns:
+            try:
+                match_pattern(doc, pattern)
+            except PatternError as pe:
+                site.append((pe.skip, pe.path, str(pe)))
+                continue
+            return None
+        return tuple(site)
 
     def _pctx(self, policy: Policy, resource: dict) -> PolicyContext:
         factory = getattr(self, '_pctx_factory', None)

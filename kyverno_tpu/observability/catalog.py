@@ -104,6 +104,13 @@ METRICS: Dict[str, Metric] = {
         'scanner, one per distinct tuple of a context\'s inputs in a '
         'scan pass; result=ok|failed. Lookups minus loads are the '
         'memo\'s hits.'),
+    'kyverno_tpu_fail_message_memo_total': Metric(
+        'counter', 'FAIL cells the device decided, of programs whose '
+        'message has variables and a plan (compiler/ir.py '
+        'message_inputs); result=miss (the Validator worded the cell: '
+        'the first of its key in a scan pass, or a row with no key)|hit '
+        '(the cell took the response worded for an earlier row with the '
+        'same fail site and the same values of the message\'s inputs).'),
     # device-coverage ledger (observability/coverage.py)
     'kyverno_tpu_rule_placement_info': Metric(
         'gauge', '1 per compiled (policy, rule, path); placement=device|'

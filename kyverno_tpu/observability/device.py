@@ -47,6 +47,7 @@ STAGE_RETRIES = 'kyverno_tpu_scan_stage_retries_total'
 PACK_BATCHES = 'kyverno_tpu_pack_batches_total'
 CONTEXT_LOOKUPS = 'kyverno_tpu_context_lookups_total'
 CONTEXT_LOADS = 'kyverno_tpu_context_loads_total'
+FAIL_MESSAGE_MEMO = 'kyverno_tpu_fail_message_memo_total'
 
 #: canonical stage labels.  The pipeline's, in order: ``match`` (host
 #: match sieve), ``encode`` (in a worker process or inline),
@@ -448,6 +449,18 @@ def record_context(lookups: int, loads_ok: int, loads_failed: int) -> None:
         if loads_failed:
             _registry.inc(CONTEXT_LOADS, float(loads_failed),
                           result='failed')
+
+
+def record_fail_message_memo(hits: int, misses: int) -> None:
+    """One assembled window's FAIL cells of programs whose message has
+    variables and a plan (``compiler/scan.py`` ``_fail_memoized``): a
+    miss is a cell the Validator worded, a hit one that took the
+    response worded for an earlier row of the same key in the pass."""
+    if _registry is not None:
+        if hits:
+            _registry.inc(FAIL_MESSAGE_MEMO, float(hits), result='hit')
+        if misses:
+            _registry.inc(FAIL_MESSAGE_MEMO, float(misses), result='miss')
 
 
 def record_encode_result_bytes(lanes, answer) -> None:

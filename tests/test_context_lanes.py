@@ -426,6 +426,66 @@ def test_a_set_without_such_a_condition_gets_no_lane():
     assert lanes.zero_lanes(64) == {}
 
 
+def _device_program(policies):
+    """What the device runs for ``policies``: the packed layout of one
+    admission-capacity batch and the evaluator's lowered text."""
+    import jax
+    import numpy as np
+    from kyverno_tpu.compiler import admission
+    from kyverno_tpu.compiler.context_lanes import ContextLanes
+    from kyverno_tpu.compiler.encode import encode_batch
+    from kyverno_tpu.compiler.scan import WARM_POD
+    from kyverno_tpu.ops.eval import build_evaluator, pack_batch
+    cps = compile_policies(policies)
+    evaluator = build_evaluator(cps)
+    tensors = encode_batch([WARM_POD], cps, padded_n=64).tensors()
+    tensors['__match__'] = np.zeros((64, evaluator.n_uniq), np.uint8)
+    if evaluator.adm_table is not None:
+        tensors.update(admission.zero_lanes(evaluator.adm_table, 64))
+    tensors.update(ContextLanes(cps).zero_lanes(64))
+    packed, layout = pack_batch(tensors)
+    with evaluator.compile_lock, jax.enable_x64(True):
+        evaluator.layout_holder['layout'] = layout
+        text = evaluator.jitted.lower(packed).as_text()
+    return cps, layout, text
+
+
+def _configuration_policies(name):
+    packs = benchlib.load_policies(['pss', 'pack', 'config4'])
+    if name == 'admission-1k-tenants':
+        tenants = benchlib.load_module('generators', 'tenant_policies')
+        return packs + [Policy(raw) for raw in
+                        tenants.generate(SEED, namespaces=6)]
+    if name == 'context-configmap-100k':
+        return packs + benchlib.load_policies(['context'])
+    return packs
+
+
+@pytest.mark.parametrize('name', [
+    # three configurations validate with the committed packs alone
+    'pss-mixed-100k+admission-1k-enforce+admission-mutate-defaults',
+    'admission-1k-tenants', 'context-configmap-100k'])
+def test_a_messages_plan_changes_nothing_the_device_runs(name, monkeypatch):
+    """``RuleProgram.message_inputs`` is the host's: compiled with it and
+    without it (the parent commit's compiler), each of the five
+    configurations' sets has the same lanes in the same layout and the
+    same lowered program, and outside ``packs/context.yaml`` no program
+    has a plan at all."""
+    from kyverno_tpu.compiler import compile as compile_mod
+    policies = _configuration_policies(name)
+    cps, layout, text = _device_program(policies)
+    planned = {p.policy_name for p in cps.programs
+               if p.message_inputs is not None}
+    assert planned == (set(PACK) if name == 'context-configmap-100k'
+                       else set())
+    monkeypatch.setattr(compile_mod, '_message_inputs',
+                        lambda *a, **k: None)
+    bare, bare_layout, bare_text = _device_program(policies)
+    assert all(p.message_inputs is None for p in bare.programs)
+    assert layout == bare_layout and list(layout) == list(bare_layout)
+    assert text == bare_text
+
+
 # -- chunks encoded by worker processes ---------------------------------------
 
 def test_the_lanes_join_chunks_that_worker_processes_encoded(cluster,
