@@ -306,6 +306,8 @@ class StatusExpr:
       const     — constant status (operand = status code)
       leaf      — BoolExpr ``expr``: True → PASS, False → FAIL
       seq       — children in walk order; first non-PASS child decides
+                  (and gives its fail detail; ``pss_bit`` children
+                  give a mask of those that failed instead)
       cond      — (k) condition anchor: key absent → SKIP; present and
                   ``sub`` non-PASS → SKIP; else PASS   (handlers.go:31)
       global    — <(k): key absent → PASS; present and ``sub`` non-PASS →
@@ -339,6 +341,11 @@ class StatusExpr:
     # position (path template) the host would report for a FAIL decided at
     # this node; None → a FAIL here is not message-synthesizable on device
     fail_site: Optional[int] = None
+    # a leaf of a podSecurity program: the check's index in
+    # pss/checks.py DEFAULT_CHECKS (compile_pod_security alone sets it).
+    # A FAIL of a ``seq`` that holds such leaves ships, as its fail
+    # detail, the OR of ``1 << pss_bit`` over those that failed
+    pss_bit: Optional[int] = None
 
     @staticmethod
     def const(status: int) -> 'StatusExpr':

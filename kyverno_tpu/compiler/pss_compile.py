@@ -13,16 +13,31 @@ scanner has the native check library word it from the document
 no Validator around it; a rule with context or preconditions goes through
 the Validator).
 
+Each check's leaf carries its index in DEFAULT_CHECKS (``StatusExpr.
+pss_bit``), and a FAIL's fail-detail cell, which a podSecurity program
+has no site to put in, holds the mask of the checks that failed
+(ops/eval.py ``eval_status``, the ``seq`` branch; -1 where a check is
+undecided on the device).  The library then runs those checks alone
+(pss/evaluate.py ``evaluate_failed_checks``) and still words every one of
+them from the document: the mask says where to look, never what to say,
+and a mask the library does not confirm check for check is dropped for a
+full run.
+
 The pod spec prefix is derived from the rule's matched kinds
 (pss/evaluate.py extract_pod_spec, reference: pkg/engine/validation.go:481):
 Pod → the resource itself; template workloads → ``spec.template``;
 CronJob → ``spec.jobTemplate.spec.template``.  Autogen has already split
 rules per kind class, so a compilable rule maps to exactly one prefix.
 
-Two checks scan map keys (AppArmor annotations, volume type keys), which
-the slot model cannot address; those use *virtual gathers* — encoder-side
-Python closures marked ``__pss:...`` that project a boolean per resource
-(host-exact by construction, still ~50× cheaper than a full host run).
+Three checks scan map keys (the AppArmor annotations, the seccomp
+annotations of the 1.0 variant of ``seccompProfile_baseline``, volume type
+keys), which the slot model cannot address; those use *virtual gathers* —
+encoder-side Python closures marked ``__pss:...`` that project, per
+resource, which of them it violates (host-exact by construction, still
+~50× cheaper than a full host run).  The two that read the annotations
+share one gather, whose value is False, or the list [AppArmor violated,
+seccomp violated] of a pod that violates either: a list of two booleans
+takes the lanes the one boolean took.
 """
 
 from __future__ import annotations
@@ -30,7 +45,7 @@ from __future__ import annotations
 from typing import Any, Callable, List, Optional, Tuple
 
 from ..pss.checks import (_ALLOWED_SELINUX_TYPES, _ALLOWED_SYSCTLS,
-                          _BASELINE_CAPS, LEVEL_BASELINE)
+                          _BASELINE_CAPS, DEFAULT_CHECKS, LEVEL_BASELINE)
 from .ir import (BoolExpr, CompileError, CompiledPolicySet, CondCheck,
                  GatherSlot, Leaf, Slot, StatusExpr)
 
@@ -44,6 +59,9 @@ _TEMPLATE_PREFIX: dict = {
     'ReplicationController': ('spec', 'template'),
     'CronJob': ('spec', 'jobTemplate', 'spec', 'template'),
 }
+
+
+_CHECK_BIT = {check.id: bit for bit, check in enumerate(DEFAULT_CHECKS)}
 
 
 def _rule_kinds(rule: dict) -> List[str]:
@@ -100,9 +118,11 @@ def compile_pod_security(cps: CompiledPolicySet, pod_security: dict,
             ('capabilities_restricted', b.capabilities_restricted()),
         ]
     # DEFAULT_CHECKS order: first failing check decides; the check
-    # library words the exact forbidden-reason message on any non-pass
+    # library words the exact forbidden-reason message on any non-pass,
+    # from the checks whose bits the FAIL's fail detail carries
     return StatusExpr.seq(
-        [StatusExpr('leaf', expr=e) for _, e in checks])
+        [StatusExpr('leaf', expr=e, pss_bit=_CHECK_BIT[check_id])
+         for check_id, e in checks])
 
 
 class _Builder:
@@ -141,13 +161,20 @@ class _Builder:
         return BoolExpr.all([
             self.quant('all_elem', self.spec + (f,), fn) for f in fields])
 
-    def virtual(self, check: str) -> BoolExpr:
-        """True when the virtual projection reports a violation."""
+    def virtual(self, check: str, which: Optional[int] = None) -> BoolExpr:
+        """True when the virtual projection reports a violation: True,
+        or for a projection of several checks a list with True at
+        ``which``."""
         expr = f'__pss:{check}:' + '.'.join(self.prefix)
         gather = GatherSlot(expr)
         self.cps.gather_id(gather)
-        return BoolExpr.of_cond(CondCheck(
-            gather=gather, op='equals', values=(True,), list_value=False))
+        if which is None:
+            return BoolExpr.of_cond(CondCheck(
+                gather=gather, op='equals', values=(True,),
+                list_value=False))
+        return BoolExpr.any([BoolExpr.of_cond(CondCheck(
+            gather=gather, op='equals', values=flags, list_value=True))
+            for flags in _ANNOTATION_FLAGS if flags[which]])
 
     # -- baseline ---------------------------------------------------------
 
@@ -177,7 +204,7 @@ class _Builder:
             lambda p: BoolExpr.negate(self.L(p + ('hostPort',), 'truthy'))))
 
     def app_armor(self) -> BoolExpr:
-        return BoolExpr.negate(self.virtual('apparmor'))
+        return BoolExpr.negate(self.virtual('annotations', _APPARMOR))
 
     def selinux_options(self) -> BoolExpr:
         def ok(sc: Tuple[str, ...]) -> BoolExpr:
@@ -213,7 +240,13 @@ class _Builder:
         pod_ok = BoolExpr.negate(self.L(
             self.spec + ('securityContext', 'seccompProfile', 'type'),
             'eq_str', 'Unconfined'))
-        return BoolExpr.all([pod_ok, self.all_containers(ok)])
+        # the check fails where either of its versioned variants does
+        # (pss/evaluate.py runs both): the fields, or the annotations of
+        # before 1.19
+        annotations_ok = BoolExpr.negate(self.virtual(
+            'annotations', _SECCOMP_1_0))
+        return BoolExpr.all([pod_ok, self.all_containers(ok),
+                             annotations_ok])
 
     def sysctls(self) -> BoolExpr:
         return self.quant(
@@ -296,12 +329,12 @@ def _nullish(b: _Builder, path: Tuple[str, ...]) -> BoolExpr:
 # virtual gathers (encoder-side projections for map-key scans)
 
 class _VirtualSearcher:
-    def __init__(self, fn: Callable[[dict], bool],
+    def __init__(self, fn: Callable[[dict], Any],
                  prefix: Tuple[str, ...]):
         self._fn = fn
         self._prefix = prefix
 
-    def search(self, data: dict) -> bool:
+    def search(self, data: dict):
         doc = (data.get('request') or {}).get('object') or {}
         for part in self._prefix:
             doc = doc.get(part) if isinstance(doc, dict) else None
@@ -311,10 +344,21 @@ class _VirtualSearcher:
         return self._fn(doc if isinstance(doc, dict) else {})
 
 
-def _apparmor_violation(pod: dict) -> bool:
-    from ..pss.checks import check_app_armor
-    return not check_app_armor(pod.get('metadata') or {},
-                               pod.get('spec') or {}).allowed
+#: the ``annotations`` gather: where each check stands in its list, and
+#: the lists a pod that violates one can give
+_APPARMOR, _SECCOMP_1_0 = 0, 1
+_ANNOTATION_FLAGS = ((True, False), (False, True), (True, True))
+
+
+def _annotation_violations(pod: dict):
+    from ..pss.checks import check_app_armor, check_seccomp_baseline_1_0
+    meta = pod.get('metadata') or {}
+    if not meta.get('annotations'):
+        return False  # both checks read nothing else to find a violation
+    spec = pod.get('spec') or {}
+    flags = [not check_app_armor(meta, spec).allowed,
+             not check_seccomp_baseline_1_0(meta, spec).allowed]
+    return flags if any(flags) else False
 
 
 def _volumes_violation(pod: dict) -> bool:
@@ -323,7 +367,8 @@ def _volumes_violation(pod: dict) -> bool:
                                         pod.get('spec') or {}).allowed
 
 
-_VIRTUALS = {'apparmor': _apparmor_violation, 'volumes': _volumes_violation}
+_VIRTUALS = {'annotations': _annotation_violations,
+             'volumes': _volumes_violation}
 
 
 def virtual_searcher(expr: str) -> _VirtualSearcher:

@@ -34,6 +34,8 @@ from ..engine.engine import Engine, Validator, pod_security_response
 from ..engine.match import matches_resource_description
 from ..engine.validate_pattern import PatternError, match_pattern
 from ..observability import coverage
+from ..pss.evaluate import (evaluate_failed_checks, evaluate_pod_security,
+                            parse_version)
 from .. import faults
 from . import admission as admission_lanes
 from .compile import compile_policies
@@ -66,6 +68,26 @@ _HOST_MARKER = object()
 
 #: entries a scanner's message caches hold before they start over
 _MESSAGE_CACHE_MAX = 65536
+
+
+def _masked_evaluator(mask: int, tally):
+    """``evaluate_pod_security`` for one cell whose failed checks the
+    device named in ``mask``: those checks alone, where each of them
+    fails in the library too; else the mask was wrong, the mismatch is
+    counted and every check runs.  For a rule without ``exclude`` (one
+    with it never compiles)."""
+    def evaluate(pod_security: dict, pod: dict):
+        level, _version = parse_version(pod_security)
+        checks = evaluate_failed_checks(level, pod, mask)
+        if checks is None:
+            if tally is not None:
+                tally.pss_mask_mismatch += 1
+            return evaluate_pod_security(pod_security, pod)
+        if tally is not None:
+            tally.pss_masked_cells += 1
+            tally.pss_checks_run += mask.bit_count()
+        return False, checks
+    return evaluate
 
 #: the ledger's reason for each status the context fill writes over the
 #: device's (compiler/context_lanes.py): the cell is the host's
@@ -1704,12 +1726,14 @@ class BatchScanner:
                     if background_mode and not background_ok[j]:
                         acc[k].append((prog.policy_index, None))
                         continue
-                    rr = self._cell(prog, j, int(st_row[j]),
-                                    int(det_row[j]), fdet[k], ts, fly,
-                                    tally, resources[start + k])
+                    st = int(st_row[j])
+                    rr = self._cell(prog, j, st, int(det_row[j]), fdet[k],
+                                    ts, fly, tally, resources[start + k])
                     if rr is _HOST:
-                        rr = self._materialize(prog,
-                                               resources[start + k])
+                        rr = self._materialize(
+                            prog, resources[start + k],
+                            int(fdet[k, j]) if st == STATUS_FAIL else None,
+                            tally)
                         if rr is not None:
                             rr.timestamp = ts
                     acc[k].append((prog.policy_index,
@@ -1737,8 +1761,10 @@ class BatchScanner:
                     if rr is _HOST:
                         # anchor-SKIP / HOST / unsynthesizable FAIL:
                         # re-run on the host for exact status+message
-                        rr = self._materialize(prog,
-                                               resources[start + k])
+                        rr = self._materialize(
+                            prog, resources[start + k],
+                            int(fdet[k, j]) if st == STATUS_FAIL else None,
+                            tally)
                         if rr is not None:
                             rr.timestamp = ts
                     acc[k].append((p_idx, None if rr is None or
@@ -1937,7 +1963,7 @@ class BatchScanner:
                 hits = 0
                 for k in sg.tolist():
                     rr, hit = self._fail_memoized(
-                        prog, j, fdet[k], resources[base + k], ts)
+                        prog, j, fdet[k], resources[base + k], ts, tally)
                     if rr is None:
                         continue
                     if hit:
@@ -2157,7 +2183,7 @@ class BatchScanner:
             if msg is None:
                 if doc is not None and j in self._msg_plans:
                     rr, hit = self._fail_memoized(prog, j, fdet_row, doc,
-                                                  ts)
+                                                  ts, tally)
                     if tally is not None:
                         if hit:
                             tally.device(prog)
@@ -2290,6 +2316,10 @@ class BatchScanner:
                              fdet_row) -> Optional[str]:
         """Memoized message synthesis: distinct (program, fail-detail)
         combinations are few, so scans hit the cache almost always."""
+        if prog.pss is not None:
+            # no site, no static message: its fail detail is the mask of
+            # failed checks, for the check library (_materialize)
+            return None
         meta = self._evaluator.any_meta.get(j) \
             if prog.any_fail_sites is not None else None
         if meta is not None:
@@ -2365,7 +2395,7 @@ class BatchScanner:
             self._msg_memo_hits = self._msg_memo_misses = 0
 
     def _fail_memoized(self, prog: RuleProgram, j: int, fdet_row,
-                       doc: dict, ts: int
+                       doc: dict, ts: int, tally=None
                        ) -> Tuple[Optional[RuleResponse], bool]:
         """``(response, hit)`` for a FAIL the device decided and the
         host words (``_fail_message_cached`` gave None).
@@ -2398,7 +2428,7 @@ class BatchScanner:
                     self._msg_memo_hits += 1
                     return rr, True
             self._msg_memo_misses += 1
-        rr = self._materialize(prog, doc)
+        rr = self._materialize(prog, doc, int(fdet_row[j]), tally)
         if rr is not None:
             rr.timestamp = ts
             if key is not None and rr.status == RuleStatus.FAIL:
@@ -2445,8 +2475,9 @@ class BatchScanner:
             return pctx
         return PolicyContext(policy, new_resource=resource)
 
-    def _materialize(self, prog: RuleProgram,
-                     resource: dict) -> Optional[RuleResponse]:
+    def _materialize(self, prog: RuleProgram, resource: dict,
+                     fail_detail: Optional[int] = None,
+                     tally=None) -> Optional[RuleResponse]:
         """Produce the exact host-engine rule response for one rule.
 
         A podSecurity rule with neither context nor preconditions reads
@@ -2456,7 +2487,17 @@ class BatchScanner:
         function the Validator itself answers with.  Nothing of it is
         kept: every cell pays its own call.  An empty document (a
         DELETE, which the Validator answers with None) and every other
-        rule go through the Validator."""
+        rule go through the Validator.
+
+        ``fail_detail`` is the cell's fail detail where the device
+        decided a FAIL, else None.  For such a podSecurity cell it is
+        the mask of the checks that failed (ops/eval.py ``eval_status``),
+        and the library's own evaluator then runs those checks alone
+        (:func:`_masked_evaluator`); -1 (a check undecided on the
+        device, a cell beyond the fail-detail budget) and an evaluator
+        the engine was given run them all.  The mask chooses what runs,
+        never what is said: every check it names words its own result
+        from the document, and one that passes there voids it."""
         rule, pod_security = self._host_rule[prog]
         if pod_security is not None:
             # the Validator reads pctx.new_resource: with a factory
@@ -2465,9 +2506,15 @@ class BatchScanner:
             doc = resource if factory is None \
                 else factory(resource).new_resource
             if doc:
+                evaluator = self.engine.pss_evaluator
+                if fail_detail is not None:
+                    if tally is not None:
+                        tally.pss_worded_cells += 1
+                    if fail_detail > 0 and \
+                            evaluator is evaluate_pod_security:
+                        evaluator = _masked_evaluator(fail_detail, tally)
                 return pod_security_response(
-                    rule.name, pod_security, doc,
-                    self.engine.pss_evaluator)
+                    rule.name, pod_security, doc, evaluator)
         pctx = self._pctx(self.policies[prog.policy_index], resource)
         return Validator(self.engine, pctx, rule).validate()
 

@@ -371,7 +371,9 @@ def pod_security_response(rule_name: str, pod_security: dict,
     (reference: pkg/engine/validation.go:535 validatePodSecurity).  The
     one wording of it: ``Validator`` answers with it, and so does the
     scanner for a cell whose rule reads nothing but the resource
-    (compiler/scan.py ``_materialize``)."""
+    (compiler/scan.py ``_materialize``; its ``evaluator`` may be the
+    library's run of the checks the device named as failed alone, which
+    gives what the full run gives or hands over to it)."""
     from ..pss.evaluate import extract_pod_spec, format_checks_print
     try:
         pod = extract_pod_spec(resource)

@@ -191,13 +191,24 @@ def compile_placements(policies: List[Any], cps: Any) -> List[RulePlacement]:
     return out
 
 
+#: how the check library worded the FAIL cells of podSecurity programs
+#: (compiler/scan.py ``_materialize``), counted in a scan's tally and
+#: summed by the ledger: the cells it worded; of them, those it worded
+#: from the checks named by the device's mask of failed checks alone;
+#: the checks it ran for those; and the masks it did not confirm (a
+#: check named there passed), after which it ran every check
+PSS_COUNTERS = ('pss_worded_cells', 'pss_masked_cells', 'pss_checks_run',
+                'pss_mask_mismatch')
+
+
 class ScanTally:
     """Per-scan accumulator: plain dict increments on the assembly hot
     path (no locks, no metric emission per cell), absorbed into the
     global ledger in one batch when the scan finishes."""
 
     __slots__ = ('_ledger', 'total_rows', 'device_rows', 'host_rows',
-                 'by_reason', 'rule_device', 'rule_host', '_finished')
+                 'by_reason', 'rule_device', 'rule_host', '_finished'
+                 ) + PSS_COUNTERS
 
     def __init__(self, ledger: 'CoverageLedger'):
         self._ledger = ledger
@@ -210,6 +221,10 @@ class ScanTally:
         self.rule_device: Dict[Tuple[str, str, str], int] = {}
         # (policy, rule, path, reason) -> rows
         self.rule_host: Dict[Tuple[str, str, str, str], int] = {}
+        # the podSecurity FAIL cells the check library worded (host rows
+        # of reason pss_direct_message), and how: PSS_COUNTERS
+        for name in PSS_COUNTERS:
+            setattr(self, name, 0)
         self._finished = False
 
     @staticmethod
@@ -296,6 +311,7 @@ class CoverageLedger:
         self.total_rows = 0
         self.scans = 0
         self.last_ratio: Optional[float] = None
+        self.pss = dict.fromkeys(PSS_COUNTERS, 0)
 
     # -- placement ---------------------------------------------------------
 
@@ -417,6 +433,8 @@ class CoverageLedger:
             self.device_rows += tally.device_rows
             self.host_rows += tally.host_rows
             self.total_rows += tally.total_rows
+            for name in PSS_COUNTERS:
+                self.pss[name] += getattr(tally, name)
             self.scans += 1
             ratio = tally.ratio()
             if ratio is not None:
@@ -460,6 +478,7 @@ class CoverageLedger:
             'scans': self.scans,
             'last_scan_ratio': round(self.last_ratio, 6)
             if self.last_ratio is not None else None,
+            **self.pss,
         }
 
     def totals(self) -> dict:

@@ -1519,7 +1519,9 @@ def build_evaluator(cps: CompiledPolicySet):
         [R]+[E]*depth.  ``fdet`` identifies, for FAIL statuses, the walk
         position the host would report: site id in bits 16+, the
         outer/inner element indices in bytes 0/1; -1 = a FAIL here has no
-        synthesizable message (host re-run)."""
+        synthesizable message (host re-run).  A podSecurity program's
+        FAIL carries the mask of its failed checks there instead (the
+        ``seq`` branch)."""
         def zd(ref):
             return jnp.zeros(ref.shape, jnp.int8)
 
@@ -1562,12 +1564,31 @@ def build_evaluator(cps: CompiledPolicySet):
             return sub_s, sub_d, jnp.where(g.t, sub_fd, jnp.int32(-1))
         if kind == 'seq':
             s, d, fd = eval_status(t, node.children[0], depth)
+            statuses = [s]
             for c in node.children[1:]:
                 cs, cd, cfd = eval_status(t, c, depth)
                 take = s == PASS
                 s = jnp.where(take, cs, s)
                 d = jnp.where(take, cd, d)
                 fd = jnp.where(take, cfd, fd)
+                statuses.append(cs)
+            checks = [(c.pss_bit, cs)
+                      for c, cs in zip(node.children, statuses)
+                      if c.pss_bit is not None]
+            if checks:
+                # a podSecurity program (its leaves have no fail site,
+                # and nothing else in its seq can FAIL): a FAIL's detail
+                # is the mask of ALL the checks that failed, which the
+                # host's check library then runs alone; -1 where one of
+                # them is undecided here, and the library runs them all
+                mask = jnp.zeros(s.shape, jnp.int32)
+                decided = jnp.ones(s.shape, bool)
+                for bit, cs in checks:
+                    mask = mask | jnp.where(cs == FAIL, jnp.int32(1 << bit),
+                                            jnp.int32(0))
+                    decided = decided & ((cs == PASS) | (cs == FAIL))
+                fd = jnp.where(s == FAIL,
+                               jnp.where(decided, mask, jnp.int32(-1)), fd)
             return s, d, fd
         if kind == 'any':
             evals = [eval_status(t, c, depth) for c in node.children]

@@ -30,30 +30,64 @@ def parse_version(rule: dict) -> Tuple[str, str]:
     return level, version
 
 
+def _append_failures(check, meta: dict, spec: dict,
+                     results: List[dict]) -> None:
+    # EVERY versioned variant runs, regardless of the requested
+    # version, and failing variants each append a result — the
+    # reference does not dedup (evaluate.go:24-35), so a pod
+    # failing two variants reports the violation twice
+    for variant in (check.fns or (check.fn,)):
+        result = variant(meta, spec)
+        if not result.allowed:
+            results.append({
+                'id': check.id,
+                'checkResult': {
+                    'allowed': False,
+                    'forbiddenReason': result.forbidden_reason,
+                    'forbiddenDetail': result.forbidden_detail,
+                },
+            })
+
+
 def evaluate_pss(level: str, pod: dict) -> List[dict]:
     """Run the default checks and return failing results
     (reference: pkg/pss/evaluate.go:17 evaluatePSS)."""
     meta = pod.get('metadata') or {}
     spec = pod.get('spec') or {}
-    results = []
+    results: List[dict] = []
     for check in DEFAULT_CHECKS:
         if level == LEVEL_BASELINE and check.level != level:
             continue
-        # EVERY versioned variant runs, regardless of the requested
-        # version, and failing variants each append a result — the
-        # reference does not dedup (evaluate.go:24-35), so a pod
-        # failing two variants reports the violation twice
-        for variant in (check.fns or (check.fn,)):
-            result = variant(meta, spec)
-            if not result.allowed:
-                results.append({
-                    'id': check.id,
-                    'checkResult': {
-                        'allowed': False,
-                        'forbiddenReason': result.forbidden_reason,
-                        'forbiddenDetail': result.forbidden_detail,
-                    },
-                })
+        _append_failures(check, meta, spec, results)
+    return results
+
+
+def evaluate_failed_checks(level: str, pod: dict,
+                           mask: int) -> Optional[List[dict]]:
+    """What :func:`evaluate_pss` returns for a pod that fails exactly
+    the checks whose bit (the check's index in ``DEFAULT_CHECKS``) is set
+    in ``mask``, from running those checks alone: in the same order,
+    every versioned variant of each, the same results appended.
+
+    The mask is a hint (the compiled program's, compiler/pss_compile.py)
+    and the checks confirm it: None where one of them passes, where the
+    level does not run one, or where the mask names none — the caller
+    then runs them all, and that answer stands."""
+    if mask <= 0 or mask >> len(DEFAULT_CHECKS):
+        return None
+    meta = pod.get('metadata') or {}
+    spec = pod.get('spec') or {}
+    results: List[dict] = []
+    while mask:
+        low = mask & -mask
+        mask ^= low
+        check = DEFAULT_CHECKS[low.bit_length() - 1]
+        if level == LEVEL_BASELINE and check.level != level:
+            return None
+        found = len(results)
+        _append_failures(check, meta, spec, results)
+        if len(results) == found:
+            return None
     return results
 
 

@@ -35,3 +35,42 @@ for _name in FILES:
     _twice = sorted(set(_found) & set(globals()))
     assert not _twice, f'{_name}.py defines {_twice} a second time'
     globals().update(_found)
+
+
+# what the fast files above do not hold: a layer file that reads the coverage
+# block (the reports drivers snapshot every number of ``coverage.totals()``
+# under 'coverage') names a number the ledger has, so that a renamed counter
+# fails here and does not read as "nothing to read" on the chip
+
+import glob  # noqa: E402
+import os  # noqa: E402
+
+import pytest  # noqa: E402
+
+
+def _coverage_keys(name: str) -> list:
+    args = benchlib.load_data('layers', name).get('args', {})
+    return [value[len('coverage.'):] for value in args.values()
+            if isinstance(value, str) and value.startswith('coverage.')]
+
+
+COVERAGE_LAYERS = [
+    name for name in sorted(
+        os.path.basename(path)[:-len('.json')] for path in glob.glob(
+            os.path.join(benchlib.BENCH_DIR, 'layers', '*.json')))
+    if _coverage_keys(name)]
+
+
+@pytest.mark.parametrize('name', COVERAGE_LAYERS)
+def test_a_coverage_layer_names_a_number_of_the_ledger(name):
+    from kyverno_tpu.observability import coverage
+    from kyverno_tpu.observability.metrics import MetricsRegistry
+    totals = coverage.CoverageLedger(MetricsRegistry()).totals()
+    for key in _coverage_keys(name):
+        assert isinstance(totals.get(key), (int, float)), key
+
+
+def test_the_pss_mask_layers_are_among_them():
+    assert {'pss_mask_share', 'pss_mask_share.ctx', 'pss_checks_per_cell',
+            'pss_checks_per_cell.ctx', 'host_rows_share'} <= \
+        set(COVERAGE_LAYERS)

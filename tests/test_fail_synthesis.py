@@ -276,9 +276,9 @@ class TestFailSynthesis:
         calls = [0]
         inner = scanner._materialize
 
-        def counting(prog, doc):
+        def counting(prog, doc, *cell):
             calls[0] += 1
-            return inner(prog, doc)
+            return inner(prog, doc, *cell)
         scanner._materialize = counting
         out = scanner.scan(resources)
         decisions = sum(len(r.policy_response.rules)
@@ -308,9 +308,9 @@ class TestFailSynthesis:
         direct = [0]
         inner = scanner._materialize
 
-        def counting(prog, doc):
+        def counting(prog, doc, *cell):
             direct[0] += 1
-            return inner(prog, doc)
+            return inner(prog, doc, *cell)
         scanner._materialize = counting
         fails = 0
         for resource, responses in zip(resources,
