@@ -69,6 +69,12 @@ STATUS_CTX_UNRESOLVED = 7  # a context value's variable did not resolve
 STATUS_CTX_WIDE = 8        # more list elements than the value lane holds
 STATUS_CTX_SHAPE = 9       # a value outside the device's exact zone
 
+# what a fail-detail cell reads on the host where its column was not
+# among the row's first KTPU_FDET_K relevant ones (ops/eval.py
+# expand_compact): negative like -1 (no site: the host words the cell),
+# and apart from it and from -2 (an anyPattern child that was skipped)
+FDET_BEYOND_BUDGET = -3
+
 # a context value's lanes (CondCheck mode C): the value itself and up to
 # CTX_WIDTH list elements, each the first CTX_HEAD bytes of its string
 # form and its length

@@ -42,10 +42,10 @@ from .compile import compile_policies
 from .encode import encode_batch, encode_worker, encode_worker_init
 from .shapes import canonical_capacity, canonical_caps
 from .context_lanes import ContextLanes, _input_walker, _row_key
-from .ir import (STATUS_CTX_LOAD, STATUS_CTX_SHAPE, STATUS_CTX_UNRESOLVED,
-                 STATUS_CTX_WIDE, STATUS_FAIL, STATUS_HOST, STATUS_PASS,
-                 STATUS_SKIP, STATUS_SKIP_PRECOND, STATUS_VAR_ERR,
-                 CompiledPolicySet, RuleProgram)
+from .ir import (FDET_BEYOND_BUDGET, STATUS_CTX_LOAD, STATUS_CTX_SHAPE,
+                 STATUS_CTX_UNRESOLVED, STATUS_CTX_WIDE, STATUS_FAIL,
+                 STATUS_HOST, STATUS_PASS, STATUS_SKIP, STATUS_SKIP_PRECOND,
+                 STATUS_VAR_ERR, CompiledPolicySet, RuleProgram)
 
 _SIMPLE_MATCH_KEYS = {'kinds', 'namespaces', 'operations'}
 
@@ -1041,6 +1041,7 @@ class BatchScanner:
             cm = match[start:start + len(part)] if match is not None \
                 else (match_fn(start, part) if match_fn is not None
                       else None)
+            devtel.record_match_cells(cm)
             # canonical capacity padding (compiler/shapes.py): every
             # part pads to one of the few canonical row shapes and the
             # evaluator masks the tail rows via the __rowvalid__ lane,
@@ -1371,6 +1372,7 @@ class BatchScanner:
             cm = match[start:start + len(part)] if match is not None \
                 else (match_fn(start, part) if match_fn is not None
                       else None)
+            devtel.record_match_cells(cm)
             bucket = chunk if n > chunk else canonical_capacity(
                 len(part), chunk=chunk, small=self.SMALL_BATCH)
             encs = []
@@ -1987,8 +1989,10 @@ class BatchScanner:
                     if hits:
                         tally.device_n(prog, hits)
                     if hits < sg.size:
-                        tally.fallback_n(prog, self._message_reason(prog),
-                                         int(sg.size) - hits)
+                        tally.fallback_n(
+                            prog, self._message_reason(prog, j,
+                                                       fdet[sg[0]]),
+                            int(sg.size) - hits)
                 continue
             cell_key = (j, STATUS_FAIL, msg)
             cell = fly.get(cell_key)
@@ -2188,11 +2192,12 @@ class BatchScanner:
                         if hit:
                             tally.device(prog)
                         else:
-                            tally.fallback(prog,
-                                           self._message_reason(prog))
+                            tally.fallback(prog, self._message_reason(
+                                prog, j, fdet_row))
                     return rr
                 if tally is not None:
-                    tally.fallback(prog, self._message_reason(prog))
+                    tally.fallback(prog, self._message_reason(
+                        prog, j, fdet_row))
                 return _HOST_MARKER
             key = (j, STATUS_FAIL, msg)
             rr = fly.get(key)
@@ -2312,6 +2317,16 @@ class BatchScanner:
                        .replace('{e1}', str((fd >> 8) & 0xFF))
         return tmpl
 
+    def _detail_columns(self, prog: RuleProgram, j: int) -> slice:
+        """The fail-detail columns program ``j``'s FAIL message is made
+        from: its own, or its anyPattern children's block."""
+        meta = self._evaluator.any_meta.get(j) \
+            if prog.any_fail_sites is not None else None
+        if meta is None:
+            return slice(j, j + 1)
+        p = len(self.cps.programs)
+        return slice(p + meta[0], p + meta[0] + meta[1])
+
     def _fail_message_cached(self, prog: RuleProgram, j: int,
                              fdet_row) -> Optional[str]:
         """Memoized message synthesis: distinct (program, fail-detail)
@@ -2320,14 +2335,8 @@ class BatchScanner:
             # no site, no static message: its fail detail is the mask of
             # failed checks, for the check library (_materialize)
             return None
-        meta = self._evaluator.any_meta.get(j) \
-            if prog.any_fail_sites is not None else None
-        if meta is not None:
-            p = len(self.cps.programs)
-            key = (j,) + tuple(
-                int(x) for x in fdet_row[p + meta[0]:p + meta[0] + meta[1]])
-        else:
-            key = (j, int(fdet_row[j]))
+        key = (j,) + tuple(
+            int(x) for x in fdet_row[self._detail_columns(prog, j)])
         cache = self._fail_msg_cache
         if key in cache:
             return cache[key]
@@ -2527,12 +2536,19 @@ class BatchScanner:
             return coverage.REASON_STATUS_HOST
         return _CTX_STATUS_REASON.get(st) or self._message_reason(prog)
 
-    def _message_reason(self, prog: RuleProgram) -> str:
+    def _message_reason(self, prog: RuleProgram, j: int = -1,
+                        fdet_row=None) -> str:
         """The ledger's reason for a cell whose verdict the device
         decided and whose message the host words: the check library
-        called directly, or the Validator."""
+        called directly, or the Validator; and of the Validator's, told
+        apart, a FAIL (its ``fdet_row`` given) whose fail detail was
+        lost to the per-row budget, in the columns its message is made
+        from (column ``j``, or the anyPattern child block)."""
         if self._host_rule[prog][1] is not None:
             return coverage.REASON_PSS_DIRECT
+        if fdet_row is not None and FDET_BEYOND_BUDGET in \
+                fdet_row[self._detail_columns(prog, j)]:
+            return coverage.REASON_FAIL_DETAIL_BUDGET
         return coverage.REASON_UNSYNTHESIZABLE
 
     def _new_response(self, policy_index: int, resource: dict,

@@ -123,6 +123,12 @@ METRICS: Dict[str, Metric] = {
         'the first of its key in a scan pass, or a row with no key)|hit '
         '(the cell took the response worded for an earlier row with the '
         'same fail site and the same values of the message\'s inputs).'),
+    'kyverno_tpu_match_cells_total': Metric(
+        'counter', '(resource, rule program) cells of the match matrices '
+        'a scan handed to the device, one matrix a chunk (compiler/'
+        'scan.py stage_encode); result=matched (the sieve says the rule '
+        'applies to the resource: the evaluator\'s verdict is read and '
+        'the report gets a row)|unmatched.'),
     # device-coverage ledger (observability/coverage.py)
     'kyverno_tpu_rule_placement_info': Metric(
         'gauge', '1 per compiled (policy, rule, path); placement=device|'

@@ -69,6 +69,12 @@ REASON_PSS_DIRECT = 'pss_direct_message'  # verdict the device's; the
 #   Validator and no PolicyContext.  A podSecurity rule with context or
 #   preconditions still goes through the Validator and keeps
 #   unsynthesizable_message
+REASON_FAIL_DETAIL_BUDGET = 'fail_detail_budget'  # verdict FAIL, the
+#   device's; its fail detail did not come home because its column was
+#   not among the row's first KTPU_FDET_K relevant ones (ops/eval.py
+#   FDET_BEYOND_BUDGET), so the host words a cell whose message the
+#   detail would have given.  A podSecurity cell beyond the budget
+#   stays pss_direct_message: it is a host row either way
 REASON_CONTEXT_LOAD = 'context_load_failed'  # rule context load failed;
 #   host materialization produces the exact error response
 # Context values in conditions (compiler/compile.py _ContextScope): a
@@ -137,7 +143,7 @@ REASON_STAGE_RETRY_EXHAUSTED = 'stage_retry_exhausted'  # a scan
 REASONS = frozenset({
     REASON_UNSUPPORTED_OPERATOR, REASON_HOST_CLOSURE, REASON_API_CALL,
     REASON_POLICY_COUPLING, REASON_STATUS_HOST, REASON_UNSYNTHESIZABLE,
-    REASON_PSS_DIRECT, REASON_CONTEXT_LOAD,
+    REASON_PSS_DIRECT, REASON_FAIL_DETAIL_BUDGET, REASON_CONTEXT_LOAD,
     REASON_CONTEXT_IN_PATTERN, REASON_CONTEXT_IN_FOREACH,
     REASON_CONTEXT_IN_KEY, REASON_CONTEXT_ENTRY_KIND,
     REASON_CONTEXT_VALUE_EXPR, REASON_CONTEXT_VALUE_INPUTS,

@@ -48,6 +48,7 @@ PACK_BATCHES = 'kyverno_tpu_pack_batches_total'
 CONTEXT_LOOKUPS = 'kyverno_tpu_context_lookups_total'
 CONTEXT_LOADS = 'kyverno_tpu_context_loads_total'
 FAIL_MESSAGE_MEMO = 'kyverno_tpu_fail_message_memo_total'
+MATCH_CELLS = 'kyverno_tpu_match_cells_total'
 
 #: canonical stage labels.  The pipeline's, in order: ``match`` (host
 #: match sieve), ``encode`` (in a worker process or inline),
@@ -537,6 +538,23 @@ def record_context(lookups: int, loads_ok: int, loads_failed: int) -> None:
         if loads_failed:
             _registry.inc(CONTEXT_LOADS, float(loads_failed),
                           result='failed')
+
+
+def record_match_cells(match) -> None:
+    """One chunk's match matrix ``[rows, programs]`` as the scan hands
+    it to the device (``compiler/scan.py`` ``stage_encode``): the
+    (resource, rule program) cells the sieve matched, which the
+    evaluator's verdicts are read for and reports get a row for, and
+    those it did not.  One ``count_nonzero`` a chunk, and none where
+    metrics are off."""
+    if _registry is not None and match is not None and match.size:
+        import numpy as np
+        matched = int(np.count_nonzero(match))
+        if matched:
+            _registry.inc(MATCH_CELLS, float(matched), result='matched')
+        if match.size - matched:
+            _registry.inc(MATCH_CELLS, float(match.size - matched),
+                          result='unmatched')
 
 
 def record_fail_message_memo(hits: int, misses: int) -> None:

@@ -39,8 +39,9 @@ from ..compiler.ir import (STR_LEN, TAG_ARRAY, TAG_BOOL, TAG_FLOAT, TAG_INT,
                            TAG_MAP, TAG_MISSING, TAG_NULL, TAG_STRING,
                            TAIL_LEN, BoolExpr, CompiledPolicySet, CondCheck,
                            Leaf, RuleProgram, StatusExpr)
-from ..compiler.ir import (STATUS_FAIL, STATUS_HOST, STATUS_PASS, STATUS_SKIP,
-                           STATUS_SKIP_PRECOND, STATUS_VAR_ERR)
+from ..compiler.ir import (FDET_BEYOND_BUDGET, STATUS_FAIL, STATUS_HOST,
+                           STATUS_PASS, STATUS_SKIP, STATUS_SKIP_PRECOND,
+                           STATUS_VAR_ERR)
 from ..engine import pattern as leaf_pattern
 from ..engine.operators import _sprint
 from ..utils.duration import parse_duration
@@ -1889,7 +1890,7 @@ def build_evaluator(cps: CompiledPolicySet):
     #: host.  fdet is ~75% of the chunk's device→host bytes; only
     #: (matched, FAIL) cells are ever read, so the device compacts them
     #: to the first K relevant columns.  Overflow rows keep exactness: their
-    #: missing cells read -1 → host materialization.
+    #: missing cells read FDET_BEYOND_BUDGET → host materialization.
     fdet_k = int(os.environ.get('KTPU_FDET_K', '32'))
 
     def evaluate_packed(packed: Dict[str, jnp.ndarray]):
@@ -2188,11 +2189,11 @@ def fold_match_unique(mm: np.ndarray, evaluator) -> np.ndarray:
 def expand_compact(out8: np.ndarray, out32: np.ndarray, evaluator):
     """Reconstruct program-space (statuses, details, dense fdet,
     admission-match) from the unique-space compact device outputs.
-    Cells beyond the per-row budget stay -1, which downstream message
-    synthesis treats as 'materialize on host' — exactness is never
-    lost.  The trailing admission columns (None when the policy set has
-    no admission-eligible rules) are the in-graph per-row match
-    decisions for ``evaluator.adm_cols``."""
+    Cells beyond the per-row budget read ``FDET_BEYOND_BUDGET``, which
+    downstream message synthesis treats like -1, as 'materialize on
+    host' — exactness is never lost.  The trailing admission columns
+    (None when the policy set has no admission-eligible rules) are the
+    in-graph per-row match decisions for ``evaluator.adm_cols``."""
     n_adm = getattr(evaluator, 'n_adm', 0)
     width = out8.shape[1] - n_adm
     n_uniq = width // 2
@@ -2205,6 +2206,18 @@ def expand_compact(out8: np.ndarray, out32: np.ndarray, evaluator):
     dense_u = np.full((out8.shape[0], evaluator.n_cols_u), -1, np.int32)
     rr, kk = np.nonzero(cols < evaluator.n_cols_u)
     dense_u[rr, cols[rr, kk]] = fds[rr, kk]
+    if 0 < k < evaluator.n_cols_u:
+        # the budget can bind: a row whose last slot is used shipped its
+        # k lowest relevant columns, so every relevant one above that
+        # slot's was lost.  Those read FDET_BEYOND_BUDGET and not -1, so
+        # that the ledger can tell them from a program without a site
+        # (a row with exactly k relevant columns has none above)
+        full = np.flatnonzero(cols[:, k - 1] < evaluator.n_cols_u)
+        if full.size:
+            above = np.arange(evaluator.n_cols_u) > \
+                cols[full, k - 1][:, None]
+            dense_u[full] = np.where(above, FDET_BEYOND_BUDGET,
+                                     dense_u[full])
     if evaluator.expand_identity:
         return s_u, d_u, dense_u, adm
     pid = evaluator.uniq_idx
