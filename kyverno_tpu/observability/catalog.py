@@ -188,6 +188,10 @@ METRICS: Dict[str, Metric] = {
         'but held every unchanged partition\'s subrow — the row '
         're-scanned against only the touched partitions\' policies '
         '(verdictcache/partitioned.py).'),
+    'kyverno_tpu_verdict_snapshot_results_total': Metric(
+        'counter', 'Results a verdict-cache flush wrote, by '
+        'entry=ref (a row\'s reference to a result) | table (a '
+        'distinct result in the snapshot\'s table, written once).'),
     'kyverno_tpu_rescan_rows_scanned': Metric(
         'gauge', 'Rows the most recent background reconcile evaluated '
         'on the dense device path.'),

@@ -546,8 +546,13 @@ class BackgroundScanController:
                              replayed=replayed, scoped=len(scoped_work))
         if vc is not None:
             with devtel.install_capture(cap), \
-                    devtel.stage('flush', parent=span):
+                    devtel.stage('flush', parent=span) as stage:
                 vc.flush()
+                # result references the snapshot's rows make, and the
+                # distinct results its table writes for them
+                refs, distinct = vc.last_flush
+                stage.set_attribute('results', refs)
+                stage.set_attribute('distinct', distinct)
         own_s += sum(cap.stage_s(k) for k in _OWN_STAGES)
         # the reconcile's wall, and what of it no stage of this thread
         # covers: the measure of how much of the pace-setting thread's

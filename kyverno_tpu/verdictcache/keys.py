@@ -24,7 +24,7 @@ from typing import Optional
 
 #: bump to invalidate every persisted verdict row (snapshot format or
 #: engine-semantics changes not captured by the source digests below)
-VERDICT_VERSION = 1
+VERDICT_VERSION = 2
 
 #: metadata fields the API server rewrites on every update without
 #: changing anything a policy can meaningfully evaluate — excluded from

@@ -43,7 +43,7 @@ import threading
 from typing import Dict, FrozenSet, List, Optional, Tuple
 
 from .store import (VERDICT_CACHE_HITS, VERDICT_CACHE_MISSES, VerdictCache,
-                    _env_enabled, _env_root)
+                    _env_enabled, _env_root, replay_row)
 
 VERDICT_CACHE_PARTIAL_HITS = 'kyverno_tpu_verdict_cache_partial_hits_total'
 
@@ -206,15 +206,7 @@ class PartitionedVerdictCache:
                ) -> Tuple[List[dict], dict, list]:
         """Identical contract to :meth:`VerdictCache.replay`; operates
         on the composed row, so stored subrows stay timestamp-free."""
-        if row.get('t') == ts:
-            results = row['r']
-        else:
-            stamp = {'seconds': ts}
-            results = [dict(r, timestamp=stamp) for r in row['r']]
-            row['r'] = results
-            row['t'] = ts
-        return (results, dict(row['s']),
-                [policies[p] for p in row['p'] if p < len(policies)])
+        return replay_row(row, policies, ts)
 
     # -- writes ------------------------------------------------------------
 
@@ -293,6 +285,16 @@ class PartitionedVerdictCache:
         for sub in self._parts.values():
             wrote = sub.flush() or wrote
         return wrote
+
+    @property
+    def last_flush(self) -> Tuple[int, int]:
+        """The members' last flushes summed: (result references, table
+        entries)."""
+        refs = distinct = 0
+        for sub in self._parts.values():
+            refs += sub.last_flush[0]
+            distinct += sub.last_flush[1]
+        return refs, distinct
 
     def stats(self) -> Dict[str, int]:
         entries = len(self)

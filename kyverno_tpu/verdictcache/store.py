@@ -10,13 +10,26 @@ fingerprint pins policy-set order, so indexes are stable across
 processes).  Rescans replay hit rows in O(1) instead of re-evaluating
 the resource×rule matrix; only digests that changed ship to the device.
 
+Result dicts are **shared between rows**.  The fused path hands
+``store`` flyweights that one (rule response, policy, second) shares
+across every resource (``reports/results.py`` ``_rule_result``);
+``store`` keeps one timestamp-free copy per flyweight it is handed
+(interned by the flyweight's identity until the next ``flush``), so
+rows that received the same result refer to one object.  Stored
+results are immutable, like the flyweights they copy.
+
 Persistence reuses the ``aotcache/store.py`` protocol: one snapshot
 file per generation (``<fingerprint>-<rev>.vrows``), written
 tmp-file + ``os.replace`` so readers never observe a partial snapshot,
-framed with a magic + SHA-256 header so a torn or bit-flipped file is
-deleted and reloaded as empty — a bad snapshot costs a rescan, never a
-crash or a stale verdict.  Disk eviction is LRU by mtime against a
-byte budget; the memory front is an entry-capped LRU.
+framed with a magic (``KTVC2``) + SHA-256 header so a torn or
+bit-flipped file — or one of an older codec — is deleted and reloaded
+as empty: a bad snapshot costs a rescan, never a crash or a stale
+verdict.  The zlib-compressed JSON payload is a table:
+``{"t": [result, ...], "r": {digest: {"u", "r": [table index, ...],
+"s", "p"}}}``.  Each distinct result is written once (deduplicated by
+identity, then by its serialised bytes); a reload shares one dict per
+table entry among the rows that refer to it.  Disk eviction is LRU by
+mtime against a byte budget; the memory front is an entry-capped LRU.
 
 Knobs:
 
@@ -47,13 +60,16 @@ from .keys import engine_rev, generation_key
 _log = logging.getLogger('kyverno.verdictcache')
 
 #: snapshot framing: magic + 32-byte SHA-256 of the payload, then payload
-_MAGIC = b'KTVC1\n'
+#: (KTVC2: the result table; a KTVC1 snapshot of per-row copies fails
+#: the magic check and loads as empty)
+_MAGIC = b'KTVC2\n'
 _DIGEST_LEN = 32
 _SUFFIX = '.vrows'
 
 VERDICT_CACHE_HITS = 'kyverno_tpu_verdict_cache_hits_total'
 VERDICT_CACHE_MISSES = 'kyverno_tpu_verdict_cache_misses_total'
 VERDICT_CACHE_EVICTIONS = 'kyverno_tpu_verdict_cache_evictions_total'
+VERDICT_SNAPSHOT_RESULTS = 'kyverno_tpu_verdict_snapshot_results_total'
 RESCAN_ROWS_SCANNED = 'kyverno_tpu_rescan_rows_scanned'
 RESCAN_ROWS_REPLAYED = 'kyverno_tpu_rescan_rows_replayed'
 
@@ -61,6 +77,39 @@ _DEFAULT_MAX_BYTES = 256 << 20
 #: memory-front entry cap (rows are a few hundred bytes; 2M entries is
 #: the 1M-Pod steady state with headroom, bounded without a knob)
 _MEM_MAX_ENTRIES = 2_000_000
+#: flyweights interned between two flushes before the table starts
+#: over, which bounds what it pins (a reconcile hands a few hundred
+#: shared ones, plus one per PSS FAIL cell that no other row shares)
+_INTERN_MAX = 1 << 16
+
+_dumps = json.JSONEncoder(separators=(',', ':')).encode
+_dump_str = json.encoder.encode_basestring_ascii
+
+
+def replay_row(row: dict, policies, ts: int
+               ) -> Tuple[List[dict], dict, list]:
+    """Row → the ``(results, summary, row_policies)`` triple
+    ``scan_report_results`` would yield, stamped with ``ts`` (all
+    results of one fused row share the tick's timestamp, so sort order
+    is unaffected).
+
+    Re-stamping is lazy: the stamped form is kept on the row (``'rt'``)
+    with the tick second it carries (``'t'``), so replays within the
+    same second (fast reconcile loops over a large cache) return the
+    shared dicts with zero per-result copies.  Stamped results are
+    immutable from then on — a later tick with a different second
+    builds fresh copies, never mutating what an earlier report may
+    still reference.  ``'r'`` keeps the timestamp-free results that
+    ``flush`` persists; neither ``'t'`` nor ``'rt'`` is persisted."""
+    if row.get('t') == ts:
+        results = row['rt']
+    else:
+        stamp = {'seconds': ts}
+        results = [dict(r, timestamp=stamp) for r in row['r']]
+        row['rt'] = results
+        row['t'] = ts
+    return (results, dict(row['s']),
+            [policies[p] for p in row['p'] if p < len(policies)])
 
 
 def _reg():
@@ -101,8 +150,13 @@ def _env_max_bytes() -> int:
 class VerdictCache:
     """One generation of digest-keyed verdict rows.
 
-    Row schema (JSON-stable): ``{'u': uid, 'r': [result dicts, no
-    timestamp key], 's': summary, 'p': [policy indexes]}``.
+    Row schema in memory: ``{'u': uid, 'r': [result dicts, no
+    timestamp key], 's': summary, 'p': [policy indexes]}``, plus the
+    replay's ``'t'`` / ``'rt'`` (:func:`replay_row`).  The result dicts
+    are shared: every row handed the same flyweight, or loaded from the
+    same snapshot table entry, refers to one immutable dict.  In the
+    snapshot a row is ``{'u', 'r': [table indexes], 's', 'p'}`` beside
+    the table ``'t'`` of distinct results (see the module docstring).
     """
 
     def __init__(self, fingerprint: str, root: Optional[str] = None,
@@ -116,7 +170,13 @@ class VerdictCache:
         self._lock = threading.Lock()
         self._rows: 'OrderedDict[str, dict]' = OrderedDict()
         self._by_uid: Dict[str, Set[str]] = {}
+        # id(flyweight) -> its timestamp-free copy; the flyweights are
+        # pinned, so no id of theirs is reused while it keys the map
+        self._interned: Dict[int, dict] = {}
+        self._pinned: List[dict] = []
         self._dirty = False
+        #: what the last flush wrote: (result references, table entries)
+        self.last_flush = (0, 0)
         # local lookup outcome counters: benchmarks and the decision-
         # provenance cross-checks read them without a metrics registry
         self._hits = 0
@@ -190,17 +250,26 @@ class VerdictCache:
     def store(self, digest: str, uid: str, results: List[dict],
               summary: dict, policy_indexes: List[int]) -> None:
         """Record one scanned row.  ``results`` are the shared fused-path
-        flyweight dicts — never mutated; the stored copies drop the
-        ``timestamp`` key so replay can stamp the replaying tick."""
-        row = {
-            'u': uid,
-            'r': [{k: v for k, v in r.items() if k != 'timestamp'}
-                  for r in results],
-            's': dict(summary),
-            'p': list(policy_indexes),
-        }
+        flyweight dicts — never mutated; the row refers to one
+        timestamp-free copy per flyweight (the first row handed it
+        makes the copy, every later one shares it), so replay can stamp
+        the replaying tick and ``flush`` finds the sharing by identity."""
         evicted = 0
         with self._lock:
+            interned = self._interned
+            if len(interned) > _INTERN_MAX:
+                interned.clear()
+                self._pinned.clear()
+            kept = []
+            for r in results:
+                copy = interned.get(id(r))
+                if copy is None:
+                    copy = interned[id(r)] = {
+                        k: v for k, v in r.items() if k != 'timestamp'}
+                    self._pinned.append(r)
+                kept.append(copy)
+            row = {'u': uid, 'r': kept, 's': dict(summary),
+                   'p': list(policy_indexes)}
             old = self._rows.get(digest)
             if old is not None:
                 self._unindex(digest, old)
@@ -243,27 +312,8 @@ class VerdictCache:
 
     def replay(self, row: dict, policies, ts: int
                ) -> Tuple[List[dict], dict, list]:
-        """Row → the ``(results, summary, row_policies)`` triple
-        ``scan_report_results`` would yield, stamped with ``ts`` (all
-        results of one fused row share the tick's timestamp, so sort
-        order is unaffected).
-
-        Re-stamping is lazy: the stamped form is written back onto the
-        row with the tick second it carries, so replays within the same
-        second (fast reconcile loops over a large cache) return the
-        shared dicts with zero per-result copies.  Stamped results are
-        immutable from then on — a later tick with a different second
-        builds fresh copies, never mutating what an earlier report may
-        still reference."""
-        if row.get('t') == ts:
-            results = row['r']
-        else:
-            stamp = {'seconds': ts}
-            results = [dict(r, timestamp=stamp) for r in row['r']]
-            row['r'] = results
-            row['t'] = ts
-        return (results, dict(row['s']),
-                [policies[p] for p in row['p'] if p < len(policies)])
+        """:func:`replay_row`."""
+        return replay_row(row, policies, ts)
 
     # -- persistence -------------------------------------------------------
 
@@ -291,7 +341,11 @@ class VerdictCache:
             self._drop_file(path)
             return
         try:
-            rows = json.loads(zlib.decompress(payload).decode())
+            doc = json.loads(zlib.decompress(payload).decode())
+            table = doc['t']
+            rows = doc['r']
+            for row in rows.values():
+                row['r'] = [table[i] for i in row['r']]
         except Exception:  # noqa: BLE001 - stale codec decodes as empty
             self._drop_file(path)
             return
@@ -319,14 +373,23 @@ class VerdictCache:
         dirty, then evict older generation snapshots LRU-by-mtime to fit
         the byte budget.  Returns True when a snapshot was written."""
         path = self.path()
-        if path is None:
-            return False
         with self._lock:
-            if not self._dirty:
+            # the next reconcile hands flyweights of its own; what it
+            # shares with these rows is found again by bytes below
+            self._interned.clear()
+            self._pinned.clear()
+            self.last_flush = (0, 0)
+            if path is None or not self._dirty:
                 return False
-            payload = zlib.compress(json.dumps(
-                self._rows, separators=(',', ':')).encode(), 3)
+            text, refs, distinct = self._snapshot_json()
+            payload = zlib.compress(text.encode(), 3)
             self._dirty = False
+        reg = _reg()
+        if reg is not None:
+            reg.inc(VERDICT_SNAPSHOT_RESULTS, float(refs), entry='ref')
+            reg.inc(VERDICT_SNAPSHOT_RESULTS, float(distinct),
+                    entry='table')
+        self.last_flush = (refs, distinct)
         framed = _MAGIC + hashlib.sha256(payload).digest() + payload
         try:
             fd, tmp = tempfile.mkstemp(dir=self.root, suffix='.tmp')
@@ -341,6 +404,42 @@ class VerdictCache:
             return False
         self._evict_disk(keep=path)
         return True
+
+    def _snapshot_json(self) -> Tuple[str, int, int]:
+        """The snapshot's JSON text, the result references its rows make
+        and the table entries they refer to.  Each row is written
+        straight to text, so the snapshot keeps no container a row
+        alive (thousands of them would wake the cyclic collector over
+        the scan's whole heap).  Caller holds the lock."""
+        table: List[str] = []
+        by_id: Dict[int, str] = {}     # id(result) -> its index, as text
+        by_text: Dict[str, str] = {}   # a result's JSON -> its index
+        policies: Dict[tuple, str] = {}  # a row's policy indexes, as text
+        rows = []
+        refs = 0
+        for digest, row in self._rows.items():
+            results = row['r']
+            refs += len(results)
+            idx = []
+            for r in results:
+                i = by_id.get(id(r))
+                if i is None:
+                    text = _dumps(r)
+                    i = by_text.get(text)
+                    if i is None:
+                        i = by_text[text] = str(len(table))
+                        table.append(text)
+                    by_id[id(r)] = i
+                idx.append(i)
+            key = tuple(row['p'])
+            p = policies.get(key)
+            if p is None:
+                p = policies[key] = ','.join(map(str, key))
+            rows.append('%s:{"u":%s,"r":[%s],"s":%s,"p":[%s]}' % (
+                _dump_str(digest), _dump_str(row['u']), ','.join(idx),
+                _dumps(row['s']), p))
+        return ('{"t":[%s],"r":{%s}}' % (','.join(table), ','.join(rows)),
+                refs, len(table))
 
     def _evict_disk(self, keep: str) -> None:
         """Drop oldest generation snapshots until the directory fits the

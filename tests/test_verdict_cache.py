@@ -1,11 +1,16 @@
 """Digest-keyed verdict cache (ISSUE 6): spec-digest stability, store
 hit/miss/invalidation/eviction semantics, controller integration
 (replay vs scan partition, delete invalidation, policy-set flush), the
-KTPU_VERDICT_CACHE=off bit-identity oracle, and second-process
-disk-store reuse."""
+KTPU_VERDICT_CACHE=off bit-identity oracle, second-process
+disk-store reuse, and the KTVC2 snapshot table (ISSUE 39): each
+distinct result written once, reloaded to what the KTVC1 codec gave."""
 
+import copy
+import hashlib
+import json
 import os
 import sys
+import zlib
 
 import pytest
 import yaml
@@ -351,3 +356,212 @@ class TestControllerIntegration:
         assert second.rescan_stats == {
             'rows_pending': 2, 'rows_scanned': 0, 'rows_replayed': 2}
         assert reports_of(second) == reports_of(first)
+
+
+# ---------------------------------------------------------------------------
+# the snapshot table (KTVC2)
+
+
+def _result(policy, rule, result='pass', message='ok', ts=1, **extra):
+    return dict({'source': 'kyverno', 'policy': policy, 'rule': rule,
+                 'message': message, 'result': result, 'scored': True,
+                 'timestamp': {'seconds': ts}}, **extra)
+
+
+def _summary(results):
+    out = {'pass': 0, 'fail': 0, 'warn': 0, 'error': 0, 'skip': 0}
+    for r in results:
+        out[r['result']] += 1
+    return out
+
+
+def shared_rows():
+    """Rows as the fused path hands them: flyweights shared across
+    rows, two flyweights of one content from two seconds, and one
+    per-cell FAIL dict no other row shares."""
+    a = _result('p-a', 'r1')
+    b = _result('p-b', 'r2', 'fail', 'bad', category='Best Practices',
+                properties={'standard': 'baseline', 'version': 'latest',
+                            'controls': 'hostPort'})
+    a_later = _result('p-a', 'r1', ts=2)   # a's content, a's next second
+    rows = []
+    for i in range(6):
+        results = [a if i % 2 else a_later, b]
+        if i == 3:
+            results.append(_result('p-c', 'r3', 'fail',
+                                   f'container "c{i}" \u00e9 fails'))
+        rows.append((f'd{i}', f'default/pod-{i}', results,
+                     _summary(results), [0, 1] if i != 3 else [0, 1, 2]))
+    return rows
+
+
+def parent_rows(rows):
+    """The KTVC1 codec's reload of the same stores: its ``store`` copied
+    each result without ``timestamp`` and its ``flush`` dumped the rows
+    as they were."""
+    stored = {d: {'u': u, 'r': [{k: v for k, v in r.items()
+                                 if k != 'timestamp'} for r in res],
+                  's': dict(s), 'p': list(p)}
+              for d, u, res, s, p in rows}
+    return json.loads(json.dumps(stored, separators=(',', ':')))
+
+
+def payload_of(vc):
+    raw = open(vc.path(), 'rb').read()
+    assert raw.startswith(b'KTVC2\n')
+    return json.loads(zlib.decompress(raw[len(b'KTVC2\n') + 32:]))
+
+
+POLICIES3 = [Policy(POLICY), Policy(OTHER_POLICY), Policy(POLICY)]
+
+
+class TestSnapshotTable:
+    def test_shared_results_flush_to_a_table_once(self, tmp_path,
+                                                   _registry):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        rows = shared_rows()
+        for row in rows:
+            vc.store(*row)
+        assert vc.flush()
+        doc = payload_of(vc)
+        # a, b and the FAIL dict: a_later has a's bytes
+        assert len(doc['t']) == 3
+        assert all('timestamp' not in r for r in doc['t'])
+        refs = sum(len(r[2]) for r in rows)
+        assert sum(len(r['r']) for r in doc['r'].values()) == refs
+        assert doc['r']['d0']['r'] == doc['r']['d1']['r']
+        assert set(doc['r']['d0']) == {'u', 'r', 's', 'p'}
+        assert _registry.counter_value(
+            'kyverno_tpu_verdict_snapshot_results_total',
+            entry='ref') == float(refs)
+        assert _registry.counter_value(
+            'kyverno_tpu_verdict_snapshot_results_total',
+            entry='table') == 3.0
+        assert vc.last_flush == (refs, 3)
+        assert not vc.flush() and vc.last_flush == (0, 0)
+
+    def test_reload_equals_the_parent_codecs(self, tmp_path):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        rows = shared_rows()
+        for row in rows:
+            vc.store(*row)
+        vc.flush()
+        again = VerdictCache('fp', root=str(tmp_path))
+        want = parent_rows(rows)
+        assert len(again) == len(want)
+        for digest, row in want.items():
+            got = again.lookup(digest)
+            assert {k: got[k] for k in ('u', 'r', 's', 'p')} == row
+        # one dict per table entry, shared by the rows that refer to it
+        assert again.lookup('d0')['r'][0] is again.lookup('d1')['r'][0]
+        assert again.lookup('d0')['r'][1] is again.lookup('d5')['r'][1]
+        assert again.invalidate_uid('default/pod-2') == 1
+
+    def test_replay_after_reload_equals_replay_before_flush(self,
+                                                           tmp_path):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        rows = shared_rows()
+        for row in rows:
+            vc.store(*row)
+        before = {}
+        for d, *_ in rows:
+            results, summary, policies = vc.replay(vc.peek(d), POLICIES3, 77)
+            before[d] = (copy.deepcopy(results), summary, policies)
+        vc.flush()
+        again = VerdictCache('fp', root=str(tmp_path))
+        for d, *_ in rows:
+            assert again.replay(again.peek(d), POLICIES3, 77) == before[d]
+
+    def test_ktvc1_snapshot_loads_as_empty(self, tmp_path):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        payload = zlib.compress(json.dumps(
+            parent_rows(shared_rows()), separators=(',', ':')).encode(), 3)
+        with open(vc.path(), 'wb') as f:
+            f.write(b'KTVC1\n' + hashlib.sha256(payload).digest() + payload)
+        old = VerdictCache('fp', root=str(tmp_path))
+        assert len(old) == 0
+        assert not os.path.exists(vc.path())
+
+    def test_store_neither_copies_per_row_nor_mutates(self, tmp_path):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        rows = shared_rows()
+        handed = copy.deepcopy([r[2] for r in rows])
+        for row in rows:
+            vc.store(*row)
+        assert [r[2] for r in rows] == handed
+        assert all('timestamp' in r for res in handed for r in res)
+        # two rows handed one dict share one stored, timestamp-free dict
+        r1, r3 = vc.peek('d1')['r'], vc.peek('d3')['r']
+        assert r1[0] is r3[0] and r1[1] is r3[1]
+        assert 'timestamp' not in r1[0]
+        assert vc.peek('d0')['r'][1] is r1[1]
+
+    def test_replayed_row_flushes_and_reloads_to_the_same_replay(
+            self, tmp_path):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        rows = shared_rows()
+        for row in rows:
+            vc.store(*row)
+        row = vc.peek('d3')
+        results, summary, policies = vc.replay(row, POLICIES3, 90)
+        first = (copy.deepcopy(results), summary, policies)
+        assert row['t'] == 90 and 'timestamp' not in row['r'][0]
+        assert vc.replay(row, POLICIES3, 90)[0] is row['rt']
+        vc.store('d9', 'default/pod-9', *ROW)  # dirty again
+        vc.flush()
+        doc = payload_of(vc)
+        assert set(doc['r']['d3']) == {'u', 'r', 's', 'p'}
+        again = VerdictCache('fp', root=str(tmp_path))
+        assert again.replay(again.peek('d3'), POLICIES3, 90) == first
+        later = again.replay(again.peek('d3'), POLICIES3, 91)
+        assert [r['timestamp'] for r in later[0]] == \
+            [{'seconds': 91}] * len(first[0])
+
+
+class TestSnapshotEndToEnd:
+    def test_pss_pack_replays_bit_identical_from_the_table(
+            self, tmp_path, monkeypatch):
+        """A real reconcile of the PSS pack over generated Pods and
+        Deployments: a second controller loads the KTVC2 snapshot,
+        replays every row without a scan, and stores the first
+        controller's reports bit for bit."""
+        import benchlib
+        policies = benchlib.load_policies(['pss', 'pack', 'config4'])
+        cluster = benchlib.load_module('generators', 'mixed_cluster') \
+            .generate(2 ** 31 + 39, 192, deployment_share=0.3)
+
+        def run(scan_ok):
+            monkeypatch.setenv('KTPU_VERDICT_CACHE', '1')
+            monkeypatch.setenv('KTPU_VERDICT_CACHE_DIR', str(tmp_path / 'vc'))
+            ctrl = BackgroundScanController(FakeClient(), policies,
+                                            cache=MetadataCache())
+            if not scan_ok:
+                monkeypatch.setattr(
+                    ctrl.scanner, 'scan_report_results',
+                    lambda *a, **k: pytest.fail('warm: must not scan'))
+            for r in cluster:
+                ctrl.cache.update(r)
+            ctrl.enqueue_all()
+            ctrl.reconcile(now=NOW)
+            ctrl.close()
+            out = {}
+            for ns in {r['metadata']['namespace'] for r in cluster}:
+                for rep in ctrl.client.list_resource(
+                        'kyverno.io/v1alpha2', 'BackgroundScanReport', ns,
+                        None):
+                    out[(ns, rep['metadata']['name'])] = dict(
+                        rep, metadata={
+                            k: v for k, v in rep['metadata'].items()
+                            if k not in ('resourceVersion', 'uid')})
+            return ctrl, out
+
+        first, reports = run(True)
+        assert first.rescan_stats['rows_scanned'] == len(cluster)
+        doc = payload_of(first.verdict_cache)
+        assert len(doc['r']) == len(cluster)
+        refs = sum(len(r['r']) for r in doc['r'].values())
+        assert len(doc['t']) < refs / 2  # the flyweights, once each
+        second, replayed = run(False)
+        assert second.rescan_stats['rows_replayed'] == len(cluster)
+        assert len(reports) == len(cluster)
+        assert replayed == reports
