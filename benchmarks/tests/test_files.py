@@ -95,25 +95,88 @@ def test_every_name_has_its_file_and_the_config_file_says_the_same(bench):
                        'read')
 
 
-def test_a_layer_metric_is_reported_where_its_entry_yields_what_it_moves(
-        bench):
-    """The rule that places a per-layer metric: every cell of its entry
-    point that reports the end-to-end metric it moves, and no other."""
+def layer_file(name: str) -> dict:
+    return benchlib.load_data('layers', name)
+
+
+def test_what_a_cells_traffic_yields_is_what_it_reports(bench):
     end_to_end = {m['name']: m for m in bench['end_to_end']}
-    cells = {}
     for w in bench['workloads']:
-        entry = benchlib.load_data('configs', w['config'])['entry']
         yields = set(benchlib.load_data('traffic', w['traffic'])['yields'])
-        cells[w['name']] = (entry, yields)
-        # what the traffic yields is what BENCHMARK.json says the cell reports
         assert yields == {n for n, m in end_to_end.items() if n != 'setup_s'
                           and w['name'] in m.get('workloads',
                                                  [w['name']])}
+
+
+def placed_right(bench, name: str, cell: str) -> bool:
+    """The rule that places a per-layer metric, which only its
+    ``workloads`` in BENCHMARK.json does: a cell it lists is a cell of the
+    benchmark, with a driver, whose traffic yields the end-to-end metric the
+    metric moves.  A cell joins a metric that other cells read by adding its
+    name there; no layer file names a cell or a driver."""
+    metric = next(m for m in bench['per_layer'] if m['name'] == name)
+    work = next((w for w in bench['workloads'] if w['name'] == cell), None)
+    if work is None:
+        return False
+    config = benchlib.load_data('configs', work['config'])
+    yields = benchlib.load_data('traffic', work['traffic'])['yields']
+    return metric['moves'] in yields and hasattr(
+        benchlib.load_module('drivers', config['entry']), 'Driver')
+
+
+with open(os.path.join(benchlib.ROOT, 'BENCHMARK.json')) as _f:
+    PLACED = [(m['name'], cell) for m in json.load(_f)['per_layer']
+              for cell in m['workloads']]
+
+
+@pytest.mark.parametrize('name, cell', PLACED)
+def test_a_layer_metric_is_read_in_a_cell_that_yields_what_it_moves(
+        bench, name, cell):
+    assert placed_right(bench, name, cell)
+
+
+def test_a_metric_listed_in_a_cell_that_yields_something_else_is_misplaced(
+        bench):
+    yields = {w['name']: benchlib.load_data('traffic', w['traffic'])['yields']
+              for w in bench['workloads']}
+    name, cell = next((m['name'], cell) for m in bench['per_layer']
+                      for cell in yields if m['moves'] not in yields[cell])
+    metric = next(m for m in bench['per_layer'] if m['name'] == name)
+    spoiled = dict(bench, per_layer=[
+        dict(metric, workloads=metric['workloads'] + [cell])])
+    assert not placed_right(spoiled, name, cell)
+    assert not placed_right(bench, name, 'no_such_cell')
+
+
+def twins(bench, layer=layer_file) -> list:
+    """Pairs of metrics that move the same end-to-end metric through the
+    same reader with the same arguments: one metric read in more cells is
+    one metric whose ``workloads`` lists more cells."""
+    seen, found = {}, []
     for m in bench['per_layer']:
-        entry = benchlib.load_data('layers', m['name'])['entry']
-        want = sorted(name for name, (e, y) in cells.items()
-                      if e == entry and m['moves'] in y)
-        assert sorted(m['workloads']) == want, m['name']
+        spec = layer(m['name'])
+        key = (m['moves'], spec['reader'],
+               json.dumps(spec.get('args', {}), sort_keys=True))
+        if key in seen:
+            found.append((seen[key], m['name']))
+        else:
+            seen[key] = m['name']
+    return found
+
+
+# twins kept apart while tests/test_benchmark_checks.py names their files
+KEPT_TWINS = [('pss_mask_share', 'pss_mask_share.ctx'),
+              ('pss_checks_per_cell', 'pss_checks_per_cell.ctx')]
+
+
+def test_no_two_metrics_that_move_one_number_read_it_alike(bench):
+    assert twins(bench) == KEPT_TWINS
+    first = bench['per_layer'][0]['name']
+    spoiled = dict(bench, per_layer=bench['per_layer'] + [
+        dict(bench['per_layer'][0], name='a_copy')])
+    assert twins(spoiled, lambda n: layer_file(first if n == 'a_copy'
+                                               else n)) == \
+        KEPT_TWINS + [(first, 'a_copy')]
 
 
 @pytest.mark.parametrize('kind, spoil', [
