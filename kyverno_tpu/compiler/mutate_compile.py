@@ -25,7 +25,7 @@ patchJSON6902.go, mutation.go ForEach).
 from __future__ import annotations
 
 import re
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from ..engine import operators
 from ..engine.api import RuleStatus
@@ -312,11 +312,47 @@ def compile_json6902(patch_text: Any, rule_name: str = '',
 
 # -- foreach ----------------------------------------------------------------
 
-def _compile_element_conditions(conditions: Any) -> Optional[Callable]:
-    """Per-element precondition evaluator for conditions whose keys are
-    single {{element...}} JMESPath expressions and values are static."""
+class ElementCondition(NamedTuple):
+    """One per-element precondition of a ``foreach`` entry: a key that is
+    exactly one ``{{ ... }}`` over ``element``, a static operator and a
+    static value.  ``path`` and ``default`` are set where the key is the
+    plain ``element.<path>`` or ``element.<path> || '<static>'`` (None
+    otherwise: only the host applier takes such a key)."""
+    expr: str
+    searcher: Any
+    operator: str
+    value: Any
+    path: Optional[tuple]
+    default: Optional[str]
+
+
+class ForeachShape(NamedTuple):
+    """What :func:`parse_foreach` reads from a single-entry ``foreach``:
+    the list it walks (``request.object.<list_path>``, and whether the
+    expression ends ``|| `[]```, so that an absent list is no element
+    where the engine would otherwise fail the rule), its condition blocks
+    ``[(mode, [ElementCondition])]`` and the element overlay's flattened
+    ``(path, add_only, value)`` sets."""
+    list_path: tuple
+    list_default: bool
+    blocks: list
+    elem_sets: list
+
+
+#: ``element.<path>`` and ``element.<path> || '<static>'``
+_ELEMENT_KEY_RE = re.compile(
+    r"^element((?:\.[A-Za-z_][A-Za-z0-9_]*)+)(?:\s*\|\|\s*'([^'\\]*)')?$")
+#: the foreach list: ``request.object.<path>``, optionally ``|| `[]```
+_LIST_RE = re.compile(
+    r'^request\.object((?:\.[A-Za-z_][A-Za-z0-9_]*)+)(\s*\|\|\s*`\[\]`)?$')
+
+
+def _element_condition_blocks(conditions: Any) -> Optional[list]:
+    """``[(mode, [ElementCondition])]`` for per-element preconditions
+    whose keys are single {{element...}} JMESPath expressions and whose
+    values are static; None outside that shape."""
     if conditions is None:
-        return lambda element: True
+        return []
     blocks: List[Tuple[str, list]] = []
     if isinstance(conditions, dict):
         for mode in ('all', 'any'):
@@ -326,7 +362,7 @@ def _compile_element_conditions(conditions: Any) -> Optional[Callable]:
         blocks.append(('all', conditions))
     else:
         return None
-    compiled_blocks = []
+    parsed = []
     for mode, conds in blocks:
         compiled = []
         for cond in conds or []:
@@ -349,17 +385,25 @@ def _compile_element_conditions(conditions: Any) -> Optional[Callable]:
                 searcher = jp_compile(expr)
             except Exception:  # noqa: BLE001
                 return None
-            compiled.append((searcher, str(cond.get('operator', '')),
-                             value))
-        compiled_blocks.append((mode, compiled))
+            plain = _ELEMENT_KEY_RE.match(expr) if key == stripped else None
+            compiled.append(ElementCondition(
+                expr, searcher, str(cond.get('operator', '')), value,
+                tuple(plain.group(1)[1:].split('.')) if plain else None,
+                plain.group(2) if plain else None))
+        parsed.append((mode, compiled))
+    return parsed
+
+
+def _condition_evaluator(blocks: list) -> Callable:
+    """The host applier's per-element evaluation of parsed blocks."""
 
     def evaluate(element: Any) -> Optional[bool]:
         ctx = {'element': element}
-        for mode, compiled in compiled_blocks:
+        for mode, compiled in blocks:
             outcomes = []
-            for searcher, op, value in compiled:
+            for cond in compiled:
                 try:
-                    key_val = searcher.search(ctx)
+                    key_val = cond.searcher.search(ctx)
                 except Exception:  # noqa: BLE001 - engine decides
                     return None
                 if key_val is None:
@@ -367,8 +411,8 @@ def _compile_element_conditions(conditions: Any) -> Optional[Callable]:
                     # errors; anything null-ish leaves the fast path
                     return None
                 outcomes.append(operators.evaluate(
-                    None, {'key': key_val, 'operator': op,
-                           'value': value}))
+                    None, {'key': key_val, 'operator': cond.operator,
+                           'value': cond.value}))
             if mode == 'all' and not all(outcomes):
                 return False
             if mode == 'any' and outcomes and not any(outcomes):
@@ -378,11 +422,12 @@ def _compile_element_conditions(conditions: Any) -> Optional[Callable]:
     return evaluate
 
 
-def compile_foreach(foreach_list: Any, rule: dict,
-                    policy_name: str = '') -> Optional[CompiledMutation]:
-    """Single-entry foreach over a list of named maps with an inner
-    merge-by-name overlay (the imagePullPolicy shape)."""
-    rule_name = str(rule.get('name', ''))
+def parse_foreach(foreach_list: Any, rule: dict) -> Optional[ForeachShape]:
+    """The one reading of a ``foreach`` mutation, shared by the host
+    applier (:func:`compile_foreach`) and the device lowering
+    (``kyverno_tpu/mutate/plan.py``): a single entry over a list of named
+    maps with an inner merge-by-name overlay (the imagePullPolicy shape),
+    or None."""
     if rule.get('preconditions') is not None or \
             not isinstance(foreach_list, list) or len(foreach_list) != 1:
         return None
@@ -391,13 +436,13 @@ def compile_foreach(foreach_list: Any, rule: dict,
             entry.get('patchesJson6902') is not None:
         return None
     list_expr = entry.get('list', '')
-    if not isinstance(list_expr, str) or '{{' in list_expr:
+    m = _LIST_RE.match(list_expr.strip()) \
+        if isinstance(list_expr, str) else None
+    if m is None:
         return None
-    if not list_expr.startswith('request.object.'):
-        return None
-    list_path = tuple(list_expr[len('request.object.'):].split('.'))
-    cond_eval = _compile_element_conditions(entry.get('preconditions'))
-    if cond_eval is None:
+    list_path = tuple(m.group(1)[1:].split('.'))
+    blocks = _element_condition_blocks(entry.get('preconditions'))
+    if blocks is None:
         return None
     overlay = entry.get('patchStrategicMerge')
     # expected shape: the list path mirrored with ONE element dict whose
@@ -416,8 +461,23 @@ def compile_foreach(foreach_list: Any, rule: dict,
             name_ref.replace(' ', '') != '{{element.name}}':
         return None
     elem_sets = _compile_overlay(elem_overlay)
-    if elem_sets is None:
+    if elem_sets is None or any(add_only for _p, add_only, _v in elem_sets):
+        # the engine's anchor pass takes a ``+(key)`` of the element
+        # overlay to every element of the list, not only to the one the
+        # merge finds by name
         return None
+    return ForeachShape(list_path, m.group(2) is not None, blocks, elem_sets)
+
+
+def compile_foreach(foreach_list: Any, rule: dict,
+                    policy_name: str = '') -> Optional[CompiledMutation]:
+    """The host applier of a :func:`parse_foreach` shape."""
+    rule_name = str(rule.get('name', ''))
+    shape = parse_foreach(foreach_list, rule)
+    if shape is None:
+        return None
+    list_path, elem_sets = shape.list_path, shape.elem_sets
+    cond_eval = _condition_evaluator(shape.blocks)
 
     def apply(doc: dict):
         cur: Any = doc
@@ -425,6 +485,9 @@ def compile_foreach(foreach_list: Any, rule: dict,
             if not isinstance(cur, dict):
                 return _fallback(REASON_NON_DICT, rule_name, policy_name)
             cur = cur.get(part)
+        if cur is None and shape.list_default:
+            # ``|| `[]```: no list is no element, and nothing to patch
+            return (RuleStatus.PASS, _success_message(doc), False, doc)
         if not isinstance(cur, list) or \
                 not all(isinstance(e, dict) for e in cur):
             return _fallback(REASON_NON_DICT, rule_name, policy_name)

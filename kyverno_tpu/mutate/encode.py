@@ -33,6 +33,14 @@ Plus two per-resource lanes:
                 not a string, two elements of one name).  The kernel
                 falls back on -1 and on more than ``MAX_ELEMENTS``
 
+A program with ``foreach`` rules has a column of ``llen`` for each list
+they walk, by the engine's ``foreach`` (``_walk_foreach_list``), and,
+where they have per-element conditions, the condition lanes: per (row,
+condition, slot) ``ctag`` / ``cmilli`` / ``cslen`` / ``csbytes``, the
+key's value after its ``||`` default held as a leaf is, or ``ctag``
+``CTAG_ESCAPE`` where the device cannot decide.  A program without
+them has neither, and the lanes it had.
+
 The walk mirrors ``mutate_compile._apply_sets``' decision loop byte for
 byte — non-map intermediates, null-as-creatable intermediates, and the
 leaf-parent map check — and, for an element site, the host engine's
@@ -170,6 +178,113 @@ def _leaf_tag(value: Any) -> int:
     return TAG_MISSING
 
 
+def _walk_foreach_list(doc: dict, list_path: Tuple[str, ...],
+                       list_default: bool):
+    """``(llen, elements, istate)`` of the list a ``foreach`` walks, as
+    :func:`_walk_list` gives them, by the engine's ``foreach``: every
+    element is processed and merged back by its name, so the lanes stand
+    for the list only where every element is a map with a name of its
+    own that is a non-empty string; an absent list is no element where
+    the list expression ends ``|| `[]``` and the engine's error
+    otherwise.  ``llen`` -1 (a per-row fallback) for everything else."""
+    istate, value = _walk_site(doc, list_path)
+    if istate == 0 and (value is _MISSING or value is None):
+        return (0 if list_default else -1), [], 0
+    if istate != 0 or not isinstance(value, list):
+        return -1, [], 0
+    names = set()
+    for element in value:
+        name = element.get('name') if isinstance(element, dict) else None
+        if not isinstance(name, str) or not name or name in names:
+            return -1, [], 0
+        names.add(name)
+    return len(value), list(value), 0
+
+
+#: ``ctag`` of a condition the device cannot decide: the row falls back
+CTAG_ESCAPE = -1
+
+
+def _falsy(value: Any) -> bool:
+    """JMESPath's false values, which ``||`` passes over."""
+    return value is _MISSING or value is None or value is False or \
+        (isinstance(value, (str, list, dict)) and not value)
+
+
+def _condition_key(element: dict, path: Tuple[str, ...]) -> Any:
+    """What ``element.<path>`` reads: _MISSING where a map lacks the key
+    (or holds null, which the engine's context drops), None where the
+    way passes a value that is no map."""
+    cur: Any = element
+    for part in path:
+        if not isinstance(cur, dict):
+            return None
+        cur = cur.get(part)
+        if cur is None:
+            return _MISSING
+    return cur
+
+
+def condition_window(program: MutateSetProgram) -> int:
+    """Byte width of the condition lanes' string window, 8-aligned: the
+    longest string the conditions compare with."""
+    longest = 1
+    for _prog, cond in program.conditions:
+        for value in cond.values:
+            if isinstance(value, str):
+                longest = max(longest, len(value.encode('utf-8')))
+    return min(MAX_STR_WINDOW, (longest + 7) & ~7)
+
+
+def _encode_value(lanes: Dict[str, np.ndarray], prefix: str, at: tuple,
+                  value: Any, w: int) -> None:
+    """Write one value the way a site's leaf is held (tag, milli, string
+    length and window) into the lanes ``<prefix>tag`` ... at ``at``."""
+    tag = _leaf_tag(value)
+    lanes[prefix + 'tag'][at] = tag
+    if tag in (TAG_BOOL, TAG_INT, TAG_FLOAT):
+        m = exact_milli(value)
+        if m is not None:
+            lanes[prefix + 'milli'][at] = m
+            if prefix + 'milli_ok' in lanes:
+                lanes[prefix + 'milli_ok'][at] = True
+    elif tag == TAG_STRING:
+        b = value.encode('utf-8')
+        lanes[prefix + 'slen'][at] = len(b)
+        head = b[:w]
+        if head:
+            lanes[prefix + 'sbytes'][at + (slice(0, len(head)),)] = \
+                np.frombuffer(head, np.uint8)
+
+
+def _encode_conditions(lanes: Dict[str, np.ndarray], r: int,
+                       program: MutateSetProgram, walked: list,
+                       cw: int) -> None:
+    """Row ``r``'s condition lanes: per (condition, slot) of an element
+    that is there, the key's value after its ``||`` default, or
+    ``CTAG_ESCAPE`` where the engine's answer is not one the device
+    gives (an unresolved key without a default, which the engine
+    reports as a substitution error; for the ``In`` operators a list
+    key, compared as a set, or a string with a wildcard, matched both
+    ways)."""
+    for c, (prog, cond) in enumerate(program.conditions):
+        col = program.list_column(prog, prog.foreach.list_path)
+        llen, elements, _istate = walked[col]
+        for slot in range(min(max(llen, 0), MAX_ELEMENTS)):
+            key = _condition_key(elements[slot], cond.path)
+            if cond.default is not None and _falsy(key):
+                key = cond.default
+            at = (r, c, slot)
+            if key is _MISSING or key is None or (
+                    cond.operator in ('anyin', 'anynotin') and (
+                        isinstance(key, list) or (
+                            isinstance(key, str) and
+                            ('*' in key or '?' in key)))):
+                lanes['ctag'][at] = CTAG_ESCAPE
+                continue
+            _encode_value(lanes, 'c', at, key, cw)
+
+
 def encode_mutate_batch(resources: List[dict],
                         program: MutateSetProgram,
                         padded_n: int = 0,
@@ -177,14 +292,20 @@ def encode_mutate_batch(resources: List[dict],
     """Lane tensors for ``resources`` over the program's edit sites.
     ``padded_n`` is a canonical capacity (``compiler/shapes.py``):
     padding rows encode as all-MISSING and carry ``valid=False``, so
-    the kernel's edit bitmasks ignore them entirely."""
-    sites: List[EditSite] = [s for prog in program.programs
-                             for s in prog.sites]
+    the kernel's edit bitmasks ignore them entirely.  A program with
+    per-element conditions also has the condition lanes, per (row,
+    condition, slot): ``ctag`` (``CTAG_ESCAPE`` where the device cannot
+    decide), ``cmilli``, ``cslen``, ``csbytes``."""
+    site_progs = [(prog, s) for prog in program.programs
+                  for s in prog.sites]
+    sites: List[EditSite] = [s for _prog, s in site_progs]
     # an element site's (column of its list in ``llen``, slot, leaf
     # path); None for a plain site
-    splits = [split and (program.lists.index(split[0]),) + split[1:]
-              for split in (split_element_path(site.path)
-                            for site in sites)]
+    splits = []
+    for prog, site in site_progs:
+        split = split_element_path(site.path)
+        splits.append(split and
+                      (program.list_column(prog, split[0]),) + split[1:])
     n = max(len(resources), padded_n)
     s = len(sites)
     w = width or string_window(program)
@@ -196,13 +317,25 @@ def encode_mutate_batch(resources: List[dict],
         'slen': np.zeros((n, s), np.int32),
         'sbytes': np.zeros((n, s, w), np.uint8),
         'valid': np.arange(n) < len(resources),
-        'llen': np.zeros((n, max(1, len(program.lists))), np.int32),
+        'llen': np.zeros((n, program.n_list_columns), np.int32),
     }
+    nc = len(program.conditions)
+    cw = condition_window(program)
+    if nc:
+        lanes.update(
+            ctag=np.zeros((n, nc, MAX_ELEMENTS), np.int8),
+            cmilli=np.zeros((n, nc, MAX_ELEMENTS), np.int64),
+            cslen=np.zeros((n, nc, MAX_ELEMENTS), np.int32),
+            csbytes=np.zeros((n, nc, MAX_ELEMENTS, cw), np.uint8))
     for r, doc in enumerate(resources):
         walked = [_walk_list(doc, list_path)
-                  for list_path in program.lists]
+                  for list_path in program.lists] + \
+            [_walk_foreach_list(doc, list_path, list_default)
+             for list_path, list_default in program.foreach_lists]
         for col, (llen, _elements, _istate) in enumerate(walked):
             lanes['llen'][r, col] = llen
+        if nc:
+            _encode_conditions(lanes, r, program, walked, cw)
         for k, site in enumerate(sites):
             if splits[k] is None:
                 istate, value = _walk_site(doc, site.path)
@@ -218,18 +351,5 @@ def encode_mutate_batch(resources: List[dict],
                     else:
                         istate, value = _walk_site(element, leaf_path)
             lanes['istate'][r, k] = istate
-            tag = _leaf_tag(value)
-            lanes['tag'][r, k] = tag
-            if tag in (TAG_BOOL, TAG_INT, TAG_FLOAT):
-                m = exact_milli(value)
-                if m is not None:
-                    lanes['milli'][r, k] = m
-                    lanes['milli_ok'][r, k] = True
-            elif tag == TAG_STRING:
-                b = value.encode('utf-8')
-                lanes['slen'][r, k] = len(b)
-                head = b[:w]
-                if head:
-                    lanes['sbytes'][r, k, :len(head)] = \
-                        np.frombuffer(head, np.uint8)
+            _encode_value(lanes, '', (r, k), value, w)
     return lanes

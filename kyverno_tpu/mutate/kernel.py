@@ -21,7 +21,21 @@ outputs:
                        intermediate, 3 = equality undecidable in the
                        encoded lanes, 4 = a list of an element site is
                        longer than ``MAX_ELEMENTS`` or of a shape the
-                       lanes cannot stand for (``llen`` -1)
+                       lanes cannot stand for (``llen`` -1), 5 = a
+                       per-element condition the device cannot decide
+                       (``CTAG_ESCAPE``; the engine answers the row)
+
+A program with ``foreach`` rules compares each per-element condition's
+key lanes with its static values, ANDs the ``all`` block (and ORs the
+``any`` block) per (row, rule, slot), gates every element site of the
+rule at that slot on the result, and returns a fourth output:
+
+  elem    i32 [R, NR]  of a foreach rule, bit i: its conditions held at
+                       slot i; bit ``MAX_ELEMENTS`` + i: one of them
+                       could not be decided there (0 for other rules)
+
+A program without them computes exactly what it did before foreach
+lowered, and returns three.
 
 The kernel is intentionally tiny (a few element-wise ops and one
 masked reduction per output) — it is not AOT-persisted; XLA
@@ -40,8 +54,10 @@ import numpy as np
 
 from ..compiler.ir import TAG_BOOL, TAG_FLOAT, TAG_INT, TAG_MISSING, \
     TAG_STRING
-from .encode import ISTATE_NO_ELEMENT, exact_milli, string_window
-from .plan import MAX_ELEMENTS, MutateSetProgram, split_element_path
+from .encode import (CTAG_ESCAPE, ISTATE_NO_ELEMENT, condition_window,
+                     exact_milli, string_window)
+from .plan import (CONDITION_OPERATORS, MAX_ELEMENTS, MutateSetProgram,
+                   split_element_path)
 
 #: per-(resource, rule) device statuses
 MUT_SKIP = 0
@@ -54,6 +70,7 @@ RC_REPLACE_MISSING = 1
 RC_NON_DICT = 2
 RC_UNDECIDABLE = 3
 RC_LIST_SHAPE = 4
+RC_PRECONDITION_ESCAPE = 5
 
 
 class MutateKernel:
@@ -79,7 +96,7 @@ class MutateKernel:
         self._bit_w = np.zeros(s, np.int64)
         # list → rule selector: the rules with an element site on the
         # list of each ``llen`` column
-        self._list_rules = np.zeros((max(1, len(program.lists)),
+        self._list_rules = np.zeros((program.n_list_columns,
                                      self.n_rules), bool)
         for idx, (ri, k, site) in enumerate(sites):
             v = site.value
@@ -99,8 +116,85 @@ class MutateKernel:
             self._bit_w[idx] = np.int64(1) << np.int64(k)
             split = split_element_path(site.path)
             if split is not None:
-                self._list_rules[program.lists.index(split[0]), ri] = True
+                self._list_rules[program.list_column(
+                    program.programs[ri], split[0]), ri] = True
+        self.has_foreach = program.has_foreach
+        if self.has_foreach:
+            self._init_conditions(program, sites)
         self._jitted = None
+
+    def _init_conditions(self, program: MutateSetProgram, sites) -> None:
+        """The compile-time side of the per-element conditions: the
+        static values each compares with, which rule's ``all`` / ``any``
+        block it is in, and for each site of a ``foreach`` rule the
+        (rule, slot) whose conditions gate it."""
+        e, nr = MAX_ELEMENTS, self.n_rules
+        conds = program.conditions
+        c = len(conds)
+        k = max([len(cond.values) for _p, cond in conds] + [1])
+        cw = condition_window(program)
+        self._c_op = np.zeros((c, 1), np.int8)
+        self._c_is_bool = np.zeros((c, 1), bool)
+        self._c_milli = np.zeros((c, 1), np.int64)
+        self._c_len = np.full((c, 1, k), -1, np.int32)
+        self._c_bytes = np.zeros((c, 1, k, cw), np.uint8)
+        self._c_all = np.zeros((c, nr, 1), bool)
+        self._c_any = np.zeros((c, nr, 1), bool)
+        for i, (prog, cond) in enumerate(conds):
+            self._c_op[i] = CONDITION_OPERATORS.index(cond.operator)
+            for j, value in enumerate(cond.values):
+                if isinstance(value, bool):
+                    self._c_is_bool[i] = True
+                    self._c_milli[i] = exact_milli(value)
+                else:
+                    b = value.encode('utf-8')
+                    self._c_len[i, 0, j] = len(b)
+                    self._c_bytes[i, 0, j, :len(b)] = np.frombuffer(
+                        b, np.uint8)
+            block = self._c_all if cond.mode == 'all' else self._c_any
+            block[i, prog.rule_index] = True
+        self._has_any = self._c_any.any(axis=0)[None]       # [1, NR, 1]
+        self._foreach_rule = np.array(
+            [p.foreach is not None for p in program.programs])
+        self._site_gated = np.array(
+            [program.programs[ri].foreach is not None
+             for ri, _k, _s in sites], bool)
+        self._site_cell = np.array(
+            [ri * e + (split_element_path(site.path)[1]
+                       if program.programs[ri].foreach is not None else 0)
+             for ri, _k, site in sites], np.int32)
+        self._slot_w = (np.int32(1) << np.arange(e, dtype=np.int32))
+
+    def _conditions(self, lanes, n: int):
+        """``(met, escaped)`` per (row, rule, slot): the rule's conditions
+        all held at the element of that slot (and one of its ``any``
+        block, where it has one), and whether one of them could not be
+        decided here."""
+        import jax.numpy as jnp
+        e, nr = MAX_ELEMENTS, self.n_rules
+        if not self._c_op.size:
+            return (jnp.ones((n, nr, e), bool), jnp.zeros((n, nr, e), bool))
+        ctag = lanes['ctag']
+        is_str = ctag == TAG_STRING
+        same = jnp.all(lanes['csbytes'][:, :, :, None, :] ==
+                       self._c_bytes[None], axis=-1)
+        member = jnp.any(is_str[..., None] &
+                         (lanes['cslen'][..., None] == self._c_len[None]) &
+                         same, axis=-1)                      # [n, C, E]
+        eq = jnp.where(self._c_is_bool,
+                       (ctag == TAG_BOOL) &
+                       (lanes['cmilli'] == self._c_milli), member)
+        str_or_num = is_str | (ctag == TAG_INT) | (ctag == TAG_FLOAT)
+        op = self._c_op
+        held = jnp.where(op == 0, eq, jnp.where(
+            op == 1, ~eq, jnp.where(op == 2, member, str_or_num & ~member)))
+        esc = ctag == CTAG_ESCAPE
+        # [n, C, 1, E] against the blocks [C, NR, 1]: reduce over C
+        held4, esc4 = held[:, :, None, :], esc[:, :, None, :]
+        all_ok = ~jnp.any(self._c_all & ~held4, axis=1)
+        any_ok = jnp.any(self._c_any & held4, axis=1) | ~self._has_any
+        escaped = jnp.any((self._c_all | self._c_any) & esc4, axis=1)
+        return all_ok & any_ok, escaped
 
     def mutate_eval(self, lanes):
         import jax.numpy as jnp
@@ -130,6 +224,15 @@ class MutateKernel:
                          jnp.where(self._add_only, False,
                                    present & ~eq))
         rep_bad = self._replace & ((istate != 0) | missing)
+        if self.has_foreach:
+            # a foreach rule's site is decided only at an element whose
+            # conditions held: the engine leaves the others alone
+            n = tag.shape[0]
+            met, escaped = self._conditions(lanes, n)
+            gate = jnp.where(
+                self._site_gated,
+                met.reshape(n, -1)[:, self._site_cell], True)
+            edit, bad, undec = edit & gate, bad & gate, undec & gate
 
         # per-rule reductions over the rule's own sites, as masked
         # reduces: the TPU has no s64 matrix product (its 64-bit
@@ -148,31 +251,45 @@ class MutateKernel:
             ((llen < 0) | (llen > MAX_ELEMENTS))[:, :, None] &
             self._list_rules, axis=1)
         fb = rep_any | list_any | bad_any | undec_any
+        if self.has_foreach:
+            esc_any = jnp.any(escaped, axis=2)
+            fb = fb | esc_any
         status = jnp.where(
             fb, MUT_FALLBACK,
             jnp.where(edits != 0, MUT_PASS, MUT_SKIP)).astype(jnp.int8)
         # first-fault reason in the host fast path's check order:
-        # replace guard, then the walk (a list's shape before the maps
-        # under it), then equality
+        # replace guard, then the walk (a list's shape before its
+        # elements' conditions, and those before the maps under them),
+        # then equality
+        walk = jnp.where(bad_any, RC_NON_DICT,
+                         jnp.where(undec_any, RC_UNDECIDABLE, RC_NONE))
+        if self.has_foreach:
+            walk = jnp.where(esc_any, RC_PRECONDITION_ESCAPE, walk)
         reason = jnp.where(
             rep_any, RC_REPLACE_MISSING,
-            jnp.where(list_any, RC_LIST_SHAPE,
-                      jnp.where(bad_any, RC_NON_DICT,
-                                jnp.where(undec_any, RC_UNDECIDABLE,
-                                          RC_NONE)))).astype(jnp.int8)
+            jnp.where(list_any, RC_LIST_SHAPE, walk)).astype(jnp.int8)
         # ragged batches: capacity-padding rows (all-MISSING leaves
         # would otherwise read as "every edit applies") are masked to
         # SKIP / empty-bitmask / no-reason inside the program
         valid = lanes.get('valid')
+        vcol = valid[:, None] if valid is not None else True
         if valid is not None:
-            vcol = valid[:, None]
             status = jnp.where(vcol, status, MUT_SKIP).astype(jnp.int8)
             edits = jnp.where(vcol, edits, 0)
             reason = jnp.where(vcol, reason, RC_NONE).astype(jnp.int8)
-        return status, edits, reason
+        if not self.has_foreach:
+            return status, edits, reason
+        # a foreach rule's elements: bit i, the conditions held at slot
+        # i; bit MAX_ELEMENTS + i, one could not be decided there
+        elem = jnp.sum(jnp.where(met, self._slot_w, 0), axis=2) + \
+            (jnp.sum(jnp.where(escaped, self._slot_w, 0), axis=2)
+             << MAX_ELEMENTS)
+        elem = jnp.where(vcol & self._foreach_rule, elem,
+                         0).astype(jnp.int32)
+        return status, edits, reason, elem
 
-    def __call__(self, lanes: Dict[str, np.ndarray]
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    def __call__(self, lanes: Dict[str, np.ndarray]) -> Tuple[np.ndarray,
+                                                              ...]:
         n = lanes['tag'].shape[0]
         if self.n_sites == 0:
             return (np.zeros((n, self.n_rules), np.int8),

@@ -12,7 +12,18 @@ list, and lowers to **element sites**, one per leaf and element slot
 (``('spec', 'containers', 2, 'imagePullPolicy')``).  The device program
 then decides, per (resource, site), whether the edit applies, and emits
 a compact per-rule edit bitmask the host decodes back into patched JSON
-(``scanner.py``).  Anything outside that vocabulary — foreach, contexts,
+(``scanner.py``).  A ``foreach`` lowers where
+``compiler/mutate_compile.py`` ``parse_foreach`` reads it (one entry over
+``request.object.<list>``, optionally ``|| `[]```, merging static leaves
+by ``{{element.name}}``) and its per-element conditions are in the
+device's vocabulary (keys ``element.<path>`` with an optional ``||
+'<static>'`` default, ``Equals`` / ``NotEquals`` against a plain string
+or a bool, ``AnyIn`` / ``AnyNotIn`` against plain strings): its leaves
+become element sites of the list, gated on the device by the
+conditions at each slot (``ForeachProgram``).  The rules are the ones
+the engine runs, autogen's included, so a Pod rule also lowers on
+``spec.template`` and ``spec.jobTemplate.spec.template``.  Anything
+outside that vocabulary — other foreach shapes, contexts, rule-level
 preconditions, variables, anchors needing live lookups, any other list
 patch (several maps, other anchors, scalars, nested lists, appends),
 null values (RFC-7386 deletes), non-scalar values — does NOT lower and
@@ -24,7 +35,8 @@ the device decides every rule against the ORIGINAL document.  The two
 agree exactly when (a) every lowered rule's match block is simple
 (kinds/namespaces/operations — unaffected by scalar edits that cannot
 touch identity fields) and (b) no two rules' edit sites overlap in the
-prefix-or-equal sense.  ``compile_mutate_set`` enforces both; a set
+prefix-or-equal sense, nor one rule's sites the paths another's
+conditions read.  ``compile_mutate_set`` enforces both; a set
 that violates them places every mutate rule on the host with reason
 ``edit_site_conflict`` / ``policy_coupling``.
 """
@@ -34,8 +46,10 @@ from __future__ import annotations
 from typing import Any, List, NamedTuple, Optional, Tuple
 
 from ..api.policy import Policy, Rule
+from ..autogen.autogen import compute_rules
 from ..observability import coverage
-from ..compiler.mutate_compile import _compile_overlay, parse_json6902_sets
+from ..compiler.mutate_compile import (_compile_overlay, parse_foreach,
+                                       parse_json6902_sets)
 
 #: edit bitmask budget: one i32 lane per (resource, rule)
 MAX_SITES = 32
@@ -68,29 +82,69 @@ class EditSite(NamedTuple):
     replace: bool           # json6902 replace: whole path must exist
 
 
+#: the operators a per-element condition may use on the device
+CONDITION_OPERATORS = ('equals', 'notequals', 'anyin', 'anynotin')
+#: static strings an ``AnyIn`` / ``AnyNotIn`` value may list
+MAX_CONDITION_VALUES = 8
+
+
+class DeviceCondition(NamedTuple):
+    """A per-element condition the device decides: the key's element
+    path and its ``|| '<static>'`` default (None: the key has none), the
+    operator in lower case, and the static values compared with (one for
+    ``equals`` / ``notequals``, a str or a bool; the strings of the list
+    for the ``In`` pair).  ``mode`` is its block, ``all`` or ``any``."""
+    mode: str
+    path: tuple
+    default: Optional[str]
+    operator: str
+    values: tuple
+
+
+class ForeachProgram(NamedTuple):
+    """What a lowered ``foreach`` adds to its rule's element sites: the
+    list it walks, whether an absent list is no element (``|| `[]```)
+    or the engine's error, and its per-element conditions."""
+    list_path: tuple
+    list_default: bool
+    conditions: Tuple[DeviceCondition, ...]
+
+
 class RuleMutateProgram:
     """One lowered mutate rule: its edit sites + response metadata.
 
     ``path``/``pss`` satisfy the coverage ledger's program duck type
     (``ScanTally`` reads both), so mutate rows land on the ledger as
-    ``path="mutate"`` next to validate/pss rows.
+    ``path="mutate"`` next to validate/pss rows.  A ``foreach`` rule
+    carries its :class:`ForeachProgram`; its sites are element sites
+    of that list, one per leaf and slot.
     """
 
     pss = None
     path = 'mutate'
 
     __slots__ = ('policy_name', 'rule_name', 'rule', 'kind', 'sites',
-                 'policy_index', 'rule_index')
+                 'policy_index', 'rule_index', 'foreach')
 
     def __init__(self, policy_name: str, rule_name: str, rule: Rule,
-                 kind: str, sites: Tuple[EditSite, ...]):
+                 kind: str, sites: Tuple[EditSite, ...],
+                 foreach: Optional[ForeachProgram] = None):
         self.policy_name = policy_name
         self.rule_name = rule_name
         self.rule = rule
-        self.kind = kind              # 'strategic' | 'json6902'
+        self.kind = kind              # 'strategic' | 'json6902' | 'foreach'
         self.sites = sites
         self.policy_index = -1        # filled by compile_mutate_set
         self.rule_index = -1
+        self.foreach = foreach
+
+    def read_paths(self) -> List[tuple]:
+        """The paths its conditions read, one per condition and slot."""
+        if self.foreach is None:
+            return []
+        return [self.foreach.list_path + (i,) + cond.path
+                for cond in self.foreach.conditions
+                for i in range(MAX_ELEMENTS)]
 
 
 def _identity_site(path: tuple) -> bool:
@@ -150,9 +204,6 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
         raise LowerError(coverage.REASON_HOST_CLOSURE,
                          'mutate-existing rides the UpdateRequest '
                          'pipeline')
-    if mutation.get('foreach') is not None:
-        raise LowerError(coverage.REASON_UNSUPPORTED_OPERATOR,
-                         'foreach mutation keeps the host fast path')
     from ..compiler.scan import _rule_match_is_simple
     if not _rule_match_is_simple(raw):
         raise LowerError(
@@ -160,6 +211,8 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
             'non-simple match: the cumulative chain re-matches per '
             'policy, so only kind/namespace/operation matches are '
             'stable under device edits')
+    if mutation.get('foreach') is not None:
+        return _lower_foreach(rule, mutation['foreach'], policy_name)
     overlay = mutation.get('patchStrategicMerge')
     json6902 = mutation.get('patchesJson6902')
     if overlay is not None and not json6902:
@@ -191,6 +244,80 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
                      'empty or mixed patch document')
 
 
+def _comparable_string(value: Any) -> bool:
+    """A static string the engine's ``Equals`` and ``In`` operators
+    compare with a string key by plain equality: no wildcard, and not a
+    duration, a quantity or a number (each compares by value there)."""
+    from ..engine import operators
+    if not isinstance(value, str) or '*' in value or '?' in value or \
+            operators._try_duration(value) is not None or \
+            operators._try_quantity(value) is not None:
+        return False
+    try:
+        float(value)
+    except ValueError:
+        return True
+    return False
+
+
+def _device_condition(mode: str, cond) -> DeviceCondition:
+    """One parsed ``ElementCondition`` in the device's vocabulary, or a
+    :class:`LowerError`."""
+    op = cond.operator.lower()
+    if cond.path is None:
+        raise LowerError(
+            coverage.REASON_UNSUPPORTED_OPERATOR,
+            f'condition key {cond.expr!r} is not element.<path> '
+            f"[|| '<static>']")
+    if op not in CONDITION_OPERATORS:
+        raise LowerError(coverage.REASON_UNSUPPORTED_OPERATOR,
+                         f'condition operator {cond.operator!r}')
+    value = cond.value
+    if op in ('equals', 'notequals'):
+        ok = isinstance(value, bool) or _comparable_string(value)
+        values = (value,)
+    else:
+        ok = isinstance(value, list) and \
+            0 < len(value) <= MAX_CONDITION_VALUES and \
+            all(_comparable_string(v) for v in value)
+        values = tuple(value) if ok else ()
+    if not ok:
+        raise LowerError(
+            coverage.REASON_UNSUPPORTED_OPERATOR,
+            f'condition value {value!r} of {cond.operator} is not a '
+            f'plain string or a bool (a list of such strings for the In '
+            f'operators)')
+    return DeviceCondition(mode, cond.path, cond.default, op, values)
+
+
+def _lower_foreach(rule: Rule, foreach_list: Any,
+                   policy_name: str) -> RuleMutateProgram:
+    """A ``foreach`` that :func:`parse_foreach` reads, with conditions
+    in the device's vocabulary, lowered to element sites of its list."""
+    raw = rule.raw
+    shape = parse_foreach(foreach_list, raw)
+    if shape is None or not shape.elem_sets:
+        raise LowerError(
+            coverage.REASON_UNSUPPORTED_OPERATOR,
+            'foreach outside one entry over request.object.<list> with a '
+            'merge-by-name overlay of static leaves and element '
+            'conditions')
+    conditions = []
+    for mode, conds in shape.blocks:
+        if mode == 'any' and not conds:
+            raise LowerError(coverage.REASON_UNSUPPORTED_OPERATOR,
+                             'an empty any block is never met')
+        conditions.extend(_device_condition(mode, c) for c in conds)
+    sites = _check_sites([
+        EditSite(shape.list_path + (i,) + leaf, add_only, value, False)
+        for i in range(MAX_ELEMENTS)
+        for leaf, add_only, value in shape.elem_sets])
+    return RuleMutateProgram(
+        policy_name, str(raw.get('name', '')), rule, 'foreach', sites,
+        ForeachProgram(shape.list_path, shape.list_default,
+                       tuple(conditions)))
+
+
 def _paths_conflict(a: tuple, b: tuple) -> bool:
     """Prefix-or-equal overlap.  An element slot is one more path part,
     so two element paths are compared slot by slot, and a plain path
@@ -218,7 +345,11 @@ class MutateSetProgram:
         failures: List[Tuple[int, Rule, LowerError]] = []
         lowered: List[Tuple[int, RuleMutateProgram]] = []
         for pi, policy in enumerate(self.policies):
-            mutate_rules = [r for r in policy.rules if r.has_mutate()]
+            # the rules the engine runs: autogen's too, on their
+            # template paths (spec.template, spec.jobTemplate.spec.
+            # template), in the engine's order
+            mutate_rules = [r for r in map(Rule, compute_rules(policy))
+                            if r.has_mutate()]
             if (policy.apply_rules or 'All') == 'One' and \
                     len(mutate_rules) > 1:
                 self.device_ok = False
@@ -236,8 +367,10 @@ class MutateSetProgram:
                     continue
                 prog.policy_index = pi
                 lowered.append((pi, prog))
-        # cross-rule edit-site conflicts: prefix-or-equal overlap makes
-        # original-document decisions order-dependent
+        # cross-rule conflicts: a prefix-or-equal overlap of one rule's
+        # edit sites with another's sites, or with the paths another's
+        # conditions read, makes original-document decisions
+        # order-dependent (the chain is cumulative)
         conflicted: set = set()
         for i in range(len(lowered)):
             for j in range(i + 1, len(lowered)):
@@ -245,11 +378,14 @@ class MutateSetProgram:
                 pb, b = lowered[j]
                 if a is b:
                     continue
-                for sa in a.sites:
-                    for sb in b.sites:
-                        if _paths_conflict(sa.path, sb.path):
-                            conflicted.add(id(a))
-                            conflicted.add(id(b))
+                a_writes = [s.path for s in a.sites]
+                b_writes = [s.path for s in b.sites]
+                pairs = [(x, y) for x in a_writes
+                         for y in b_writes + b.read_paths()] + \
+                    [(x, y) for x in a.read_paths() for y in b_writes]
+                if any(_paths_conflict(x, y) for x, y in pairs):
+                    conflicted.add(id(a))
+                    conflicted.add(id(b))
         if conflicted:
             self.device_ok = False
         # placements: device across the board, or host with the most
@@ -285,10 +421,26 @@ class MutateSetProgram:
                 'mutate', coverage.PLACEMENT_HOST, e.reason, e.detail,
                 pi))
 
-        #: the distinct list paths the device programs' element sites
+        #: the distinct list paths the anchored overlays' element sites
         #: walk, in first-seen order: one ``llen`` lane column each
         self.lists: List[tuple] = []
+        #: the same of the ``foreach`` rules, with whether an absent list
+        #: is no element: their columns follow, since a foreach walks a
+        #: list by its own rules (an element without a name is the
+        #: engine's error, not an element to leave alone)
+        self.foreach_lists: List[Tuple[tuple, bool]] = []
+        #: every per-element condition, ``(program, condition)``: one
+        #: row of condition lanes each
+        self.conditions: List[Tuple[RuleMutateProgram,
+                                    DeviceCondition]] = []
         for prog in self.programs:
+            if prog.foreach is not None:
+                key = (prog.foreach.list_path, prog.foreach.list_default)
+                if key not in self.foreach_lists:
+                    self.foreach_lists.append(key)
+                self.conditions.extend((prog, cond)
+                                       for cond in prog.foreach.conditions)
+                continue
             for site in prog.sites:
                 split = split_element_path(site.path)
                 if split is not None and split[0] not in self.lists:
@@ -297,6 +449,24 @@ class MutateSetProgram:
     @property
     def n_sites(self) -> int:
         return sum(len(p.sites) for p in self.programs)
+
+    @property
+    def has_foreach(self) -> bool:
+        return bool(self.foreach_lists)
+
+    @property
+    def n_list_columns(self) -> int:
+        """Columns of the ``llen`` lane (one zero column where no site
+        walks a list)."""
+        return max(1, len(self.lists) + len(self.foreach_lists))
+
+    def list_column(self, prog: RuleMutateProgram, list_path: tuple) -> int:
+        """The ``llen`` column of the list ``prog``'s element sites on
+        ``list_path`` walk."""
+        if prog.foreach is None:
+            return self.lists.index(list_path)
+        return len(self.lists) + self.foreach_lists.index(
+            (prog.foreach.list_path, prog.foreach.list_default))
 
 
 def compile_mutate_set(policies: List[Policy]) -> MutateSetProgram:

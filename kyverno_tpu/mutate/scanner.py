@@ -14,7 +14,9 @@ evaluates admission batches as one device dispatch:
    ``apply_edit_list`` copy-on-write patch → ``generate_patches`` diff
    → the exact ``EngineResponse`` the handler's engine loop would have
    produced (PASS message via ``_success_message``, SKIP as
-   ``no patches applied``)
+   ``no patches applied``); a ``foreach`` rule merges its elements one
+   at a time from the last, appending each one's patches, and is PASS
+   whether or not one changed, as the engine's ``ForEachMutator`` is
 
 FALLBACK rows re-run the faulting policy on the host engine with the
 row's cumulative ``PolicyContext`` — and every *later* policy of that
@@ -29,7 +31,11 @@ old_resources``), so mutate tickets ride the same queue and coalescing
 loop as validate tickets.  Its four steps are leaf stages of
 ``observability/device.py`` ``stage()``: ``mutate_match``,
 ``mutate_encode``, ``mutate_eval`` (the jitted call to its results on
-the host: it is synchronous) and ``mutate_decode``.
+the host: it is synchronous) and ``mutate_decode``; inside the last, once
+a dispatch, ``last_counts`` (rows by the autogen path their kind takes,
+foreach elements by what the device decided) is counted from the
+kernel's outputs, for the batcher's ``stats()`` and the counters
+``kyverno_tpu_mutate_rows_total`` / ``_element_cells_total``.
 """
 
 from __future__ import annotations
@@ -51,11 +57,13 @@ from ..compiler.mutate_compile import apply_edit_list
 from ..observability import coverage
 from ..observability import device as devtel
 from ..observability.metrics import global_registry
+from ..autogen.autogen import POD_CONTROLLER_CRONJOB, POD_CONTROLLERS
 from .encode import encode_mutate_batch, string_window
 from .kernel import (MUT_FALLBACK, MUT_PASS, MUT_SKIP, RC_LIST_SHAPE,
-                     RC_NON_DICT, RC_REPLACE_MISSING, RC_UNDECIDABLE,
-                     MutateKernel)
-from .plan import MutateSetProgram, compile_mutate_set
+                     RC_NON_DICT, RC_PRECONDITION_ESCAPE, RC_REPLACE_MISSING,
+                     RC_UNDECIDABLE, MutateKernel)
+from .plan import (MAX_ELEMENTS, MutateSetProgram, compile_mutate_set,
+                   split_element_path)
 
 MUTATE_EDITS = 'kyverno_tpu_mutate_device_edits_total'
 
@@ -64,7 +72,17 @@ _RC_REASON = {
     RC_NON_DICT: coverage.REASON_NON_DICT,
     RC_UNDECIDABLE: coverage.REASON_PATCH_UNDECIDABLE,
     RC_LIST_SHAPE: coverage.REASON_LIST_SHAPE,
+    RC_PRECONDITION_ESCAPE: coverage.REASON_PRECONDITION_ESCAPE,
 }
+
+ROW_PATHS = devtel.MUTATE_ROW_PATHS
+ELEMENT_RESULTS = devtel.MUTATE_ELEMENT_RESULTS
+#: the autogen path a row's kind takes: the Pod's own fields, a
+#: controller's ``spec.template`` or a CronJob's
+#: ``spec.jobTemplate.spec.template`` (any kind autogen does not convert
+#: counts as ``pod``: its rules read its own fields)
+_KIND_PATH = {kind: 'template' for kind in POD_CONTROLLERS.split(',')}
+_KIND_PATH[POD_CONTROLLER_CRONJOB] = 'cronjob'
 
 
 class MutateScanner:
@@ -100,6 +118,20 @@ class MutateScanner:
         self.fingerprint = policy_set_fingerprint(self.policies)
         self._kernel = MutateKernel(self.program) if self.ok else None
         self._width = string_window(self.program) if self.ok else 0
+        #: per foreach program (by rule index): the slot of each of its
+        #: sites, and the bits of its edit mask that belong to each slot
+        self._site_slots = {}
+        self._slot_masks = {}
+        for prog in self.program.programs if self.ok else ():
+            if prog.foreach is not None:
+                slots = [split_element_path(s.path)[1] for s in prog.sites]
+                self._site_slots[prog.rule_index] = slots
+                self._slot_masks[prog.rule_index] = [
+                    sum(1 << k for k, at in enumerate(slots) if at == i)
+                    for i in range(MAX_ELEMENTS)]
+        #: counts of the last scan: rows by ``ROW_PATHS`` and foreach
+        #: elements by ``ELEMENT_RESULTS`` (the batcher sums them)
+        self.last_counts = dict.fromkeys(ROW_PATHS + ELEMENT_RESULTS, 0)
 
     def warmup(self) -> float:
         """Compile the admission-shape kernel bucket before traffic."""
@@ -162,12 +194,45 @@ class MutateScanner:
                                         padded_n=canonical_capacity(n),
                                         width=self._width)
         with devtel.stage('mutate_eval', {'rows': n}):
-            status, edits, reason = self._kernel(lanes)
+            out = self._kernel(lanes)
+        status, edits, reason = out[:3]
         self.last_fallback_rows = 0
         with devtel.stage('mutate_decode', {'rows': n}):
+            self._count(resources, match, lanes['llen'][:n], edits[:n],
+                        out[3][:n] if len(out) > 3 else None)
             return [self._decode_row(resources[i], match[i], status[i],
                                      edits[i], reason[i], pctx_factory)
                     for i in range(n)]
+
+    def _count(self, resources, match, llen, edits, elem) -> None:
+        """``last_counts`` of one dispatch, from the kernel's outputs:
+        its rows by the autogen path their kind takes, and every (row,
+        matched foreach rule, element there) by what the device decided
+        for it; into the registry too where metrics are on."""
+        counts = dict.fromkeys(ROW_PATHS + ELEMENT_RESULTS, 0)
+        for doc in resources:
+            counts[_KIND_PATH.get(doc.get('kind'), 'pod')] += 1
+        for j, masks in self._slot_masks.items():
+            prog = self.program.programs[j]
+            col = llen[:, self.program.list_column(
+                prog, prog.foreach.list_path)]
+            # elements the lanes stand for: none where the row falls
+            # back for its list's shape
+            there = np.where((col >= 0) & (col <= MAX_ELEMENTS), col, 0)
+            for i in range(MAX_ELEMENTS):
+                cell = match[:, j] & (there > i)
+                escaped = cell & ((elem[:, j] >> (MAX_ELEMENTS + i)) & 1
+                                  ).astype(bool)
+                met = cell & ~escaped & ((elem[:, j] >> i) & 1).astype(bool)
+                edited = met & ((edits[:, j] & masks[i]) != 0)
+                counts['escaped'] += int(escaped.sum())
+                counts['unmet'] += int((cell & ~escaped & ~met).sum())
+                counts['edited'] += int(edited.sum())
+                counts['kept'] += int((met & ~edited).sum())
+        self.last_counts = counts
+        devtel.record_mutate_counts(
+            {path: counts[path] for path in ROW_PATHS},
+            {result: counts[result] for result in ELEMENT_RESULTS})
 
     # -- decode -----------------------------------------------------------
 
@@ -242,7 +307,12 @@ class MutateScanner:
                 tally.total_rows += 1
             st = int(status[j])
             rule_start = time.time()
-            if st == MUT_SKIP:
+            if prog.foreach is not None:
+                rr, cum, applied = self._foreach_response(
+                    prog, int(edits[j]), cum)
+                if registry is not None and applied:
+                    registry.inc(MUTATE_EDITS, float(applied))
+            elif st == MUT_SKIP:
                 rr = RuleResponse(prog.rule_name, RuleType.MUTATION,
                                   'no patches applied', RuleStatus.SKIP,
                                   patches=None)
@@ -271,3 +341,29 @@ class MutateScanner:
         resp.patched_resource = cum
         self.engine._build_response(ctx, resp, start)
         return resp
+
+    def _foreach_response(self, prog, mask: int, cum: dict):
+        """``(RuleResponse, patched, edits applied)`` of one foreach
+        rule the device decided, as the engine's ``ForEachMutator``
+        gives it: the elements merged one at a time from the last to
+        the first, each one's patches appended in that order, and PASS
+        with the success message whether or not an element changed
+        (one entry processed)."""
+        slots = self._site_slots[prog.rule_index]
+        patches: list = []
+        applied = 0
+        for slot in reversed(range(MAX_ELEMENTS)):
+            changes = [(site.path, site.value)
+                       for k, site in enumerate(prog.sites)
+                       if mask >> k & 1 and slots[k] == slot]
+            if not changes:
+                continue
+            patched = apply_edit_list(cum, changes)
+            if patched is None:
+                raise RuntimeError('edit list failed to apply')
+            patches.extend(generate_patches(cum, patched))
+            cum = patched
+            applied += len(changes)
+        return (RuleResponse(prog.rule_name, RuleType.MUTATION,
+                             _success_message(cum), RuleStatus.PASS,
+                             patches=patches), cum, applied)

@@ -242,6 +242,19 @@ METRICS: Dict[str, Metric] = {
     'kyverno_tpu_mutate_device_edits_total': Metric(
         'counter', 'Individual edits applied from device-decided '
         'mutate edit lists.'),
+    'kyverno_tpu_mutate_rows_total': Metric(
+        'counter', 'Rows of the device mutate dispatches by the autogen '
+        'path their kind takes (mutate/scanner.py _count): path=pod (a '
+        'Pod, or a kind autogen does not convert)|template (a '
+        'controller\'s spec.template)|cronjob (spec.jobTemplate.spec.'
+        'template).'),
+    'kyverno_tpu_mutate_element_cells_total': Metric(
+        'counter', '(row, matched foreach rule, element there) cells of '
+        'the device mutate dispatches, one count a dispatch where decode '
+        'reads the kernel\'s outputs: result=edited (its conditions held '
+        'and the device wrote a field)|kept (they held and the fields '
+        'had their values)|unmet|escaped (a condition the device cannot '
+        'decide: the row falls back to the host engine).'),
     # decision provenance (observability/provenance.py)
     'kyverno_tpu_decision_duration_seconds': Metric(
         'histogram', 'End-to-end per-decision latency by serving '

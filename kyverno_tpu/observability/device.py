@@ -49,6 +49,12 @@ CONTEXT_LOOKUPS = 'kyverno_tpu_context_lookups_total'
 CONTEXT_LOADS = 'kyverno_tpu_context_loads_total'
 FAIL_MESSAGE_MEMO = 'kyverno_tpu_fail_message_memo_total'
 MATCH_CELLS = 'kyverno_tpu_match_cells_total'
+MUTATE_ELEMENT_CELLS = 'kyverno_tpu_mutate_element_cells_total'
+MUTATE_ROWS = 'kyverno_tpu_mutate_rows_total'
+#: the labels of those two: the autogen path a mutate row's kind takes,
+#: and what the device decided for one element of a foreach rule
+MUTATE_ROW_PATHS = ('pod', 'template', 'cronjob')
+MUTATE_ELEMENT_RESULTS = ('edited', 'kept', 'unmet', 'escaped')
 
 #: canonical stage labels.  The pipeline's, in order: ``match`` (host
 #: match sieve), ``encode`` (in a worker process or inline),
@@ -555,6 +561,25 @@ def record_match_cells(match) -> None:
         if match.size - matched:
             _registry.inc(MATCH_CELLS, float(match.size - matched),
                           result='unmatched')
+
+
+def record_mutate_counts(rows: dict, cells: dict) -> None:
+    """One mutate dispatch as its decode read the kernel's outputs
+    (``mutate/scanner.py`` ``_count``): its rows by the autogen path
+    their kind takes (``pod``, ``template``, ``cronjob``), and the (row,
+    matched foreach rule, element there) cells by what the device
+    decided (``edited``, ``kept``: the conditions held and the fields
+    already had their values, ``unmet``, ``escaped``: a condition the
+    device could not decide, so the row fell back).  Nothing where
+    metrics are off."""
+    if _registry is None:
+        return
+    for path, n in rows.items():
+        if n:
+            _registry.inc(MUTATE_ROWS, float(n), path=path)
+    for result, n in cells.items():
+        if n:
+            _registry.inc(MUTATE_ELEMENT_CELLS, float(n), result=result)
 
 
 def record_fail_message_memo(hits: int, misses: int) -> None:

@@ -75,6 +75,10 @@ class Cache:
         self._kind_type: Dict[str, Dict[str, Set[str]]] = {}
         # (PolicyType, kind) -> the installed list, until the next write
         self._installed: Dict[tuple, List[Policy]] = {}
+        #: one more on every write: lists handed out at one generation
+        #: are the sets of one installed state (of different kinds or
+        #: namespaces), never one another's successors
+        self.generation = 0
         self._on_change = on_change
 
     # -- writes --------------------------------------------------------------
@@ -84,6 +88,7 @@ class Cache:
         with self._lock:
             self._unset_locked(key)
             self._policies[key] = policy
+            self.generation += 1
             enforce = _compute_enforce(policy)
             kind_states: Dict[str, dict] = {}
             for raw_rule in compute_rules(policy):
@@ -141,6 +146,7 @@ class Cache:
     def unset(self, key: str) -> None:
         with self._lock:
             self._unset_locked(key)
+            self.generation += 1
         if self._on_change:
             self._on_change()
 

@@ -74,6 +74,8 @@ from typing import Callable, Dict, Optional
 
 from .. import faults
 from ..observability import coverage, tracing
+from ..observability.device import (MUTATE_ELEMENT_RESULTS,
+                                    MUTATE_ROW_PATHS)
 from ..observability.metrics import MetricsRegistry, global_registry
 from . import shed as shed_policy
 from .queue import RequestQueue, Ticket
@@ -254,6 +256,10 @@ class AdmissionBatcher:
         self._mutate_waits_s: deque = deque(maxlen=8192)
         self._mutate_rows = 0
         self._mutate_fallback_rows = 0
+        # of the mutate rows, those by the autogen path their kind
+        # takes and the foreach elements by what the device decided
+        # (``MutateScanner.last_counts``)
+        self._mutate_counts: Dict[str, int] = {}
         self._mutate_paths = {True: 0, False: 0}
         # consecutive all-failed dispatch count per key; touched only
         # by the batcher thread (dispatches are serialized), reset the
@@ -484,6 +490,10 @@ class AdmissionBatcher:
             with self._stats_lock:
                 self._mutate_rows += len(batch)
                 self._mutate_fallback_rows += scanner.last_fallback_rows
+                for name, n in getattr(scanner, 'last_counts',
+                                       {}).items():
+                    self._mutate_counts[name] = \
+                        self._mutate_counts.get(name, 0) + n
         if cap is not None:
             wall = time.monotonic() - t_in
             cpu = time.thread_time() - c_in
@@ -652,6 +662,14 @@ class AdmissionBatcher:
                 mutate_host_loop_requests=self._mutate_paths[False],
                 mutate_rows=self._mutate_rows,
                 mutate_fallback_rows=self._mutate_fallback_rows)
+            cells = {result: self._mutate_counts.get(result, 0)
+                     for result in MUTATE_ELEMENT_RESULTS}
+            by_kind.update(
+                {f'mutate_rows_{path}': self._mutate_counts.get(path, 0)
+                 for path in MUTATE_ROW_PATHS},
+                mutate_element_cells=sum(cells.values()),
+                **{f'mutate_element_{result}': n
+                   for result, n in cells.items()})
         timing = {f'batch_{field}_ms': stage_ms.get(name, 0.0)
                   for name, field in _BATCH_STAGES}
         return {
@@ -706,6 +724,7 @@ class AdmissionBatcher:
                 n.update(dict.fromkeys(n, 0))
             self._mutate_waits_s.clear()
             self._mutate_rows = self._mutate_fallback_rows = 0
+            self._mutate_counts.clear()
             self._mutate_paths = dict.fromkeys(self._mutate_paths, 0)
         self.sheds.reset()
 

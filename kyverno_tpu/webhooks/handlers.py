@@ -479,8 +479,8 @@ class ResourceHandlers:
         # the hot-swap predecessor search matches on identity overlap;
         # and while a successor compiles, the predecessor serves the
         # requests whose own policies it holds (_serving_predecessor)
-        self._scanner_sets: Dict[tuple, Tuple[frozenset, Any,
-                                              frozenset]] = {}
+        self._scanner_sets: Dict[tuple, Tuple[frozenset, Any, frozenset,
+                                              Optional[int]]] = {}
         self._building: set = set()
         # per-policy-set circuit breakers (serving/breaker.py): a set
         # that keeps failing (build or scan) opens and serves the host
@@ -581,6 +581,7 @@ class ResourceHandlers:
                     self._breakers.probe_abort(base)
                 return None
             self._building.add(key)
+        generation = self.cache.generation
 
         def build():
             try:
@@ -599,7 +600,7 @@ class ResourceHandlers:
                     if self.serving_mode == 'batch':
                         self._get_batcher().record_build()
                 self._install_scanner(key, base, kind, policies,
-                                      scanner)
+                                      scanner, generation)
             except Exception as e:  # noqa: BLE001
                 # a policy set that cannot compile must trip the circuit
                 # breaker, or every request re-spawns a doomed
@@ -621,14 +622,19 @@ class ResourceHandlers:
         return None
 
     def _install_scanner(self, key: tuple, base: tuple, kind: str,
-                         policies, scanner) -> None:
+                         policies, scanner,
+                         generation: Optional[int] = None) -> None:
         """Insert a freshly built scanner, hot-swapping any live
         predecessor serving the same logical policy set.
 
         Policy churn replaces the Policy objects, so the successor's
         id()-tuple key never matches the predecessor's — the logical
-        set is matched by (namespace, name) identity overlap instead.
-        The swap is atomic under the scanner lock AFTER the successor
+        set is matched by (namespace, name) identity overlap instead,
+        among the sets built before the cache's last write
+        (``generation``, the cache's when the build was asked for): two
+        sets of one generation are of one installed state, such as a
+        Pod's mutate set and a Deployment's, which share all but the
+        policies autogen does not convert, and both stay.  The swap is atomic under the scanner lock AFTER the successor
         is fully built and warmed: requests keep riding the predecessor
         (or the host loop, with identical verdicts) until the flip, so
         a churn event never sheds and never 500s.  In-flight batches
@@ -644,8 +650,10 @@ class ResourceHandlers:
             for k in self._scanners:
                 if k[0] != key[0] or k == key:
                     continue
-                prev = self._scanner_sets.get(k, ((),))[0]
-                if not prev:
+                prev, _pols, _ids, prev_gen = self._scanner_sets.get(
+                    k, ((), None, None, None))
+                if not prev or (None not in (prev_gen, generation) and
+                                prev_gen >= generation):
                     continue
                 ratio = len(ident & prev) / max(len(ident), len(prev), 1)
                 if ratio > best_ratio:
@@ -660,7 +668,8 @@ class ResourceHandlers:
                 evicted, _ = self._scanners.popitem(last=False)
                 self._scanner_sets.pop(evicted, None)
             self._scanners[key] = scanner
-            self._scanner_sets[key] = (ident, policies, frozenset(base))
+            self._scanner_sets[key] = (ident, policies, frozenset(base),
+                                       generation)
         if swapped is None:
             return
         old, state = swapped

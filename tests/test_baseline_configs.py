@@ -15,14 +15,13 @@ import random
 import pytest
 
 import benchlib
-from kyverno_tpu.api.policy import load_policies_from_yaml
 from kyverno_tpu.compiler.apply import BatchApplier
 from kyverno_tpu.compiler.scan import BatchScanner
-from kyverno_tpu.conformance import corpus
 from kyverno_tpu.engine.api import PolicyContext
 from kyverno_tpu.engine.engine import Engine
 
 mixed_cluster = benchlib.load_module('generators', 'mixed_cluster')
+config5_dump = benchlib.load_module('generators', 'config5_dump')
 
 
 class TestConfig4JMESPathHeavy:
@@ -76,13 +75,12 @@ class TestConfig4JMESPathHeavy:
 class TestConfig5MutateGenerate:
     @pytest.fixture(scope='class')
     def policies(self):
-        return load_policies_from_yaml(corpus.CONFIG5_PACK)
+        return benchlib.load_policies(['config5'])
 
     @pytest.fixture(scope='class')
     def dump(self):
         rng = random.Random(11)
-        return [corpus.make_config5_resource(rng, i, mixed_cluster.make_pod)
-                for i in range(300)]
+        return [config5_dump.make_resource(rng, i) for i in range(300)]
 
     def test_applier_matches_engine_loop(self, policies, dump):
         applier = BatchApplier(policies, processes=0)

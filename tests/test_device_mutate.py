@@ -24,8 +24,12 @@ from kyverno_tpu.observability import coverage
 
 
 def policy(name, rule):
+    # Pods only: the rules autogen adds for the controller kinds are
+    # tests/test_mutate_foreach.py's
     return Policy({'apiVersion': 'kyverno.io/v1', 'kind': 'ClusterPolicy',
-                   'metadata': {'name': name},
+                   'metadata': {'name': name, 'annotations': {
+                       'pod-policies.kyverno.io/autogen-controllers':
+                           'none'}},
                    'spec': {'rules': [rule]}})
 
 

@@ -7,16 +7,14 @@ import random
 import pytest
 
 import benchlib
-from kyverno_tpu.api.policy import load_policies_from_yaml
 from kyverno_tpu.compiler.apply import BatchApplier
-from kyverno_tpu.conformance import corpus
 
-make_pod = benchlib.load_module('generators', 'mixed_cluster').make_pod
+make_resource = benchlib.load_module('generators', 'config5_dump').make_resource
 
 
 @pytest.fixture(scope='module')
 def policies():
-    return load_policies_from_yaml(corpus.CONFIG5_PACK)
+    return benchlib.load_policies(['config5'])
 
 
 def _run(policies, resources, fast, monkey):
@@ -36,7 +34,7 @@ def test_config5_pack_compiles_fast(policies, monkeypatch):
 
 def test_fast_matches_engine_bit_identical(policies, monkeypatch):
     rng = random.Random(23)
-    resources = [corpus.make_config5_resource(rng, i, make_pod)
+    resources = [make_resource(rng, i)
                  for i in range(400)]
     # shape escapes: labels as non-dict, containers missing
     resources.append({'apiVersion': 'v1', 'kind': 'Pod',
@@ -68,7 +66,7 @@ def test_fast_matches_engine_bit_identical(policies, monkeypatch):
 def test_fast_rate_improvement(policies, monkeypatch):
     import time
     rng = random.Random(7)
-    resources = [corpus.make_config5_resource(rng, i, make_pod)
+    resources = [make_resource(rng, i)
                  for i in range(1500)]
     monkeypatch.setenv('KTPU_FAST_MUTATE', '1')
     applier = BatchApplier(policies, processes=0)

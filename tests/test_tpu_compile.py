@@ -176,6 +176,27 @@ def test_mutate_kernel_compiles_for_one_chip(one_chip,
         _report(f'mutate@{capacity}', compiled)
 
 
+def test_foreach_mutate_kernel_compiles_for_one_chip(one_chip,
+                                                     no_persistent_cache):
+    """The kernel of a foreach set: condition lanes, the gate of the
+    element sites and the fourth output, at the admission capacity."""
+    import jax
+    import benchlib
+    from kyverno_tpu.compiler.scan import WARM_POD
+    from kyverno_tpu.mutate.encode import encode_mutate_batch
+    from kyverno_tpu.mutate.kernel import MutateKernel
+    from kyverno_tpu.mutate.plan import compile_mutate_set
+    program = compile_mutate_set(benchlib.load_policies(['mutate-foreach']))
+    assert program.device_ok and len(program.conditions) == 18
+    kernel = MutateKernel(program)
+    lanes = encode_mutate_batch([WARM_POD], program, padded_n=64)
+    assert lanes['ctag'].shape == (64, 18, 4)
+    with jax.enable_x64(True):
+        compiled = jax.jit(kernel.mutate_eval).lower(
+            _shapes(lanes, 64, one_chip)).compile()
+    _report('foreach mutate@64', compiled)
+
+
 def test_sharded_step_compiles_for_the_2x2_mesh(pack, topo,
                                                 no_persistent_cache):
     import jax
