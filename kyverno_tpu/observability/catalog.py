@@ -435,6 +435,12 @@ PIPELINE_STAGES: Dict[str, str] = {
              'the rows of one report window.',
     'filter': 'Reconcile: pending rows + verdict-cache lookup pass.',
     'flush': 'Reconcile: the verdict cache persisted, after the scan.',
+    'flush_delta': 'Inside flush: what changed since the last flush '
+                   'appended to the verdict snapshot as one delta frame '
+                   '(histogram only).',
+    'flush_compact': 'Inside flush: the verdict snapshot rewritten as one '
+                     'base frame of every resident row (no base yet, or '
+                     'the log would outgrow the base; histogram only).',
     'reconcile': 'Wall of one reconcile() (histogram only).',
     'unnamed': 'reconcile minus filter + chunk_wait + report + store + '
                'flush (histogram only).',

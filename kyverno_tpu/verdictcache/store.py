@@ -1,5 +1,5 @@
-"""Digest-keyed verdict cache: in-memory LRU front + atomic on-disk
-snapshots.
+"""Digest-keyed verdict cache: in-memory LRU front + on-disk snapshots
+that grow by appended deltas.
 
 One **generation** per (policy-set fingerprint × engine rev) holds
 ``spec digest → verdict row`` where a row is the fused report-path
@@ -18,18 +18,36 @@ across every resource (``reports/results.py`` ``_rule_result``);
 rows that received the same result refer to one object.  Stored
 results are immutable, like the flyweights they copy.
 
-Persistence reuses the ``aotcache/store.py`` protocol: one snapshot
-file per generation (``<fingerprint>-<rev>.vrows``), written
-tmp-file + ``os.replace`` so readers never observe a partial snapshot,
-framed with a magic (``KTVC2``) + SHA-256 header so a torn or
-bit-flipped file — or one of an older codec — is deleted and reloaded
-as empty: a bad snapshot costs a rescan, never a crash or a stale
-verdict.  The zlib-compressed JSON payload is a table:
-``{"t": [result, ...], "r": {digest: {"u", "r": [table index, ...],
-"s", "p"}}}``.  Each distinct result is written once (deduplicated by
-identity, then by its serialised bytes); a reload shares one dict per
-table entry among the rows that refer to it.  Disk eviction is LRU by
-mtime against a byte budget; the memory front is an entry-capped LRU.
+Persistence: one snapshot file per generation
+(``<fingerprint>-<rev>.vrows``): the magic ``KTVC3``, then a sequence of
+frames, each its kind (``B`` base or ``D`` delta), its payload's length
+and SHA-256, then the payload.  A payload is zlib-compressed JSON, a
+table: ``{"t": [result, ...], "r": {digest: {"u", "r": [table index,
+...], "s", "p"}}}``; a delta adds ``"x": [dropped digest, ...]``.  Each
+distinct result is written once a frame (deduplicated by identity, then
+by its serialised bytes); a reload shares one dict per table entry among
+the rows of a frame that refer to it.
+
+The first frame is a base: every resident row.  Each later flush appends
+one delta: the rows stored and the digests dropped (invalidated or
+evicted from the memory front) since the flush before it.  A flush
+**compacts** instead, rewriting the file as one base through tmp-file +
+``os.replace`` (the ``aotcache/store.py`` protocol), when there is no base
+it can append to or when the log's entries since the base (a row stored
+or a digest dropped, this flush's included) would exceed the base's row
+count: the file and a reload stay under twice a base, and the rewrites
+cost one base write per base-sized log.  A full scan therefore writes
+what one snapshot writes, and a churn tick writes what it changed.
+
+A reload replays the base, then each delta (its drops, then its rows).
+A bad magic — ``KTVC2`` and ``KTVC1`` files among them — or a bad base
+frame deletes the file and loads as empty: a bad snapshot costs a rescan,
+never a crash or a stale verdict.  A delta frame that fails its length or
+hash check ends the read: the frames before it are the state after an
+earlier complete flush, and they load; the next flush compacts, so
+nothing is appended after a torn tail.  Disk eviction is LRU by mtime
+against a byte budget (a file's size includes its log); the memory front
+is an entry-capped LRU.
 
 Knobs:
 
@@ -47,23 +65,28 @@ import hashlib
 import json
 import logging
 import os
+import struct
 import tempfile
 import threading
 import time
 import zlib
 from collections import OrderedDict
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from .. import faults
+from ..observability import device as devtel
 from .keys import engine_rev, generation_key
 
 _log = logging.getLogger('kyverno.verdictcache')
 
-#: snapshot framing: magic + 32-byte SHA-256 of the payload, then payload
-#: (KTVC2: the result table; a KTVC1 snapshot of per-row copies fails
-#: the magic check and loads as empty)
-_MAGIC = b'KTVC2\n'
-_DIGEST_LEN = 32
+#: snapshot file: the magic, then frames (KTVC3: a base and appended
+#: deltas; a KTVC2 or KTVC1 snapshot fails the magic check and loads as
+#: empty)
+_MAGIC = b'KTVC3\n'
+#: a frame's header: its kind, its payload's length and SHA-256
+_FRAME = struct.Struct('>cQ32s')
+_BASE = b'B'
+_DELTA = b'D'
 _SUFFIX = '.vrows'
 
 VERDICT_CACHE_HITS = 'kyverno_tpu_verdict_cache_hits_total'
@@ -84,6 +107,45 @@ _INTERN_MAX = 1 << 16
 
 _dumps = json.JSONEncoder(separators=(',', ':')).encode
 _dump_str = json.encoder.encode_basestring_ascii
+
+
+def _frame(kind: bytes, payload: bytes) -> bytes:
+    return _FRAME.pack(kind, len(payload),
+                       hashlib.sha256(payload).digest()) + payload
+
+
+def _read_frame(raw: bytes, pos: int
+                ) -> Optional[Tuple[bytes, Dict[str, dict], list, int]]:
+    """The frame at ``pos``: its kind, its rows (results resolved from
+    its table), the digests it drops and where it ends; None where it is
+    cut short, fails its hash or does not decode."""
+    start = pos + _FRAME.size
+    if start > len(raw):
+        return None
+    kind, length, digest = _FRAME.unpack_from(raw, pos)
+    payload = raw[start:start + length]
+    if len(payload) != length or hashlib.sha256(payload).digest() != digest:
+        return None
+    try:
+        doc = json.loads(zlib.decompress(payload).decode())
+        table = doc['t']
+        rows = doc['r']
+        for row in rows.values():
+            row['r'] = [table[i] for i in row['r']]
+        dropped = doc.get('x', [])
+    except Exception:  # noqa: BLE001 - a frame that does not decode
+        return None
+    return kind, rows, dropped, start + length
+
+
+def _stat_id(path: str) -> Optional[Tuple[int, int]]:
+    """``(inode, size)`` of the file at ``path``; None where there is
+    none."""
+    try:
+        st = os.stat(path)
+    except OSError:
+        return None
+    return st.st_ino, st.st_size
 
 
 def replay_row(row: dict, policies, ts: int
@@ -174,7 +236,17 @@ class VerdictCache:
         # pinned, so no id of theirs is reused while it keys the map
         self._interned: Dict[int, dict] = {}
         self._pinned: List[dict] = []
-        self._dirty = False
+        # what changed since the last flush, disjoint: digests stored
+        # (in the order of their last store) and digests dropped
+        self._stored: Dict[str, None] = {}
+        self._dropped: Set[str] = set()
+        # the snapshot file as this cache last wrote or read it: the rows
+        # of its base (None: no base to append to), the entries of its
+        # deltas since, and its (inode, size); guarded by _write_lock
+        self._write_lock = threading.Lock()
+        self._base_rows: Optional[int] = None
+        self._log_entries = 0
+        self._file_id: Optional[Tuple[int, int]] = None
         #: what the last flush wrote: (result references, table entries)
         self.last_flush = (0, 0)
         # local lookup outcome counters: benchmarks and the decision-
@@ -254,7 +326,6 @@ class VerdictCache:
         timestamp-free copy per flyweight (the first row handed it
         makes the copy, every later one shares it), so replay can stamp
         the replaying tick and ``flush`` finds the sharing by identity."""
-        evicted = 0
         with self._lock:
             interned = self._interned
             if len(interned) > _INTERN_MAX:
@@ -276,11 +347,10 @@ class VerdictCache:
             self._rows[digest] = row
             self._rows.move_to_end(digest)
             self._by_uid.setdefault(uid, set()).add(digest)
-            while len(self._rows) > self.max_entries:
-                d, dropped = self._rows.popitem(last=False)
-                self._unindex(d, dropped)
-                evicted += 1
-            self._dirty = True
+            self._stored.pop(digest, None)
+            self._stored[digest] = None
+            self._dropped.discard(digest)
+            evicted = self._evict_memory()
         reg = _reg()
         if evicted and reg is not None:
             reg.inc(VERDICT_CACHE_EVICTIONS, float(evicted))
@@ -296,10 +366,26 @@ class VerdictCache:
             dropped = 0
             for d in digests:
                 if self._rows.pop(d, None) is not None:
+                    self._drop(d)
                     dropped += 1
-            if dropped:
-                self._dirty = True
         return dropped
+
+    def _drop(self, digest: str) -> None:
+        """Log a digest that left the memory front.  Caller holds the
+        lock."""
+        self._stored.pop(digest, None)
+        self._dropped.add(digest)
+
+    def _evict_memory(self) -> int:
+        """Drop the least recently used rows over the entry cap; returns
+        how many.  Caller holds the lock."""
+        evicted = 0
+        while len(self._rows) > self.max_entries:
+            d, row = self._rows.popitem(last=False)
+            self._unindex(d, row)
+            self._drop(d)
+            evicted += 1
+        return evicted
 
     def _unindex(self, digest: str, row: dict) -> None:
         digests = self._by_uid.get(row.get('u', ''))
@@ -318,9 +404,12 @@ class VerdictCache:
     # -- persistence -------------------------------------------------------
 
     def _load(self) -> None:
-        """Populate the memory front from this generation's snapshot.
-        A short, unframed, digest-mismatched, or undecodable snapshot
-        is deleted and loaded as empty — never raised."""
+        """Populate the memory front from this generation's snapshot:
+        the base frame, then each delta frame in order (its drops, then
+        its rows).  A short or unframed file, or a base frame that fails
+        its length, hash or decoding, is deleted and loaded as empty —
+        never raised.  A delta frame that fails them ends the read: the
+        frames before it load, and the next flush compacts."""
         path = self.path()
         if path is None or not os.path.exists(path):
             return
@@ -329,33 +418,49 @@ class VerdictCache:
             # like an unreadable file: load as empty, rescan refills
             faults.check(faults.SITE_VERDICT_SNAPSHOT)
             with open(path, 'rb') as f:
+                inode = os.fstat(f.fileno()).st_ino
                 raw = f.read()
         except Exception:  # noqa: BLE001 - unreadable snapshot: empty
             return
-        header = len(_MAGIC) + _DIGEST_LEN
-        payload = raw[header:]
-        if (len(raw) < header or not raw.startswith(_MAGIC) or
-                hashlib.sha256(payload).digest() != raw[len(_MAGIC):header]):
+        rows: Optional[Dict[str, dict]] = None
+        base_rows = entries = 0
+        pos = len(_MAGIC) if raw.startswith(_MAGIC) else len(raw)
+        while pos < len(raw):
+            frame = _read_frame(raw, pos)
+            if frame is None:
+                break
+            kind, got, dropped, pos_next = frame
+            if rows is None:
+                if kind != _BASE:
+                    break
+                rows, base_rows = got, len(got)
+            else:
+                if kind != _DELTA:
+                    break
+                for d in dropped:
+                    rows.pop(d, None)
+                for d, row in got.items():
+                    rows.pop(d, None)
+                    rows[d] = row
+                entries += len(got) + len(dropped)
+            pos = pos_next
+        if rows is None:
             _log.warning('verdict snapshot %s corrupt; dropping',
                          os.path.basename(path))
-            self._drop_file(path)
-            return
-        try:
-            doc = json.loads(zlib.decompress(payload).decode())
-            table = doc['t']
-            rows = doc['r']
-            for row in rows.values():
-                row['r'] = [table[i] for i in row['r']]
-        except Exception:  # noqa: BLE001 - stale codec decodes as empty
             self._drop_file(path)
             return
         with self._lock:
             for digest, row in rows.items():
                 self._rows[digest] = row
                 self._by_uid.setdefault(row.get('u', ''), set()).add(digest)
-            while len(self._rows) > self.max_entries:
-                d, dropped = self._rows.popitem(last=False)
-                self._unindex(d, dropped)
+            self._evict_memory()
+        if pos < len(raw):
+            _log.warning('verdict snapshot %s: torn tail after %d bytes; '
+                         'the next flush compacts', os.path.basename(path),
+                         pos)
+        else:
+            self._base_rows, self._log_entries = base_rows, entries
+            self._file_id = (inode, len(raw))
         try:
             os.utime(path)  # disk LRU works off mtime, like the AOT store
         except OSError:
@@ -369,55 +474,97 @@ class VerdictCache:
             pass
 
     def flush(self) -> bool:
-        """Atomically persist this generation's rows (tmp + rename) when
-        dirty, then evict older generation snapshots LRU-by-mtime to fit
-        the byte budget.  Returns True when a snapshot was written."""
+        """Persist what changed since the last flush, then evict older
+        generation snapshots LRU-by-mtime to fit the byte budget.
+
+        Appends one delta frame (the rows stored and the digests dropped
+        since the last flush), or **compacts**: rewrites the generation
+        as one base frame of every resident row, tmp + rename, where
+        there is no base to append to (no file yet, a torn tail, a failed
+        write), where the file is not as this cache left it, or where
+        the log's entries since the base, this flush's included, would
+        exceed the base's rows.  Records the stage ``flush_delta`` or
+        ``flush_compact``.  Returns True when a frame was written."""
         path = self.path()
-        with self._lock:
-            # the next reconcile hands flyweights of its own; what it
-            # shares with these rows is found again by bytes below
-            self._interned.clear()
-            self._pinned.clear()
-            self.last_flush = (0, 0)
-            if path is None or not self._dirty:
-                return False
-            text, refs, distinct = self._snapshot_json()
-            payload = zlib.compress(text.encode(), 3)
-            self._dirty = False
-        reg = _reg()
-        if reg is not None:
-            reg.inc(VERDICT_SNAPSHOT_RESULTS, float(refs), entry='ref')
-            reg.inc(VERDICT_SNAPSHOT_RESULTS, float(distinct),
-                    entry='table')
-        self.last_flush = (refs, distinct)
-        framed = _MAGIC + hashlib.sha256(payload).digest() + payload
-        try:
-            fd, tmp = tempfile.mkstemp(dir=self.root, suffix='.tmp')
+        t0 = time.monotonic()
+        with self._write_lock:
+            with self._lock:
+                # the next reconcile hands flyweights of its own; what
+                # it shares with these rows is found again by bytes below
+                self._interned.clear()
+                self._pinned.clear()
+                self.last_flush = (0, 0)
+                stored, dropped = self._stored, self._dropped
+                if path is None or not (stored or dropped):
+                    stored.clear()
+                    dropped.clear()
+                    return False
+                self._stored, self._dropped = {}, set()
+                entries = len(stored) + len(dropped)
+                compact = (self._base_rows is None or
+                           _stat_id(path) != self._file_id or
+                           self._log_entries + entries > self._base_rows)
+                if compact:
+                    base_rows = len(self._rows)
+                    text, refs, distinct = self._snapshot_json(
+                        self._rows.items())
+                else:
+                    text, refs, distinct = self._snapshot_json(
+                        [(d, self._rows[d]) for d in stored], dropped)
+                payload = zlib.compress(text.encode(), 3)
+            reg = _reg()
+            if reg is not None:
+                reg.inc(VERDICT_SNAPSHOT_RESULTS, float(refs), entry='ref')
+                reg.inc(VERDICT_SNAPSHOT_RESULTS, float(distinct),
+                        entry='table')
+            self.last_flush = (refs, distinct)
             try:
-                with os.fdopen(fd, 'wb') as f:
-                    f.write(framed)
-                os.replace(tmp, path)
-            except BaseException:
-                self._drop_file(tmp)
-                raise
-        except OSError:
-            return False
+                if compact:
+                    self._replace(path, _MAGIC + _frame(_BASE, payload))
+                    self._base_rows, self._log_entries = base_rows, 0
+                else:
+                    with open(path, 'ab') as f:
+                        f.write(_frame(_DELTA, payload))
+                    self._log_entries += entries
+                self._file_id = _stat_id(path)
+            except OSError:
+                self._base_rows = None
+                return False
         self._evict_disk(keep=path)
+        if compact:
+            devtel.record_stage('flush_compact', time.monotonic() - t0)
+        else:
+            devtel.record_stage('flush_delta', time.monotonic() - t0)
         return True
 
-    def _snapshot_json(self) -> Tuple[str, int, int]:
-        """The snapshot's JSON text, the result references its rows make
-        and the table entries they refer to.  Each row is written
-        straight to text, so the snapshot keeps no container a row
-        alive (thousands of them would wake the cyclic collector over
-        the scan's whole heap).  Caller holds the lock."""
+    def _replace(self, path: str, data: bytes) -> None:
+        """Write ``path`` whole through a tmp file and ``os.replace``, so
+        a reader never sees it half written."""
+        fd, tmp = tempfile.mkstemp(dir=self.root, suffix='.tmp')
+        try:
+            with os.fdopen(fd, 'wb') as f:
+                f.write(data)
+            os.replace(tmp, path)
+        except BaseException:
+            self._drop_file(tmp)
+            raise
+
+    def _snapshot_json(self, items: Iterable[Tuple[str, dict]],
+                       dropped: Optional[Set[str]] = None
+                       ) -> Tuple[str, int, int]:
+        """A frame's JSON text over ``items`` (digest, row), the result
+        references those rows make and the table entries they refer to;
+        with ``dropped``, a delta's: the digests it drops under ``"x"``.
+        Each row is written straight to text, so the snapshot keeps no
+        container a row alive (thousands of them would wake the cyclic
+        collector over the scan's whole heap).  Caller holds the lock."""
         table: List[str] = []
         by_id: Dict[int, str] = {}     # id(result) -> its index, as text
         by_text: Dict[str, str] = {}   # a result's JSON -> its index
         policies: Dict[tuple, str] = {}  # a row's policy indexes, as text
         rows = []
         refs = 0
-        for digest, row in self._rows.items():
+        for digest, row in items:
             results = row['r']
             refs += len(results)
             idx = []
@@ -438,8 +585,10 @@ class VerdictCache:
             rows.append('%s:{"u":%s,"r":[%s],"s":%s,"p":[%s]}' % (
                 _dump_str(digest), _dump_str(row['u']), ','.join(idx),
                 _dumps(row['s']), p))
-        return ('{"t":[%s],"r":{%s}}' % (','.join(table), ','.join(rows)),
-                refs, len(table))
+        text = '{"t":[%s],"r":{%s}' % (','.join(table), ','.join(rows))
+        if dropped is not None:
+            text += ',"x":[%s]' % ','.join(map(_dump_str, dropped))
+        return text + '}', refs, len(table)
 
     def _evict_disk(self, keep: str) -> None:
         """Drop oldest generation snapshots until the directory fits the

@@ -400,7 +400,7 @@ class TestCpuBesideWall:
         import os
         assert set(devtel.CPU_STAGES) == {'match', 'encode', 'context',
                                           'report'}
-        assert len(devtel.STAGES) == len(set(devtel.STAGES)) == 44
+        assert len(devtel.STAGES) == len(set(devtel.STAGES)) == 46
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         read = set()
         for path in glob.glob(os.path.join(root, 'benchmarks', 'layers',
@@ -502,7 +502,8 @@ class TestOverlapRatio:
         assert {'report_cpu', 'encode_walk'} <= set(cap.stages)
         assert set(cap.stages) <= set(devtel.STAGES)
         left_out = {k for k in cap.stages if k not in devtel.BUSY_STAGES}
-        assert left_out <= {'filter', 'flush', 'chunk_wait', 'encode_wait',
+        assert left_out <= {'filter', 'flush', 'flush_compact',
+                            'flush_delta', 'chunk_wait', 'encode_wait',
                             'device_wait', 'pool_start', 'encode_submit',
                             'encode_idle', 'encode_return', 'encode_gather',
                             'encode_clear', 'encode_walk', 'encode_column',

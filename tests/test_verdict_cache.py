@@ -2,13 +2,17 @@
 hit/miss/invalidation/eviction semantics, controller integration
 (replay vs scan partition, delete invalidation, policy-set flush), the
 KTPU_VERDICT_CACHE=off bit-identity oracle, second-process
-disk-store reuse, and the KTVC2 snapshot table (ISSUE 39): each
-distinct result written once, reloaded to what the KTVC1 codec gave."""
+disk-store reuse, the snapshot table (each distinct result written
+once, reloaded to what the KTVC1 codec gave), and the KTVC3 log: a base
+frame and appended deltas that reload to what one full snapshot of the
+same memory reloads to, a torn tail that loads the last complete flush,
+and the compaction rule."""
 
 import copy
 import hashlib
 import json
 import os
+import struct
 import sys
 import zlib
 
@@ -341,20 +345,30 @@ class TestControllerIntegration:
                                              monkeypatch):
         """A fresh controller (new process: cold memory, same cache dir
         and policy set) replays from the persisted snapshot with zero
-        device scans."""
+        device scans — a base and the deltas two churn ticks appended
+        to it."""
         first = make_ctrl(tmp_path, monkeypatch)
-        pods = [pod('a', team='x'), pod('b')]
+        pods = [pod(f'p{i}', team='x') for i in range(6)] + [pod('b')]
         seed(first, pods)
         first.reconcile(now=NOW)
-        first.close()  # daemon-shutdown flush
+        pods[2]['metadata']['labels'] = {}  # tick 1: one row changed
+        seed(first, [pods[2]])
+        first.reconcile(now=NOW)
+        pods.append(pod('c', team='y'))     # tick 2: one row added
+        seed(first, [pods[-1]])
+        first.reconcile(now=NOW)
+        first.close()  # daemon-shutdown flush: nothing left to write
+        frames, tail = frames_of(first.verdict_cache.path())
+        assert [k for k, _ in frames] == ['B', 'D', 'D'] and tail == 0
         second = make_ctrl(tmp_path, monkeypatch)
         monkeypatch.setattr(
             second.scanner, 'scan_report_results',
             lambda *a, **k: pytest.fail('disk-warm rescan must not scan'))
-        seed(second, [pod('a', team='x'), pod('b')])
-        assert len(second.reconcile(now=NOW)) == 2
+        seed(second, copy.deepcopy(pods))
+        assert len(second.reconcile(now=NOW)) == len(pods)
         assert second.rescan_stats == {
-            'rows_pending': 2, 'rows_scanned': 0, 'rows_replayed': 2}
+            'rows_pending': len(pods), 'rows_scanned': 0,
+            'rows_replayed': len(pods)}
         assert reports_of(second) == reports_of(first)
 
 
@@ -406,10 +420,28 @@ def parent_rows(rows):
     return json.loads(json.dumps(stored, separators=(',', ':')))
 
 
+def frames_of(path):
+    """The snapshot's frames, read without the cache's own reader:
+    ``[(kind, payload JSON)]``, and the bytes after the last whole one."""
+    raw = open(path, 'rb').read()
+    assert raw.startswith(b'KTVC3\n')
+    pos, frames = len(b'KTVC3\n'), []
+    while pos + 41 <= len(raw):
+        kind, length, digest = struct.unpack_from('>cQ32s', raw, pos)
+        payload = raw[pos + 41:pos + 41 + length]
+        if len(payload) != length or \
+                hashlib.sha256(payload).digest() != digest:
+            break
+        frames.append((kind.decode(), json.loads(zlib.decompress(payload))))
+        pos += 41 + length
+    return frames, len(raw) - pos
+
+
 def payload_of(vc):
-    raw = open(vc.path(), 'rb').read()
-    assert raw.startswith(b'KTVC2\n')
-    return json.loads(zlib.decompress(raw[len(b'KTVC2\n') + 32:]))
+    """The base frame of a snapshot that holds nothing else."""
+    frames, tail = frames_of(vc.path())
+    assert [k for k, _ in frames] == ['B'] and tail == 0
+    return frames[0][1]
 
 
 POLICIES3 = [Policy(POLICY), Policy(OTHER_POLICY), Policy(POLICY)]
@@ -472,12 +504,24 @@ class TestSnapshotTable:
         for d, *_ in rows:
             assert again.replay(again.peek(d), POLICIES3, 77) == before[d]
 
-    def test_ktvc1_snapshot_loads_as_empty(self, tmp_path):
+    @pytest.mark.parametrize('magic', [b'KTVC1\n', b'KTVC2\n'],
+                             ids=['KTVC1', 'KTVC2'])
+    def test_ktvc1_snapshot_loads_as_empty(self, tmp_path, magic):
+        """A snapshot of an older codec, whole and rightly hashed, fails
+        the magic check: it is deleted, loads as empty, and costs one
+        rescan.  KTVC1 held per-row copies, KTVC2 one table and no
+        frames."""
         vc = VerdictCache('fp', root=str(tmp_path))
+        rows = parent_rows(shared_rows())
+        if magic == b'KTVC2\n':
+            table = [r for row in rows.values() for r in row['r']]
+            rows = {'t': table, 'r': {d: dict(row, r=[table.index(r) for r
+                                                       in row['r']])
+                                      for d, row in rows.items()}}
         payload = zlib.compress(json.dumps(
-            parent_rows(shared_rows()), separators=(',', ':')).encode(), 3)
+            rows, separators=(',', ':')).encode(), 3)
         with open(vc.path(), 'wb') as f:
-            f.write(b'KTVC1\n' + hashlib.sha256(payload).digest() + payload)
+            f.write(magic + hashlib.sha256(payload).digest() + payload)
         old = VerdictCache('fp', root=str(tmp_path))
         assert len(old) == 0
         assert not os.path.exists(vc.path())
@@ -565,3 +609,224 @@ class TestSnapshotEndToEnd:
         assert second.rescan_stats['rows_replayed'] == len(cluster)
         assert len(reports) == len(cluster)
         assert replayed == reports
+
+
+# ---------------------------------------------------------------------------
+# the snapshot log (KTVC3): a base frame, then one delta frame a flush
+
+
+SHARED = _result('p-a', 'r1')
+
+
+def log_row(i, variant=0):
+    """Resource ``i`` at content ``variant``, as the fused path hands
+    its row: a flyweight every row shares and a result of its own."""
+    own = _result('p-b', 'r2', 'fail' if (i + variant) % 3 else 'pass',
+                  f'row {i} v{variant}')
+    results = [SHARED, own]
+    return (f'd{i}-{variant}', f'uid-{i}', results, _summary(results),
+            [0, 1])
+
+
+def run_ops(vc, ops):
+    for op, *args in ops:
+        if op == 'put':
+            vc.store(*log_row(*args))
+        elif op == 'drop':
+            vc.invalidate_uid(f'uid-{args[0]}')
+        elif op == 'look':
+            assert vc.lookup(log_row(*args)[0]) is not None
+        else:
+            vc.flush()
+
+
+def puts(ids, variant=0):
+    return [('put', i, variant) for i in ids]
+
+
+def rows_of(vc):
+    """The rows a cache holds, by value, and its uid index."""
+    with vc._lock:
+        rows = {d: {k: row[k] for k in ('u', 'r', 's', 'p')}
+                for d, row in vc._rows.items()}
+        by_uid = {u: set(ds) for u, ds in vc._by_uid.items()}
+    return json.loads(json.dumps(rows)), by_uid
+
+
+def full_reload(vc, root):
+    """What a reload of ONE full snapshot of ``vc``'s memory holds: the
+    rows stored into a fresh cache whose single flush writes them as
+    one base frame."""
+    one = VerdictCache(vc.fingerprint, root=str(root),
+                       max_entries=vc.max_entries)
+    with vc._lock:
+        rows = list(vc._rows.items())
+    for d, row in rows:
+        one.store(d, row['u'], row['r'], row['s'], row['p'])
+    assert one.flush()
+    assert [k for k, _ in frames_of(one.path())[0]] == ['B']
+    return VerdictCache(vc.fingerprint, root=str(root),
+                        max_entries=vc.max_entries)
+
+
+#: (memory cap, operations, the frames the file holds at the end)
+LOG_SEQUENCES = {
+    'one_delta': (None, puts(range(10)) + [
+        ('flush',), ('drop', 3), ('put', 3, 1), ('put', 10, 0),
+        ('flush',)], 'BD'),
+    'many_deltas': (None, puts(range(20)) + [('flush',)] + [
+        op for t in range(1, 6) for op in (
+            ('drop', t), ('put', t, 1), ('put', 20 + t, 0), ('flush',))],
+        'BDDDDD'),
+    # 2 entries a tick against a base of 6 rows: the fourth tick's log
+    # would hold 8, so it compacts, and the fifth appends to that base
+    'deltas_then_compaction': (None, puts(range(6)) + [('flush',)] + [
+        op for t in range(5) for op in (
+            ('drop', t), ('put', t, 1), ('flush',))], 'BD'),
+    # a digest dropped and stored again in one interval is stored; one
+    # stored and dropped in one interval is dropped; a delta of drops
+    # alone; a digest dropped by an earlier delta stored again
+    'restore_dropped': (None, puts(range(6)) + [
+        ('flush',), ('drop', 2), ('put', 2, 0), ('put', 7, 0),
+        ('drop', 7), ('flush',), ('drop', 4), ('flush',), ('put', 4, 0),
+        ('flush',)], 'BDDD'),
+    # the memory front's LRU evicts d1 and d2 (d0 was refreshed), then
+    # d3: a reload must not bring them back
+    'memory_lru_eviction': (6, puts(range(6)) + [
+        ('flush',), ('look', 0, 0), ('put', 6, 0), ('put', 7, 0),
+        ('flush',), ('put', 8, 0), ('flush',)], 'BDD'),
+}
+
+
+class TestSnapshotLog:
+    @pytest.mark.parametrize('name', list(LOG_SEQUENCES))
+    def test_reload_of_the_log_equals_a_reload_of_one_snapshot(
+            self, tmp_path, name):
+        cap, ops, kinds = LOG_SEQUENCES[name]
+        vc = VerdictCache('fp', root=str(tmp_path / 'log'),
+                          max_entries=cap or 1000)
+        run_ops(vc, ops)
+        frames, tail = frames_of(vc.path())
+        assert ''.join(k for k, _ in frames) == kinds and tail == 0
+        # a frame's rows and drops are disjoint: the last change wins
+        assert not [d for _, doc in frames
+                    for d in doc.get('x', []) if d in doc['r']]
+        again = VerdictCache('fp', root=str(tmp_path / 'log'),
+                             max_entries=vc.max_entries)
+        want = rows_of(full_reload(vc, tmp_path / 'one'))
+        assert rows_of(again) == want == rows_of(vc)
+        if name == 'memory_lru_eviction':
+            assert not {'d1-0', 'd2-0', 'd3-0'} & set(want[0])
+        # the rows of a frame share one dict per table entry of it
+        with again._lock:
+            shared = {id(row['r'][0]) for row in again._rows.values()}
+        assert len(shared) <= len(frames)
+
+    def test_a_tick_writes_what_it_changed(self, tmp_path):
+        """1,000 rows resident, 10 of them changed: the delta holds the
+        10 new rows and the 10 digests they replace, and each flush
+        records exactly one of the two stages."""
+        from kyverno_tpu.observability import device as devtel
+        reg = devtel.configure(MetricsRegistry())
+        try:
+            vc = VerdictCache('fp', root=str(tmp_path))
+            for i in range(1000):
+                vc.store(*log_row(i))
+            assert vc.flush()
+            assert vc.last_flush == (2000, 1001)
+            changed = range(0, 1000, 100)
+            for i in changed:
+                vc.invalidate_uid(f'uid-{i}')
+                vc.store(*log_row(i, 1))
+            assert vc.flush()
+            assert vc.last_flush == (20, 11)
+            assert not vc.flush()   # nothing changed: nothing written
+            counts = {s: reg.histogram_count(
+                devtel.SCAN_STAGE_DURATION, stage=s)
+                for s in ('flush_compact', 'flush_delta')}
+        finally:
+            devtel.disable()
+        assert counts == {'flush_compact': 1, 'flush_delta': 1}
+        frames, _ = frames_of(vc.path())
+        assert [k for k, _ in frames] == ['B', 'D']
+        delta = frames[1][1]
+        assert sorted(delta['r']) == sorted(f'd{i}-1' for i in changed)
+        assert sorted(delta['x']) == sorted(f'd{i}-0' for i in changed)
+        assert 'x' not in frames[0][1]
+
+    @pytest.mark.parametrize('damage', ['cut', 'flipped'])
+    def test_a_torn_tail_loads_the_last_complete_flush(self, tmp_path,
+                                                       damage):
+        vc = VerdictCache('fp', root=str(tmp_path / 'log'))
+        run_ops(vc, puts(range(8)) + [('flush',), ('drop', 1),
+                                      ('put', 1, 1), ('flush',)])
+        want = rows_of(full_reload(vc, tmp_path / 'one'))
+        run_ops(vc, [('drop', 2), ('put', 2, 1), ('put', 9, 0),
+                     ('flush',)])
+        raw = bytearray(open(vc.path(), 'rb').read())
+        if damage == 'cut':   # the last append stopped part-way
+            raw = raw[:-7]
+        else:                 # a bit of the last frame's payload flipped
+            raw[-3] ^= 0x40
+        open(vc.path(), 'wb').write(bytes(raw))
+        again = VerdictCache('fp', root=str(tmp_path / 'log'))
+        assert rows_of(again) == want
+        # nothing is appended after a torn tail: the next flush compacts
+        run_ops(again, [('put', 10, 0), ('flush',)])
+        frames, tail = frames_of(again.path())
+        assert [k for k, _ in frames] == ['B'] and tail == 0
+        assert rows_of(VerdictCache('fp', root=str(tmp_path / 'log'))) \
+            == rows_of(again)
+
+    def test_a_corrupt_base_loads_as_empty(self, tmp_path):
+        vc = VerdictCache('fp', root=str(tmp_path))
+        run_ops(vc, puts(range(8)) + [('flush',), ('drop', 1),
+                                      ('put', 1, 1), ('flush',)])
+        raw = bytearray(open(vc.path(), 'rb').read())
+        raw[len(b'KTVC3\n') + 41 + 5] ^= 0x01   # inside the base payload
+        open(vc.path(), 'wb').write(bytes(raw))
+        fresh = VerdictCache('fp', root=str(tmp_path))
+        assert len(fresh) == 0
+        assert not os.path.exists(vc.path())
+
+    def test_a_file_another_cache_evicted_is_rewritten_whole(self,
+                                                             tmp_path):
+        """Two live generations share a directory (the partitioned
+        cache's members do): one's flush can evict the other's file by
+        the byte budget, and the other's next flush must not append a
+        delta to nothing."""
+        a = VerdictCache('fp-a', root=str(tmp_path))
+        run_ops(a, puts(range(10)) + [('flush',)])
+        os.utime(a.path(), (1, 1))
+        b = VerdictCache('fp-b', root=str(tmp_path), max_bytes=1)
+        run_ops(b, puts(range(3)) + [('flush',)])
+        assert not os.path.exists(a.path())
+        run_ops(a, [('drop', 1), ('put', 1, 1), ('flush',)])
+        frames, tail = frames_of(a.path())
+        assert [k for k, _ in frames] == ['B'] and tail == 0
+        assert rows_of(VerdictCache('fp-a', root=str(tmp_path))) == \
+            rows_of(a)
+
+    @pytest.mark.parametrize('budget', ['base_fits', 'log_fits'])
+    def test_disk_eviction_counts_the_log(self, tmp_path, budget):
+        """An older generation is evicted by its whole size, log
+        included: a budget its base alone fits evicts it."""
+        old = VerdictCache('fp-old', root=str(tmp_path))
+        run_ops(old, puts(range(40)) + [('flush',)])
+        base_size = os.path.getsize(old.path())
+        run_ops(old, [op for t in range(3) for op in (
+            ('drop', t), ('put', t, 1), ('flush',))])
+        frames, _ = frames_of(old.path())
+        assert [k for k, _ in frames] == ['B', 'D', 'D', 'D']
+        old_size = os.path.getsize(old.path())
+        assert old.stats()['snapshot_bytes'] == old_size > base_size
+        os.utime(old.path(), (1, 1))  # age the old generation
+        new = VerdictCache('fp-new', root=str(tmp_path))
+        run_ops(new, puts(range(2)) + [('flush',)])
+        new_size = os.path.getsize(new.path())
+        new.max_bytes = new_size + (
+            (base_size + old_size) // 2 if budget == 'base_fits'
+            else old_size)
+        new._evict_disk(keep=new.path())
+        assert os.path.exists(old.path()) == (budget == 'log_fits')
+        assert os.path.exists(new.path())
