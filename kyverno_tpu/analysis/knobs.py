@@ -33,9 +33,12 @@ def _k(name, default, kind, module, help):  # noqa: A002 - table DSL
 
 KNOBS: Dict[str, Knob] = dict([
     # -- device scan pipeline ------------------------------------------------
-    _k('KTPU_SCAN_CHUNK', '16384', 'int', 'compiler/scan.py',
+    _k('KTPU_SCAN_CHUNK', '8192', 'int', 'compiler/scan.py',
        'Rows per device chunk (the bulk canonical batch capacity); '
-       'large scans stream fixed-size chunks.'),
+       'large scans stream fixed-size chunks.  A scan\'s first report '
+       'waits for one whole chunk\'s encode, and every chunk pays a '
+       'fixed host cost, so the size trades pipeline fill against '
+       'per-chunk overhead.'),
     _k('KTPU_SMALL_BATCH', '64', 'int', 'compiler/scan.py',
        'The small canonical batch capacity (compiler/shapes.py): '
        'batches at or below this size pad to it — the admission shape. '

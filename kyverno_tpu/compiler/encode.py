@@ -1482,8 +1482,8 @@ def _fill_elem_gather_column(rows: list, lanes: Lanes, meta, egwidth: int,
 # this module because this module imports no jax: a worker, and the fork
 # server it comes from, import nothing else.
 #
-# A chunk's lanes do not travel home through the pool's result pipe (272
-# MB at capacity 16,384: the parent's result thread reads a pipe 64 kB
+# A chunk's lanes do not travel home through the pool's result pipe (136
+# MB at capacity 8,192: the parent's result thread reads a pipe 64 kB
 # at a time and retakes the interpreter lock after every read).  The
 # worker encodes them in place into a shared-memory block, as views of
 # the packed buffers it lays there, and returns what places them: the

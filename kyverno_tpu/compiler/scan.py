@@ -916,7 +916,7 @@ class BatchScanner:
 
     #: fixed device-chunk size: XLA compiles the evaluator once per
     #: distinct batch shape, so large scans stream fixed-size chunks
-    CHUNK = int(__import__('os').environ.get('KTPU_SCAN_CHUNK', '16384'))
+    CHUNK = int(__import__('os').environ.get('KTPU_SCAN_CHUNK', '8192'))
     #: the admission batch capacity: batches at or below this size pad
     #: to it (compiler/shapes.py) and run on the default device like
     #: every other batch

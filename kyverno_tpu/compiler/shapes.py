@@ -6,19 +6,22 @@ deserialize-or-compile per shape before it serves (BENCH r03-r05
 measured the power-of-two bucket ladder at 49-93s of warm against ~28s
 of actual scan).  This module replaces that ladder with a *canonical
 capacity table* — by default just ``{KTPU_SMALL_BATCH, KTPU_SCAN_CHUNK}``
-— so a policy set compiles at most two row shapes, ever:
+= ``{64, 8192}`` — so a policy set compiles at most two row shapes, ever:
 
 * batches at or below the small capacity pad to it (the admission
   shape);
 * everything else pads to the chunk capacity (the bulk-scan shape;
-  multi-chunk scans stream it).
+  multi-chunk scans stream it).  The chunk is sized for pipeline fill:
+  a streamed scan's first report waits for one whole chunk's encode,
+  and the device (busy well under 1% of a full scan on a TPU v5e)
+  gains nothing from a larger dispatch.
 
 The evaluator takes the row count along with the tensors (the
 ``__rowvalid__`` lane emitted by ``encode_batch``) and masks the tail
 rows inside the jitted program, so occupancy is ragged while the
 compiled shape stays fixed — the Ragged Paged Attention trick applied
 to policy batches.  ``KTPU_CANONICAL_CAPS`` inserts extra capacities
-(e.g. ``64,1024,16384``) for deployments whose mid-size rescans are
+(e.g. ``64,1024,8192``) for deployments whose mid-size rescans are
 transfer-bound; every entry is one more executable to warm.
 
 ktpu-lint KTPU204 flags any ``encode_batch`` / ``encode_mutate_batch``
@@ -57,7 +60,7 @@ def canonical_caps(chunk: Optional[int] = None,
         except ValueError:
             pass
     if chunk is None:
-        chunk = _env_int('KTPU_SCAN_CHUNK', 16384)
+        chunk = _env_int('KTPU_SCAN_CHUNK', 8192)
     if small is None:
         small = _env_int('KTPU_SMALL_BATCH', 64)
     return tuple(sorted({max(small, 1), max(chunk, 1)}))

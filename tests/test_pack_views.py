@@ -48,7 +48,7 @@ from kyverno_tpu.observability.metrics import MetricsRegistry  # noqa: E402
 from kyverno_tpu.ops import eval as eval_mod  # noqa: E402
 
 ADMISSION_CAP = 64
-CHUNK = 128  # a scan's chunk capacity here (16,384 on the chip)
+CHUNK = 128  # a scan's chunk capacity here (8,192 on the chip)
 tenant_policies = benchlib.load_module('generators', 'tenant_policies')
 
 
@@ -197,12 +197,14 @@ class TestWhatTheEvaluatorIsHanded:
             assert not scanner._encoder_pool._broken
 
 
-@pytest.mark.parametrize('rows', [64, 16384])
+@pytest.mark.parametrize('rows', [64, 8192, 16384])
 def test_the_committed_pack_at_the_chips_capacities(rows):
     """The cells' batch (elements, gathers and element gathers 4 wide)
-    at the capacities the chip runs: planned offset for offset as the
-    parent plans it.  Zero-stride stand-ins: a plan reads dtype and
-    shape, and 16,384 rows of lanes are 272 MB."""
+    at the capacities the chip runs (64 and the bulk default 8,192; 16,384
+    a ``KTPU_SCAN_CHUNK`` a deployment may set): planned offset for offset
+    as the parent plans it.  Zero-stride
+    stand-ins: a plan reads dtype and shape, and 16,384 rows of lanes
+    are 272 MB."""
     from kyverno_tpu.compiler.encode import lane_signature
     scanner = BatchScanner(policy_set('pack'))
     joining = scanner._arena.joining

@@ -71,15 +71,20 @@ def pod(i):
 
 
 class TestShapeTable:
-    def test_default_table_is_small_and_chunk(self):
-        caps = canonical_caps(chunk=16384, small=64)
-        assert caps == (64, 16384)
-        assert canonical_capacity(1, chunk=16384, small=64) == 64
-        assert canonical_capacity(64, chunk=16384, small=64) == 64
-        assert canonical_capacity(65, chunk=16384, small=64) == 16384
+    def test_default_table_is_small_and_chunk(self, monkeypatch):
+        for name in ('KTPU_CANONICAL_CAPS', 'KTPU_SCAN_CHUNK',
+                     'KTPU_SMALL_BATCH'):
+            monkeypatch.delenv(name, raising=False)
+        assert canonical_caps() == (64, 8192)
+        assert (BatchScanner.SMALL_BATCH, BatchScanner.CHUNK) == (64, 8192)
+        caps = canonical_caps(chunk=8192, small=64)
+        assert caps == (64, 8192)
+        assert canonical_capacity(1, chunk=8192, small=64) == 64
+        assert canonical_capacity(64, chunk=8192, small=64) == 64
+        assert canonical_capacity(65, chunk=8192, small=64) == 8192
         # spill: the top entry also serves row counts beyond it
         # (callers chunk above it)
-        assert canonical_capacity(99999, chunk=16384, small=64) == 16384
+        assert canonical_capacity(99999, chunk=8192, small=64) == 8192
 
     def test_env_override_is_the_whole_table(self, monkeypatch):
         monkeypatch.setenv('KTPU_CANONICAL_CAPS', '32, 512,4096')
@@ -163,7 +168,7 @@ class TestValidateMaskBoundaries:
         policies = validate_pack()
         scanner = BatchScanner(policies)
         # shrink the chunk so the spill (capacity+1) case streams two
-        # canonically-shaped parts instead of a 16384-row pad
+        # canonically-shaped parts instead of a bulk-capacity pad
         scanner.CHUNK = 128
         cap = scanner.SMALL_BATCH  # the small canonical capacity
         for n in (1, cap - 1, cap, cap + 1, 129):

@@ -86,6 +86,13 @@ def pack():
     return cps, evaluator, packed, layout
 
 
+def bulk_capacity() -> int:
+    """The bulk canonical capacity a streamed scan dispatches at (the
+    top of ``compiler/shapes.py``'s default table)."""
+    from kyverno_tpu.compiler.shapes import canonical_caps
+    return canonical_caps()[-1]
+
+
 def _shapes(packed, capacity, sharding):
     import jax
     return {k: jax.ShapeDtypeStruct((capacity,) + v.shape[1:], v.dtype,
@@ -105,10 +112,12 @@ def _report(what, compiled):
     return mem
 
 
-@pytest.mark.parametrize('capacity', [64, 16384])
+@pytest.mark.parametrize('capacity', [64, 'bulk'])
 def test_evaluator_compiles_for_one_chip(pack, one_chip,
                                          no_persistent_cache, capacity):
     import jax
+    if capacity == 'bulk':
+        capacity = bulk_capacity()
     _cps, evaluator, packed, layout = pack
     assert any(v.dtype == np.int64 for v in packed.values()), \
         'the pack no longer carries the i64 lanes this test is about'
@@ -145,11 +154,12 @@ def test_context_evaluator_compiles_for_one_chip(one_chip,
     assert len(lanes) == 8
     tensors.update(lanes)
     packed, layout = pack_batch(tensors)
+    capacity = bulk_capacity()
     with evaluator.compile_lock, jax.enable_x64(True):
         evaluator.layout_holder['layout'] = layout
         compiled = evaluator.jitted.lower(
-            _shapes(packed, 16384, one_chip)).compile()
-    _report('context evaluator@16384', compiled)
+            _shapes(packed, capacity, one_chip)).compile()
+    _report(f'context evaluator@{capacity}', compiled)
 
 
 def test_mutate_kernel_compiles_for_one_chip(one_chip,
@@ -169,7 +179,7 @@ def test_mutate_kernel_compiles_for_one_chip(one_chip,
     lanes = encode_mutate_batch([WARM_POD], program, padded_n=64,
                                 width=string_window(program))
     assert lanes['milli'].dtype == np.int64
-    for capacity in (64, 16384):
+    for capacity in (64, bulk_capacity()):
         with jax.enable_x64(True):
             compiled = jax.jit(kernel.mutate_eval).lower(
                 _shapes(lanes, capacity, one_chip)).compile()
@@ -214,7 +224,7 @@ def test_sharded_step_compiles_for_the_2x2_mesh(pack, topo,
     # (parallel/mesh.py distributed_scan_step)
     packed, layout = pack_batch(
         encode_batch([WARM_POD], cps, padded_n=64).tensors())
-    capacity = 16384
+    capacity = bulk_capacity()
     rows = NamedSharding(mesh, P('data', None))
     with step.evaluator.compile_lock, jax.enable_x64(True):
         step.evaluator.layout_holder['layout'] = layout
