@@ -29,10 +29,10 @@ module API, and exit codes are unchanged).
   (generated from the same catalog) can never document spans that no
   longer exist.
 * **KTPU507** — pipeline stage-label drift: a ``stage('<s>')`` /
-  ``exec_scope`` / ``ChunkPipeline`` stage-list / ``add_backpressure``
+  ``ChunkPipeline`` stage-list / ``add_backpressure``
   label used under ``compiler/`` that is not registered in
-  ``observability/catalog.py:PIPELINE_STAGES`` (the timeline
-  critical-path walk and the blame metric group by registered names,
+  ``observability/catalog.py:PIPELINE_STAGES`` (the stage
+  histogram and the trace group by registered names,
   so an unregistered label silently drops out of attribution), or a
   registered stage with no use site anywhere in the tree (dead-stage
   check, the KTPU503/505 analogue).
@@ -499,7 +499,7 @@ def collect_stage_labels(files: List[SourceFile]
     """Pipeline stage-label sites across a parsed file set:
     ``stage('<s>')`` timers, ``record_stage('<s>', ...)`` samples,
     ``annotation('<s>')`` marks, ``add_backpressure('<s>', ...)``
-    attributions, ``exec_scope(tl, c, '<s>')`` inline wrappers, and the
+    attributions, and the
     literal ``(name, fn)`` stage lists handed to ``ChunkPipeline``.
     Non-literal labels are skipped (variables flow from these same
     literal surfaces)."""
@@ -516,11 +516,6 @@ def collect_stage_labels(files: List[SourceFile]
             if attr in ('stage', 'add_backpressure', 'record_stage',
                         'annotation'):
                 arg = node.args[0]
-                if isinstance(arg, ast.Constant) and \
-                        isinstance(arg.value, str):
-                    sites.append((sf, node.lineno, arg.value))
-            elif attr == 'exec_scope' and len(node.args) >= 3:
-                arg = node.args[2]
                 if isinstance(arg, ast.Constant) and \
                         isinstance(arg.value, str):
                     sites.append((sf, node.lineno, arg.value))
@@ -551,8 +546,7 @@ def _check_stage_labels(ctx: Context) -> Iterable[Finding]:
                 'KTPU507', line,
                 f'stage label {label!r} is not a registered pipeline '
                 f'stage (observability/catalog.py PIPELINE_STAGES) — '
-                f'register it, or the timeline critical-path walk and '
-                f'the blame metric silently drop its intervals')
+                f'register it, or its samples drop out of attribution')
     used = {label for _sf, _l, label in sites}
     anchor = ctx.by_rel('kyverno_tpu/observability/catalog.py')
 
@@ -572,7 +566,7 @@ def _check_stage_labels(ctx: Context) -> Iterable[Finding]:
         target, line = locate(name)
         yield target.finding(
             'KTPU507', line,
-            f'stage registry: {name!r} has no stage()/exec_scope/'
+            f'stage registry: {name!r} has no stage()/'
             f'ChunkPipeline/add_backpressure site in the tree — '
             f'remove the entry or add the stage')
 

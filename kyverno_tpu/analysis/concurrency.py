@@ -20,7 +20,7 @@ rules now:
   every such write would drown the signal (the rule checks lock
   *discipline*, not the absence of a threading design).
 * **KTPU602** — a thread target whose reachable set records stage
-  spans (``stage(...)`` / ``exec_scope(...)``) but never re-installs
+  spans (``stage(...)``) but never re-installs
   telemetry (``install_capture`` / ``install_span`` /
   ``ScanCapture``) — the worker's device work would record into no
   capture and parent to no request span.
@@ -52,7 +52,7 @@ _LOCK_CTORS = {'Lock', 'RLock', 'Condition', 'Semaphore',
                'BoundedSemaphore'}
 
 #: calls that record into the ambient stage-span machinery
-_STAGE_CALLS = {'stage', 'exec_scope'}
+_STAGE_CALLS = {'stage'}
 
 #: calls that (re-)install the ambient telemetry on a thread
 _INSTALL_CALLS = {'install_capture', 'install_span', 'ScanCapture'}

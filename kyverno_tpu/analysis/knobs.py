@@ -206,17 +206,6 @@ KNOBS: Dict[str, Knob] = dict([
     _k('KTPU_SLO_TARGET', '0.99', 'float', 'observability/slo.py',
        'Fraction of decisions that must meet KTPU_SLO_P99_MS; the '
        'error budget is 1 - target.'),
-    _k('KTPU_TIMELINE', '1', 'bool', 'observability/timeline.py',
-       'Arm the per-chunk pipeline timeline recorder when the '
-       'observability stack configures it; 0 keeps the recorder off '
-       'entirely — the scan path is bit-identical to a build without '
-       'it (same contract as the flight recorder and the SLO '
-       'engine).'),
-    _k('KTPU_TIMELINE_N', '4096', 'int', 'observability/timeline.py',
-       'Per-scan timeline event budget (enqueue/exec/retry/block '
-       'intervals); events beyond it are counted as dropped, never '
-       'buffered, so the recorder stays bounded however long a scan '
-       'streams.'),
     _k('KTPU_PROFILE_DIR', '<repo>/.cache/profiles', 'path',
        'observability/profiling.py',
        'Directory for on-demand deep-profile captures '

@@ -1619,9 +1619,7 @@ def encode_worker_init(cps, joining=None) -> None:
 
 def encode_worker(args):
     """Encode one chunk into a block; returns ``(block name, (the
-    batch's shape key, the buffers' layout), stage seconds, (t0, t1,
-    pid))``."""
-    import os
+    batch's shape key, the buffers' layout), stage seconds, (t0, t1))``."""
     global _WORKER_LAST_END
     docs, contexts, padded_n, offer = args
     arena = _WORKER_ARENA
@@ -1630,7 +1628,7 @@ def encode_worker(args):
     # process — the pipeline threads re-install the scan's ScanCapture,
     # and this is the process-side analogue: measure into a fresh local
     # capture and ship the stage seconds (plus the wall interval, for
-    # the timeline) home with the layout; the resolving pipeline
+    # encode_submit/_return) home with the layout; the resolving pipeline
     # thread re-attributes them via devtel.merge_worker_stages.  With
     # ``encode`` go its CPU twin (the worker has one thread, so wall
     # less CPU is what the machine kept from it), encode_batch's five
@@ -1657,4 +1655,4 @@ def encode_worker(args):
     key = batch.arena_key
     del batch  # its lanes are views of the mapping about to close
     shm.close()
-    return shm.name, (key, layout), dict(cap.stages), (t0, t1, os.getpid())
+    return shm.name, (key, layout), dict(cap.stages), (t0, t1)

@@ -91,11 +91,8 @@ class ChunkPipeline:
                  depth: Optional[int] = None, capture=None,
                  parent_span=None,
                  cleanup: Optional[Callable[[Any], None]] = None,
-                 retries: Optional[int] = None, timeline=None):
+                 retries: Optional[int] = None):
         self.stages = list(stages)
-        #: per-scan event recorder (observability/timeline.py
-        #: ScanTimeline) — None keeps every hook on its no-cost branch
-        self.timeline = timeline
         self.depth = depth if depth is not None else pipeline_depth()
         self.capture = capture
         self.parent_span = parent_span
@@ -129,9 +126,6 @@ class ChunkPipeline:
         t0 = time.monotonic()
         q.put(item)
         devtel.add_backpressure(stage, time.monotonic() - t0)
-        tl = self.timeline
-        if tl is not None and isinstance(item, _Item):
-            tl.block(item.seq, stage, t0)
 
     def _cleanup(self, value: Any) -> None:
         """Best-effort owner cleanup for a chunk that will never reach
@@ -160,12 +154,9 @@ class ChunkPipeline:
                 # KeyboardInterrupt/SystemExit must surface immediately
                 if attempt <= self.retries and isinstance(e, Exception) \
                         and not self._stop.is_set():
-                    t_r = time.monotonic()
                     from ..observability import device as devtel
                     devtel.record_stage_retry(name)
                     time.sleep(_RETRY_BACKOFF_S * (2.0 ** (attempt - 1)))
-                    if self.timeline is not None:
-                        self.timeline.retry(item.seq, name, t_r, attempt)
                     continue
                 if attempt > 1:
                     # the whole retry budget burned: mark the error so
@@ -192,9 +183,6 @@ class ChunkPipeline:
         name, fn = self.stages[i]
         qin = self._queues[i]
         qout = self._queues[i + 1] if i + 1 < len(self.stages) else self._out
-        next_name = self.stages[i + 1][0] if i + 1 < len(self.stages) \
-            else None
-        tl = self.timeline
         # worker threads have no ambient span/capture: re-install the
         # scan's so stage spans join the caller's trace and stage time
         # lands on the right provenance record
@@ -206,24 +194,15 @@ class ChunkPipeline:
                     qout.put(item)
                     return
                 if item.error is None and not self._stop.is_set():
-                    if tl is not None:
-                        tl.start(item.seq, name)
                     # what this thread's stages write into the
                     # profiler's trace names the chunk they belong to
                     with devtel.trace_ids(chunk=item.seq):
                         self._run_stage(name, fn, item)
-                    if tl is not None:
-                        tl.end(item.seq, name, ok=item.error is None)
                 self._put(qout, name, item)
-                if tl is not None and next_name is not None \
-                        and item.error is None:
-                    tl.enqueue(item.seq, next_name)
 
     def _feed(self, items: Iterable) -> None:
         from ..observability import device as devtel
         intake = self._queues[0]
-        first_stage = self.stages[0][0] if self.stages else ''
-        tl = self.timeline
         try:
             for seq, value in enumerate(items):
                 waited = 0.0
@@ -233,15 +212,10 @@ class ChunkPipeline:
                         return
                 if waited:
                     devtel.add_backpressure('intake', waited)
-                    if tl is not None:
-                        tl.record('intake', seq,
-                                  time.monotonic() - waited, kind='block')
                 if self._stop.is_set():
                     self._slots.release()
                     return
                 self._track(1)
-                if tl is not None:
-                    tl.enqueue(seq, first_stage)
                 self._put(intake, 'intake', _Item(value, seq))
         finally:
             intake.put(_SENTINEL)
@@ -292,8 +266,3 @@ class ChunkPipeline:
             with self._inflight_lock:
                 self._inflight = 0
             devtel.set_pipeline_inflight(0)
-            if self.timeline is not None:
-                # workers are joined: close exec intervals a stage had
-                # open when the stream was torn down, so the timeline
-                # never leaks orphan intervals on early generator close
-                self.timeline.close_open()

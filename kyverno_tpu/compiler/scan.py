@@ -948,7 +948,7 @@ class BatchScanner:
                               contexts: Optional[List[dict]] = None,
                               match: Optional[np.ndarray] = None,
                               adm_plan: Optional[Any] = None,
-                              match_fn=None, timeline=None):
+                              match_fn=None):
         """Yield ``(start, status, detail, fdet, adm, chunk_match)`` per
         fixed-size chunk; ``adm`` is the device's per-row
         admission-match decision for the eligible program columns (None
@@ -980,11 +980,10 @@ class BatchScanner:
             return
         if self._composer is not None:
             yield from self._partitioned_status_chunks(
-                resources, contexts, match, match_fn, timeline)
+                resources, contexts, match, match_fn)
             return
         import jax
         from ..observability import device as devtel
-        from ..observability import timeline as tlmod
         from ..observability import tracing
         from ..ops.eval import expand_compact, shard_batch
         from .pipeline import ChunkPipeline
@@ -1126,9 +1125,7 @@ class BatchScanner:
                         # stage seconds measured inside the worker:
                         # fold into the parent's histogram and the
                         # ambient ScanCapture (installed on this
-                        # pipeline thread), and pin the worker's wall
-                        # interval on the timeline with its process
-                        # identity — processes of one host share the
+                        # pipeline thread); processes of one host share the
                         # monotonic clock on Linux
                         devtel.record_encode_worker('ok')
                         devtel.merge_worker_stages(wstages)
@@ -1142,11 +1139,6 @@ class BatchScanner:
                         devtel.record_stage(
                             'encode_return',
                             max(0.0, t_home - max(wspan[1], t_wait)))
-                        if timeline is not None and wspan is not None:
-                            timeline.record(
-                                'encode', start // chunk, wspan[0],
-                                wspan[1],
-                                thread='ktpu-encproc-%d' % wspan[2])
                 # home once: a retry of this stage starts from the lanes
                 p['enc'] = tensors
             cm = p['cm']
@@ -1272,14 +1264,10 @@ class BatchScanner:
                         parent=tel_parent):
                 p = None
                 try:
-                    with tlmod.exec_scope(timeline, 0, 'encode'):
-                        p = stage_encode(0)
-                    with tlmod.exec_scope(timeline, 0, 'h2d'):
-                        p = stage_h2d(p)
-                    with tlmod.exec_scope(timeline, 0, 'device_eval'):
-                        p = stage_eval(p)
-                    with tlmod.exec_scope(timeline, 0, 'd2h'):
-                        result = stage_d2h(p)
+                    p = stage_encode(0)
+                    p = stage_h2d(p)
+                    p = stage_eval(p)
+                    result = stage_d2h(p)
                 except BaseException:
                     # the inline path has no pipeline cleanup hook: a
                     # stage crash must still hand the chunk's encode
@@ -1301,7 +1289,7 @@ class BatchScanner:
             [('encode', stage_encode), ('h2d', stage_h2d),
              ('device_eval', stage_eval), ('d2h', stage_d2h)],
             depth=depth, capture=tel_capture, parent_span=tel_parent,
-            cleanup=release_chunk, timeline=timeline)
+            cleanup=release_chunk)
         yield from pipe.run(range(0, n, chunk))
 
     def context_digest(self, resource: dict) -> Optional[tuple]:
@@ -1331,7 +1319,7 @@ class BatchScanner:
     def _partitioned_status_chunks(self, resources: List[dict],
                                    contexts: Optional[List[dict]] = None,
                                    match: Optional[np.ndarray] = None,
-                                   match_fn=None, timeline=None):
+                                   match_fn=None):
         """Partitioned twin of ``_device_status_chunks``: each chunk
         encodes and dispatches once per partition runtime (the
         partition's own slot vocabulary, match plane and executable),
@@ -1354,7 +1342,6 @@ class BatchScanner:
         n = len(resources)
         import jax
         from ..observability import device as devtel
-        from ..observability import timeline as tlmod
         from ..observability import tracing
         from ..ops.eval import (expand_compact, fold_match_unique,
                                 shard_batch)
@@ -1459,22 +1446,14 @@ class BatchScanner:
                     tracing.tracer().start_span(
                         'kyverno/device/chunk', {'chunk_start': 0},
                         parent=tel_parent):
-                with tlmod.exec_scope(timeline, 0, 'encode'):
-                    p = stage_encode(0)
-                with tlmod.exec_scope(timeline, 0, 'h2d'):
-                    p = stage_h2d(p)
-                with tlmod.exec_scope(timeline, 0, 'device_eval'):
-                    p = stage_eval(p)
-                with tlmod.exec_scope(timeline, 0, 'd2h'):
-                    result = stage_d2h(p)
+                result = stage_d2h(stage_eval(stage_h2d(stage_encode(0))))
             yield result
             return
 
         pipe = ChunkPipeline(
             [('encode', stage_encode), ('h2d', stage_h2d),
              ('device_eval', stage_eval), ('d2h', stage_d2h)],
-            capture=tel_capture, parent_span=tel_parent,
-            timeline=timeline)
+            capture=tel_capture, parent_span=tel_parent)
         yield from pipe.run(range(0, n, chunk))
 
     def _next_chunk(self, chunks, n: int, at: int):
@@ -1628,12 +1607,10 @@ class BatchScanner:
         # the with-block): holding one span across yields would leak the
         # current-span contextvar into the consumer and record a bogus
         # error when the consumer stops iterating early
-        from ..observability import timeline as tlmod
         from ..observability import tracing
-        tl = tlmod.begin_scan()
         chunk_cap = max(self.CHUNK, 1)
         chunks = self._device_status_chunks(resources, contexts, match,
-                                            adm_plan=plan, timeline=tl)
+                                            adm_plan=plan)
         tally = coverage.scan_tally()
         start = 0
         try:
@@ -1657,7 +1634,6 @@ class BatchScanner:
                             match[np.ix_(start + vr, self._adm_cols)] = \
                                 adm_out[vr].astype(bool)
                     span.set_attribute('resources', status.shape[0])
-                    t_rep = time.monotonic() if tl is not None else 0.0
                     with devtel.stage('report',
                                       {'rows': status.shape[0],
                                        'chunk': start // chunk_cap}
@@ -1678,8 +1654,6 @@ class BatchScanner:
                                 span.set_attribute(
                                     'device_coverage_ratio',
                                     round(ratio, 4))
-                    if tl is not None:
-                        tl.record('report', start // chunk_cap, t_rep)
                 start += status.shape[0]
                 yield from chunk_rows
         finally:
@@ -1691,11 +1665,7 @@ class BatchScanner:
                 cap = devtel.current_capture()
                 if cap is not None:
                     cap.coverage_ratio = tally.ratio()
-            # tear the pipeline down BEFORE finalizing the timeline:
-            # close_open/drain must have run so the blame walk sees
-            # every interval closed (deterministic on early close too)
             chunks.close()
-            tlmod.finish_scan(tl)
 
     def _assemble_chunk(self, resources, wrapped, match, start, status,
                         detail, fdet, now, ts, background_mode,
@@ -2052,12 +2022,9 @@ class BatchScanner:
             return self.match_matrix(part, [Resource(r) for r in part])
 
         from ..observability import device as devtel
-        from ..observability import timeline as tlmod
-        tl = tlmod.begin_scan()
         chunk_cap = max(self.CHUNK, 1)
         chunks = self._device_status_chunks(resources, None,
-                                            match_fn=match_fn,
-                                            timeline=tl)
+                                            match_fn=match_fn)
         tally = coverage.scan_tally()
         flush = max(1, self.REPORT_FLUSH_ROWS)
         host_idx = [p_idx for p_idx in self._host_policy_idx
@@ -2083,7 +2050,6 @@ class BatchScanner:
                     w1 = min(w0 + flush, m)
                     wm = w1 - w0
                     ids = {'chunk': seq, 'rows': wm}
-                    t_rep = time.monotonic() if tl is not None else 0.0
                     with devtel.stage('report', ids) as rstage:
                         rows, row_pols, counts = \
                             self._assemble_report_window(
@@ -2098,8 +2064,6 @@ class BatchScanner:
                                 rstage.set_attribute(
                                     'device_coverage_ratio',
                                     round(ratio, 4))
-                    if tl is not None:
-                        tl.record('report', start // chunk_cap, t_rep)
                     # ``store``: what the consumer of the rows does
                     # with the thread between the rows of this window
                     # (the reports controller: build, label, cache and
@@ -2165,10 +2129,7 @@ class BatchScanner:
                 cap = devtel.current_capture()
                 if cap is not None:
                     cap.coverage_ratio = tally.ratio()
-            # pipeline teardown first (close_open/drain), then the
-            # blame walk — see _scan_inner
             chunks.close()
-            tlmod.finish_scan(tl)
 
     def _cell(self, prog, j: int, st: int, det: int, fdet_row, ts: int,
               fly: Dict[Tuple, Any], tally=None, doc=None):

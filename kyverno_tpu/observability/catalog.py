@@ -278,12 +278,6 @@ METRICS: Dict[str, Metric] = {
     'kyverno_tpu_executable_device_seconds_total': Metric(
         'counter', 'Cumulative seconds the dispatches took to '
         'enqueue (not device time) per executable acquisition source.'),
-    # pipeline critical-path observatory (observability/timeline.py)
-    'kyverno_tpu_pipeline_blame_seconds_total': Metric(
-        'counter', 'Exclusive critical-path blame per streaming-scan '
-        'stage: seconds of scan wall the timeline walk attributed to '
-        'stage= (executing or gated-waiting while on the e2e critical '
-        'path); per-scan fractions drive the bottleneck advisor.'),
     # mesh-step telemetry (parallel/mesh.py, observability/fleet.py)
     'kyverno_tpu_mesh_step_duration_seconds': Metric(
         'histogram', 'Sharded-dispatch wall per mesh step: one series '
@@ -402,8 +396,7 @@ SPANS: Dict[str, str] = {
 #: sample, ``annotation('<s>')`` mark, ``ChunkPipeline`` stage-list
 #: entry, and backpressure attribution in the tree (ktpu-lint KTPU507:
 #: an unregistered label under ``compiler/`` or a dead registry entry
-#: is catalog drift).  The timeline recorder and its critical-path
-#: blame walk (observability/timeline.py) group events by these names.
+#: is catalog drift).
 PIPELINE_STAGES: Dict[str, str] = {
     'intake': 'Feeder admission into the streaming pipeline (chunk '
               'slot acquire + first-queue handoff).',
@@ -421,8 +414,7 @@ PIPELINE_STAGES: Dict[str, str] = {
     'device_eval': 'Device evaluation dispatch (the enqueue).',
     'd2h': 'Device-to-host readback (stall-watchdog armed): wait + copy.',
     'report': 'Report-row assembly / flush window.',
-    # leaf stages beside the pipeline's legs (not blamed by the
-    # timeline walk: the first four lie inside a leg's interval)
+    # leaf stages (the first four lie inside a leg's interval)
     'match': 'Host match sieve (inside the encode leg on the scan path).',
     'encode_wait': 'h2d leg waiting out a worker\'s encode of the '
                    'chunk (no transfer: the lanes are in a block).',

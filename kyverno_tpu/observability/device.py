@@ -244,7 +244,7 @@ class ScanCapture:
     wall clock — for a scan whose stages run once a chunk or a window,
     not for a dispatch that answers requests."""
 
-    __slots__ = ('stages', 'aot', 'coverage_ratio', 'critical_path',
+    __slots__ = ('stages', 'aot', 'coverage_ratio',
                  'pack_views', 'cpu', '_lock')
 
     def __init__(self, cpu: bool = False):
@@ -255,9 +255,6 @@ class ScanCapture:
         #: buffers their lanes were encoded into (:func:`record_pack`)
         self.pack_views = 0
         self.coverage_ratio: Optional[float] = None
-        #: critical-path blame summary for this scan, filled by the
-        #: timeline recorder (observability/timeline.py) when armed
-        self.critical_path: Optional[Dict[str, Any]] = None
         self._lock = threading.Lock()
 
     def add(self, stage: str, seconds: float) -> None:

@@ -2,7 +2,7 @@
 federation.
 
 The single-host observability stack (stage spans, coverage, SLO burn,
-critical-path timelines) says nothing about the sharded mesh path —
+provenance) says nothing about the sharded mesh path —
 ``parallel/mesh.py`` dispatches over N devices and, before this module,
 emitted no per-shard attribution at all.  Three pieces close that gap:
 
@@ -19,7 +19,7 @@ emitted no per-shard attribution at all.  Three pieces close that gap:
   (``KTPU_FLEET_SKEW_WINDOW``) of per-step skew ratios (max-shard /
   mean-shard) per mesh shape.  Sustained skew with a stable slowest
   shard names the device, renders a ``bound_by=straggler`` verdict
-  through the critical-path advisor (``timeline.advise``) and fires
+  with a one-line note and fires
   the rate-limited deep profile (``profiling.deep_profile``, same
   single-fire/backoff contract as the SLO engine's auto-capture).
 
@@ -107,8 +107,8 @@ class SkewAnalyzer:
     One step's skew is ``max(shard_walls) / mean(shard_walls)`` — 1.0
     is perfectly balanced.  A window of steps with high mean skew AND a
     stable slowest shard is a *straggler*: the verdict names the shard
-    and its device, carries ``bound_by=straggler`` for the critical-path
-    advisor, and (once per :data:`PROFILE_MIN_INTERVAL_S`) captures a
+    and its device, carries ``bound_by=straggler`` and a one-line
+    note, and (once per :data:`PROFILE_MIN_INTERVAL_S`) captures a
     deep profile of the stalling process.
     """
 
@@ -129,7 +129,7 @@ class SkewAnalyzer:
              devices: Sequence[str]) -> Dict[str, Any]:
         """Fold one step's per-shard walls in; returns the step verdict
         (skew ratio, slowest shard/device, sustained flag and — when
-        sustained — the advisor's straggler note)."""
+        sustained — the straggler note)."""
         walls = [max(0.0, float(w)) for w in shard_walls]
         mean = sum(walls) / len(walls) if walls else 0.0
         peak = max(walls) if walls else 0.0
@@ -167,13 +167,14 @@ class SkewAnalyzer:
             'sustained': sustained,
         }
         if sustained:
-            # the straggler verdict rides the same advisor surface the
-            # pipeline critical path uses: the excess fraction is how
+            # the excess fraction is how
             # much of the slowest shard's wall is pure imbalance
-            from . import timeline
             frac = 1.0 - (mean / peak) if peak > 0 else 0.0
-            suggest, note = timeline.advise(
-                'straggler', frac, detail=f'shard {slow} ({device})')
+            suggest, note = {}, (  # no host knob fixes a slow device
+                f'mesh straggler: shard {slow} ({device}) carries '
+                f'{frac * 100:.0f}% excess device-eval wall over the skew '
+                f'window — rebalance or drain that device/host; deepening '
+                f'the host pipeline cannot help a slow shard')
             verdict['bound_by'] = 'straggler'
             verdict['suggest'] = suggest
             verdict['note'] = note

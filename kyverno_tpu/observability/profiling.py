@@ -351,30 +351,6 @@ def _r_deep_profile(query):
     return _json_body(deep_profile(seconds=seconds, trigger='manual'))
 
 
-@debug_route('/debug/timeline',
-             'Pipeline critical-path observatory: per-scan blame '
-             'summaries + cumulative stage blame (JSON; '
-             '`?format=chrome` exports the recent-scan timelines as '
-             'Chrome trace-event JSON — load it in Perfetto).')
-def _r_timeline(query):
-    from . import timeline
-    rec = timeline.recorder()
-    if rec is None:
-        return _json_body({'enabled': False})
-    if query.get('format', [''])[0] == 'chrome':
-        return _json_body(rec.chrome_trace())
-    return _json_body({
-        'enabled': True,
-        'scans': rec.n_scans,
-        'last': rec.last_summary,
-        'blame_totals_s': {s: round(v, 6)
-                           for s, v in rec.blame_totals().items()},
-        'wall_total_s': round(rec.wall_total(), 6),
-        'summaries': [tl.summary for tl in rec.scans()
-                      if tl.summary is not None],
-    })
-
-
 @debug_route('/metrics',
              'Prometheus text exposition of the active registry.')
 def _r_metrics(query):

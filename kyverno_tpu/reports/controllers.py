@@ -518,11 +518,6 @@ class BackgroundScanController:
                     busy = cap.busy_s()
                     span.set_attribute('overlap_ratio',
                                        round(busy / scan_wall, 4))
-                if cap.critical_path:
-                    from ..observability import timeline as tlmod
-                    span.set_attribute(
-                        'critical_path',
-                        tlmod.format_summary(cap.critical_path))
                 if prov_on:
                     # dense-scanned rows are riders of one shared tick
                     # scan: the tick's device_eval time amortizes over

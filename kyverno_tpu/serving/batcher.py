@@ -460,11 +460,6 @@ class AdmissionBatcher:
                 rows = scanner.scan(resources, contexts=contexts,
                                     admission=lead.admission,
                                     pctx_factory=pctx_factory, **extra)
-                if cap is not None and cap.critical_path is not None:
-                    from ..observability import timeline as tlmod
-                    bspan.set_attribute(
-                        'critical_path',
-                        tlmod.format_summary(cap.critical_path))
             with devtel.stage('resolve', parent=lead.span):
                 if prov_on:
                     device_eval_s = cap.stage_s('device_eval')

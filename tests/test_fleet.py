@@ -1,6 +1,6 @@
 """Fleet observatory unit surface (ISSUE 18): federation merge rules,
 the registry series-cardinality guard, skew-analyzer rate limiting,
-the persistent-cache feature guard, and the straggler advisor branch.
+the persistent-cache feature guard, and the straggler note.
 
 The mesh-integration half (bit-identity under KTPU_FLEET=0, injected
 straggler blame, endpoint/CLI agreement) lives in
@@ -11,7 +11,7 @@ import os
 
 import pytest
 
-from kyverno_tpu.observability import fleet, timeline
+from kyverno_tpu.observability import fleet
 from kyverno_tpu.observability.metrics import (SERIES_DROPPED,
                                                MetricsRegistry,
                                                global_registry,
@@ -275,17 +275,21 @@ class TestCacheFeatureGuard:
             set_global_registry(prev)
 
 
-# -- straggler advisor branch -------------------------------------------------
+# -- straggler note -----------------------------------------------------------
 
 class TestStragglerAdvice:
     def test_straggler_branch_names_the_shard(self):
-        suggest, note = timeline.advise('straggler', 0.7,
-                                        detail='shard 3 (TPU_3)')
-        assert suggest == {}  # no host-pipeline knob fixes a slow chip
-        assert 'shard 3 (TPU_3)' in note
-        assert '70%' in note
-
-    def test_existing_two_arg_callers_unchanged(self):
-        suggest, note = timeline.advise('device_eval', 0.5)
-        assert isinstance(suggest, dict) and isinstance(note, str)
-        assert 'straggler' not in note
+        an = fleet.SkewAnalyzer(window=2, now=lambda: 0.0,
+                                profile_trigger=lambda: None)
+        for _ in range(2):
+            v = an.fold('2x2', [1.0, 1.0, 1.0, 4.0],
+                        ['TPU_0', 'TPU_1', 'TPU_2', 'TPU_3'])
+        assert v['sustained']
+        assert v['bound_by'] == 'straggler'
+        assert v['suggest'] == {}  # no host-pipeline knob fixes a slow chip
+        # the wording operators alert on, held to the letter
+        assert v['note'] == (
+            'mesh straggler: shard 3 (TPU_3) carries 56% excess '
+            'device-eval wall over the skew window — rebalance or drain '
+            'that device/host; deepening the host pipeline cannot help '
+            'a slow shard')

@@ -209,33 +209,6 @@ class TestDataRoutes:
         assert out['enabled'] is True
         assert out['paths']['batch']['count'] == 1
 
-    def test_timeline_route_disabled_then_live(self, server):
-        from kyverno_tpu.observability import timeline
-        timeline.disable()
-        code, _, body = get(server, '/debug/timeline')
-        assert code == 200 and json.loads(body) == {'enabled': False}
-        timeline.configure(max_events=64)
-        try:
-            tl = timeline.begin_scan()
-            t0 = tl.t0
-            tl.record('encode', 0, t0, t0 + 0.01)
-            tl.record('device_eval', 0, t0 + 0.01, t0 + 0.03)
-            timeline.finish_scan(tl)
-            code, _, body = get(server, '/debug/timeline')
-            out = json.loads(body)
-            assert out['enabled'] is True and out['scans'] == 1
-            assert out['last']['bound_by'] == 'device_eval'
-            assert out['blame_totals_s']
-            assert out['summaries']
-            code, ctype, body = get(server,
-                                    '/debug/timeline?format=chrome')
-            assert code == 200 and ctype.startswith('application/json')
-            trace = json.loads(body)
-            assert timeline.validate_chrome_trace(trace) == []
-            assert trace['traceEvents']
-        finally:
-            timeline.disable()
-
     def test_concurrent_gets(self, server):
         """The threading server answers parallel requests — a slow
         sampling profile must not block the index."""

@@ -12,7 +12,10 @@ Pins the tentpole contracts of the streaming rebuild:
 * the d2h stall watchdog and the flight-recorder dump still fire when
   the readback runs on a pipeline worker thread;
 * verdict-cache replays interleave with miss chunks through the
-  streaming reconcile.
+  streaming reconcile;
+* closing the row generator early drains the pipeline clean, an
+  injected stage fault is retried without losing a row, and forked
+  encode workers ship their stage time home.
 """
 
 import json
@@ -29,6 +32,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import benchlib  # noqa: E402
+from kyverno_tpu import faults  # noqa: E402
 from kyverno_tpu.compiler.scan import BatchScanner  # noqa: E402
 from kyverno_tpu.observability import device as devtel  # noqa: E402
 from kyverno_tpu.observability import provenance  # noqa: E402
@@ -285,3 +289,97 @@ class TestReplayInterleavedWithMisses:
         key = lambda r: r['metadata']['name']  # noqa: E731
         assert [content(r) for r in sorted(reports, key=key)] == \
             [content(r) for r in sorted(dense_reports, key=key)]
+
+
+class TestEarlyClose:
+    # the arena only holds buffers of chunks encoded in this process:
+    # a chunk a pool worker encoded comes home as pickled tensors and
+    # has nothing to return, so the two placements are pinned apart
+    # (the default follows the host's CPU count)
+    @pytest.mark.parametrize('procs', [0, 2],
+                             ids=['inprocess', 'workers'])
+    def test_early_generator_close_drains_clean(self, small_chunk_scanner,
+                                                procs):
+        scanner = small_chunk_scanner
+        registry = MetricsRegistry()
+        devtel.configure(registry)
+        scanner._encoder_pool.procs = procs
+        released = []
+        inner_release = scanner._arena.release
+
+        def counting_release(batch):
+            released.append(1)
+            return inner_release(batch)
+        scanner._arena.release = counting_release
+        try:
+            docs = pods(4 * CAP)
+            gen = scanner.scan_report_results(docs)
+            next(gen)
+            gen.close()
+            if procs == 0:
+                assert released, \
+                    'early close returned no buffers to arena'
+            else:
+                assert not scanner._encoder_pool._broken
+                assert not released, \
+                    'worker-encoded chunks own no arena buffers'
+            assert registry.gauge_value(
+                'kyverno_tpu_scan_pipeline_inflight_chunks') == 0.0
+            # the scanner is fully reusable after the abort
+            rows = list(scanner.scan_report_results(docs))
+            assert len(rows) == len(docs)
+        finally:
+            scanner._arena.release = inner_release
+            scanner._encoder_pool.close()
+            devtel.disable()
+
+
+class TestRetries:
+    def test_injected_fault_lands_as_retry_event(self,
+                                                 small_chunk_scanner):
+        scanner = small_chunk_scanner
+        docs = pods(3 * CAP)
+        # warm first so compile/jit noise stays out of the fault scan
+        for _ in scanner.scan_report_results(docs[:CAP]):
+            pass
+        registry = MetricsRegistry()
+        devtel.configure(registry)
+        # second device_eval dispatch of the scan below fails once; the
+        # pipeline's per-chunk retry budget absorbs it
+        faults.configure('site=device_eval,nth=2')
+        try:
+            rows = list(scanner.scan_report_results(docs))
+        finally:
+            faults.disable()
+            devtel.disable()
+        assert len(rows) == len(docs), 'retry did not recover the chunk'
+        assert registry.counter_value(devtel.STAGE_RETRIES,
+                                      stage='device_eval') >= 1
+
+
+class TestForkedEncodeAttribution:
+    def test_forked_workers_ship_stage_time_home(self, policies,
+                                                 monkeypatch):
+        """KTPU_ENCODE_PROCS workers encode in their own process; their
+        measured encode seconds must land in the ambient ScanCapture,
+        not silently vanish (the regression this pins re-installed
+        capture context on the process side)."""
+        monkeypatch.setenv('KTPU_ENCODE_PROCS', '1')
+        registry = MetricsRegistry()
+        devtel.configure(registry)
+        scanner = BatchScanner(policies)
+        scanner.CHUNK = CAP
+        scanner.ENCODE_TIMEOUT_S = 60
+        try:
+            docs = pods(3 * CAP)
+            cap = devtel.ScanCapture()
+            with devtel.install_capture(cap):
+                rows = list(scanner.scan_report_results(docs))
+            assert len(rows) == len(docs)
+            assert not scanner._encoder_pool._broken, \
+                'encode pool fell back to in-process'
+            # capture attribution survived the process boundary
+            assert cap.stage_s('encode') > 0.0
+        finally:
+            scanner._encoder_pool.close()
+            devtel.disable()
