@@ -5,7 +5,11 @@ Dispatches pending UpdateRequests to the generate or mutate-existing
 processor, with bounded retries and cleanup of completed URs — the same
 worker/workqueue discipline as the reference, driven here by an explicit
 ``process_pending`` step so it composes with any scheduler (thread pool,
-asyncio, or a test loop).
+asyncio, or a test loop).  A pass hands its pending URs, in order, to the
+mutate-existing processor's batch (``process_pass``), which decides the
+mutate URs of the policies the device holds through the device mutate
+kernel and hands every other UR back to be processed here one at a time;
+``device=False`` processes every UR one at a time.
 """
 
 from __future__ import annotations
@@ -14,7 +18,7 @@ from typing import List, Optional
 
 from ..dclient.client import NotFoundError
 from .generate import GenerateController
-from .mutate_existing import MutateExistingController
+from .mutate_existing import MutateExistingController, WriteLog
 from .updaterequest import (
     KYVERNO_NAMESPACE, STATE_COMPLETED, STATE_FAILED, STATE_PENDING,
     UR_GENERATE, UR_MUTATE, UpdateRequest,
@@ -26,10 +30,15 @@ MAX_RETRIES = 10  # reference: update_request_controller.go:39 maxRetries
 class UpdateRequestController:
     """reference: pkg/background/update_request_controller.go:74"""
 
-    def __init__(self, client, engine, policy_getter=None):
+    def __init__(self, client, engine, policy_getter=None,
+                 device: bool = True):
         self.client = client
-        self.generate = GenerateController(client, engine, policy_getter)
-        self.mutate = MutateExistingController(client, engine, policy_getter)
+        self.device = device
+        # the processors write through one log, so that a batched pass
+        # knows what the URs it hands back wrote
+        log = WriteLog(client)
+        self.generate = GenerateController(log, engine, policy_getter)
+        self.mutate = MutateExistingController(log, engine, policy_getter)
         self._retries = {}
 
     def list_urs(self, state: Optional[str] = None) -> List[UpdateRequest]:
@@ -42,10 +51,14 @@ class UpdateRequestController:
     def process_pending(self) -> int:
         """One reconcile pass over all pending URs. Returns the number
         processed (reference: syncUpdateRequest worker loop)."""
-        n = 0
-        for ur in self.list_urs(STATE_PENDING):
-            self.sync_update_request(ur)
-            n += 1
+        pending = self.list_urs(STATE_PENDING)
+        if self.device:
+            self.mutate.process_pass(pending, self.sync_update_request,
+                                     self._finish)
+        else:
+            for ur in pending:
+                self.sync_update_request(ur)
+        n = len(pending)
         # synchronize=true generate URs re-reconcile continuously: the
         # reference watches downstream/source changes and re-enqueues
         # the UR (pkg/background/update_request_controller.go informer
@@ -92,6 +105,11 @@ class UpdateRequestController:
             self._store_status(ur)
             return
         span.set_attribute('result', 'error' if err is not None else 'ok')
+        self._finish(ur, err)
+
+    def _finish(self, ur: UpdateRequest, err: Optional[Exception]) -> None:
+        """A processed UR's retries and stored status, from the error its
+        processor returned."""
         if err is not None:
             key = ur.name
             self._retries[key] = self._retries.get(key, 0) + 1

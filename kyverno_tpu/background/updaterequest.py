@@ -5,6 +5,7 @@ pkg/webhooks/updaterequest/generator.go).
 
 from __future__ import annotations
 
+import bisect
 import copy
 import itertools
 from typing import Dict, List, Optional
@@ -151,20 +152,115 @@ class UpdateRequestGenerator:
                 return self.client.update_resource(
                     'kyverno.io/v1beta1', 'UpdateRequest',
                     KYVERNO_NAMESPACE, old)
-        ur = {
-            'apiVersion': 'kyverno.io/v1beta1',
-            'kind': 'UpdateRequest',
-            'metadata': {
-                'generateName': 'ur-',
-                'name': f'ur-{next(_counter)}',
-                'namespace': KYVERNO_NAMESPACE,
-                'labels': labels,
-            },
-            'spec': copy.deepcopy(ur_spec),
-            'status': {'state': STATE_PENDING},
-        }
         return self.client.create_resource(
-            'kyverno.io/v1beta1', 'UpdateRequest', KYVERNO_NAMESPACE, ur)
+            'kyverno.io/v1beta1', 'UpdateRequest', KYVERNO_NAMESPACE,
+            _new_ur(labels, ur_spec))
+
+    def apply_all(self, ur_specs: List[dict]) -> List[dict]:
+        """``apply`` of each spec in turn, from one listing of the URs
+        instead of one listing by selector a spec: a spec reuses the first
+        pending UR, in the listing's order, whose labels hold its own, the
+        URs this call created or updated included.  For what a policy
+        install spawns (``policy/controller.py``), thousands of URs at a
+        time; ``apply`` stays for one UR at admission, where the server
+        filters by the selector."""
+        index = _LabelIndex(self.client.list_resource(
+            'kyverno.io/v1beta1', 'UpdateRequest', KYVERNO_NAMESPACE))
+        out = []
+        for ur_spec in ur_specs:
+            labels = (generate_labels_set
+                      if ur_spec.get('requestType') == UR_GENERATE
+                      else mutate_labels_set)(
+                ur_spec.get('policy', ''), _trigger_from_spec(ur_spec))
+            for old in index.matching(labels):
+                state = ((old.get('status') or {}).get('state'))
+                if state in (None, '', STATE_PENDING):
+                    old['spec'] = copy.deepcopy(ur_spec)
+                    old.setdefault('status', {})['state'] = STATE_PENDING
+                    done = self.client.update_resource(
+                        'kyverno.io/v1beta1', 'UpdateRequest',
+                        KYVERNO_NAMESPACE, old)
+                    index.replace(old, done)
+                    break
+            else:
+                done = self.client.create_resource(
+                    'kyverno.io/v1beta1', 'UpdateRequest',
+                    KYVERNO_NAMESPACE, _new_ur(labels, ur_spec))
+                index.add(done)
+            out.append(done)
+        return out
+
+
+class _LabelIndex:
+    """URs by the values of a set of label keys, each list in the order
+    ``list_resource`` gives (namespace, then name): what a ``matchLabels``
+    selector of those keys selects, without a listing a selector."""
+
+    def __init__(self, urs: List[dict]):
+        self.urs = list(urs)
+        self._at = {id(ur): i for i, ur in enumerate(self.urs)}
+        self._by_keys: Dict[tuple, Dict[tuple, List[dict]]] = {}
+
+    @staticmethod
+    def _labels(ur: dict) -> Dict[str, str]:
+        return {str(k): str(v) for k, v in
+                ((ur.get('metadata') or {}).get('labels') or {}).items()}
+
+    @staticmethod
+    def _order(ur: dict) -> tuple:
+        meta = ur.get('metadata') or {}
+        return (meta.get('namespace', ''), meta.get('name', ''))
+
+    def _table(self, keys: tuple) -> Dict[tuple, List[dict]]:
+        table = self._by_keys.get(keys)
+        if table is None:
+            table = self._by_keys[keys] = {}
+            for ur in self.urs:
+                self._put(table, keys, ur)
+        return table
+
+    def _put(self, table, keys: tuple, ur: dict) -> None:
+        labels = self._labels(ur)
+        if all(k in labels for k in keys):
+            row = table.setdefault(tuple(labels[k] for k in keys), [])
+            bisect.insort(row, ur, key=self._order)
+
+    def matching(self, selector: Dict[str, str]) -> List[dict]:
+        keys = tuple(sorted(selector))
+        return list(self._table(keys).get(
+            tuple(str(selector[k]) for k in keys), ()))
+
+    def add(self, ur: dict) -> None:
+        self._at[id(ur)] = len(self.urs)
+        self.urs.append(ur)
+        for keys, table in self._by_keys.items():
+            self._put(table, keys, ur)
+
+    def replace(self, old: dict, new: dict) -> None:
+        """``old`` written back as ``new`` (its labels unchanged)."""
+        at = self._at.pop(id(old))
+        self.urs[at] = new
+        self._at[id(new)] = at
+        labels = self._labels(old)
+        for keys, table in self._by_keys.items():
+            if all(k in labels for k in keys):
+                row = table[tuple(labels[k] for k in keys)]
+                row[next(i for i, ur in enumerate(row) if ur is old)] = new
+
+
+def _new_ur(labels: Dict[str, str], ur_spec: dict) -> dict:
+    return {
+        'apiVersion': 'kyverno.io/v1beta1',
+        'kind': 'UpdateRequest',
+        'metadata': {
+            'generateName': 'ur-',
+            'name': f'ur-{next(_counter)}',
+            'namespace': KYVERNO_NAMESPACE,
+            'labels': labels,
+        },
+        'spec': copy.deepcopy(ur_spec),
+        'status': {'state': STATE_PENDING},
+    }
 
 
 def _trigger_from_spec(ur_spec: dict) -> Optional[dict]:

@@ -29,6 +29,13 @@ patch (several maps, other anchors, scalars, nested lists, appends),
 null values (RFC-7386 deletes), non-scalar values — does NOT lower and
 keeps the host engine, attributed on the coverage ledger.
 
+A rule with ``targets`` (mutate-existing) lowers for the background
+controller (``existing=True``): its match is decided on the trigger, its
+overlay on each target, and each target spec is resolved on the host,
+so a spec may read only the trigger's name and namespace
+(``TARGET_VARIABLE_RE``).  There each policy is placed on its own
+(``MutateSetProgram``).
+
 Set-level coupling: the admission mutate chain is CUMULATIVE (policy
 k+1 sees policy k's patched output — handlers.py Mutate loop), while
 the device decides every rule against the ORIGINAL document.  The two
@@ -43,6 +50,7 @@ that violates them places every mutate rule on the host with reason
 
 from __future__ import annotations
 
+import re
 from typing import Any, List, NamedTuple, Optional, Tuple
 
 from ..api.policy import Policy, Rule
@@ -189,9 +197,70 @@ def _check_sites(sites: List[EditSite]) -> Tuple[EditSite, ...]:
     return tuple(sites)
 
 
-def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
+#: a target spec's fields, and the variables its namespace and name may
+#: hold: the trigger's own name and namespace, which the controller
+#: substitutes as it resolves the spec (``background/mutate_existing.py``)
+TARGET_FIELDS = ('apiVersion', 'kind', 'namespace', 'name')
+TARGET_VARIABLE_RE = re.compile(
+    r'\{\{\s*request\.object\.metadata\.(name|namespace)\s*\}\}')
+
+#: what the background controller writes into a patched target after the
+#: patch (``background/mutate_existing.py``): no lowered edit may write
+#: them, or the patches of a later policy would not be the host's
+_CONTROLLER_PATHS = (
+    ('metadata', 'annotations', 'policies.kyverno.io/last-applied-patches'),
+    ('metadata', 'resourceVersion'))
+
+
+def _check_targets(targets: Any, existing: bool) -> None:
+    """A rule with ``targets``: for the background controller every spec
+    must be static but for the trigger's name and namespace in its
+    ``namespace`` and ``name``; at admission the engine applies the
+    overlay to the admitted object after substituting the whole rule,
+    targets included, so a variable there (which can fail to resolve
+    against that object) keeps the host."""
+    from ..engine.variables import tree_has_variables
+    if not existing:
+        if tree_has_variables(targets):
+            raise LowerError(
+                coverage.REASON_UNSUPPORTED_OPERATOR,
+                'variables in targets: the engine substitutes them '
+                'against the admitted object')
+        return
+    ok = isinstance(targets, list) and all(
+        isinstance(t, dict) and set(t) <= set(TARGET_FIELDS) and
+        all(isinstance(t.get(f), str) and t[f] and
+            not tree_has_variables(t[f]) for f in ('apiVersion', 'kind')) and
+        all(isinstance(t.get(f, ''), str) and not tree_has_variables(
+            TARGET_VARIABLE_RE.sub('', t.get(f, '')))
+            for f in ('namespace', 'name'))
+        for t in targets)
+    if not ok:
+        raise LowerError(
+            coverage.REASON_UNSUPPORTED_OPERATOR,
+            'target spec outside static apiVersion / kind and a namespace '
+            'and name that read only {{request.object.metadata.name}} or '
+            '{{request.object.metadata.namespace}}')
+
+
+def lower_mutate_rule(rule: Rule, policy_name: str,
+                      existing: bool = False) -> RuleMutateProgram:
     """Lower one mutate rule or raise :class:`LowerError` with the
-    taxonomy reason the placement record carries."""
+    taxonomy reason the placement record carries.  ``existing``: the
+    rule is applied by the background controller to the targets its
+    ``targets`` name, the match decided on the trigger."""
+    prog = _lower(rule, policy_name, existing)
+    if existing:
+        for site in prog.sites:
+            if any(site.path[:len(p)] == p for p in _CONTROLLER_PATHS):
+                raise LowerError(
+                    coverage.REASON_UNSUPPORTED_OPERATOR,
+                    f'edit writes {_path_text(site.path)}, which the '
+                    f'background controller stamps after the patch')
+    return prog
+
+
+def _lower(rule: Rule, policy_name: str, existing: bool) -> RuleMutateProgram:
     raw = rule.raw
     if raw.get('context'):
         raise LowerError(coverage.REASON_API_CALL,
@@ -201,9 +270,7 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
                          'preconditions keep the engine path')
     mutation = raw.get('mutate') or {}
     if mutation.get('targets'):
-        raise LowerError(coverage.REASON_HOST_CLOSURE,
-                         'mutate-existing rides the UpdateRequest '
-                         'pipeline')
+        _check_targets(mutation['targets'], existing)
     from ..compiler.scan import _rule_match_is_simple
     if not _rule_match_is_simple(raw):
         raise LowerError(
@@ -212,6 +279,11 @@ def lower_mutate_rule(rule: Rule, policy_name: str) -> RuleMutateProgram:
             'policy, so only kind/namespace/operation matches are '
             'stable under device edits')
     if mutation.get('foreach') is not None:
+        if existing:
+            raise LowerError(
+                coverage.REASON_UNSUPPORTED_OPERATOR,
+                'foreach in a rule with targets: the background controller '
+                'applies only an overlay or a JSON patch to a target')
         return _lower_foreach(rule, mutation['foreach'], policy_name)
     overlay = mutation.get('patchStrategicMerge')
     json6902 = mutation.get('patchesJson6902')
@@ -329,15 +401,27 @@ def _paths_conflict(a: tuple, b: tuple) -> bool:
 class MutateSetProgram:
     """A whole mutate policy set lowered (or not) for device serving.
 
-    ``device_ok`` is all-or-nothing: the cumulative admission chain
-    means one unlowered or conflicting rule invalidates original-
-    document device decisions for everything after it, so a set either
+    At admission ``device_ok`` is all-or-nothing: the cumulative
+    admission chain means one unlowered or conflicting rule invalidates
+    original-document decisions for everything after it, so a set either
     serves entirely on device (with per-row host fallback) or entirely
     on the host engine.
+
+    ``existing``: the set's mutate-existing rules as the background
+    controller applies them (``background/mutate_existing.py``: a
+    policy's own ``spec.rules`` with ``targets``, no autogen, every rule
+    whatever ``applyRules`` says).  There each policy is placed on its
+    own: one whose rule does not lower, or whose edit sites overlap
+    another device policy's, keeps the engine path with its reason while
+    its siblings run on the device, and ``device_ok`` says that some
+    policy does.  The controller applies a host policy's requests in
+    their turn, so no device decision is taken against a document a
+    host policy wrote (it watches what the engine writes).
     """
 
-    def __init__(self, policies: List[Policy]):
+    def __init__(self, policies: List[Policy], existing: bool = False):
         self.policies = list(policies)
+        self.existing = existing
         self.programs: List[RuleMutateProgram] = []
         self.per_policy: List[List[RuleMutateProgram]] = []
         self.placements: List[coverage.RulePlacement] = []
@@ -345,13 +429,18 @@ class MutateSetProgram:
         failures: List[Tuple[int, Rule, LowerError]] = []
         lowered: List[Tuple[int, RuleMutateProgram]] = []
         for pi, policy in enumerate(self.policies):
-            # the rules the engine runs: autogen's too, on their
-            # template paths (spec.template, spec.jobTemplate.spec.
-            # template), in the engine's order
-            mutate_rules = [r for r in map(Rule, compute_rules(policy))
-                            if r.has_mutate()]
-            if (policy.apply_rules or 'All') == 'One' and \
-                    len(mutate_rules) > 1:
+            if existing:
+                from ..engine.background import is_mutate_existing
+                mutate_rules = [r for r in policy.rules
+                                if is_mutate_existing(r)]
+            else:
+                # the rules the engine runs: autogen's too, on their
+                # template paths (spec.template, spec.jobTemplate.spec.
+                # template), in the engine's order
+                mutate_rules = [r for r in map(Rule, compute_rules(policy))
+                                if r.has_mutate()]
+            if not existing and (policy.apply_rules or 'All') == 'One' \
+                    and len(mutate_rules) > 1:
                 self.device_ok = False
                 for r in mutate_rules:
                     failures.append((pi, r, LowerError(
@@ -360,7 +449,7 @@ class MutateSetProgram:
                 continue
             for r in mutate_rules:
                 try:
-                    prog = lower_mutate_rule(r, policy.name)
+                    prog = lower_mutate_rule(r, policy.name, existing)
                 except LowerError as e:
                     self.device_ok = False
                     failures.append((pi, r, e))
@@ -371,11 +460,14 @@ class MutateSetProgram:
         # edit sites with another's sites, or with the paths another's
         # conditions read, makes original-document decisions
         # order-dependent (the chain is cumulative)
+        host_policies = {pi for pi, _r, _e in failures}
+        candidates = [(pi, prog) for pi, prog in lowered
+                      if not existing or pi not in host_policies]
         conflicted: set = set()
-        for i in range(len(lowered)):
-            for j in range(i + 1, len(lowered)):
-                pa, a = lowered[i]
-                pb, b = lowered[j]
+        for i in range(len(candidates)):
+            for j in range(i + 1, len(candidates)):
+                pa, a = candidates[i]
+                pb, b = candidates[j]
                 if a is b:
                     continue
                 a_writes = [s.path for s in a.sites]
@@ -386,15 +478,22 @@ class MutateSetProgram:
                 if any(_paths_conflict(x, y) for x, y in pairs):
                     conflicted.add(id(a))
                     conflicted.add(id(b))
-        if conflicted:
+        if existing:
+            host_policies |= {pi for pi, prog in lowered
+                              if id(prog) in conflicted}
+            self.device_ok = len(host_policies) < len(self.policies)
+        elif conflicted:
             self.device_ok = False
-        # placements: device across the board, or host with the most
+        # placements: device across the board (at admission) or policy by
+        # policy (for the background controller), else host with the most
         # specific reason each rule earned
         for pi, policy in enumerate(self.policies):
             progs = [prog for ppi, prog in lowered if ppi == pi]
-            self.per_policy.append(progs if self.device_ok else [])
+            on_device = pi not in host_policies if existing \
+                else self.device_ok
+            self.per_policy.append(progs if on_device else [])
             for prog in progs:
-                if self.device_ok:
+                if on_device:
                     prog.rule_index = len(self.programs)
                     self.programs.append(prog)
                     self.placements.append(coverage.RulePlacement(
@@ -413,8 +512,9 @@ class MutateSetProgram:
                         policy.name, prog.rule_name, 'mutate',
                         coverage.PLACEMENT_HOST,
                         coverage.REASON_POLICY_COUPLING,
-                        'rule lowered but a sibling mutate rule keeps '
-                        'the set on the host engine', pi))
+                        f'rule lowered but a sibling mutate rule keeps '
+                        f'the {"policy" if existing else "set"} on the '
+                        f'host engine', pi))
         for pi, r, e in failures:
             self.placements.append(coverage.RulePlacement(
                 self.policies[pi].name, str(r.raw.get('name', '')),
@@ -469,5 +569,6 @@ class MutateSetProgram:
             (prog.foreach.list_path, prog.foreach.list_default))
 
 
-def compile_mutate_set(policies: List[Policy]) -> MutateSetProgram:
-    return MutateSetProgram(policies)
+def compile_mutate_set(policies: List[Policy],
+                       existing: bool = False) -> MutateSetProgram:
+    return MutateSetProgram(policies, existing)

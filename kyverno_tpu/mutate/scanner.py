@@ -1,4 +1,6 @@
-"""MutateScanner: batched device-side mutate for admission serving.
+"""MutateScanner: batched device-side mutate for admission serving, and
+the bulk entry ``decide`` the background controller's mutate-existing pass
+dispatches at the bulk capacity (``background/mutate_existing.py``).
 
 Compiles a mutate policy set once (``plan.compile_mutate_set``) and
 evaluates admission batches as one device dispatch:
@@ -98,10 +100,11 @@ class MutateScanner:
     kind = 'mutate'
 
     def __init__(self, policies: List[Policy],
-                 engine: Optional[Engine] = None):
+                 engine: Optional[Engine] = None, existing: bool = False):
         self.policies = list(policies)
         self.engine = engine or Engine()
-        self.program: MutateSetProgram = compile_mutate_set(self.policies)
+        self.program: MutateSetProgram = compile_mutate_set(self.policies,
+                                                            existing)
         self.ok = self.program.device_ok and bool(self.program.programs)
         # serving coalesces on the scanner serial alone: the match sieve
         # below runs per row with that row's own admission tuple, so
@@ -203,6 +206,41 @@ class MutateScanner:
             return [self._decode_row(resources[i], match[i], status[i],
                                      edits[i], reason[i], pctx_factory)
                     for i in range(n)]
+
+    def decide(self, resources: List[dict]):
+        """The bulk entry (the background controller's,
+        ``background/mutate_existing.py``): ``(status, edits, reason)``
+        ``[n, rules]`` of every rule of the set against each document,
+        as the kernel decides them.  Dispatches of up to the bulk
+        canonical capacity (``compiler/shapes.py``, the scan's chunk)
+        rows, one executable, a longer list in turn; stages
+        ``mutate_encode`` and ``mutate_eval`` once a dispatch.  Which
+        rule a caller reads for a row, the match, and the decode are the
+        caller's."""
+        if not self.ok:
+            raise RuntimeError('mutate set is not device-lowered')
+        from ..compiler.shapes import canonical_caps
+        cap = canonical_caps()[-1]
+        parts = []
+        for lo in range(0, len(resources), cap):
+            part = resources[lo:lo + cap]
+            with devtel.stage('mutate_encode', {'rows': len(part)}):
+                lanes = encode_mutate_batch(
+                    part, self.program, padded_n=cap, width=self._width)
+            with devtel.stage('mutate_eval', {'rows': len(part)}):
+                out = self._kernel(lanes)
+            parts.append([o[:len(part)] for o in out[:3]])
+        nr = len(self.program.programs)
+        if not parts:
+            return (np.zeros((0, nr), np.int8), np.zeros((0, nr), np.int64),
+                    np.zeros((0, nr), np.int8))
+        return tuple(np.concatenate(col) for col in zip(*parts))
+
+    def edit_list(self, prog, mask: int) -> list:
+        """The ``(path, value)`` edits that bit mask ``mask`` of rule
+        ``prog``'s sites stands for, in site order."""
+        return [(site.path, site.value)
+                for k, site in enumerate(prog.sites) if mask >> k & 1]
 
     def _count(self, resources, match, llen, edits, elem) -> None:
         """``last_counts`` of one dispatch, from the kernel's outputs:
@@ -317,10 +355,7 @@ class MutateScanner:
                                   'no patches applied', RuleStatus.SKIP,
                                   patches=None)
             else:  # MUT_PASS
-                mask = int(edits[j])
-                changes = [(site.path, site.value)
-                           for k, site in enumerate(prog.sites)
-                           if mask & (1 << k)]
+                changes = self.edit_list(prog, int(edits[j]))
                 patched = apply_edit_list(cum, changes)
                 if patched is None:
                     # cannot happen for conflict-free site sets; keep

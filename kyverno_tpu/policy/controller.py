@@ -12,7 +12,7 @@ from __future__ import annotations
 import threading
 from typing import List, Optional
 
-from ..api.policy import Policy
+from ..api.policy import Policy, Rule
 from ..api.unstructured import Resource
 from ..background.updaterequest import (UR_GENERATE, UR_MUTATE,
                                         UpdateRequestGenerator)
@@ -99,7 +99,8 @@ class PolicyController:
 
     def _spawn_update_requests(self, policy: Policy) -> None:
         """Create URs for the triggers each generate / mutate-existing
-        rule matches (reference: policy_controller.go:428-551)."""
+        rule matches (reference: policy_controller.go:428-551), in one
+        ``mx_spawn`` stage."""
         has_generate = any(r.has_generate() for r in policy.rules)
         mutate_existing = any(
             r.has_mutate() and (r.raw.get('mutate') or {}).get('targets')
@@ -113,17 +114,14 @@ class PolicyController:
             has_generate = False
         if not has_generate and not mutate_existing:
             return
-        for trigger in self._triggers(policy):
-            resp = self.engine.filter_background_rules(
-                PolicyContext(policy, new_resource=trigger.obj))
-            applied = [r for r in resp.policy_response.rules
-                       if r.status == RuleStatus.PASS]
-            if not applied:
-                continue
+        from ..observability import device as devtel
+        with devtel.stage('mx_spawn', {'policy': policy.name}):
+            triggers = self._triggers(policy)
             request_type = UR_GENERATE if has_generate else UR_MUTATE
-            self.ur_generator.apply({
+            key = self._key(Policy(policy.raw))
+            self.ur_generator.apply_all([{
                 'requestType': request_type,
-                'policy': self._key(Policy(policy.raw)),
+                'policy': key,
                 'resource': {
                     'kind': trigger.kind,
                     'apiVersion': trigger.api_version,
@@ -131,7 +129,49 @@ class PolicyController:
                     'name': trigger.name,
                 },
                 'context': {},
-            })
+            } for trigger, applied in zip(
+                triggers, self._applied(policy, triggers)) if applied])
+
+    def _applied(self, policy: Policy, triggers: List[Resource]) -> List[bool]:
+        """Whether each trigger selects a generate / mutate-existing rule
+        of ``policy`` (``engine.filter_background_rules`` answers PASS for
+        one).  Where every such rule's match is simple (kinds, namespaces,
+        operations: ``compiler/scan.py`` ``_rule_match_is_simple``) and it
+        has neither context nor preconditions, the answer is a function of
+        the trigger's kind, apiVersion and namespace, so it is the
+        engine's match once for each of those, as the scan's sieve takes
+        it; otherwise the engine is asked for each trigger."""
+        from ..compiler.scan import _group_key, _rule_match_is_simple
+        from ..engine.background import is_mutate_existing
+        from ..engine.match import matches_resource_description
+        rules = [Rule(r) for r in self.engine._compute_rules(policy)]
+        background = [r for r in rules
+                      if r.has_generate() or is_mutate_existing(r)]
+        if not all(_rule_match_is_simple(r.raw) and not r.raw.get('context')
+                   and r.raw.get('preconditions') is None
+                   for r in background):
+            out = []
+            for trigger in triggers:
+                resp = self.engine.filter_background_rules(
+                    PolicyContext(policy, new_resource=trigger.obj))
+                out.append(any(r.status == RuleStatus.PASS
+                               for r in resp.policy_response.rules))
+            return out
+        by_group: dict = {}
+        out = []
+        for trigger in triggers:
+            key = _group_key(trigger.obj)
+            got = by_group.get(key)
+            if got is None:
+                pctx = PolicyContext(policy, new_resource=trigger.obj)
+                got = by_group[key] = any(
+                    matches_resource_description(
+                        trigger, r, pctx.admission_info,
+                        pctx.exclude_group_roles, pctx.namespace_labels, '',
+                        pctx.subresource) is None
+                    for r in background)
+            out.append(got)
+        return out
 
     def _triggers(self, policy: Policy) -> List[Resource]:
         """List cluster resources matching the policy's rule kinds
@@ -163,11 +203,15 @@ class PolicyController:
     def run(self, interval: Optional[float] = None) -> None:
         """Start the force-reconciliation loop
         (reference: policy_controller.go:388 forceReconciliation)."""
+        from ..observability import tracing
         interval = interval or self.FORCE_RECONCILE_INTERVAL
+        # the spawn's stage spans join the span that started the loop
+        parent = tracing.current_span()
 
         def loop():
-            while not self._stop.wait(interval):
-                self.reconcile()
+            with tracing.install_span(parent):
+                while not self._stop.wait(interval):
+                    self.reconcile()
 
         self._thread = threading.Thread(target=loop, daemon=True)
         self._thread.start()

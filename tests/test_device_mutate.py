@@ -136,10 +136,19 @@ class TestLowering:
           'mutate': {'foreach': [{'list': 'request.object.spec.containers',
                                   'patchStrategicMerge': {}}]}},
          coverage.REASON_UNSUPPORTED_OPERATOR),
-        ({'name': 'r', 'match': {'any': [{'resources': {'kinds': ['Pod']}}]},
-          'mutate': {'targets': [{'kind': 'ConfigMap'}],
-                     'patchStrategicMerge': {'metadata': {}}}},
-         coverage.REASON_HOST_CLOSURE),
+        # at admission the engine substitutes the whole rule, targets
+        # included, against the admitted object: a variable there keeps
+        # the host (the background controller resolves it per trigger).
+        # The case keeps the id it had when every rule with targets was
+        # refused as a host closure.
+        pytest.param(
+            {'name': 'r',
+             'match': {'any': [{'resources': {'kinds': ['Pod']}}]},
+             'mutate': {'targets': [{
+                 'apiVersion': 'v1', 'kind': 'ConfigMap',
+                 'namespace': '{{request.object.metadata.namespace}}'}],
+                 'patchStrategicMerge': {'metadata': {}}}},
+            coverage.REASON_UNSUPPORTED_OPERATOR, id='rule3-host_closure'),
         # roles make the match non-simple: the cumulative chain
         # re-matches per policy, so only kind/ns/op matches lower
         ({'name': 'r', 'match': {'any': [{'subjects': [

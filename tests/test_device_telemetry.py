@@ -400,7 +400,7 @@ class TestCpuBesideWall:
         import os
         assert set(devtel.CPU_STAGES) == {'match', 'encode', 'context',
                                           'report'}
-        assert len(devtel.STAGES) == len(set(devtel.STAGES)) == 46
+        assert len(devtel.STAGES) == len(set(devtel.STAGES)) == 49
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         read = set()
         for path in glob.glob(os.path.join(root, 'benchmarks', 'layers',

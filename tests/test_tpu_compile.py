@@ -207,6 +207,30 @@ def test_foreach_mutate_kernel_compiles_for_one_chip(one_chip,
     _report('foreach mutate@64', compiled)
 
 
+def test_mutate_existing_kernel_compiles_for_one_chip(one_chip,
+                                                     no_persistent_cache):
+    """The background controller's set (``packs/mutate-existing.yaml``,
+    placed policy by policy) at the bulk capacity, the one shape a
+    mutate-existing pass dispatches."""
+    import jax
+    import benchlib
+    from kyverno_tpu.mutate.encode import encode_mutate_batch
+    from kyverno_tpu.mutate.kernel import MutateKernel
+    from kyverno_tpu.mutate.plan import compile_mutate_set
+    program = compile_mutate_set(
+        benchlib.load_policies(['mutate-existing']), existing=True)
+    assert program.device_ok and len(program.programs) == 4
+    kernel = MutateKernel(program)
+    doc = benchlib.load_module('generators', 'existing_cluster').generate(
+        1, 4, namespaces=1)[-1]
+    lanes = encode_mutate_batch([doc], program, padded_n=64)
+    capacity = bulk_capacity()
+    with jax.enable_x64(True):
+        compiled = jax.jit(kernel.mutate_eval).lower(
+            _shapes(lanes, capacity, one_chip)).compile()
+    _report(f'mutate-existing@{capacity}', compiled)
+
+
 def test_sharded_step_compiles_for_the_2x2_mesh(pack, topo,
                                                 no_persistent_cache):
     import jax
